@@ -15,10 +15,6 @@ import sys
 
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
-from maggy_tpu.util import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -25,10 +25,6 @@ import time
 
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
-from maggy_tpu.util import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

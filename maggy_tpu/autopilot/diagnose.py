@@ -9,7 +9,7 @@ one dominant bottleneck per window plus an evidence struct naming exactly
 the metrics (and the derived shares) behind the verdict, so every
 ``autopilot.diagnosis`` telemetry event is auditable after the fact.
 
-Taxonomy (per scope, in precedence order — the first matching rule wins):
+Verdicts (per scope, in precedence order — the first matching rule wins):
 
 * ``train``: ``memory_bound`` (HBM headroom below the floor) →
   ``input_bound`` (input-pipeline wait dominates the step wall) →

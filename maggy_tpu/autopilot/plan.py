@@ -199,7 +199,7 @@ class Planner:
             # when none are pinned yet (offline; racing the full grid is
             # the startup tuner's job)
             if current.get("train.flash_bwd_block_q") is None:
-                best = FLASH_TILE_CHOICES[2]  # 512: BENCH_NOTES round-2 winner
+                best = FLASH_TILE_CHOICES[2]  # 512: the round-2 sweep's winner (one v5e)
                 moves.append(
                     Move("train.flash_bwd_block_q", best, diag.reason)
                 )

@@ -2,8 +2,8 @@
 
 ``TuneConfig`` declares the system-configuration search the autotuner
 (:mod:`maggy_tpu.tune`) explores: candidate mesh shapes (``ShardingSpec``
-presets or instances), global batch sizes, microbatch counts, remat policies
-and flash tile sizes — plus the two-stage budget controls: the static stage's
+presets or instances), global batch sizes, microbatch counts and remat
+policies — plus the two-stage budget controls: the static stage's
 HBM budget for AOT pruning and the measured stage's ASHA step schedule.
 
 This is deliberately NOT a :class:`~maggy_tpu.config.base.LagomConfig`: the
@@ -15,7 +15,7 @@ machinery hyperparameter tuning does.
 
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence, Tuple, Union
+from typing import Any, Optional, Sequence, Union
 
 
 class TuneConfig:
@@ -32,10 +32,6 @@ class TuneConfig:
         ``maggy_tpu.models.transformer.REMAT_POLICIES``). ``None`` leaves the
         model exactly as configured; a name forces ``remat=True`` with that
         policy (only for models whose config carries those fields).
-    :param flash_blocks: candidate flash-attention backward tile sizes as
-        ``(block_q, block_k)`` tuples, or ``None`` for the kernel's
-        auto-tuned default (applied via the ``MAGGY_TPU_FLASH_BWD_Q/K``
-        knobs the bench playbook already uses).
     :param seq_len: sequence length of the synthetic tuning batches.
     :param hbm_budget_bytes: per-device memory budget for the static stage's
         AOT prune. ``None`` asks the device (``memory_stats()["bytes_limit"]``
@@ -62,7 +58,6 @@ class TuneConfig:
         batch_sizes: Sequence[int] = (8, 16, 32),
         microbatches: Sequence[Optional[int]] = (None,),
         remat_policies: Sequence[Optional[str]] = (None,),
-        flash_blocks: Sequence[Optional[Tuple[int, int]]] = (None,),
         seq_len: int = 128,
         hbm_budget_bytes: Optional[int] = None,
         measure: bool = True,
@@ -91,7 +86,6 @@ class TuneConfig:
         self.batch_sizes = tuple(int(b) for b in batch_sizes)
         self.microbatches = tuple(microbatches)
         self.remat_policies = tuple(remat_policies)
-        self.flash_blocks = tuple(flash_blocks)
         self.seq_len = int(seq_len)
         self.hbm_budget_bytes = (
             None if hbm_budget_bytes is None else int(hbm_budget_bytes)
@@ -119,7 +113,6 @@ class TuneConfig:
             "batch_sizes": list(self.batch_sizes),
             "microbatches": list(self.microbatches),
             "remat_policies": list(self.remat_policies),
-            "flash_blocks": [list(b) if b else None for b in self.flash_blocks],
             "seq_len": self.seq_len,
             "measure": self.measure,
         }

@@ -36,12 +36,9 @@ def device_groups(devices_per_trial: int = 1) -> List[list]:
     device group (sub-slice), so N trials train concurrently on one host without
     contending for chips.
     """
-    try:
-        import jax
+    import jax
 
-        devices = jax.local_devices()
-    except Exception:
-        return [[]]
+    devices = jax.local_devices()
     k = max(1, devices_per_trial)
     n_groups = max(1, len(devices) // k)
     return [devices[i * k : (i + 1) * k] for i in range(n_groups)]

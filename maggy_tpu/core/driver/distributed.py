@@ -631,12 +631,9 @@ class DistributedTrainingDriver(Driver):
     def _device_groups(self) -> List[list]:
         # one worker per process; with several local workers each leases a
         # disjoint device group, with one worker it spans every local device
-        try:
-            import jax
+        import jax
 
-            devices = jax.local_devices()
-        except Exception:
-            return [[]]
+        devices = jax.local_devices()
         if self.pod_mode:
             # remote pod workers span their whole host; the driver's local
             # partition must match, not take a 1/num_executors lease

@@ -729,12 +729,9 @@ class HyperparameterOptDriver(Driver):
             return super()._device_groups()
         # the local executor spans this host's devices; remote workers lease
         # their own hosts' devices themselves
-        try:
-            import jax
+        import jax
 
-            return [jax.local_devices()]
-        except Exception:
-            return [[]]
+        return [jax.local_devices()]
 
     def _executor_fn(self, train_fn: Callable, partition_id: int, devices: list) -> Callable:
         return trial_executor_fn(

@@ -7,10 +7,16 @@ kwarg injection → train_fn → normalize return value → finalize_metric} unt
 GSTOP. Early stops arrive as EarlyStopException out of ``reporter.broadcast``
 and keep the last metric (trial_executor.py:194-196).
 
-TPU-native differences: the worker holds a lease on a disjoint device group
-(passed as the ``devices`` kwarg, usable as ``jax.jit(..., device=devices[0])``
-or a sub-mesh); train_fn errors are reported to the driver as errored trials
-instead of killing a Spark task.
+TPU-native differences: the worker holds a lease on a disjoint device group;
+train_fn errors are reported to the driver as errored trials instead of
+killing a Spark task.
+
+The lease reaches the train_fn only through what it asks for: ``ctx`` (a
+``TrainContext`` whose mesh spans exactly the leased devices — ``ctx.trainer``
+and ``ctx.shard`` place everything there) or ``devices`` (the raw list, for a
+``jax.device_put``/sub-mesh of its own). A train_fn that asks for neither
+computes on JAX's default device — chip 0 on a TPU host — whatever its lease
+says, so concurrent trials would all share that one chip.
 """
 
 from __future__ import annotations

@@ -87,13 +87,12 @@ def initialize_data_plane(
 
 
 def jax_backend_initialized() -> bool:
-    """True if XLA backends already exist (without creating them)."""
-    try:
-        from jax._src import xla_bridge
+    """True if XLA backends already exist (without creating them). jax has no
+    public probe for this; if the private one moves, this raises instead of
+    guessing an answer."""
+    from jax._src import xla_bridge
 
-        return bool(xla_bridge._backends)
-    except Exception:  # internal API moved — assume initialized (safe side)
-        return True
+    return xla_bridge.backends_are_initialized()
 
 
 def driver_address(config) -> Optional[str]:
@@ -318,12 +317,13 @@ def _worker_devices():
     local (thread) executors get from devices_per_trial, extended to pod
     workers. CPU/GPU hosts only, or TPU processes already chip-partitioned
     by the platform (TPU_VISIBLE_CHIPS etc.): a plain TPU runtime is
-    host-exclusive, so two unpartitioned processes cannot both initialize it.
+    host-exclusive, so two unpartitioned processes cannot both initialize it
+    (``python -m maggy_tpu.run`` refuses that launch; on one TPU host the
+    supported shape is one process whose thread executors lease the chips).
 
     Returns None (no lease) or a zero-arg CALLABLE resolving to the device
     list — deferred so the worker never touches the jax backend before it
-    registers with the driver (a wedged accelerator transport would
-    otherwise hang it invisibly; executors keep jax lazy by design,
+    registers with the driver (executors keep jax lazy by design,
     core/executors/trial.py)."""
     spec = os.environ.get("MAGGY_TPU_WORKER_DEVICES", "").strip()
     if not spec:

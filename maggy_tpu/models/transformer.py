@@ -288,40 +288,66 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
+    """Why the automatic dispatch keeps a shape off the Pallas flash kernel
+    (None: it tiles). head_dim must fill the 128 lanes and both sequence
+    lengths be multiples of the 128 block, which is what guarantees that
+    ``ops/flash.py``'s auto-chosen tiles are ones Mosaic can compile."""
+    if jax.default_backend() != "tpu":
+        return f"backend is {jax.default_backend()}"
+    if d % 128:
+        return f"head_dim {d} is not a multiple of 128"
+    if sq % 128 or sk % 128:
+        return f"sequence lengths ({sq}, {sk}) are not multiples of 128"
+    return None
+
+
+def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = ""):
+    """Journal which kernel the automatic dispatch chose for this shape as
+    one ``attention.kernel`` event. The dispatch runs at trace time, so
+    events count traces (init, forward, a rematerialized backward), never
+    steps."""
+    from maggy_tpu import telemetry
+
+    telemetry.get().event(
+        "attention.kernel", kernel=kernel, reason=reason,
+        q=list(q.shape), kv=list(k.shape), segmented=segment_ids is not None,
+    )
+
+
 def auto_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     """Pick the fastest correct kernel for the backend/shape: the Pallas flash
-    kernel (fwd+bwd) on TPU when the geometry tiles onto the MXU (head_dim a
-    multiple of the 128 lanes, seq a multiple of the 128 block), otherwise the
-    XLA dense path. With the auto-tuned MXU-sized blocks (ops/flash.py
-    ``_auto_blocks``: 512-row q tiles) the kernel wins the full train step at
-    every measured length — 66.9k vs 60.7k tok/s at S=1024 and 44.0k vs 22.8k
-    at S=8192 against the dense path on v5e (BENCH_NOTES round 2; the old
-    128x128 blocks LOST to dense everywhere, so block size is the whole
-    game). On a multi-device mesh the kernel runs per-shard under shard_map
-    (a pallas_call has no GSPMD partitioning rule); incompatible layouts
-    (sp/pp axes, non-divisible batch/heads) fall back to the XLA path."""
+    kernel (fwd+bwd) on TPU when the geometry tiles onto the MXU
+    (:func:`flash_tileable`), otherwise the XLA dense path. With the
+    auto-tuned MXU-sized blocks (ops/flash.py ``_auto_blocks``: 512-row q
+    tiles) the kernel won the full train step at every length measured on one
+    v5e in round 2 (2026-07-29) — 66.9k vs 60.7k tok/s at S=1024 and 44.0k vs
+    22.8k at S=8192 against the dense path; the old 128x128 blocks LOST to
+    dense everywhere, so block size is the whole game. On a multi-device mesh
+    the kernel runs per-shard under shard_map (a pallas_call has no GSPMD
+    partitioning rule); incompatible layouts (sp/pp axes, non-divisible
+    batch/heads) take the XLA path. The choice is recorded
+    (:func:`record_attention_kernel`), never silent."""
     from maggy_tpu.ops.flash import (  # late: avoid import cycle
         flash_attention,
         sharded_flash_attention,
     )
     from maggy_tpu.parallel.mesh import ambient_mesh
 
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    if (
-        jax.default_backend() == "tpu"
-        and d % 128 == 0
-        and sq % 128 == 0
-        and sk % 128 == 0
-    ):
+    why = flash_tileable(q.shape[1], k.shape[1], q.shape[3])
+    if why is None:
         mesh = ambient_mesh()
         if mesh is None or mesh.size == 1:
+            record_attention_kernel("flash", q, k, segment_ids)
             return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids)
         out = sharded_flash_attention(
             q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids
         )
         if out is not None:
+            record_attention_kernel("flash_sharded", q, k, segment_ids)
             return out
+        why = f"mesh {dict(mesh.shape)} does not divide batch/heads or uses seq/stage axes"
+    record_attention_kernel("xla_dense", q, k, segment_ids, why)
     return default_attention(q, k, v, causal=causal, segment_ids=segment_ids)
 
 

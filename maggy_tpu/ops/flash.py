@@ -16,25 +16,33 @@ This makes the kernel a drop-in for the *training* hot path — the gap the
 round-1 verdict called out (training previously fell back to the XLA fused
 dense path, which materializes [B, H, S, S] fp32 logits in HBM).
 
-Falls back to the interpreter off-TPU so tests run on CPU meshes; shapes that
-do not tile evenly fall back to ``blockwise_attention`` (differentiable).
+Off-TPU the kernels run under the Pallas interpreter so tests run on CPU
+meshes, and shapes that do not tile evenly fall back to
+``blockwise_attention`` (differentiable). Compiled (on a TPU, or with
+``interpret=False``) there is no fallback: a shape Mosaic cannot tile raises,
+naming the dimension.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from maggy_tpu.ops.attention import NEG_INF, _repeat_kv, blockwise_attention
-from maggy_tpu.util import shard_map
 
 _LANES = 128
+
+# every kernel's grid is (batch*heads, outer blocks, reduction blocks): only
+# the last axis carries the VMEM accumulators from one step to the next
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
 def _tile_mask(q_start, k_start, block_q, block_k):
@@ -80,7 +88,7 @@ def _fwd_kernel(
         ) * scale
         mask = _tile_mask(q_start, k_start, block_q, block_k) if causal else None
         if segmented:
-            smask = qseg_ref[0][:, None] == kseg_ref[0][None, :]
+            smask = qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
             mask = smask if mask is None else (mask & smask)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
@@ -119,7 +127,10 @@ def _fwd_call(q, k, v, segs, *, causal, block_q, block_k, group, heads, interpre
     segmented = segs is not None
     # GQA lives in the index map: q-head row i reads KV row i // group, so the
     # repeated [B,S,H,D] K/V never materialize in HBM (review finding r2);
-    # segment ids are per (batch, seq) — row i // heads — shared by all heads
+    # segment ids are per (batch, seq) — row i // heads — shared by all heads.
+    # They arrive as [B, 1, S]: Mosaic wants a block's last two dims to be
+    # (8, 128)-aligned or the array's full extent, which a (1, block) tile of
+    # [B, S] is only at B == 1
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, qi, ki: (i, qi, 0), memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda i, qi, ki: (i // group, ki, 0), memory_space=pltpu.VMEM),
@@ -128,8 +139,8 @@ def _fwd_call(q, k, v, segs, *, causal, block_q, block_k, group, heads, interpre
     operands = [q, k, v]
     if segmented:
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda i, qi, ki: (i // heads, qi), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda i, qi, ki: (i // heads, ki), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), lambda i, qi, ki: (i // heads, 0, qi), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_k), lambda i, qi, ki: (i // heads, 0, ki), memory_space=pltpu.VMEM),
         ]
         operands += [segs, segs]
     return pl.pallas_call(
@@ -158,6 +169,8 @@ def _fwd_call(q, k, v, segs, *, causal, block_q, block_k, group, heads, interpre
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
+        name="flash_fwd",
         interpret=interpret,
     )(*operands)
 
@@ -220,8 +233,8 @@ def _dq_kernel(
         _, ds = _recompute_p_ds(
             q_ref[0], k, v_ref[0], o_ref[0], do_ref[0], lse_ref[0, 0],
             scale=scale, causal=causal, q_start=q_start, k_start=k_start,
-            qseg=qseg_ref[0] if segmented else None,
-            kseg=kseg_ref[0] if segmented else None,
+            qseg=qseg_ref[0, 0] if segmented else None,
+            kseg=kseg_ref[0, 0] if segmented else None,
         )
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
@@ -264,8 +277,8 @@ def _dkv_kernel(
         p, ds = _recompute_p_ds(
             q, k_ref[0], v_ref[0], o_ref[0], do, lse_ref[0, 0],
             scale=scale, causal=causal, q_start=q_start, k_start=k_start,
-            qseg=qseg_ref[0] if segmented else None,
-            kseg=kseg_ref[0] if segmented else None,
+            qseg=qseg_ref[0, 0] if segmented else None,
+            kseg=kseg_ref[0, 0] if segmented else None,
         )
         # dV += P^T dO ; dK += dS^T Q — contract the q dim of both operands
         dv_acc_ref[:] += jax.lax.dot_general(
@@ -300,8 +313,8 @@ def _bwd_call(
     operands = [q, k, v, o, do, lse]
     if segmented:
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda i, qi, ki: (i // heads, qi), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda i, qi, ki: (i // heads, ki), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), lambda i, qi, ki: (i // heads, 0, qi), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_k), lambda i, qi, ki: (i // heads, 0, ki), memory_space=pltpu.VMEM),
         ]
         operands += [segs, segs]
     dq = pl.pallas_call(
@@ -314,6 +327,8 @@ def _bwd_call(
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        name="flash_dq",
         interpret=interpret,
     )(*operands)
 
@@ -330,8 +345,8 @@ def _bwd_call(
     operands2 = [q, k, v, o, do, lse]
     if segmented:
         in_specs2 += [
-            pl.BlockSpec((1, block_q), lambda i, ki, qi: (i // heads, qi), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda i, ki, qi: (i // heads, ki), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), lambda i, ki, qi: (i // heads, 0, qi), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_k), lambda i, ki, qi: (i // heads, 0, ki), memory_space=pltpu.VMEM),
         ]
         operands2 += [segs, segs]
     dk, dv = pl.pallas_call(
@@ -350,6 +365,8 @@ def _bwd_call(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
+        name="flash_dkv",
         interpret=interpret,
     )(*operands2)
     return dq, dk, dv
@@ -364,7 +381,7 @@ def _flash_core(
     """Differentiable flash attention on q [B*H, S, D], k/v [B*Kh, S, D]
     (GQA group = H // Kh handled by kernel index maps — the repeated K/V
     never exist, in HBM or as residuals). With ``segmented``, a fourth
-    [B, S] int32 operand masks attention across packed-sequence
+    [B, 1, S] int32 operand masks attention across packed-sequence
     boundaries (zero cotangent). Backward tiles are independent of the
     forward's — the dq/dkv kernels hold 6+ operands per tile, so their VMEM
     sweet spot can differ (tools/tune_flash.py sweeps both on silicon)."""
@@ -409,55 +426,51 @@ def _flash_core(
     return core
 
 
-def _env_tile(name: str):
-    """Optional hardware-tuned backward tile override (set by the watchdog
-    playbook after a tools/tune_flash.py sweep on live silicon; see
-    tools/tpu_playbook.py). Invalid values are ignored, not fatal."""
-    val = os.environ.get(name, "")
-    try:
-        n = int(val)
-    except ValueError:
-        return None
-    return n if n > 0 else None
-
-
 def _pick_divisor(s: int, cap: int) -> int:
     """Largest power-of-two-stepped divisor of ``s`` that is ≤ cap (floor 8;
-    the floor can be a non-divisor for odd/tiny s, which the alignment check
-    in _flash_attention_jit then routes to blockwise)."""
+    the floor can be a non-divisor for odd/tiny s, which ``_untileable``
+    then reports)."""
     b = min(cap, s)
     while s % b:
         b //= 2
     return max(b, 8)
 
 
-def _snap_tile(tile, s: int):
-    """Snap an env-sourced tile to the largest sublane-aligned (multiple of
-    8) real divisor of the call's sequence ≤ tile, so a size tuned at one
-    geometry cannot silently demote a differently-shaped call to the
-    blockwise fallback (an explicit function argument, by contrast, is
-    honored verbatim). Returns None — meaning 'use the auto default' — when
-    no aligned divisor exists."""
-    if not tile:
-        return None
-    b = min(tile, s)
-    b -= b % 8
-    while b >= 8:
-        if s % b == 0:
-            return b
-        b -= 8
-    return None
-
-
 def _auto_blocks(sq: int, sk: int) -> tuple:
     """Largest MXU-friendly tile sizes that divide the sequence. Measured in
-    the full train step on v5e (BENCH_NOTES round 2): 512-row q tiles are
+    the full train step on one v5e (round 2, 2026-07-29): 512-row q tiles are
     ~2.7x faster than the FlashAttention-conventional 128 (66.9k vs 24.6k
     tok/s at S=1024 — small tiles leave the MXU idle between grid steps);
     k tiles of 512, widening to 1024 at long S, were best of the sweep."""
     return _pick_divisor(sq, 512), _pick_divisor(sk, 1024 if sk >= 4096 else 512)
 
 
+def _untileable(sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
+                segmented, compiled):
+    """Why Mosaic cannot tile this call, or None: blocks must divide the
+    sequence and stay sublane-aligned (multiples of 8 rows), head_dim must
+    fill the 128 lanes, and compiled segmented runs put the segment-id block
+    in the lane dim, so they need 128-aligned blocks too."""
+    if d % _LANES:
+        return f"head_dim {d} is not a multiple of {_LANES}"
+    need = _LANES if (segmented and compiled) else 8
+    for name, blk, s in (
+        ("block_q", block_q, sq), ("block_k", block_k, sk),
+        ("bwd_block_q", bwd_block_q, sq), ("bwd_block_k", bwd_block_k, sk),
+    ):
+        if s % blk:
+            return f"{name}={blk} does not divide sequence length {s}"
+        if blk % need:
+            return f"{name}={blk} (sequence length {s}) is not a multiple of {need}"
+    return None
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "causal", "block_q", "block_k", "bwd_block_q", "bwd_block_k", "interpret",
+    ),
+)
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -476,41 +489,15 @@ def flash_attention(
     sequence length (``_auto_blocks``); ``bwd_block_q``/``bwd_block_k``
     default to the forward's and can be tuned independently (the backward
     kernels carry 6+ operand tiles, so their VMEM sweet spot differs —
-    tools/tune_flash.py; MAGGY_TPU_FLASH_BWD_Q/_K carry a measured winner
-    into processes that never pass tiles explicitly, resolved here OUTSIDE
-    the jit cache so an env change cannot hit a stale compilation).
-    ``segment_ids`` [B, S] masks attention across packed-sequence
-    boundaries in-kernel."""
-    if bwd_block_q is None:
-        bwd_block_q = _snap_tile(_env_tile("MAGGY_TPU_FLASH_BWD_Q"), q.shape[1])
-    if bwd_block_k is None:
-        bwd_block_k = _snap_tile(_env_tile("MAGGY_TPU_FLASH_BWD_K"), k.shape[1])
-    return _flash_attention_jit(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        bwd_block_q=bwd_block_q, bwd_block_k=bwd_block_k,
-        interpret=interpret, segment_ids=segment_ids,
-    )
+    tools/tune_flash.py). ``segment_ids`` [B, S] masks attention across
+    packed-sequence boundaries in-kernel.
 
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "causal", "block_q", "block_k", "bwd_block_q", "bwd_block_k", "interpret",
-    ),
-)
-def _flash_attention_jit(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    block_q: Optional[int] = None,
-    block_k: Optional[int] = None,
-    bwd_block_q: Optional[int] = None,
-    bwd_block_k: Optional[int] = None,
-    interpret: Optional[bool] = None,
-    segment_ids=None,
-) -> jax.Array:
+    ``interpret`` defaults to the Pallas interpreter off-TPU and the compiled
+    kernel on a TPU. Interpreted, a shape that does not tile falls back to
+    ``blockwise_attention``; compiled, it raises ``ValueError`` naming the
+    dimension — callers that want a silent choice use
+    ``models.transformer.auto_attention``, which checks the shape first and
+    records what it chose."""
     b, sq, h, d = q.shape
     kh = k.shape[2]
     sk = k.shape[1]
@@ -521,19 +508,18 @@ def _flash_attention_jit(
     bwd_block_k = min(bwd_block_k, sk) if bwd_block_k else block_k
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # fall back unless blocks tile evenly AND stay sublane-aligned (multiple
-    # of 8 rows) — Mosaic cannot lower arbitrary-row tiles. Segment-id tiles
-    # [1, block] put the block in the lane dim, so compiled (non-interpret)
-    # segmented runs additionally need lane-aligned blocks.
-    blocks = (block_q, block_k, bwd_block_q, bwd_block_k)
-    unaligned = (
-        sq % block_q or sk % block_k or sq % bwd_block_q or sk % bwd_block_k
-        or d % _LANES or any(bq % 8 for bq in blocks)
+    segmented = segment_ids is not None
+    why = _untileable(
+        sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
+        segmented, compiled=not interpret,
     )
-    seg_unaligned = segment_ids is not None and not interpret and any(
-        bq % _LANES for bq in blocks
-    )
-    if unaligned or seg_unaligned:
+    if why is not None:
+        if not interpret:
+            raise ValueError(
+                f"flash_attention cannot compile for q{q.shape} k{k.shape}: "
+                f"{why}. Pad the sequence, pass tiles that fit, or call "
+                "auto_attention, which routes such shapes to the XLA path."
+            )
         return blockwise_attention(
             q, k, v, causal=causal, segment_ids=segment_ids
         )  # repeats GQA itself
@@ -542,11 +528,10 @@ def _flash_attention_jit(
     kr = k.transpose(0, 2, 1, 3).reshape(b * kh, sk, d)
     vr = v.transpose(0, 2, 1, 3).reshape(b * kh, sk, d)
 
-    segmented = segment_ids is not None
     segs = (
-        segment_ids.astype(jnp.int32)
+        segment_ids.astype(jnp.int32).reshape(b, 1, sq)
         if segmented
-        else jnp.zeros((b, sq), jnp.int32)  # placeholder, never read
+        else jnp.zeros((b, 1, sq), jnp.int32)  # placeholder, never read
     )
     out = _flash_core(
         causal, block_q, block_k, bwd_block_q, bwd_block_k, h // kh, h,

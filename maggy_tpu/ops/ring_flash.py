@@ -21,7 +21,7 @@ rotation delivers each chunk's finished dK/dV straight into its home device's
 output buffer.
 
 Memory plan (VMEM is ~16MB/core): q/o and the f32 accumulators live in HBM
-(``pltpu.ANY``); the kernel stages one q row-tile and one KV chunk at a time
+(``pl.ANY``); the kernel stages one q row-tile and one KV chunk at a time
 into VMEM scratch. Communication buffers are per-(batch, kv-head) HBM slots so
 grid cells may skew across devices without clobbering each other. Causal runs
 skip fully-masked chunks (the compute, not the rotation).
@@ -37,42 +37,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from maggy_tpu.util import shard_map
 
 NEG_INF = -1e30
-
-# pallas-TPU API names across jax versions (new: MemorySpace/CompilerParams,
-# old <= 0.4.x: TPUMemorySpace/TPUCompilerParams — same members, minus kwargs
-# the old dataclass doesn't know, which _compiler_params drops)
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-
-
-def _compiler_params(**kwargs):
-    import dataclasses as _dc
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    known = {f.name for f in _dc.fields(cls)}
-    return cls(**{k: v for k, v in kwargs.items() if k in known})
-
-
-def _interpret_mode(flag: bool):
-    """pallas_call interpret argument: the TPU interpret machine
-    (InterpretParams, emulates remote DMAs) where available, else the plain
-    boolean interpreter of older jax."""
-    if not flag:
-        return False
-    params = getattr(pltpu, "InterpretParams", None)
-    return params() if params is not None else True
-
 
 def _neighbor(mesh, axis_name: str, offset: int):
     """Mesh coordinates of the ring neighbor at ``offset`` along ``axis_name``
     (same pattern as pallas's reference all-gather kernel)."""
     idx = lax.axis_index(axis_name)
-    # static axis extent from the mesh (lax.axis_size only exists on new jax)
     size = dict(mesh.shape)[axis_name]
     nxt = lax.rem(idx + offset + size, size)
     return tuple(
@@ -355,7 +329,7 @@ def _ring_flash_local(q, k, v, *, mesh, axis_name, num_shards, causal,
         jax.ShapeDtypeStruct((B, C, KH, G), f32),          # m
         jax.ShapeDtypeStruct((B, C, KH, G), f32),          # l
     )
-    any_spec = pl.BlockSpec(memory_space=_MEMSPACE.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     o = pl.pallas_call(
         kernel,
         grid=(B, KH),
@@ -375,10 +349,10 @@ def _ring_flash_local(q, k, v, *, mesh, axis_name, num_shards, causal,
             pltpu.SemaphoreType.REGULAR((B, KH)),      # ack
             pltpu.SemaphoreType.DMA((8,)),             # local staging sems
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=7, has_side_effects=True
         ),
-        interpret=_interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(qg, k, v)
     if return_stats:
         return o[0].reshape(B, C, H, D), o[4], o[5]
@@ -711,7 +685,7 @@ def _ring_bwd_local(q, k, v, o, do, lse, *, mesh, axis_name, num_shards,
         jax.ShapeDtypeStruct((B, KH, 2, C, D), f32),       # dkbuf
         jax.ShapeDtypeStruct((B, KH, 2, C, D), f32),       # dvbuf
     )
-    any_spec = pl.BlockSpec(memory_space=_MEMSPACE.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
         grid=(B, KH),
@@ -742,10 +716,10 @@ def _ring_bwd_local(q, k, v, o, do, lse, *, mesh, axis_name, num_shards,
             pltpu.SemaphoreType.REGULAR((B, KH)),      # ack_dkv
             pltpu.SemaphoreType.DMA((10,)),            # local staging sems
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=8, has_side_effects=True
         ),
-        interpret=_interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(qg, k, v, og, dog, lse)
     dq = out[0].reshape(B, C, H, D).astype(q.dtype)
     dk = out[1].astype(k.dtype)

@@ -33,10 +33,10 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from maggy_tpu.parallel.spec import AXIS_DATA, AXIS_FSDP, AXIS_STAGE
-from maggy_tpu.util import shard_map
 
 
 def _manual_axes(mesh, axis_name) -> frozenset:

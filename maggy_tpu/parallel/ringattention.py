@@ -19,11 +19,11 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from maggy_tpu.ops import attention as ops_attn
 from maggy_tpu.parallel.spec import AXIS_SEQ
-from maggy_tpu.util import shard_map
 
 
 def _local_ring_attention(

@@ -248,30 +248,12 @@ def constrain_activation(x, logical_axes, rules=DEFAULT_RULES):
     mesh = ambient_mesh()
     if mesh is None or mesh.size == 1:
         return x
-    try:
-        # inside a shard_map body (Manual axes) placement is already manual;
-        # a constraint built from the Auto physical mesh would trace without
-        # raising but poison the region's vjp with a mesh-mismatched op
-        am = jax.sharding.get_abstract_mesh()
-        manual = getattr(jax.sharding.AxisType, "Manual", None)
-        if not am.empty and manual is not None and manual in set(am.axis_types):
-            return x
-    except AttributeError:
-        # removed/not-yet-added introspection API on older jax: detect the
-        # manual region through the trace axis-env instead — shard_map binds
-        # its manual axes there, so any mesh axis appearing bound means we
-        # are inside a manual body and the constraint must be skipped.
-        # Anything beyond these two probes must stay loud — silently
-        # skipping this guard would let an Auto-mesh constraint poison a
-        # Manual region's vjp.
-        try:
-            from jax._src.core import trace_ctx
-
-            bound = set(getattr(trace_ctx.axis_env, "axis_sizes", {}) or {})
-        except (ImportError, AttributeError):
-            bound = set()
-        if bound & set(mesh.axis_names):
-            return x
+    # inside a shard_map body (Manual axes) placement is already manual;
+    # a constraint built from the Auto physical mesh would trace without
+    # raising but poison the region's vjp with a mesh-mismatched op
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty and jax.sharding.AxisType.Manual in set(am.axis_types):
+        return x
     axes = list(logical_to_mesh_axes(logical_axes, rules))
     # slice-topology meshes: models pin activations with the DEFAULT rule
     # table, whose batch rule knows nothing of the outer slice axis — a

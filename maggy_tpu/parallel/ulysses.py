@@ -15,11 +15,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from maggy_tpu.ops.attention import _repeat_kv, blockwise_attention
 from maggy_tpu.parallel.spec import AXIS_SEQ
-from maggy_tpu.util import shard_map
 
 
 def _local_ulysses(

@@ -23,17 +23,59 @@ pinned across generations so every generation lands in the same experiment
 directory, and training scripts resume from their latest checkpoint
 (``Checkpointer.latest_step`` + ``Trainer.fit(checkpointer=...)``). The
 generation number reaches scripts as ``MAGGY_TPU_GENERATION``.
+
+One TPU host is one process: a TPU runtime belongs to the first process that
+opens it, and a second rank on the same unpartitioned host fails at backend
+start-up instead of joining the run (docs/distributed.md "One process per chip
+set"). The launcher refuses that shape up front. On one host, run the script
+directly — its thread executors lease the chips.
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import secrets
 import socket
 import subprocess
 import sys
 import time
+
+
+# environment variables with which a platform hands each process its own
+# subset of a host's TPU chips (libtpu reads them at start-up)
+_TPU_PARTITION_VARS = (
+    "TPU_VISIBLE_CHIPS", "TPU_VISIBLE_DEVICES", "TPU_PROCESS_BOUNDS",
+    "TPU_CHIPS_PER_PROCESS_BOUNDS",
+)
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted without JAX — importing a backend
+    here would make the launcher itself the process that owns them. libtpu
+    opens the chips as /dev/accel* (or vfio groups on newer kernels)."""
+    return len(glob.glob("/dev/accel[0-9]*")) or len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_tpu_host_not_shared(workers: int, env) -> None:
+    """Exit with a named error when ``workers`` ranks would open one
+    unpartitioned TPU host."""
+    if workers <= 1 or env.get("JAX_PLATFORMS", "").lower() == "cpu":
+        return
+    if any(env.get(v) for v in _TPU_PARTITION_VARS):
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        raise SystemExit(
+            f"maggy_tpu.run: --workers {workers} would start {workers} "
+            f"processes on one TPU host ({chips} chip(s), no "
+            f"{'/'.join(_TPU_PARTITION_VARS[:2])} partition in the environment). "
+            "A TPU host belongs to one process: the second rank cannot open "
+            "the device. Run the script directly (one process drives all "
+            "chips; executors are threads with device leases), or give each "
+            "rank its own chips or host."
+        )
 
 
 def _free_port() -> int:
@@ -159,6 +201,7 @@ def main(argv=None) -> int:
         parser.error("--workers must be >= 1")
     if args.elastic < 0:
         parser.error("--elastic must be >= 0")
+    check_tpu_host_not_shared(args.workers, os.environ)
 
     base_env = dict(os.environ)
     base_env.update(

@@ -18,6 +18,7 @@ TensorBoard exporters.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import signal
 import sys
@@ -46,24 +47,34 @@ def build_config(name: str, max_seq_len=None):
     return cfg
 
 
-def load_or_init_params(model, cfg, checkpoint=None, step=None, seed=0):
+def load_or_init_params(model, cfg, checkpoint=None, step=None, seed=0, mesh=None):
     """Checkpoint params (train/checkpoint.py, params-only restore) or a
-    seeded random init for checkpoint-free demo serving."""
+    seeded random init for checkpoint-free demo serving. With ``mesh`` every
+    leaf is placed by the model's logical-axis rules (the random init is born
+    sharded, never materialized on one device)."""
     import jax
     import jax.numpy as jnp
 
+    from maggy_tpu.parallel.sharding import params_shardings, unbox
+
+    dummy = jnp.zeros((1, min(8, cfg.max_seq_len)), jnp.int32)
+
+    def init():
+        return model.init(jax.random.key(seed), dummy)["params"]
+
+    shardings = (
+        None if mesh is None else params_shardings(mesh, jax.eval_shape(init))
+    )
     if checkpoint:
         from maggy_tpu.train.checkpoint import Checkpointer
 
-        return Checkpointer(checkpoint, async_save=False).restore_params(step)
-    dummy = jnp.zeros((1, min(8, cfg.max_seq_len)), jnp.int32)
-    variables = model.init(jax.random.key(seed), dummy)
-    from maggy_tpu.parallel.sharding import unbox
-
-    return unbox(variables["params"])
+        params = Checkpointer(checkpoint, async_save=False).restore_params(step)
+        return params if mesh is None else jax.device_put(params, shardings)
+    with mesh if mesh is not None else contextlib.nullcontext():
+        return unbox(jax.jit(init, out_shardings=shardings)())
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     parser = argparse.ArgumentParser(
         prog="python -m maggy_tpu.serve", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter,
@@ -121,11 +132,26 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     if args.prefill_replicas and args.replicas < 1:
         raise SystemExit("--prefill-replicas needs at least one decode replica")
+    return args
 
+
+def build_server(args: argparse.Namespace):
+    """Everything the CLI does up to a listening server: config, mesh,
+    params, engine (or fleet), scheduler, RPC front-end, started. Returns
+    ``(server, (host, port), telemetry_recorder_or_None)``; the caller owns
+    ``stop()`` / ``close()``. :func:`main` adds only the signal handler and
+    the wait, so an embedding process (``chip_smoke.py``) drives the same
+    stack."""
+    from maggy_tpu import util
     from maggy_tpu.models import Decoder
     from maggy_tpu.serve import Engine, Scheduler, ServeServer
     from maggy_tpu.telemetry import worker_telemetry
 
+    # before the first jit: the engine's programs land in the same cache the
+    # trainer uses
+    cache_dir = util.enable_compilation_cache()
+    if cache_dir:
+        print(f"[serve] compile cache: {cache_dir}", file=sys.stderr)
     cfg = build_config(args.config, args.max_seq_len)
     model = Decoder(cfg)
 
@@ -137,7 +163,6 @@ def main(argv=None) -> int:
 
         tuned = cached_best(model)
         if tuned is not None:
-            tuned.apply_env()
             mesh = tuned.mesh()
             print(
                 f"[serve] mesh auto: tuning cache hit -> {dict(mesh.shape)} "
@@ -159,7 +184,8 @@ def main(argv=None) -> int:
 
     t0 = time.time()
     params = load_or_init_params(
-        model, cfg, checkpoint=args.checkpoint, step=args.step, seed=args.seed
+        model, cfg, checkpoint=args.checkpoint, step=args.step, seed=args.seed,
+        mesh=mesh,
     )
     src = args.checkpoint or f"random init (seed {args.seed})"
     print(f"[serve] params from {src} in {time.time() - t0:.1f}s", file=sys.stderr)
@@ -214,7 +240,11 @@ def main(argv=None) -> int:
         f"{server.secret} --dashboard",
         file=sys.stderr,
     )
+    return server, (host, port), tel
 
+
+def main(argv=None) -> int:
+    server, _, tel = build_server(parse_args(argv))
     stop = []
     signal.signal(signal.SIGTERM, lambda *a: stop.append(1))
     try:

@@ -286,12 +286,23 @@ class Engine:
         # decode applies run under the mesh so activation constraints and the
         # sharded cache resolve; mesh-free (single chip / CPU) costs nothing
         self._ctx = (lambda: mesh) if mesh is not None else contextlib.nullcontext
-        self.key_data = jnp.zeros((B, 2), jnp.uint32)
+        # Host-built inputs of the decode step join the mesh, replicated: the
+        # mesh an array lives on is part of jit's trace key, so a step fed now
+        # a host array and now the previous step's own (mesh-placed) output
+        # would be traced and compiled once per mixture
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            replicated = NamedSharding(mesh, PartitionSpec())
+            self._put = lambda x: jax.device_put(x, replicated)
+        else:
+            self._put = jnp.asarray
+        self.key_data = self._put(np.zeros((B, 2), np.uint32))
         # async double buffer: the dispatched-but-undrained decode step —
         # its device token refs plus (slot -> request id) at dispatch time,
         # so a drain can discard rows whose slot churned in the meantime
         self._pending: Optional[Dict[str, Any]] = None
-        self._zero_tokens = jnp.zeros((B,), jnp.int32)
+        self._zero_tokens = self._put(np.zeros((B,), np.int32))
         # last async-drain host cost, sampled by the autopilot's serve
         # diagnoser (the gauge of the same name feeds dashboards)
         self.last_drain_ms = 0.0
@@ -1169,17 +1180,18 @@ class Engine:
             self._batch_model, jnp.zeros((B, 1), jnp.int32), mesh=self.mesh
         )
         self._push_page_table()
-        self.key_data = jnp.zeros((B, 2), jnp.uint32)
-        self._zero_tokens = jnp.zeros((B,), jnp.int32)
+        self.key_data = self._put(np.zeros((B, 2), np.uint32))
+        self._zero_tokens = self._put(np.zeros((B,), np.int32))
         self._pending = None
         # warm the decode compile at the new geometry (all rows masked)
-        zeros_i = jnp.zeros((B,), jnp.int32)
+        zeros_i = self._zero_tokens
+        zeros_b = self._put(np.zeros((B,), bool))
         with self.telemetry.span("serve.reconfigure", num_slots=B), self._ctx():
             self.cache, _, _, _ = jax.block_until_ready(
                 self._decode_jit(
                     self.params, self.cache, self.key_data,
-                    zeros_i, zeros_i, jnp.zeros((B,), bool), zeros_i,
-                    jnp.zeros((B,), bool), jnp.zeros((B,), jnp.float32),
+                    zeros_i, zeros_i, zeros_b, zeros_i,
+                    zeros_b, self._put(np.zeros((B,), np.float32)),
                     zeros_i, zeros_i,
                 )
             )
@@ -1267,7 +1279,7 @@ class Engine:
         fast path transfers nothing."""
         if not self.paged or not self.page_table.dirty:
             return
-        tbl = jnp.asarray(self.page_table.table)
+        tbl = self._put(self.page_table.table)
 
         def repl(path, leaf):
             if "pages" in jax.tree_util.keystr(path):
@@ -1359,13 +1371,13 @@ class Engine:
             prev_tokens = (
                 prev["sampled"] if prev is not None else self._zero_tokens
             )
-            active_dev = jnp.asarray(active)
-            temp_dev = jnp.asarray(temp)
-            top_k_dev = jnp.asarray(top_k)
+            active_dev = self._put(active)
+            temp_dev = self._put(temp)
+            top_k_dev = self._put(top_k)
             inputs = (
-                prev_tokens, jnp.asarray(host_tokens), jnp.asarray(use_prev),
-                jnp.asarray(pos), active_dev, temp_dev, top_k_dev,
-                jnp.asarray(gen_idx),
+                prev_tokens, self._put(host_tokens), self._put(use_prev),
+                self._put(pos), active_dev, temp_dev, top_k_dev,
+                self._put(gen_idx),
             )
             carry_static = {
                 "active": active_dev, "temp": temp_dev, "top_k": top_k_dev,

@@ -32,12 +32,14 @@ sim reports healthy headroom). Tests assert the account sum lands within
 10% of reported-used on this path — the same contract the device path is
 expected to hold.
 
-Reconciliation must *never* crash the metrics loop: every device probe is
-wrapped, and a mismatch is a gauge (``mem.unattributed``), not an error.
+Reconciliation must *never* crash the metrics loop: :meth:`MemoryLedger.tick`
+logs a failed pass and carries on, and a mismatch is a gauge
+(``mem.unattributed``), not an error.
 """
 
 from __future__ import annotations
 
+import logging
 from typing import Any, Dict, Optional, Tuple
 
 from maggy_tpu.core import lockdebug
@@ -53,29 +55,26 @@ DEFAULT_LOW_HEADROOM_PCT = 0.10
 
 
 def device_memory() -> Optional[Tuple[int, int]]:
-    """``(bytes_in_use, bytes_limit)`` summed over local devices, or None
-    when no device reports memory stats (CPU backend, or jax absent)."""
-    try:
-        import jax
+    """``(bytes_in_use, bytes_limit)`` summed over local devices, or None on
+    a backend whose devices report no memory stats (the CPU backend). A TPU
+    that reports none is an error: its numbers must never be simulated."""
+    import jax
 
-        used = limit = 0
-        found = False
-        for dev in jax.local_devices():
-            stats = getattr(dev, "memory_stats", lambda: None)()
-            if not stats:
-                continue
-            b_used = stats.get("bytes_in_use")
-            b_limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-            if b_used is None or not b_limit:
-                continue
-            used += int(b_used)
-            limit += int(b_limit)
-            found = True
-        if found and limit > 0:
-            return used, limit
-    except Exception:  # noqa: BLE001 - a probe failure must not kill the tick
-        pass
-    return None
+    used = limit = 0
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        b_used = stats.get("bytes_in_use")
+        b_limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+        if b_used is None or not b_limit:
+            if dev.platform == "tpu":
+                raise RuntimeError(
+                    f"{dev} reports no memory_stats (got {stats!r}); refusing "
+                    "to simulate device memory on a TPU backend"
+                )
+            return None
+        used += int(b_used)
+        limit += int(b_limit)
+    return used, limit
 
 
 class MemoryLedger:
@@ -165,6 +164,7 @@ class MemoryLedger:
         try:
             rec = self.reconcile()
         except Exception:  # noqa: BLE001 - reconcile must never kill the tick
+            logging.getLogger(__name__).exception("memory reconciliation failed")
             return {}
         with self._lock:
             if rec["headroom_pct"] < self.low_headroom_pct:

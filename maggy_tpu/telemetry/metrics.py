@@ -215,6 +215,9 @@ EVENTS = frozenset(
         # training runs (train/trainer.py)
         "train.run_start",
         "train.run_end",
+        # which attention kernel the automatic dispatch took for a traced
+        # shape, and why not flash (models/transformer.py auto_attention)
+        "attention.kernel",
         # autopilot decisions (autopilot/controller.py, serve/scheduler.py):
         # the auditable telemetry→config loop — diagnosis verdicts, applied
         # moves, guarded commits, automatic rollbacks

@@ -24,8 +24,8 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from maggy_tpu.util import shard_map
 from maggy_tpu.models.transformer import (
     REMAT_POLICIES,
     Decoder,
@@ -33,6 +33,8 @@ from maggy_tpu.models.transformer import (
     _dense,
     _ScannedLayer,
     default_attention,
+    flash_tileable,
+    record_attention_kernel,
 )
 
 
@@ -65,17 +67,17 @@ def _pp_local_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     """Attention inside the pipeline's shard_map must be device-local (the
     stage/data/fsdp axes are manual): the single-device Pallas flash kernel
     on TPU when the geometry tiles onto the MXU, the XLA dense path
-    otherwise — the same dispatch as auto_attention minus the mesh logic."""
+    otherwise — the same dispatch as auto_attention minus the mesh logic,
+    recorded the same way."""
     from maggy_tpu.ops.flash import flash_attention  # late: import cycle
 
-    b, s, h, d = q.shape
-    if (
-        jax.default_backend() == "tpu"
-        and segment_ids is None
-        and d % 128 == 0
-        and s % 128 == 0
-    ):
+    why = flash_tileable(q.shape[1], k.shape[1], q.shape[3])
+    if why is None and segment_ids is not None:
+        why = "packed segments take the XLA path inside a pipeline stage"
+    if why is None:
+        record_attention_kernel("flash", q, k, segment_ids)
         return flash_attention(q, k, v, causal=causal)
+    record_attention_kernel("xla_dense", q, k, segment_ids, why)
     return default_attention(q, k, v, causal=causal, segment_ids=segment_ids)
 
 

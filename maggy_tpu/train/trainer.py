@@ -353,7 +353,7 @@ class Trainer:
 
         from maggy_tpu import telemetry
         from maggy_tpu.parallel import overlap
-        from maggy_tpu.util import shard_map as _shard_map
+        from jax import shard_map as _shard_map
 
         axes_comm = tuple(manual if comm_axes is None else comm_axes)
         assert all(a in manual for a in axes_comm)

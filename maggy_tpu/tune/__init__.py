@@ -3,7 +3,7 @@
 Maggy's core trick is the oblivious training function — the same ``train_fn``
 runs as a local run, an HPO trial, or a distributed rank. This package
 applies that idea to the *system* axis: mesh shape, global batch size,
-microbatch count, remat policy and flash tile sizes are searched like
+microbatch count and remat policy are searched like
 hyperparameters, in two stages:
 
 **Stage 1 — static (no execution).** Every candidate's train step is

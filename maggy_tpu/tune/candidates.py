@@ -1,7 +1,7 @@
 """Candidate system configurations and the tuned-winner record.
 
 A :class:`Candidate` is one point in the system-configuration grid —
-(mesh shape, global batch, microbatches, remat policy, flash tiles). The
+(mesh shape, global batch, microbatches, remat policy). The
 static stage AOT-compiles each one; the measured stage races the survivors.
 The winner is frozen into a :class:`TunedConfig`, the JSON-round-trippable
 record the tuning cache stores and that builds a ready-to-``fit`` Trainer.
@@ -46,8 +46,6 @@ class Candidate:
     batch_size: int
     n_microbatches: Optional[int] = None
     remat_policy: Optional[str] = None
-    flash_block_q: Optional[int] = None
-    flash_block_k: Optional[int] = None
 
     @property
     def label(self) -> str:
@@ -56,8 +54,6 @@ class Candidate:
             parts.append(f"mb{self.n_microbatches}")
         if self.remat_policy:
             parts.append(f"remat:{self.remat_policy}")
-        if self.flash_block_q:
-            parts.append(f"fq{self.flash_block_q}/fk{self.flash_block_k}")
         return "/".join(parts)
 
     def spec_for(self, num_devices: int) -> ShardingSpec:
@@ -74,8 +70,6 @@ class Candidate:
             "batch_size": self.batch_size,
             "n_microbatches": self.n_microbatches,
             "remat_policy": self.remat_policy,
-            "flash_block_q": self.flash_block_q,
-            "flash_block_k": self.flash_block_k,
         }
 
     @classmethod
@@ -88,8 +82,6 @@ class Candidate:
             batch_size=int(d["batch_size"]),
             n_microbatches=d.get("n_microbatches"),
             remat_policy=d.get("remat_policy"),
-            flash_block_q=d.get("flash_block_q"),
-            flash_block_k=d.get("flash_block_k"),
         )
 
 
@@ -119,23 +111,19 @@ def enumerate_candidates(tune_cfg, num_devices: int) -> List[Candidate]:
                 if mb is not None and (bs % mb or (bs // mb) % dpf):
                     continue
                 for remat in tune_cfg.remat_policies:
-                    for blocks in tune_cfg.flash_blocks:
-                        fq, fk = blocks if blocks else (None, None)
-                        cand = Candidate(
-                            preset=preset,
-                            batch_size=int(bs),
-                            n_microbatches=mb,
-                            remat_policy=remat,
-                            flash_block_q=fq,
-                            flash_block_k=fk,
-                        )
-                        key = repr(cand.to_dict())
-                        if key in seen:
-                            continue
-                        seen.add(key)
-                        out.append(cand)
-                        if len(out) >= tune_cfg.max_candidates:
-                            return out
+                    cand = Candidate(
+                        preset=preset,
+                        batch_size=int(bs),
+                        n_microbatches=mb,
+                        remat_policy=remat,
+                    )
+                    key = repr(cand.to_dict())
+                    if key in seen:
+                        continue
+                    seen.add(key)
+                    out.append(cand)
+                    if len(out) >= tune_cfg.max_candidates:
+                        return out
     return out
 
 
@@ -148,21 +136,9 @@ class TunedConfig:
     batch_size: int
     n_microbatches: Optional[int] = None
     remat_policy: Optional[str] = None
-    flash_block_q: Optional[int] = None
-    flash_block_k: Optional[int] = None
     source: str = "static"  # "static" | "measured" | "cache"
     steps_per_sec: Optional[float] = None
     step_time_ms: Optional[float] = None
-
-    def apply_env(self) -> None:
-        """Export the flash tile choice through the same env knobs the bench
-        playbook uses, so existing kernels pick it up without plumbing."""
-        import os
-
-        if self.flash_block_q:
-            os.environ["MAGGY_TPU_FLASH_BWD_Q"] = str(self.flash_block_q)
-        if self.flash_block_k:
-            os.environ["MAGGY_TPU_FLASH_BWD_K"] = str(self.flash_block_k)
 
     def mesh(self, devices: Optional[list] = None):
         from maggy_tpu.parallel.mesh import make_mesh
@@ -179,11 +155,10 @@ class TunedConfig:
 
     def trainer(self, model: Any, optimizer: Any, devices: Optional[list] = None, **kw):
         """Build a ready Trainer on this config's mesh, with the remat policy
-        applied to the model and flash tiles exported. The returned trainer's
+        applied to the model. The returned trainer's
         ``fit``/``step`` run the tuned configuration directly."""
         from maggy_tpu.train.trainer import Trainer
 
-        self.apply_env()
         return Trainer(
             apply_remat(model, self.remat_policy),
             optimizer,
@@ -198,8 +173,6 @@ class TunedConfig:
             "batch_size": self.batch_size,
             "n_microbatches": self.n_microbatches,
             "remat_policy": self.remat_policy,
-            "flash_block_q": self.flash_block_q,
-            "flash_block_k": self.flash_block_k,
             "source": self.source,
             "steps_per_sec": self.steps_per_sec,
             "step_time_ms": self.step_time_ms,
@@ -212,8 +185,6 @@ class TunedConfig:
             batch_size=int(d["batch_size"]),
             n_microbatches=d.get("n_microbatches"),
             remat_policy=d.get("remat_policy"),
-            flash_block_q=d.get("flash_block_q"),
-            flash_block_k=d.get("flash_block_k"),
             source=d.get("source", "cache"),
             steps_per_sec=d.get("steps_per_sec"),
             step_time_ms=d.get("step_time_ms"),
@@ -226,7 +197,5 @@ class TunedConfig:
             batch_size=cand.batch_size,
             n_microbatches=cand.n_microbatches,
             remat_policy=cand.remat_policy,
-            flash_block_q=cand.flash_block_q,
-            flash_block_k=cand.flash_block_k,
             **kw,
         )

@@ -269,6 +269,39 @@ def test_flash_fallback_on_odd_shapes():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+def test_flash_compiled_refuses_odd_shapes_by_name():
+    """interpret=False (what a TPU picks by itself) never falls back: the
+    caller asked for the kernel, so an untileable shape raises, naming the
+    dimension — before any Mosaic lowering, so this runs off-TPU too."""
+    q, k, v = qkv(b=1, s=60, h=2, d=16)
+    with pytest.raises(ValueError, match="head_dim 16 is not a multiple of 128"):
+        flash_attention(q, k, v, causal=True, interpret=False)
+    q, k, v = qkv(b=1, s=100, h=2, d=128)
+    with pytest.raises(ValueError, match=r"block_q=100 \(sequence length 100\)"):
+        flash_attention(q, k, v, causal=True, interpret=False)
+    # packed segments put the block in the lane dim: 8-aligned is not enough
+    q, k, v = qkv(b=2, s=200, h=2, d=128)
+    seg = jnp.zeros((2, 200), jnp.int32)
+    with pytest.raises(ValueError, match="not a multiple of 128"):
+        flash_attention(q, k, v, segment_ids=seg, interpret=False)
+
+
+def test_auto_attention_records_its_choice():
+    """The automatic dispatch is never silent: one attention.kernel event
+    per trace says which kernel it took and, off the flash path, why."""
+    from maggy_tpu import telemetry
+    from maggy_tpu.models.transformer import auto_attention
+
+    tel = telemetry.Telemetry(worker="t")
+    q, k, v = qkv(b=1, s=128, h=2, d=128)
+    with telemetry.current(tel):
+        auto_attention(q, k, v)
+    (event,) = [e for e in tel.drain_events() if e["name"] == "attention.kernel"]
+    assert event["attrs"]["kernel"] == "xla_dense"
+    assert event["attrs"]["reason"] == "backend is cpu"
+    assert event["attrs"]["q"] == [1, 128, 2, 128]
+
+
 @pytest.mark.slow
 def test_decoder_with_ring_attention_e2e():
     """Decoder runs unchanged with ring attention as its attention_fn on an

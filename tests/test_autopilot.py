@@ -1,4 +1,4 @@
-"""Autopilot acceptance (ISSUE 8): diagnosis taxonomy + evidence, the
+"""Autopilot acceptance (ISSUE 8): diagnosis verdicts + evidence, the
 planner's registry-bounded moves, workload-fingerprint decision sharing,
 the tune-cache alias scoping fix, the knob-registry lint (wired into
 tier-1 here), live knob application (prefetcher depth, engine slot
@@ -62,7 +62,7 @@ def autopilot_events(tel):
 # ---------------------------------------------------------------- diagnoser
 
 
-def test_diagnose_train_taxonomy_with_evidence():
+def test_diagnose_train_verdicts_with_evidence():
     d = diagnose_train(
         {"step_time_ms": 100.0, "input_wait_ms": 40.0, "metrics_drain_ms": 2.0}
     )
@@ -89,7 +89,7 @@ def test_diagnose_train_taxonomy_with_evidence():
     assert json.loads(json.dumps(d.to_dict()))["bottleneck"] == "memory_bound"
 
 
-def test_diagnose_serve_taxonomy():
+def test_diagnose_serve_verdicts():
     flood = {
         "queue_depth": 10, "active_slots": 2, "num_slots": 2,
         "tpot_ms_p50": 5.0, "drain_ms": 0.2,

@@ -15,7 +15,6 @@ from maggy_tpu.ops.attention import blockwise_attention
 from maggy_tpu.ops.flash import flash_attention
 from maggy_tpu.parallel.ringattention import ring_attention
 from maggy_tpu.parallel.ulysses import ulysses_attention
-from maggy_tpu.util import set_mesh
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs the 8-device CPU mesh"
@@ -72,7 +71,7 @@ def test_xla_ring_segment_parity_sp4():
     q, k, v, seg = _packed()
     ref = _segwise_dense(q, k, v, seg)
     mesh = _mesh(4)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = ring_attention(
             q, k, v, mesh=mesh, causal=True, segment_ids=seg, impl="xla"
         )
@@ -92,7 +91,7 @@ def test_xla_ring_segment_grads_flow():
         m = (seg[0] == np.asarray(seg[0])[0]).astype(np.float32)
         return (out[0] * m[:, None, None] ** 1).sum()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         gk = jax.grad(loss, argnums=1)(q, k, v)
     seg0 = np.asarray(seg[0]) == np.asarray(seg[0])[0]
     assert float(jnp.abs(gk[0, ~seg0]).max()) == 0.0
@@ -103,7 +102,7 @@ def test_ulysses_segment_parity_sp4():
     q, k, v, seg = _packed(H=4, KH=4)  # ulysses: n | H
     ref = _segwise_dense(q, k, v, seg)
     mesh = _mesh(4)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = ulysses_attention(
             q, k, v, mesh=mesh, causal=True, segment_ids=seg
         )

@@ -11,21 +11,9 @@ from jax.sharding import Mesh
 from maggy_tpu.models.transformer import default_attention
 from maggy_tpu.ops.ring_flash import ring_flash_attention
 from maggy_tpu.parallel.ringattention import ring_attention
-from maggy_tpu.util import set_mesh
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs the 8-device CPU mesh"
-)
-
-# the TPU interpret machine (faithful remote-DMA/semaphore simulation on CPU)
-# only exists on newer jax; without it the RDMA kernel cannot run off-TPU
-_HAS_INTERPRET_MACHINE = hasattr(
-    __import__("jax.experimental.pallas.tpu", fromlist=["tpu"]),
-    "InterpretParams",
-)
-needs_interpret_machine = pytest.mark.skipif(
-    not _HAS_INTERPRET_MACHINE,
-    reason="jax too old for the pallas TPU interpret machine",
 )
 
 
@@ -40,27 +28,25 @@ def _qkv(B=2, S=128, H=4, KH=2, D=16):
     return q, k, v
 
 
-@needs_interpret_machine
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.slow
 def test_ring_flash_matches_dense(causal):
     mesh = _mesh(4)
     q, k, v = _qkv()
     ref = default_attention(q, k, v, causal=causal)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = ring_flash_attention(
             q, k, v, mesh=mesh, causal=causal, q_tile=16, interpret=True
         )
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
-@needs_interpret_machine
 def test_ring_flash_gqa_matches_xla_ring():
     """sp=4 mesh, grouped KV heads: the RDMA kernel and the ppermute ring are
     the same computation distributed two different ways."""
     mesh = _mesh(4)
     q, k, v = _qkv(B=1, S=64, H=4, KH=1, D=8)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         xla = ring_attention(q, k, v, mesh=mesh, causal=True, impl="xla")
         pallas = ring_attention(
             q, k, v, mesh=mesh, causal=True, impl="pallas", interpret=True
@@ -68,7 +54,6 @@ def test_ring_flash_gqa_matches_xla_ring():
     assert float(jnp.abs(pallas - xla).max()) < 2e-5
 
 
-@needs_interpret_machine
 def test_ring_flash_backward_kernel_parity():
     """The RDMA backward ring (rotating dk/dv accumulators, probabilities
     recomputed from the saved LSE) must give the same gradients as the
@@ -87,7 +72,7 @@ def test_ring_flash_backward_kernel_parity():
         out = ring_attention(q, k, v, mesh=mesh, causal=True, impl="xla")
         return (out**2).sum()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         gp = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
         gx = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gp, gx):
@@ -107,12 +92,11 @@ def test_auto_impl_gates_pallas_off_tpu(monkeypatch):
     monkeypatch.setenv("MAGGY_TPU_RING_PALLAS", "1")
     mesh = _mesh(2)
     q, k, v = _qkv(B=1, S=32, H=2, KH=2, D=8)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = ring_attention(q, k, v, mesh=mesh, causal=True, impl="auto")
     assert out.shape == q.shape
 
 
-@needs_interpret_machine
 @pytest.mark.slow
 def test_ring_flash_backward_gqa_four_ring():
     """4-device ring, grouped KV heads, several q tiles per chunk — the dK/dV
@@ -130,7 +114,7 @@ def test_ring_flash_backward_gqa_four_ring():
         out = ring_attention(q, k, v, mesh=mesh, causal=True, impl="xla")
         return (out * jnp.cos(out)).sum()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         gp = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
         gx = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gp, gx):

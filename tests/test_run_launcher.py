@@ -192,6 +192,22 @@ def test_run_launcher_arg_validation():
     assert "--workers" in proc.stderr
 
 
+def test_run_launcher_refuses_to_share_a_tpu_host(monkeypatch):
+    """Two ranks on one unpartitioned TPU host: the second cannot open the
+    device, so the launcher names the problem before it starts anything.
+    CPU runs, single ranks and chip-partitioned environments pass."""
+    from maggy_tpu import run
+
+    monkeypatch.setattr(run, "_local_tpu_chips", lambda: 4)
+    with pytest.raises(SystemExit, match="one TPU host"):
+        run.check_tpu_host_not_shared(2, {})
+    run.check_tpu_host_not_shared(1, {})
+    run.check_tpu_host_not_shared(2, {"JAX_PLATFORMS": "cpu"})
+    run.check_tpu_host_not_shared(2, {"TPU_VISIBLE_CHIPS": "0,1"})
+    monkeypatch.setattr(run, "_local_tpu_chips", lambda: 0)
+    run.check_tpu_host_not_shared(2, {})
+
+
 ELASTIC_SCRIPT = textwrap.dedent(
     """
     import os, signal, sys

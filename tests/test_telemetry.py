@@ -172,6 +172,32 @@ def test_fit_exposes_steps_per_sec_and_gauges():
     assert names.count("shard_batch") == 4
 
 
+def test_peak_flops_table_is_keyed_by_device_kind(caplog):
+    """A known kind reads its published peak; a TPU kind the table does not
+    hold yields no MFU (and a logged warning), never a default; a CPU device
+    is silently without one."""
+    import logging
+    import types
+
+    from maggy_tpu.telemetry import flops
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert flops.device_peak_flops(v5e) == 197e12
+    # 6 * 1e9 params * 1e4 tok/s over 4 chips of 197 TFLOP/s
+    assert flops.estimate_mfu(1e4, 10**9, [v5e] * 4) == pytest.approx(
+        6e13 / (4 * 197e12)
+    )
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v9 imaginary")
+    with caplog.at_level(logging.WARNING, logger="maggy_tpu.telemetry.flops"):
+        assert flops.estimate_mfu(1e4, 10**9, [unknown]) is None
+    assert "TPU v9 imaginary" in caplog.text
+    caplog.clear()
+    cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu")
+    with caplog.at_level(logging.WARNING, logger="maggy_tpu.telemetry.flops"):
+        assert flops.estimate_mfu(1e4, 10**9, [cpu]) is None
+    assert not caplog.text
+
+
 def test_fit_steps_per_sec_with_telemetry_disabled(monkeypatch):
     monkeypatch.setenv("MAGGY_TPU_TELEMETRY", "0")
     trainer, state, data = _tiny_trainer()

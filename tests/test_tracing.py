@@ -480,7 +480,9 @@ def test_fit_emits_run_trace_events():
     with rec_mod.current(tel):
         trainer.fit(state, data, num_steps=3)
     events = tel.drain_events()
-    lifecycle = [e for e in events if e["kind"] == "event"]
+    lifecycle = [
+        e for e in events if e["kind"] == "event" and e["name"].startswith("train.")
+    ]
     assert [e["name"] for e in lifecycle] == ["train.run_start", "train.run_end"]
     run_trace = lifecycle[0]["trace"]
     assert run_trace and lifecycle[1]["trace"] == run_trace

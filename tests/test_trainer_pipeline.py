@@ -17,15 +17,6 @@ pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the 8-device CPU mesh"
 )
 
-# pp x tp / pp x ep compose via nested PARTIAL-manual shard_maps (the inner
-# one inherits the context mesh with stage/data/fsdp already Manual); that
-# abstract-mesh machinery only exists on newer jax — full-manual pp x dp/fsdp
-# works everywhere
-needs_partial_manual = pytest.mark.skipif(
-    not hasattr(jax.sharding, "get_abstract_mesh"),
-    reason="pp x tp/ep needs newer jax (abstract-mesh partial-manual shard_map)",
-)
-
 
 def _batch(cfg, bsz=8, seq=32, seed=0):
     rng = np.random.default_rng(seed)
@@ -315,7 +306,6 @@ def test_pp_raises_loudly_for_unsupported():
         tr5.step(state5, tr5.shard_batch(batch))
 
 
-@needs_partial_manual
 def test_pp_tp_matches_dense_loss_and_grads():
     """pp=2 x tp=2 x dp=2 (VERDICT r4 item 2): stage params carry
     tensor-sharded dims (attn heads / mlp hidden / vocab — the model's own
@@ -365,7 +355,6 @@ def test_pp_tp_matches_dense_loss_and_grads():
         )
 
 
-@needs_partial_manual
 def test_pp_tp_trains_and_eval_matches():
     """pp x tp under adamw decreases the loss; eval_logits through the
     unstacked model matches a host-side dense apply (bf16 reduction-order
@@ -390,7 +379,6 @@ def test_pp_tp_trains_and_eval_matches():
     )
 
 
-@needs_partial_manual
 def test_pp_tp_moe_trains():
     """MoEDecoder under pp x tp: expert FFN hidden dims tensor-shard inside
     each stage; router aux still joins per stage."""
@@ -485,7 +473,6 @@ def test_pp_pipelined_eval_packed_matches_dense():
     assert abs(res["loss"] - ref) < 2e-3
 
 
-@needs_partial_manual
 def test_pp_tp_packed_matches_dense():
     """Packed batch under pp x tp: segment ids reach the nested
     tensor-manual stage attention (replicated across head shards) and the
@@ -522,7 +509,6 @@ def test_pp_tp_packed_matches_dense():
     assert abs(float(metrics["loss"]) - float(ref)) < 2e-3
 
 
-@needs_partial_manual
 def test_pp_ep_moe_matches_dense():
     """pp x ep: expert FFN weights shard over the expert axis INSIDE each
     stage (GSPMD-auto in the pipeline's partial-manual region), and the
@@ -569,7 +555,6 @@ def test_pp_ep_dense_model_refused():
         trainer.make_state(jax.random.key(0), _batch(cfg))
 
 
-@needs_partial_manual
 def test_pp_tp_ep_three_way_composition():
     """pp x tp x ep on one mesh: attention heads tensor-sharded AND expert
     FFNs expert-sharded inside each pipeline stage, training end-to-end."""
@@ -612,7 +597,6 @@ def test_pp_tp_ep_three_way_composition():
     assert float(m["aux_loss"]) > 0
 
 
-@needs_partial_manual
 def test_restore_pp_checkpoint_onto_pp_tp_mesh():
     """Checkpoint portability across LAYOUTS, not just degrees: a state
     trained on a plain pp=2 x dp mesh adopts onto a pp=2 x tp=2 mesh —

@@ -24,10 +24,6 @@ import time
 
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
-from maggy_tpu.util import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 
 DISTRIBUTIONS = {
     # durations long enough that the one-time driver bring-up (~0.4 s)

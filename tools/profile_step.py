@@ -1,6 +1,6 @@
-"""Capture a profiler trace of the bench-geometry train step (VERDICT r4
-item 1: "profile one train step"). Writes a TensorBoard-readable trace to
-tools/profile_r5/ for MFU-gap analysis on live silicon.
+"""Capture a profiler trace of the bench-geometry train step. Writes a
+TensorBoard-readable trace to chiprun_out/profile_step/ (the directory a
+chip run brings back) for MFU-gap analysis.
 
     python tools/profile_step.py [--steps 5]
 """
@@ -12,25 +12,22 @@ import time
 
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
-from maggy_tpu.util import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=5)
     parser.add_argument(
         "--out",
-        default=os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "profile_r5"),
+        default=os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "chiprun_out", "profile_step",
+        ),
     )
     args = parser.parse_args()
 
-    from bench import apply_tuned_config, bench_setup, ensure_live_backend
+    from bench import bench_setup, on_cpu
 
-    cpu = ensure_live_backend()
-    apply_tuned_config()
+    cpu = on_cpu()
 
     import jax
 
@@ -43,12 +40,12 @@ def main():
     with jax.profiler.trace(args.out):
         for _ in range(args.steps):
             state, m = trainer.step(state, batch)
-        float(m["loss"])
+        jax.block_until_ready(m)
     print(f"trace written to {args.out} ({args.steps} steps, cpu={cpu})")
     t0 = time.perf_counter()
     for _ in range(args.steps):
         state, m = trainer.step(state, batch)
-    float(m["loss"])
+    jax.block_until_ready(m)
     print(f"untraced step: {(time.perf_counter() - t0) / args.steps * 1e3:.2f} ms")
 
 
