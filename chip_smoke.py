@@ -17,8 +17,11 @@ a user calls, at the widths of ``DecoderConfig.llama3_8b()`` with the depth
             with a real train_fn: every trial's state on its leased chip.
 
 Any phase that raises fails the run; there is no fallback to the CPU. The last
-line of stdout is one JSON object naming the device, what ran and each phase's
-set-up (compile) time. Logs go under ``chiprun_out/chip_smoke/``.
+line of stdout is the verdict, one JSON object with exactly the keys ``ok`` and
+``device`` (``platform``, ``kind``, ``count``, as JAX reports them). The line
+before it, ``summary: {...}``, says what ran and each phase's set-up (compile)
+time; the same object is written to ``chiprun_out/chip_smoke/summary.json``
+beside the logs.
 
     python chip_smoke.py                  # on a machine with a TPU
     JAX_PLATFORMS=cpu python chip_smoke.py --rehearse-on-cpu   # toy sizes
@@ -779,8 +782,9 @@ def main(argv=None) -> int:
     }
     with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
         json.dump(summary, f, indent=1, default=str)
-    run.say("result:")
-    print(json.dumps(summary, default=str), flush=True)
+    run.say(f"summary: {json.dumps(summary, default=str)}")
+    # the verdict line is read by machine: these two keys and nothing else
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1
 
 

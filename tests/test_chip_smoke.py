@@ -36,9 +36,12 @@ def test_refuses_a_cpu_and_rehearses_on_request(tmp_path):
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()
     assert all(line.startswith("[REHEARSAL") for line in lines[:-1])
-    summary = json.loads(lines[-1])
+    # the verdict line: exactly these keys, the device as JAX reports it
+    device = {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    summary = json.loads(lines[-2].split("summary: ", 1)[1])
     assert summary["ok"] is True and summary["rehearsal"] is True
-    assert summary["device"] == {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert summary["device"] == device
     assert list(summary["phases"]) == ["kernels", "trainer", "server", "hpo"]
     assert summary["phases"]["server"]["compile_counts"]["decode"] == 1
     assert summary["phases"]["hpo"]["leases"] == [0, 1, 2, 3]
@@ -46,4 +49,5 @@ def test_refuses_a_cpu_and_rehearses_on_request(tmp_path):
     # a run that skips a phase can never pass
     proc = _run(["--rehearse-on-cpu", "--phases", "kernels"], tmp_path, timeout=300)
     assert proc.returncode != 0
-    assert json.loads(proc.stdout.strip().splitlines()[-1])["ok"] is False
+    verdict = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert verdict["ok"] is False and set(verdict) == {"ok", "device"}
