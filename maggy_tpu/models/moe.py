@@ -102,33 +102,36 @@ class MoEBlock(nn.Module):
             tokens = jnp.pad(tokens, ((0, pad), (0, 0)))
         grouped = tokens.reshape(n_groups, g, d)
 
-        router_logits = _dense(e, ("embed", None), cfg, "router")(grouped)
-        router_probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        # device-side scopes (telemetry/metrics.py SCOPES): metadata only
+        with jax.named_scope("moe.route"):
+            router_logits = _dense(e, ("embed", None), cfg, "router")(grouped)
+            router_probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
 
-        gate_vals, expert_idx = jax.lax.top_k(router_probs, cfg.top_k)  # [n,g,k]
-        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+            gate_vals, expert_idx = jax.lax.top_k(router_probs, cfg.top_k)  # [n,g,k]
+            gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
 
-        # GShard dispatch per group: position of each (token, k) in its expert
-        # queue; top-1 assignments win capacity slots over top-2
-        onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)  # [n,g,k,e]
-        flat = onehot.transpose(0, 2, 1, 3).reshape(n_groups, cfg.top_k * g, e)
-        pos_flat = jnp.cumsum(flat, axis=1) - flat
-        pos = pos_flat.reshape(n_groups, cfg.top_k, g, e).transpose(0, 2, 1, 3)
-        pos_in_expert = (pos * onehot).sum(-1)  # [n,g,k]
-        within = pos_in_expert < capacity
+            # GShard dispatch per group: position of each (token, k) in its expert
+            # queue; top-1 assignments win capacity slots over top-2
+            onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)  # [n,g,k,e]
+            flat = onehot.transpose(0, 2, 1, 3).reshape(n_groups, cfg.top_k * g, e)
+            pos_flat = jnp.cumsum(flat, axis=1) - flat
+            pos = pos_flat.reshape(n_groups, cfg.top_k, g, e).transpose(0, 2, 1, 3)
+            pos_in_expert = (pos * onehot).sum(-1)  # [n,g,k]
+            within = pos_in_expert < capacity
 
-        disp = (
-            jax.nn.one_hot(expert_idx, e, dtype=x.dtype)[..., None]
-            * jax.nn.one_hot(pos_in_expert, capacity, dtype=x.dtype)[..., None, :]
-            * within[..., None, None].astype(x.dtype)
-        )  # [n,g,k,e,c]
-        combine = (disp * gate_vals[..., None, None].astype(x.dtype)).sum(2)
-        dispatch = disp.sum(2)  # [n,g,e,c]
+        with jax.named_scope("moe.dispatch"):
+            disp = (
+                jax.nn.one_hot(expert_idx, e, dtype=x.dtype)[..., None]
+                * jax.nn.one_hot(pos_in_expert, capacity, dtype=x.dtype)[..., None, :]
+                * within[..., None, None].astype(x.dtype)
+            )  # [n,g,k,e,c]
+            combine = (disp * gate_vals[..., None, None].astype(x.dtype)).sum(2)
+            dispatch = disp.sum(2)  # [n,g,e,c]
 
-        expert_in = jnp.einsum("ngec,ngd->necd", dispatch, grouped)
-        expert_in = expert_in.reshape(n_groups, e, capacity, d)
-        # fold groups into the expert batch: experts see [e, n*c, d]
-        expert_in = expert_in.transpose(1, 0, 2, 3).reshape(e, n_groups * capacity, d)
+            expert_in = jnp.einsum("ngec,ngd->necd", dispatch, grouped)
+            expert_in = expert_in.reshape(n_groups, e, capacity, d)
+            # fold groups into the expert batch: experts see [e, n*c, d]
+            expert_in = expert_in.transpose(1, 0, 2, 3).reshape(e, n_groups * capacity, d)
 
         w_gate = self.param(
             "w_gate",
@@ -148,29 +151,32 @@ class MoEBlock(nn.Module):
             (e, cfg.d_ff, d),
             cfg.param_dtype,
         )
-        w_gate, w_up, w_down = (
-            jnp.asarray(w_gate, cfg.dtype),
-            jnp.asarray(w_up, cfg.dtype),
-            jnp.asarray(w_down, cfg.dtype),
-        )
-        hidden = nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, w_gate)) * jnp.einsum(
-            "ecd,edf->ecf", expert_in, w_up
-        )
-        expert_out = jnp.einsum("ecf,efd->ecd", hidden, w_down)
-        expert_out = expert_out.reshape(e, n_groups, capacity, d).transpose(1, 0, 2, 3)
+        with jax.named_scope("moe.experts"):
+            w_gate, w_up, w_down = (
+                jnp.asarray(w_gate, cfg.dtype),
+                jnp.asarray(w_up, cfg.dtype),
+                jnp.asarray(w_down, cfg.dtype),
+            )
+            hidden = nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, w_gate)) * jnp.einsum(
+                "ecd,edf->ecf", expert_in, w_up
+            )
+            expert_out = jnp.einsum("ecf,efd->ecd", hidden, w_down)
+            expert_out = expert_out.reshape(e, n_groups, capacity, d).transpose(1, 0, 2, 3)
 
-        y = jnp.einsum("ngec,necd->ngd", combine, expert_out).reshape(-1, d)
+        with jax.named_scope("moe.combine"):
+            y = jnp.einsum("ngec,necd->ngd", combine, expert_out).reshape(-1, d)
         if pad:
             y = y[:t]
         y = y.reshape(b, s, d)
 
-        # load-balancing auxiliary loss (Switch/Mixtral style); a LOCO gate
-        # scales it too, so ablated blocks add no balancing gradients
-        me = router_probs.reshape(-1, e).mean(0)  # [e] mean router prob
-        ce = jax.nn.one_hot(expert_idx[..., 0], e).reshape(-1, e).mean(0)
-        aux = (me * ce).sum() * e * cfg.router_aux_weight
-        if aux_gate is not None:
-            aux = aux * aux_gate.astype(aux.dtype)
+        with jax.named_scope("moe.route"):
+            # load-balancing auxiliary loss (Switch/Mixtral style); a LOCO gate
+            # scales it too, so ablated blocks add no balancing gradients
+            me = router_probs.reshape(-1, e).mean(0)  # [e] mean router prob
+            ce = jax.nn.one_hot(expert_idx[..., 0], e).reshape(-1, e).mean(0)
+            aux = (me * ce).sum() * e * cfg.router_aux_weight
+            if aux_gate is not None:
+                aux = aux * aux_gate.astype(aux.dtype)
         self.sow("intermediates", "router_aux_loss", aux)
         return y
 

@@ -467,8 +467,10 @@ class Attention(nn.Module):
                 cache_row, update_row, (start, 0, 0)
             )
 
-        k_all = jax.vmap(_row_write)(k_cache.value, k.astype(cfg.dtype), idx)
-        v_all = jax.vmap(_row_write)(v_cache.value, v.astype(cfg.dtype), idx)
+        # device-side scopes (telemetry/metrics.py SCOPES): metadata only
+        with jax.named_scope("kv_write"):
+            k_all = jax.vmap(_row_write)(k_cache.value, k.astype(cfg.dtype), idx)
+            v_all = jax.vmap(_row_write)(v_cache.value, v.astype(cfg.dtype), idx)
         k_cache.value = k_all
         v_cache.value = v_all
         index.value = idx + t
@@ -551,9 +553,10 @@ class Attention(nn.Module):
                 scale,
             )
 
-        carry = ops_attn.init_carry(b, h, t, hd)
-        acc, _, l = jax.lax.fori_loop(0, n_valid, body, carry)
-        return ops_attn.finalize(acc, l, q.dtype)
+        with jax.named_scope("decode_attn"):
+            carry = ops_attn.init_carry(b, h, t, hd)
+            acc, _, l = jax.lax.fori_loop(0, n_valid, body, carry)
+            return ops_attn.finalize(acc, l, q.dtype)
 
     def _paged_cached_attention(self, q, k, v, positions):
         """Paged KV cache: K/V live in a flat pool of ``num_pages`` pages of
@@ -605,12 +608,13 @@ class Attention(nn.Module):
         # lands at (pt[b, (idx+j)//P], (idx+j)%P). Distinct live rows own
         # distinct pages, so scatter indices never collide except on the
         # scratch page (masked rows), whose content is garbage by contract.
-        pos_w = idx[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-        page_slot = jnp.clip(pos_w // P, 0, max_pages - 1)
-        phys = jnp.take_along_axis(pt, page_slot, axis=1)  # [B, t]
-        off = pos_w % P
-        k_all = k_pool.value.at[phys, off].set(k.astype(cfg.dtype))
-        v_all = v_pool.value.at[phys, off].set(v.astype(cfg.dtype))
+        with jax.named_scope("kv_write"):
+            pos_w = idx[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+            page_slot = jnp.clip(pos_w // P, 0, max_pages - 1)
+            phys = jnp.take_along_axis(pt, page_slot, axis=1)  # [B, t]
+            off = pos_w % P
+            k_all = k_pool.value.at[phys, off].set(k.astype(cfg.dtype))
+            v_all = v_pool.value.at[phys, off].set(v.astype(cfg.dtype))
         k_pool.value = k_all
         v_pool.value = v_all
         index.value = idx + t
@@ -655,9 +659,10 @@ class Attention(nn.Module):
                 scale,
             )
 
-        carry = ops_attn.init_carry(b, h, t, hd)
-        acc, _, l = jax.lax.fori_loop(0, n_valid, body, carry)
-        return ops_attn.finalize(acc, l, q.dtype)
+        with jax.named_scope("decode_attn"):
+            carry = ops_attn.init_carry(b, h, t, hd)
+            acc, _, l = jax.lax.fori_loop(0, n_valid, body, carry)
+            return ops_attn.finalize(acc, l, q.dtype)
 
 
 class MLPBlock(nn.Module):
@@ -795,7 +800,8 @@ class Decoder(nn.Module):
 
         x = RMSNorm(cfg, name="final_norm")(x)
         if cfg.tie_embeddings:
-            logits = jnp.einsum("bsd,vd->bsv", x, jnp.asarray(embed, cfg.dtype))
+            with jax.named_scope("lm_head"):  # the scope the untied head's module gives
+                logits = jnp.einsum("bsd,vd->bsv", x, jnp.asarray(embed, cfg.dtype))
         else:
             logits = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, "lm_head")(x)
         if cfg.logits_softcap:

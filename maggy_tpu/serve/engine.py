@@ -15,8 +15,8 @@ and exactly three compiled programs:
   Every input that varies as requests churn (tokens, positions, mask,
   sampling params, PRNG key rows) is a same-shape array, so the step
   compiles exactly once for the life of the engine — the XLA-friendly
-  analogue of vLLM-style continuous batching. Retrace counters recorded as
-  ``serve.decode_retraces`` / ``serve.prefill_retraces`` gauges prove it.
+  analogue of vLLM-style continuous batching. The ``serve.decode_retraces``
+  gauge and the ``compile.<program>`` series of the recompile sentinel prove it.
 
 Per-request sampling keys: each request carries a base key derived from its
 seed; the key for generated-token ``i`` is ``fold_in(base, i)``, so a
@@ -100,6 +100,10 @@ MIN_PREFILL_BUCKET = 8
 
 # default KV page size (tokens) for the paged cache; must divide max_seq_len
 DEFAULT_PAGE_SIZE = 16
+# how long after the last compile of any of its programs the engine counts as
+# warming up: the longest window of the latency alerts (telemetry/alerts.py),
+# so every sample a compile stretched has aged out when they are evaluated
+WARMUP_QUIET_S = 30.0
 
 
 def _sample_one(logits, temp, top_k, key):
@@ -107,14 +111,15 @@ def _sample_one(logits, temp, top_k, key):
     (capped) top-k. ``temp <= 0`` is exact greedy — argmax, no RNG consumed —
     so greedy engine output can be compared token-for-token against
     :func:`maggy_tpu.models.generate.generate_cached`."""
-    greedy = jnp.argmax(logits).astype(jnp.int32)
-    cap = min(TOPK_CAP, logits.shape[-1])
-    top_vals = jax.lax.top_k(logits, cap)[0]  # sorted desc
-    kth = top_vals[jnp.clip(top_k - 1, 0, cap - 1)]
-    filtered = jnp.where((top_k > 0) & (logits < kth), -jnp.inf, logits)
-    scaled = filtered / jnp.maximum(temp, 1e-6)
-    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
-    return jnp.where(temp > 0.0, sampled, greedy)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits).astype(jnp.int32)
+        cap = min(TOPK_CAP, logits.shape[-1])
+        top_vals = jax.lax.top_k(logits, cap)[0]  # sorted desc
+        kth = top_vals[jnp.clip(top_k - 1, 0, cap - 1)]
+        filtered = jnp.where((top_k > 0) & (logits < kth), -jnp.inf, logits)
+        scaled = filtered / jnp.maximum(temp, 1e-6)
+        sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
+        return jnp.where(temp > 0.0, sampled, greedy)
 
 
 def _base_key_data(seed: int) -> np.ndarray:
@@ -314,7 +319,8 @@ class Engine:
         self._prefill_traces = 0
         self._admit_traces = 0
         self._prefix_traces = 0
-        self._last_compile_gauges = None
+        self._last_compile_counts = None
+        self.last_compile_ts = time.time()  # when a trace counter last rose
 
         self._decode_jit = jax.jit(self._decode_impl)
         self._admit_jit = jax.jit(self._admit_impl)
@@ -378,10 +384,16 @@ class Engine:
         tok = _sample_one(last, temp, top_k, key)
         return cache, tok
 
-    def _admit_impl(self, cache, row_cache, key_data, slot, plen, key_pair):
+    def _admit_impl(
+        self, cache, row_cache, key_data, slot, plen, key_pair, own_program=True
+    ):
         """Copy the prefilled single-row cache into batch row ``slot`` and pin
-        that row's write index to the true prompt length."""
-        self._admit_traces += 1
+        that row's write index to the true prompt length. Traced inside the
+        prefix-admit program (``own_program=False``) it counts towards that
+        program's compiles, not ``admit``'s: the recompile sentinel holds
+        ``admit`` to one compile."""
+        if own_program:
+            self._admit_traces += 1
 
         def write(path, batch_leaf, row_leaf):
             if "index" in jax.tree_util.keystr(path):
@@ -472,14 +484,16 @@ class Engine:
         key = jax.random.fold_in(jax.random.wrap_key_data(key_pair), gen0)
         tok = _sample_one(last, temp, top_k, key)
         cache, key_data = self._admit_impl(
-            cache, mutated["cache"], key_data, dst_slot, plen, key_pair
+            cache, mutated["cache"], key_data, dst_slot, plen, key_pair,
+            own_program=False,
         )
         return cache, key_data, tok
 
     # ------------------------------------------------------ paged jit bodies
 
     def _paged_admit_impl(
-        self, cache, row_cache, key_data, write_ids, slot, plen, key_pair
+        self, cache, row_cache, key_data, write_ids, slot, plen, key_pair,
+        own_program=True,
     ):
         """Write a prefilled dense single-row cache into the page pool.
 
@@ -490,8 +504,10 @@ class Engine:
         writing them would violate copy-on-write) and pages past the
         prompt. Scratch writes are garbage by contract; real pages receive
         a FULL page of row content, so the write is idempotent against any
-        masked garbage an in-flight async step may have scattered there."""
-        self._admit_traces += 1
+        masked garbage an in-flight async step may have scattered there.
+        ``own_program``: as in :meth:`_admit_impl`."""
+        if own_program:
+            self._admit_traces += 1
         row = {
             jax.tree_util.keystr(path): leaf
             for path, leaf in jax.tree_util.tree_flatten_with_path(row_cache)[0]
@@ -582,7 +598,7 @@ class Engine:
         tok = _sample_one(last, temp, top_k, key)
         cache, key_data = self._paged_admit_impl(
             cache, mutated["cache"], key_data, write_ids, dst_slot, plen,
-            key_pair,
+            key_pair, own_program=False,
         )
         return cache, key_data, tok
 
@@ -949,12 +965,13 @@ class Engine:
         if len(pages) < need:
             return False
         t0 = time.perf_counter()
-        blocks = self._tier_capture_pages(pages[:need])
-        ok = self.tier.put(
-            f"rid:{st.request.id}",
-            blocks,
-            {"tokens": tuple(tokens), "valid": valid, "kind": "resume"},
-        )
+        with self.telemetry.span("serve.spill", pages=need, kind="resume"):
+            blocks = self._tier_capture_pages(pages[:need])
+            ok = self.tier.put(
+                f"rid:{st.request.id}",
+                blocks,
+                {"tokens": tuple(tokens), "valid": valid, "kind": "resume"},
+            )
         if ok:
             self.tier_policy.note_spill(need, pressure=pressure)
             self.telemetry.count("tier.spills")
@@ -987,12 +1004,15 @@ class Engine:
             return
         need = (valid - 1) // self.page_size + 1
         t0 = time.perf_counter()
-        blocks = self._tier_capture_pages(pages[:need])
-        if self.tier.put(
-            f"px:{PrefixIndex.digest(prompt)}",
-            blocks,
-            {"tokens": prompt, "valid": valid, "kind": "prefix"},
-        ):
+        # the copy-out runs on the scheduler's thread, the device waiting
+        with self.telemetry.span("serve.spill", pages=need, kind="prefix"):
+            blocks = self._tier_capture_pages(pages[:need])
+            kept = self.tier.put(
+                f"px:{PrefixIndex.digest(prompt)}",
+                blocks,
+                {"tokens": prompt, "valid": valid, "kind": "prefix"},
+            )
+        if kept:
             self.tier_policy.note_spill(need, prefix=True)
             self.telemetry.count("tier.spills")
             self.telemetry.count("tier.prefix_spills")
@@ -1550,14 +1570,24 @@ class Engine:
     # -------------------------------------------------------------- telemetry
 
     def _record_compile_gauges(self) -> None:
-        # journaled only on change: these tick on RETRACES (rare by
-        # design), and a per-step re-emit of two constant gauges was a
-        # measurable slice of the per-token telemetry budget
-        counts = (self._decode_traces, self._prefill_traces)
-        if counts != self._last_compile_gauges:
-            self._last_compile_gauges = counts
+        # on change only: the counters tick on RETRACES (rare by design).
+        # The sentinel's ``compile.<program>`` series carry all four counts;
+        # the gauge is the monitor panel's "decode compiled once" figure
+        counts = tuple(self.compile_counts.values())
+        if counts != self._last_compile_counts:
+            self._last_compile_counts = counts
+            self.last_compile_ts = time.time()
             self.telemetry.gauge("serve.decode_retraces", self._decode_traces)
-            self.telemetry.gauge("serve.prefill_retraces", self._prefill_traces)
+
+    def warmed_up(self, now: float) -> bool:
+        """The decode step has compiled and no program of this engine has
+        for ``WARMUP_QUIET_S``: latencies read now are the engine's, not the
+        compiler's. A new prefill bucket re-opens the window (its compile
+        stalls every active row's decode)."""
+        return (
+            self._decode_traces > 0
+            and now - self.last_compile_ts >= WARMUP_QUIET_S
+        )
 
     @property
     def compile_counts(self) -> Dict[str, int]:

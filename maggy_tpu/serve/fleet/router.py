@@ -1417,7 +1417,6 @@ class Router:
         )
         self.metrics.ingest(now, gauges=fleet_gauges, counters=counters, hists=merged_hists)
         self.alerts.evaluate(now)
-        self.telemetry.gauge("alerts.firing", float(len(self.alerts.firing())))
 
     def _score_breakers(self, now: float) -> None:
         """Feed each dispatchable replica's windowed TTFT p95 to its

@@ -64,6 +64,9 @@ class Request:
     submitted_ts: float = dataclasses.field(default_factory=time.time)
     admitted_ts: Optional[float] = None
     first_token_ts: Optional[float] = None
+    # one wall-clock mark per generated token, appended where the scheduler
+    # emits it; token_ts[0] == first_token_ts
+    token_ts: List[float] = dataclasses.field(default_factory=list)
     done_ts: Optional[float] = None
     # absolute wall-clock deadline; queued or running past it -> EXPIRED
     deadline_ts: Optional[float] = None

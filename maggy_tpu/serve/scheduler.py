@@ -50,7 +50,7 @@ from maggy_tpu.serve.qos import (
     validate_qos,
 )
 from maggy_tpu.serve.request import Request, SamplingParams
-from maggy_tpu.telemetry import flightrec, timeseries, tracing
+from maggy_tpu.telemetry import NULL, flightrec, timeseries, tracing
 from maggy_tpu.telemetry.alerts import AlertEvaluator, RecompileSentinel
 from maggy_tpu.telemetry.profcap import ProfileCapture
 from maggy_tpu.telemetry.histogram import LatencyHistogram
@@ -379,13 +379,12 @@ class Scheduler:
                 },
             )
         self.sentinel.observe(self.engine.compile_counts, now, watchdog=wd)
-        transitions = self.alerts.evaluate(now, watchdog=wd)
+        transitions = self.alerts.evaluate(
+            now, watchdog=wd, warmed_up=eng.warmed_up(now)
+        )
         if self.profcap.dump_dir is None and getattr(wd, "dump_dir", None):
             self.profcap.configure(dump_dir=wd.dump_dir)
         self.profcap.tick(transitions, now=now)
-        self.telemetry.gauge(
-            "alerts.firing", len(self.alerts.firing()) + len(self.sentinel.firing())
-        )
 
     def stats(self) -> Dict[str, Any]:
         """One consistent snapshot, built entirely under the scheduler lock.
@@ -530,6 +529,10 @@ class Scheduler:
     def _emit(self, req: Request, token: int, now: float) -> bool:  # guarded-by: _lock
         """Append a generated token; True when the request just finished."""
         req.tokens.append(int(token))
+        # per-token timestamp: the true inter-token gap, pooled over requests
+        if req.token_ts:
+            self.telemetry.histogram("serve.itl_ms", (now - req.token_ts[-1]) * 1e3)
+        req.token_ts.append(now)
         # quota accounting: one windowed decode token against the class
         self.quota.charge(req.qos, 1, now)
         if req.first_token_ts is None:
@@ -840,25 +843,35 @@ class Scheduler:
         while not self._stop.is_set():
             wd.beat("serve.loop")
             now = time.time()
-            self._sweep_active(now)
-            self._maybe_reconfigure()
-            self._admit_ready(now)
+            # one span per phase of an iteration (docs/observability.md
+            # "One timeline"); an iteration with nothing active and nothing
+            # queued records only its wait and its tick
+            with self._lock:
+                queued = bool(self._queue)
+            tel_busy = tel if queued or self.engine.slots.active_count else NULL
+            with tel_busy.span("serve.sweep"):
+                self._sweep_active(now)
+                self._maybe_reconfigure()
+            with tel_busy.span("serve.admit"):
+                self._admit_ready(now)
             if self.autopilot is not None:
                 self.autopilot.maybe_sample(now)
 
-            self._preempt_for_pages()
+            with tel_busy.span("serve.preempt"):
+                self._preempt_for_pages()
             active = self.engine.slots.active_slots()
             if active:
                 t0 = time.perf_counter()
                 out = self.engine.step()
                 dt = time.perf_counter() - t0
                 now = time.time()
-                for slot, token in out.tokens.items():
-                    req = self.engine.slots.get(slot).request
-                    with self._lock:
-                        finished = self._emit(req, token, now)
-                    if finished:
-                        self._release_slot(slot)
+                with tel.span("serve.emit", tokens=len(out.tokens)):
+                    for slot, token in out.tokens.items():
+                        req = self.engine.slots.get(slot).request
+                        with self._lock:
+                            finished = self._emit(req, token, now)
+                        if finished:
+                            self._release_slot(slot)
                 rate = len(out.tokens) / dt if dt > 0 else 0.0
                 with self._lock:
                     self._tok_rate_ema = (
@@ -871,17 +884,19 @@ class Scheduler:
                 # async decode leaves the last dispatch in flight when the
                 # active set empties (its rows all belong to finished
                 # requests); retire it so no device refs linger across idle
-                self.engine.flush()
-                with self._wake:
-                    if not self._queue and not self._stop.is_set():
-                        self._wake.wait(timeout=IDLE_WAIT_S)
+                with tel.span("serve.idle_wait"):
+                    self.engine.flush()
+                    with self._wake:
+                        if not self._queue and not self._stop.is_set():
+                            self._wake.wait(timeout=IDLE_WAIT_S)
 
             with self._lock:
                 tel.gauge("serve.queue_depth", len(self._queue))
             tel.gauge("serve.active_slots", self.engine.slots.active_count)
             if time.time() - last_flush > 1.0:
-                self._retire_old(time.time())
-                self._metrics_tick(time.time(), wd)
-                tel.flush()
+                with tel.span("serve.tick"):
+                    self._retire_old(time.time())
+                    self._metrics_tick(time.time(), wd)
+                    tel.flush()
                 last_flush = time.time()
         tel.flush()

@@ -66,6 +66,10 @@ class Rule:
     objective: float = 0.99  # target attainment; budget = 1 - objective
     # ((window_s, burn_factor), ...) — all windows must exceed their factor
     windows: Tuple[Tuple[float, float], ...] = ((30.0, 2.0), (5.0, 2.0))
+    # not evaluated (and resolved if firing) while the owner reports that it
+    # is still warming up: a latency read in a compile-laden window is the
+    # compiler's, not the program's
+    after_warmup: bool = False
 
     def metrics(self) -> Tuple[str, ...]:
         """Series names this rule reads (flight-recorder dumps embed their
@@ -145,6 +149,7 @@ RULES: Tuple[Rule, ...] = (
         windows=((30.0, 3.0), (5.0, 3.0)),
         severity="warning",
         scope="any",
+        after_warmup=True,
     ),
     Rule(
         name="alert.hbm_headroom",
@@ -265,12 +270,18 @@ class AlertEvaluator:
 
     # ------------------------------------------------------------------- tick
 
-    def evaluate(self, now: Optional[float] = None, watchdog=None) -> List[Dict[str, Any]]:  # thread-entry — ticked from the owning scheduler/router thread
-        """One evaluation pass; returns the transitions (fired/resolved)."""
+    def evaluate(  # thread-entry — ticked from the owning scheduler/router thread
+        self, now: Optional[float] = None, watchdog=None, warmed_up: bool = True
+    ) -> List[Dict[str, Any]]:
+        """One evaluation pass; returns the transitions (fired/resolved).
+        ``warmed_up=False`` (the scheduler passes its engine's verdict)
+        holds every ``after_warmup`` rule at not-firing."""
         ts = now if now is not None else time.time()
         transitions: List[Dict[str, Any]] = []
         for rule in self._rules:
-            if rule.kind == "threshold":
+            if rule.after_warmup and not warmed_up:
+                cond, value = False, None
+            elif rule.kind == "threshold":
                 cond, value = self._eval_threshold(rule, ts)
             else:
                 cond, value = self._eval_burn(rule, ts)

@@ -1,7 +1,7 @@
 """Checked-in metric-name registry.
 
-Every ``gauge``/``counter``/``histogram``/``event`` name the codebase emits
-must appear here — ``tools/check_telemetry_names.py`` (wired into tier-1)
+Every ``gauge``/``counter``/``histogram``/``event``/``span`` name the codebase
+emits must appear here — ``tools/check_telemetry_names.py`` (wired into tier-1)
 walks ``maggy_tpu/`` and fails on any telemetry call whose literal name is
 missing. The failure mode this kills: a typo'd name (``serve.ttft_m``)
 silently splits a series into two, and every dashboard/percentile downstream
@@ -24,7 +24,7 @@ GAUGES = frozenset(
         # training loop (train/trainer.py)
         "step_time_ms",  # host wall clock per step
         "step_time_ms_mean",  # mean over the run, compile step excluded
-        "compile_time_ms",  # first step, synced to cover the XLA compile
+        "compile_time_ms",  # a step whose call traced the program, synced to cover the XLA compile
         "steps_per_sec",
         "tokens_per_sec",
         "mfu_est",  # 6*params FLOPs estimate vs detected chip peak
@@ -47,7 +47,6 @@ GAUGES = frozenset(
         "serve.active_slots",
         "serve.drain_ms",
         "serve.decode_retraces",
-        "serve.prefill_retraces",
         # paged KV cache (serve/paging/, docs/serving.md "Paged KV cache")
         "serve.pages_free",  # allocatable pages left in the pool
         "serve.pages_shared",  # pages aliased by >1 request (prefix reuse)
@@ -95,8 +94,6 @@ GAUGES = frozenset(
         "resilience.membership_epoch",  # current membership epoch
         "resilience.active_slices",  # slices currently in the data mesh
         "resilience.reshape_ms",  # epoch bump -> reshape barrier complete
-        # alerting (telemetry/alerts.py)
-        "alerts.firing",  # alerts currently firing at this scope
     }
 )
 
@@ -189,8 +186,72 @@ HISTOGRAMS = frozenset(
         "tier.swap_in_ms",  # host pack fetch + device scatter on admit
         "tier.spill_ms",  # device gather + host pack write on spill
         "fleet.drain_ms",  # scale-in drain: dispatch stop -> replica retired
+        "serve.itl_ms",  # gap between a request's consecutive tokens (Request.token_ts)
     }
 )
+
+# spans: timed blocks. Each also opens a ``jax.profiler.TraceAnnotation`` of
+# the same name (telemetry/recorder.py), so inside a profiler session it lies
+# on the host plane of the trace, on the device events' clock.
+SPANS = frozenset(
+    {
+        # Trainer.fit step loop (train/trainer.py), one per phase
+        "train.fit_setup",  # fit entry -> first step (resume, ledger, prefetcher)
+        "train.input_wait",  # the loop thread's blocked pull of the next batch
+        "shard_batch",  # host gather + H2D of one batch (prefetcher thread, or inline)
+        "train_step",  # dispatch of the jitted step
+        "train.drain",  # the loop thread waits for the device (metric reads, syncs)
+        "train.checkpoint",  # Checkpointer.save called from the loop
+        # checkpointing (train/checkpoint.py)
+        "checkpoint_save",
+        "checkpoint_restore",
+        "checkpoint_restore_params",
+        # Scheduler._loop_body (serve/scheduler.py), one per phase of an iteration
+        "serve.sweep",  # cancel/deadline eviction + pending reconfigure
+        "serve.admit",  # _admit_ready: queue pop -> prefill -> first token
+        "serve.preempt",  # page growth check, in-flight drain, victim preemption
+        "serve.emit",  # the token loop after a decode step (under the lock)
+        "serve.idle_wait",  # no active slot: flush + wait for a submit
+        "serve.tick",  # per-iteration gauges, ~1 Hz metrics tick + flush
+        # Engine (serve/engine.py)
+        "serve.prefill",
+        "serve.prefix_admit",
+        "serve.kv_admit",
+        "serve.decode_step",
+        "serve.spill",  # device -> host page copy-out (release / preempt victim)
+        "serve.reconfigure",
+        # experiment runtime (core/executors/, core/pod.py)
+        "trial",
+        "train_fn",
+        "await_reservations",
+        "build_context",
+        "data_plane_init",
+        # autotuner (tune/)
+        "tune.static",
+        "tune.measure",
+    }
+)
+
+# device-side scopes: ``jax.named_scope`` names put on what is not a flax
+# module (a module's own name is its scope: ``attn``, ``mlp``, ``attn_norm``,
+# ``mlp_norm``, ``final_norm``, ``lm_head``, ``moe``, ``layers``). They reach
+# the compiled HLO's ``op_name`` metadata and the profiler's device events;
+# the compiled computation is unchanged. Recomputed operations carry
+# ``REMAT_MARKER`` in ``op_name`` (what ``jax.checkpoint`` names the forward
+# it replays in the backward pass).
+SCOPES = (
+    "loss",  # float32 logits -> log-softmax -> masked mean (train/trainer.py)
+    "optimizer",  # optax update + apply, global gradient norm
+    "grad_sync",  # bucketed / ZeRO gradient collectives (train/trainer.py overlap step)
+    "moe.route",  # router logits, top-k, capacity positions, aux losses
+    "moe.dispatch",  # one-hot dispatch of tokens to expert buffers
+    "moe.experts",  # the experts' feed-forward matmuls
+    "moe.combine",  # weighted gather back to tokens
+    "decode_attn",  # page/chunk gather + online softmax over the KV cache
+    "kv_write",  # this step's K/V written into the cache
+    "sample",  # logits -> token (serve/engine.py)
+)
+REMAT_MARKER = "rematted_computation"
 
 # lifecycle events: trace-correlated milestones (telemetry/tracing.py)
 EVENTS = frozenset(
@@ -261,9 +322,10 @@ BY_KIND = {
     "count": COUNTERS,
     "histogram": HISTOGRAMS,
     "event": EVENTS,
+    "span": SPANS,
 }
 
-ALL = GAUGES | COUNTERS | HISTOGRAMS | EVENTS
+ALL = GAUGES | COUNTERS | HISTOGRAMS | EVENTS | SPANS
 
 # ---------------------------------------------------------------- units
 # Every registered name carries a unit so downstream consumers (monitor
@@ -273,7 +335,7 @@ ALL = GAUGES | COUNTERS | HISTOGRAMS | EVENTS
 VALID_UNITS = frozenset({"ms", "count", "bytes", "ratio", "per_s"})
 
 # counters and events are dimensionally counts; histograms are all latency
-# distributions in ms. Gauges are mixed, so each is mapped explicitly —
+# distributions in ms, spans durations in ms. Gauges are mixed, so each is mapped explicitly —
 # adding a gauge means adding its unit here too.
 GAUGE_UNITS = {
     "step_time_ms": "ms",
@@ -297,7 +359,6 @@ GAUGE_UNITS = {
     "serve.active_slots": "count",
     "serve.drain_ms": "ms",
     "serve.decode_retraces": "count",
-    "serve.prefill_retraces": "count",
     "serve.pages_free": "count",
     "serve.pages_shared": "count",
     "serve.pages_hot": "count",
@@ -331,9 +392,8 @@ GAUGE_UNITS = {
     "resilience.membership_epoch": "count",
     "resilience.active_slices": "count",
     "resilience.reshape_ms": "ms",
-    "alerts.firing": "count",
 }
 
 UNITS = {name: "count" for name in COUNTERS | EVENTS}
-UNITS.update({name: "ms" for name in HISTOGRAMS})
+UNITS.update({name: "ms" for name in HISTOGRAMS | SPANS})
 UNITS.update(GAUGE_UNITS)

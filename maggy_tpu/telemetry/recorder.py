@@ -18,7 +18,11 @@ snapshots — mergeable across workers, percentile-ready.
 Two clocks, deliberately: every record carries a wall-clock ``ts``
 (``time.time()``, the common base that lets the exporter merge spans from
 many workers/hosts into one Chrome trace) while durations come from
-``time.perf_counter()`` (monotonic, immune to NTP steps).
+``time.perf_counter()`` (monotonic, immune to NTP steps). A span also opens a
+``jax.profiler.TraceAnnotation`` of the same name and attributes: while a
+profiler session runs (``Trainer.fit(profile_dir=...)``, ``profcap``, the
+benchmark's ``--trace 1``) the span lies on the host plane of the
+``.xplane.pb``, on the device events' clock; outside one it is a flag check.
 
 ``MAGGY_TPU_TELEMETRY=0`` disables recording globally: :func:`get` then
 returns the shared :data:`NULL` no-op recorder, whose ``span`` hands back one
@@ -35,6 +39,8 @@ import time
 import weakref
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from maggy_tpu.core import lockdebug
 from maggy_tpu.telemetry import tracing
@@ -99,11 +105,13 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs) -> Iterator[None]:
-        """Time a block; records wall-clock start + duration on exit."""
+        """Time a block; records wall-clock start + duration on exit, and
+        annotates the profiler's trace with it when one is being taken."""
         ts = time.time()
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(name, **attrs):
+                yield
         finally:
             rec = {
                 "kind": "span",
@@ -247,6 +255,7 @@ class NullTelemetry:
     active = False
     worker = "null"
     role = "null"
+    flight = ()  # the ring a reader of ``Telemetry.flight`` finds: empty
 
     _NULL_CTX = contextlib.nullcontext()
 

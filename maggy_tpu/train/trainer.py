@@ -379,25 +379,27 @@ class Trainer:
                 mutable=["intermediates"],
             )
             aux_dev = collect_aux_losses(mods) / n_manual
-            if is_lm:
-                ll_sum, weight = _lm_loss_parts(logits, batch)
-                w_global = jax.lax.psum(weight, manual)
-                data_dev = -ll_sum / jnp.maximum(w_global, 1.0)
-            else:
-                data_dev = self.loss_fn(logits, batch) / n_manual
+            with jax.named_scope("loss"):
+                if is_lm:
+                    ll_sum, weight = _lm_loss_parts(logits, batch)
+                    w_global = jax.lax.psum(weight, manual)
+                    data_dev = -ll_sum / jnp.maximum(w_global, 1.0)
+                else:
+                    data_dev = self.loss_fn(logits, batch) / n_manual
             return data_dev + aux_dev, (data_dev, aux_dev)
 
         def reduce_bucket(vec, scatter: bool):
             # ICI before DCN: the fast intra-slice hop issues first so the
             # slow cross-slice all-reduce overlaps it (and later buckets'
             # backward) independently
-            if AXIS_DATA in axes_comm:
-                if scatter:
-                    vec = jax.lax.psum_scatter(vec, AXIS_DATA, tiled=True)
-                elif AXIS_DATA in manual:
-                    vec = jax.lax.psum(vec, AXIS_DATA)
-            if AXIS_SLICE in axes_comm:
-                vec = jax.lax.psum(vec, AXIS_SLICE)
+            with jax.named_scope("grad_sync"):
+                if AXIS_DATA in axes_comm:
+                    if scatter:
+                        vec = jax.lax.psum_scatter(vec, AXIS_DATA, tiled=True)
+                    elif AXIS_DATA in manual:
+                        vec = jax.lax.psum(vec, AXIS_DATA)
+                if AXIS_SLICE in axes_comm:
+                    vec = jax.lax.psum(vec, AXIS_SLICE)
             return vec
 
         def train_step(state: TrainState, batch):
@@ -440,14 +442,16 @@ class Trainer:
                     )
                     for name, vec in pflats.items()
                 }
-                updates, new_opt = self.optimizer.update(
-                    gshards, opt_state, pshards
-                )
-                new_shards = optax.apply_updates(pshards, updates)
-                new_flats = {
-                    name: jax.lax.all_gather(v, AXIS_DATA, tiled=True)
-                    for name, v in new_shards.items()
-                }
+                with jax.named_scope("optimizer"):
+                    updates, new_opt = self.optimizer.update(
+                        gshards, opt_state, pshards
+                    )
+                    new_shards = optax.apply_updates(pshards, updates)
+                with jax.named_scope("grad_sync"):
+                    new_flats = {
+                        name: jax.lax.all_gather(v, AXIS_DATA, tiled=True)
+                        for name, v in new_shards.items()
+                    }
                 new_params = overlap.unflatten_buckets(new_flats, plan, params)
                 # shards partition the full (slice-reduced) gradient over
                 # data, so the global sq-norm is the data-psum of local ones
@@ -493,8 +497,9 @@ class Trainer:
                     axis_names=frozenset(manual),
                 )
                 grads, (loss, aux) = fn(state.params, batch)
-                gnorm = optax.global_norm(grads)
-                new_state = state.apply_gradients(grads=grads)
+                with jax.named_scope("optimizer"):
+                    gnorm = optax.global_norm(grads)
+                    new_state = state.apply_gradients(grads=grads)
             return new_state, {
                 "loss": loss,
                 "aux_loss": aux,
@@ -935,14 +940,16 @@ class Trainer:
                 loss, grads, aux = out
             else:
                 (loss, grads), aux = out, jnp.zeros((), jnp.float32)
-            new_state = state.apply_gradients(grads=grads)
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads=grads)
+                gnorm = optax.global_norm(grads)
             # same metric semantics as the dense path: loss = data only,
             # aux_loss = router terms, total = optimized objective
             return new_state, {
                 "loss": loss,
                 "aux_loss": aux,
                 "total_loss": loss + aux,
-                "grad_norm": optax.global_norm(grads),
+                "grad_norm": gnorm,
                 "step": state.step,
             }
 
@@ -966,15 +973,17 @@ class Trainer:
                 logits, mods = state.apply_fn(
                     {"params": params}, *_model_inputs(batch), mutable=["intermediates"]
                 )
-                loss = self.loss_fn(logits, batch)
+                with jax.named_scope("loss"):
+                    loss = self.loss_fn(logits, batch)
                 aux = collect_aux_losses(mods)
                 return loss + aux, (loss, aux)
 
             (total, (loss, aux)), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 state.params
             )
-            new_state = state.apply_gradients(grads=grads)
-            gnorm = optax.global_norm(grads)
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads=grads)
+                gnorm = optax.global_norm(grads)
             return new_state, {
                 "loss": loss,
                 "aux_loss": aux,
@@ -1271,14 +1280,21 @@ class Trainer:
         telemetry, and shares committed knobs through the tune cache keyed
         by workload fingerprint.
 
-        Telemetry: each step records a ``train_step`` span plus
-        ``step_time_ms`` / ``tokens_per_sec`` / ``mfu_est`` gauges into the
-        ambient recorder (:func:`maggy_tpu.telemetry.get`; executors install
-        a per-worker one), and the first step — synced once to cover the XLA
-        compile — lands in ``compile_time_ms``. The prefetcher adds
-        ``input_wait_ms`` (host time blocked waiting for an input batch) and
-        ``prefetch_depth`` (queue occupancy) gauges, plus the ``shard_batch``
-        spans the synchronous path used to record inline. The returned
+        Telemetry: the loop records one span per phase into the ambient
+        recorder (:func:`maggy_tpu.telemetry.get`; executors install a
+        per-worker one) — ``train.fit_setup`` (entry to the first step),
+        ``train.input_wait`` (the blocked pull of the next batch),
+        ``train_step`` (dispatch), ``train.drain`` (every wait for the
+        device), ``train.checkpoint`` — which inside a profiler session lie
+        in its trace beside the device events, plus ``step_time_ms`` /
+        ``tokens_per_sec`` / ``mfu_est`` gauges. A step whose call traced the
+        program (the first of a cold trainer, a rebuild) is synced once to
+        cover the XLA compile and lands in ``compile_time_ms`` instead of
+        ``step_time_ms``; step 0 of a later ``fit`` on a warm trainer is an
+        ordinary step. The prefetcher adds ``input_wait_ms`` (host time
+        blocked waiting for an input batch) and ``prefetch_depth`` (queue
+        occupancy) gauges, plus the ``shard_batch`` spans the synchronous
+        path records inline. The returned
         metrics dict always carries the measured ``steps_per_sec``
         regardless of the telemetry flag. Host wall-clock per later step is
         measured without extra device syncs (dispatch overlaps; the device
@@ -1291,127 +1307,131 @@ class Trainer:
         from maggy_tpu.telemetry import tracing as _tracing
 
         tel = telemetry.get()
-        resumed_from = None
-        skipped = 0
-        if resume is not None:
-            if checkpointer is None:
-                raise ValueError("fit(resume=...) requires a checkpointer")
-            target = (
-                checkpointer.latest_step() if resume == "auto" else int(resume)
-            )
-            if target is not None and target > int(state.step):
-                from maggy_tpu.train.checkpoint import restore_zero_compat
-
-                start = int(state.step)
-                # zero-layout-aware restore: a checkpoint written under a
-                # different zero_stage/bucket/data-width gets its optimizer
-                # state converted (warn-and-reshard) instead of failing on
-                # the flat-vs-dense tree mismatch
-                state = restore_zero_compat(
-                    checkpointer,
-                    state,
-                    step=None if resume == "auto" else target,
-                    live_meta=self.checkpoint_meta(),
+        # entry to the first step is one phase of the timeline: a device
+        # that idles here waits on the ledger, the resume or the prefetcher
+        with tel.span("train.fit_setup"):
+            resumed_from = None
+            skipped = 0
+            if resume is not None:
+                if checkpointer is None:
+                    raise ValueError("fit(resume=...) requires a checkpointer")
+                target = (
+                    checkpointer.latest_step() if resume == "auto" else int(resume)
                 )
-                resumed_from = int(state.step)
-                skipped = resumed_from - start
-                # fast-forward: the interrupted run consumed one batch per
-                # completed step — skip them so the data stream (and the loss
-                # trajectory) continues where it left off. Loaders with a
-                # skip(n) fast path (batch_iterator, NativeBatchLoader)
-                # advance by index; plain generators drain next().
-                from maggy_tpu.train.prefetch import skip_batches
+                if target is not None and target > int(state.step):
+                    from maggy_tpu.train.checkpoint import restore_zero_compat
 
-                skip_batches(data_iter, skipped)
-                tel.count("resilience.auto_resumes")
-                tel.gauge("resumed_step", resumed_from)
-        # num_steps is the TOTAL budget for this fit call; a resumed fit only
-        # executes the remainder
-        num_steps = max(0, num_steps - skipped)
-        # preemption notice -> one final synchronous save + early return;
-        # only armed when there is a checkpointer to save into
-        hook = _preemption.install() if checkpointer is not None else None
-        chaos = _chaos.get()
-        # host-side step base: every in-loop "current step" below derives
-        # from this + the loop index, so nothing int()s the device-resident
-        # state.step (which would drain the dispatch pipeline)
-        step0 = int(state.step)
-        preempted = False
-        metrics = {}
-        profiling = False
-        prof_start = min(profile_steps[0], max(0, num_steps - 2))
-        prof_stop = min(profile_steps[1], num_steps - 1)
-        # capacity ledger (docs/observability.md "Capacity"): the training
-        # tier's HBM accounts — params, optimizer state (the ZeRO shards on
-        # a sharded mesh), and the prefetcher's staged batches — reconciled
-        # against reported device memory on the series-sample cadence
-        from maggy_tpu.telemetry import memtrack as _memtrack
+                    start = int(state.step)
+                    # zero-layout-aware restore: a checkpoint written under a
+                    # different zero_stage/bucket/data-width gets its optimizer
+                    # state converted (warn-and-reshard) instead of failing on
+                    # the flat-vs-dense tree mismatch
+                    state = restore_zero_compat(
+                        checkpointer,
+                        state,
+                        step=None if resume == "auto" else target,
+                        live_meta=self.checkpoint_meta(),
+                    )
+                    resumed_from = int(state.step)
+                    skipped = resumed_from - start
+                    # fast-forward: the interrupted run consumed one batch per
+                    # completed step — skip them so the data stream (and the loss
+                    # trajectory) continues where it left off. Loaders with a
+                    # skip(n) fast path (batch_iterator, NativeBatchLoader)
+                    # advance by index; plain generators drain next().
+                    from maggy_tpu.train.prefetch import skip_batches
 
-        ledger = _memtrack.MemoryLedger()
-        ledger.register("params", _memtrack.array_bytes(state.params))
-        ledger.register("optimizer", _memtrack.array_bytes(state.opt_state))
-        depth = _prefetch_depth(prefetch)
-        prefetcher = None
-        if depth > 0 and num_steps > 0:
-            from maggy_tpu.train.prefetch import DevicePrefetcher
+                    skip_batches(data_iter, skipped)
+                    tel.count("resilience.auto_resumes")
+                    tel.gauge("resumed_step", resumed_from)
+            # num_steps is the TOTAL budget for this fit call; a resumed fit only
+            # executes the remainder
+            num_steps = max(0, num_steps - skipped)
+            # preemption notice -> one final synchronous save + early return;
+            # only armed when there is a checkpointer to save into
+            hook = _preemption.install() if checkpointer is not None else None
+            chaos = _chaos.get()
+            # host-side step base: every in-loop "current step" below derives
+            # from this + the loop index, so nothing int()s the device-resident
+            # state.step (which would drain the dispatch pipeline)
+            step0 = int(state.step)
+            preempted = False
+            metrics = {}
+            profiling = False
+            prof_start = min(profile_steps[0], max(0, num_steps - 2))
+            prof_stop = min(profile_steps[1], num_steps - 1)
+            # capacity ledger (docs/observability.md "Capacity"): the training
+            # tier's HBM accounts — params, optimizer state (the ZeRO shards on
+            # a sharded mesh), and the prefetcher's staged batches — reconciled
+            # against reported device memory on the series-sample cadence
+            from maggy_tpu.telemetry import memtrack as _memtrack
 
-            prefetcher = DevicePrefetcher(
-                data_iter,
-                self.shard_batch,
-                depth=depth,
-                max_items=num_steps,
-                telemetry_recorder=tel,
-                ledger=ledger,
+            ledger = _memtrack.MemoryLedger()
+            ledger.register("params", _memtrack.array_bytes(state.params))
+            ledger.register("optimizer", _memtrack.array_bytes(state.opt_state))
+            depth = _prefetch_depth(prefetch)
+            prefetcher = None
+            if depth > 0 and num_steps > 0:
+                from maggy_tpu.train.prefetch import DevicePrefetcher
+
+                prefetcher = DevicePrefetcher(
+                    data_iter,
+                    self.shard_batch,
+                    depth=depth,
+                    max_items=num_steps,
+                    telemetry_recorder=tel,
+                    ledger=ledger,
+                )
+            window = max(0, int(metrics_window))
+            # autopilot: an in-loop controller fed one sample per step; its
+            # safe-live moves land on the prefetcher depth / metrics window of
+            # THIS run (built lazily at step 0, once the batch signature that
+            # names the workload fingerprint is known)
+            ap = None
+            ap_target = None
+            ap_cfg = None
+            if autopilot is not None and autopilot is not False:
+                from maggy_tpu.autopilot import AutopilotConfig as _ApConfig
+
+                ap_cfg = (
+                    autopilot if isinstance(autopilot, _ApConfig) else _ApConfig()
+                )
+                ap_target = _FitAutopilotTarget(prefetcher, window, trainer=self)
+            ap_wait_total = prefetcher.wait_ms_total if prefetcher is not None else 0.0
+            pending: deque = deque()  # (loop index, in-flight device metrics)
+            ready = None  # newest entry aged OUT of the window: safe to sync
+            last_bcast = -1  # last loop index broadcast (monotonic step guard)
+            fit_t0 = time.perf_counter()
+            tokens_per_batch = 0
+            step_ms_sum = 0.0
+            steps_timed = 0  # steps in step_ms_sum: every one that did not compile
+            # one trace per fit run: every span/gauge the loop records carries
+            # it, and the run's start/end land as lifecycle events — the
+            # training-side analogue of a serving request's lane
+            run_trace = _tracing.new_trace_id()
+            trace_prev = _tracing.current()
+            _tracing.set_current(run_trace)
+            tel.event(
+                "train.run_start", trace=run_trace, num_steps=num_steps,
+                resumed_from=resumed_from, step0=step0,
             )
-        window = max(0, int(metrics_window))
-        # autopilot: an in-loop controller fed one sample per step; its
-        # safe-live moves land on the prefetcher depth / metrics window of
-        # THIS run (built lazily at step 0, once the batch signature that
-        # names the workload fingerprint is known)
-        ap = None
-        ap_target = None
-        ap_cfg = None
-        if autopilot is not None and autopilot is not False:
-            from maggy_tpu.autopilot import AutopilotConfig as _ApConfig
+            # stall watchdog: the loop beats per step; a wedged device/step
+            # dumps the flight recorder (docs/observability.md). The threshold
+            # is far above any healthy step — a long first-step compile only
+            # risks a harmless diagnostic dump.
+            wd = _flightrec.get()
+            wd.begin("train.step", detail=step0)
+            # recompile sentinel + time-series sampling (docs/observability.md):
+            # the jitted step bumps a trace-time counter; a bump without a
+            # deliberate rebuild means XLA silently retraced (usually a drifting
+            # batch shape) and costs a full compile mid-run — alert, don't guess.
+            # The store samples the recorder on its ~1 s tick (one clock compare
+            # per step otherwise).
+            from maggy_tpu.telemetry import timeseries as _timeseries
+            from maggy_tpu.telemetry.alerts import RecompileSentinel as _Sentinel
 
-            ap_cfg = (
-                autopilot if isinstance(autopilot, _ApConfig) else _ApConfig()
-            )
-            ap_target = _FitAutopilotTarget(prefetcher, window, trainer=self)
-        ap_wait_total = prefetcher.wait_ms_total if prefetcher is not None else 0.0
-        pending: deque = deque()  # (loop index, in-flight device metrics)
-        ready = None  # newest entry aged OUT of the window: safe to sync
-        last_bcast = -1  # last loop index broadcast (monotonic step guard)
-        fit_t0 = time.perf_counter()
-        tokens_per_batch = 0
-        step_ms_sum = 0.0
-        # one trace per fit run: every span/gauge the loop records carries
-        # it, and the run's start/end land as lifecycle events — the
-        # training-side analogue of a serving request's lane
-        run_trace = _tracing.new_trace_id()
-        trace_prev = _tracing.current()
-        _tracing.set_current(run_trace)
-        tel.event(
-            "train.run_start", trace=run_trace, num_steps=num_steps,
-            resumed_from=resumed_from, step0=step0,
-        )
-        # stall watchdog: the loop beats per step; a wedged device/step
-        # dumps the flight recorder (docs/observability.md). The threshold
-        # is far above any healthy step — a long first-step compile only
-        # risks a harmless diagnostic dump.
-        wd = _flightrec.get()
-        wd.begin("train.step", detail=step0)
-        # recompile sentinel + time-series sampling (docs/observability.md):
-        # the jitted step bumps a trace-time counter; a bump without a
-        # deliberate rebuild means XLA silently retraced (usually a drifting
-        # batch shape) and costs a full compile mid-run — alert, don't guess.
-        # The store samples the recorder on its ~1 s tick (one clock compare
-        # per step otherwise).
-        from maggy_tpu.telemetry import timeseries as _timeseries
-        from maggy_tpu.telemetry.alerts import RecompileSentinel as _Sentinel
-
-        ts_store = _timeseries.SeriesStore()
-        sentinel = _Sentinel(ts_store, tel, scope="worker", steady=("train_step",))
+            ts_store = _timeseries.SeriesStore()
+            sentinel = _Sentinel(ts_store, tel, scope="worker", steady=("train_step",))
         try:
             for i in range(num_steps):  # hot-loop (tools/check_host_sync.py)
                 wd.beat("train.step", detail=step0 + i)
@@ -1428,10 +1448,11 @@ class Trainer:
                     # matching kill rule raises WorkerLost here
                     chaos.kill(tel.worker, step=step0 + i)
                 if hook is not None and hook.requested():
-                    checkpointer.save(
-                        step0 + i, state, meta=self.checkpoint_meta()
-                    )
-                    checkpointer.wait()
+                    with tel.span("train.checkpoint", step=i, why="preempt"):
+                        checkpointer.save(
+                            step0 + i, state, meta=self.checkpoint_meta()
+                        )
+                        checkpointer.wait()
                     tel.count("resilience.preempt_saves")
                     preempted = True
                     break
@@ -1443,9 +1464,11 @@ class Trainer:
                 if prefetcher is not None:
                     # sharded batches arrive pre-placed; H2D transfer of this
                     # batch overlapped compute of the previous step
-                    sharded = next(prefetcher)
+                    with tel.span("train.input_wait", step=i):
+                        sharded = next(prefetcher)
                 else:
-                    batch = next(data_iter)
+                    with tel.span("train.input_wait", step=i):
+                        batch = next(data_iter)
                     with tel.span("shard_batch", step=i):
                         sharded = self.shard_batch(batch)
                 if ap_target is not None:
@@ -1461,17 +1484,23 @@ class Trainer:
                         getattr(sharded["tokens"], "size", 0)
                     )
                 t0 = time.perf_counter()
+                traces0 = self._step_traces
                 with tel.span("train_step", step=i):
                     state, metrics = self.step(state, sharded)
-                    if i == 0 and tel.active:
-                        # one deliberate sync so the first sample covers the
-                        # XLA compile; later steps stay fully async
+                # the call traced the program (first step of a cold trainer,
+                # a rebuild, a drifting shape): one deliberate sync so the
+                # sample covers the XLA compile. Every other step, step 0 of
+                # a warm trainer's fit included, stays fully async
+                compiled = self._step_traces > traces0
+                if compiled and tel.active:
+                    with tel.span("train.drain", step=i, why="compile"):
                         jax.block_until_ready(metrics)  # sync: ok — compile timing
                 dt_ms = (time.perf_counter() - t0) * 1e3
-                if i == 0:
+                if compiled:
                     tel.gauge("compile_time_ms", dt_ms)
                 else:
                     step_ms_sum += dt_ms
+                    steps_timed += 1
                     tel.gauge("step_time_ms", dt_ms)
                 if self._expect_recompile:
                     sentinel.expect("train_step")
@@ -1495,7 +1524,8 @@ class Trainer:
                 while len(pending) > max(1, window):
                     ready = pending.popleft()
                 if profiling and i >= prof_stop:
-                    jax.block_until_ready(metrics)  # sync: ok — trace boundary
+                    with tel.span("train.drain", step=i, why="profile"):
+                        jax.block_until_ready(metrics)  # sync: ok — trace boundary
                     jax.profiler.stop_trace()
                     profiling = False
                     profile_dir = None  # one capture per fit
@@ -1511,7 +1541,8 @@ class Trainer:
                         last_bcast = j
                         tel.gauge("metrics_lag", i - j)
                         t_drain = time.perf_counter()
-                        value = metric_sign * float(lagged[metric_key])  # sync: ok — ref aged out of the window
+                        with tel.span("train.drain", step=i, why="report"):
+                            value = metric_sign * float(lagged[metric_key])  # sync: ok — ref aged out of the window
                         # host time blocked in this read: the per-step
                         # drain cost analyze_trace attributes
                         ap_drain_ms = (time.perf_counter() - t_drain) * 1e3
@@ -1520,9 +1551,10 @@ class Trainer:
                 if checkpointer is not None and checkpoint_every and (
                     (i + 1) % checkpoint_every == 0
                 ):
-                    checkpointer.save(
-                        step0 + i + 1, state, meta=self.checkpoint_meta()
-                    )
+                    with tel.span("train.checkpoint", step=i):
+                        checkpointer.save(
+                            step0 + i + 1, state, meta=self.checkpoint_meta()
+                        )
                 if ap_target is not None:
                     if ap is None:
                         # the first batch names the workload: (model config
@@ -1548,7 +1580,7 @@ class Trainer:
                             telemetry_recorder=tel,
                             workload=workload,
                         )
-                    elif i > 0:  # the compile step would poison the window
+                    elif not compiled:  # a compile step would poison the window
                         # the guard is the TRUE per-step rate — compute plus
                         # the input wait and broadcast drain a move targets
                         wall_ms = dt_ms + step_wait_ms + ap_drain_ms
@@ -1574,7 +1606,8 @@ class Trainer:
             "train.run_end", trace=run_trace, steps=num_steps,
             preempted=preempted,
         )
-        out = {k: float(v) for k, v in metrics.items()}
+        with tel.span("train.drain", why="return"):
+            out = {k: float(v) for k, v in metrics.items()}
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:
@@ -1585,8 +1618,8 @@ class Trainer:
         if num_steps > 0 and wall > 0:
             out["steps_per_sec"] = num_steps / wall
             tel.gauge("steps_per_sec", out["steps_per_sec"])
-            if num_steps > 1 and step_ms_sum > 0:
-                tel.gauge("step_time_ms_mean", step_ms_sum / (num_steps - 1))
+            if steps_timed and step_ms_sum > 0:
+                tel.gauge("step_time_ms_mean", step_ms_sum / steps_timed)
             if tokens_per_batch and tel.active:
                 tok_per_sec = tokens_per_batch * num_steps / wall
                 tel.gauge("tokens_per_sec", tok_per_sec)

@@ -7,11 +7,11 @@ silently mints a second series, and every consumer keyed on the real name
 data. This lint makes the name set closed:
 
 * ``maggy_tpu/telemetry/metrics.py`` is the registry — per-kind frozensets
-  (``GAUGES``/``COUNTERS``/``HISTOGRAMS``/``EVENTS``) plus
+  (``GAUGES``/``COUNTERS``/``HISTOGRAMS``/``EVENTS``/``SPANS``) plus
   ``DYNAMIC_PREFIXES`` for the few f-string names whose tail is a bounded
   runtime enum (request terminal states, RPC verbs).
 * This tool AST-walks ``maggy_tpu/`` for ``.gauge(`` / ``.count(`` /
-  ``.histogram(`` / ``.event(`` calls on telemetry-ish receivers (any name
+  ``.histogram(`` / ``.event(`` / ``.span(`` calls on telemetry-ish receivers (any name
   in the receiver chain containing ``tel`` — ``tel``, ``telemetry``,
   ``self.telemetry``, ``telemetry.get()`` — so ``str.count`` is never
   flagged) and checks:
@@ -53,7 +53,7 @@ from analysis import (  # noqa: E402
     walk_sources,
 )
 
-TELEMETRY_METHODS = ("gauge", "count", "histogram", "event")
+TELEMETRY_METHODS = ("gauge", "count", "histogram", "event", "span")
 
 
 def load_registry(repo: str):
