@@ -304,30 +304,44 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
 
 def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = ""):
     """Journal which kernel the automatic dispatch chose for this shape as
-    one ``attention.kernel`` event. The dispatch runs at trace time, so
+    one ``attention.kernel`` event; for the flash kernels also the tiles they
+    run at (forward q, k, backward q, k). The dispatch runs at trace time, so
     events count traces (init, forward, a rematerialized backward), never
     steps."""
     from maggy_tpu import telemetry
 
+    attrs = {}
+    if kernel.startswith("flash"):
+        from maggy_tpu.ops.flash import _auto_blocks
+
+        attrs = dict(zip(
+            ("block_q", "block_k", "bwd_block_q", "bwd_block_k"),
+            _auto_blocks(q.shape[1], k.shape[1], segment_ids is not None),
+        ))
     telemetry.get().event(
         "attention.kernel", kernel=kernel, reason=reason,
         q=list(q.shape), kv=list(k.shape), segmented=segment_ids is not None,
+        **attrs,
     )
 
 
 def auto_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     """Pick the fastest correct kernel for the backend/shape: the Pallas flash
     kernel (fwd+bwd) on TPU when the geometry tiles onto the MXU
-    (:func:`flash_tileable`), otherwise the XLA dense path. With the
-    auto-tuned MXU-sized blocks (ops/flash.py ``_auto_blocks``: 512-row q
-    tiles) the kernel won the full train step at every length measured on one
-    v5e in round 2 (2026-07-29) — 66.9k vs 60.7k tok/s at S=1024 and 44.0k vs
-    22.8k at S=8192 against the dense path; the old 128x128 blocks LOST to
-    dense everywhere, so block size is the whole game. On a multi-device mesh
-    the kernel runs per-shard under shard_map (a pallas_call has no GSPMD
-    partitioning rule); incompatible layouts (sp/pp axes, non-divisible
-    batch/heads) take the XLA path. The choice is recorded
-    (:func:`record_attention_kernel`), never silent."""
+    (:func:`flash_tileable`), otherwise the XLA dense path. Tile size is the
+    whole game: with MXU-sized blocks (ops/flash.py ``_auto_blocks``, 512-row
+    q tiles and up) the kernel won the full train step at every length
+    measured on one v5e in round 2 (2026-07-29: 66.9k vs 60.7k tok/s at
+    S=1024, 44.0k vs 22.8k at S=8192 against the dense path), where the old
+    128x128 blocks lost to dense everywhere. With ``segment_ids`` the kernels
+    also leave out the tiles a packed row masks wholly and choose their tiles
+    again (PR 25: forward plus backward 10.7 ms against 14.1 at B 2, S 4,096
+    on the packed4k rows; PERF.md section 6). On a multi-device mesh the
+    kernel runs per-shard under shard_map (a pallas_call has no GSPMD
+    partitioning rule), each shard making its visit table from its own rows;
+    incompatible layouts (sp/pp axes, non-divisible batch/heads) take the XLA
+    path. The choice is recorded (:func:`record_attention_kernel`), never
+    silent."""
     from maggy_tpu.ops.flash import (  # late: avoid import cycle
         flash_attention,
         sharded_flash_attention,

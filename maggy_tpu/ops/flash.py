@@ -2,19 +2,28 @@
 
 Same online-softmax math as :mod:`maggy_tpu.ops.attention`, hand-tiled for the
 MXU. The forward runs grid (batch*heads, q_blocks, k_blocks) with fp32 running
-statistics in VMEM scratch; causal blocks are skipped wholesale and the [S, S]
-score matrix never leaves VMEM tiles. The backward is the standard TPU
-two-kernel split (FlashAttention-2 recurrence): a dQ kernel accumulating over
-KV blocks and a dK/dV kernel accumulating over Q blocks, both recomputing the
-probabilities from the saved per-row log-sum-exp instead of storing them.
-``delta = rowsum(dO * O)`` is recomputed per tile from the O/dO blocks so the
-only extra residual is the [BH, S] LSE (stored in column layout
-``[BH, n_q, block_q, 1]`` so neither direction ever needs a sublane<->lane
-relayout).
+statistics in VMEM scratch; the [S, S] score matrix never leaves VMEM tiles.
+The backward is the standard TPU two-kernel split (FlashAttention-2
+recurrence): a dQ kernel accumulating over KV blocks and a dK/dV kernel
+accumulating over Q blocks, both recomputing the probabilities from the saved
+per-row log-sum-exp instead of storing them. ``delta = rowsum(dO * O)`` is
+recomputed per tile from the O/dO blocks so the only extra residual is the
+[BH, S] LSE (stored in column layout ``[BH, n_q, block_q, 1]`` so neither
+direction ever needs a sublane<->lane relayout).
 
-This makes the kernel a drop-in for the *training* hot path — the gap the
-round-1 verdict called out (training previously fell back to the XLA fused
-dense path, which materializes [B, H, S, S] fp32 logits in HBM).
+**The tile-visit table.** The grid is static (shapes decide it); which of its
+tiles hold an unmasked pair is data. Before each of the three kernels
+``needed_tiles`` marks, for every batch row and outer block, the reduction
+blocks that are not wholly above the causal diagonal and whose range of
+segment ids overlaps the outer block's, and ``visit_bounds`` keeps the first
+and the last of them: a few hundred int32s that reach the kernel by scalar
+prefetch. The body computes only inside first..last (a wholly masked tile
+leaves the accumulators as they were, so results are bit for bit those of
+visiting every tile), and the index maps of the reduction-side operands name
+the nearer end outside it, which is the block already in VMEM, so a step that
+computes nothing copies nothing either. One compiled step serves every
+packing. Calls with no segment ids get the same table from the diagonal
+alone (one row of ``n_outer`` bounds).
 
 Off-TPU the kernels run under the Pallas interpreter so tests run on CPU
 meshes, and shapes that do not tile evenly fall back to
@@ -30,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -51,12 +61,101 @@ def _tile_mask(q_start, k_start, block_q, block_k):
     return (q_start + rows) >= (k_start + cols)
 
 
+# ------------------------------------------------------------- tile-visit table
+
+
+def needed_tiles(segs, *, causal, sq, sk, block_q, block_k):
+    """bool [rows, sq // block_q, sk // block_k]: the tiles that can hold an
+    unmasked pair. A tile is needed when it is not wholly above the causal
+    diagonal and the two blocks' ranges of segment ids (minimum and maximum
+    over the block) overlap. With ids that never decrease along a row that is
+    exactly the tiles with an unmasked pair; with any other ids it is a
+    superset, so no pair is lost. Padding (id 0) closes a packed row, so it
+    is ordered last. ``segs`` is [B, 1, S] (numpy on the host, or traced) or
+    None, which gives one row that every batch row shares."""
+    nq, nk = sq // block_q, sk // block_k
+    need = np.ones((1, nq, nk), bool)
+    if causal:
+        q_last = np.arange(nq)[:, None] * block_q + block_q - 1
+        need = (np.arange(nk)[None, :] * block_k <= q_last)[None]
+    if segs is None:
+        return need
+    xp = np if isinstance(segs, np.ndarray) else jnp
+    ids = xp.where(segs == 0, np.iinfo(np.int32).max, segs)
+    q_ids = ids.reshape(-1, nq, 1, block_q)
+    k_ids = ids.reshape(-1, 1, nk, block_k)
+    return (
+        need
+        & (q_ids.min(-1) <= k_ids.max(-1))
+        & (k_ids.min(-1) <= q_ids.max(-1))
+    )
+
+
+def visit_bounds(segs, outer, **tiles):
+    """int32 [rows * outer blocks * 2], flat for SMEM: the first and the last
+    needed reduction block of every (row, outer block), q blocks outermost
+    (``outer="q"``: forward, dq) or k blocks (``"k"``: dkv); ``tiles`` as
+    ``needed_tiles`` takes them. The kernels visit first..last and nothing
+    else; a row with no needed block reads (0, -1)."""
+    need = needed_tiles(segs, **tiles)
+    if outer == "k":
+        need = need.swapaxes(1, 2)
+    xp = np if isinstance(need, np.ndarray) else jnp
+    first = xp.argmax(need, axis=-1)
+    last = need.shape[-1] - 1 - xp.argmax(need[..., ::-1], axis=-1)
+    last = xp.where(need.any(axis=-1), last, -1)
+    return xp.stack([first, last], axis=-1).astype(xp.int32).reshape(-1)
+
+
+def _bounds_at(bounds_ref, row, outer, n_outer):
+    at = (row * n_outer + outer) * 2
+    return bounds_ref[at], bounds_ref[at + 1]
+
+
+def _visits(bounds_ref, heads, red):
+    """In a kernel: whether reduction step ``red`` of this grid row lies in
+    its first-to-last needed block. A tile outside is wholly masked (above
+    the diagonal, or between two documents), so skipping it leaves every
+    accumulator as it was. ``heads`` grid rows share a table row; 0 means the
+    table has one row (no segment ids)."""
+    row = pl.program_id(0) // heads if heads else 0
+    first, last = _bounds_at(bounds_ref, row, pl.program_id(1), pl.num_programs(1))
+    return (first <= red) & (red <= last)
+
+
+def _resident(bounds_ref, row, outer, n_outer, red):
+    """In an index map: the reduction block to name at grid step ``red``:
+    itself inside the row's first-to-last needed block, else the nearer end,
+    which is the block already in VMEM, so a step that computes nothing
+    copies nothing."""
+    first, last = _bounds_at(bounds_ref, row, outer, n_outer)
+    return jnp.clip(red, first, jnp.maximum(last, first))
+
+
+def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None):
+    """Of the tiles in the forward kernel's grid for a packed host batch
+    (``segment_ids`` [B, S], numpy), the share the kernel visits, at the
+    automatically chosen tile sizes unless others are given. None where the
+    tiles do not divide S."""
+    seg = np.asarray(segment_ids)
+    s = seg.shape[-1]
+    auto = _auto_blocks(s, s, True)
+    block_q, block_k = block_q or auto[0], block_k or auto[1]
+    if s % block_q or s % block_k:
+        return None
+    first, last = visit_bounds(
+        seg.reshape(-1, 1, s), "q", causal=causal, sq=s, sk=s,
+        block_q=block_q, block_k=block_k,
+    ).reshape(-1, 2).T
+    return float((last - first + 1).sum()) / (len(first) * (s // block_k))
+
+
 # --------------------------------------------------------------------- forward
 
 
 def _fwd_kernel(
-    *refs,
-    scale, causal, block_q, block_k, segmented,
+    bounds_ref, *refs,
+    scale, causal, block_q, block_k, segmented, heads,
 ):
     if segmented:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
@@ -75,10 +174,9 @@ def _fwd_kernel(
 
     q_start = qi * block_q
     k_start = ki * block_k
-    # causal: skip blocks strictly above the diagonal (always "needed" otherwise)
-    needed = (k_start <= q_start + block_q - 1) if causal else (ki >= 0)
 
-    @pl.when(needed)
+    # a skipped tile leaves m, l and the accumulator as they were (corr = 1, p = 0)
+    @pl.when(_visits(bounds_ref, heads if segmented else 0, ki))
     def _compute():
         q = q_ref[0]
         k = k_ref[0]
@@ -120,28 +218,53 @@ def _fwd_kernel(
         )
 
 
-def _fwd_call(q, k, v, segs, *, causal, block_q, block_k, group, heads, interpret):
+def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer):
+    """BlockSpecs of one kernel's grid (batch*heads, outer blocks, reduction
+    blocks) with q rows (``outer="q"``: forward, dq) or k rows (``"k"``: dkv)
+    outermost. Every index map also gets the visit bounds (scalar prefetch):
+    the reduction side names ``_resident``'s block, the outer side its own.
+
+    GQA lives in the index map: q-head row i reads KV row i // group, so the
+    repeated [B,S,H,D] K/V never materialize in HBM (review finding r2);
+    segment ids are per (batch, seq) — row i // heads — shared by all heads.
+    They arrive as [B, 1, S]: Mosaic wants a block's last two dims to be
+    (8, 128)-aligned or the array's full extent, which a (1, block) tile of
+    [B, S] is only at B == 1."""
+
+    def at(which, place):
+        def index_map(i, o, r, bounds_ref):
+            if which == outer:
+                return place(i, o)
+            row = i // heads if segmented else 0
+            return place(i, _resident(bounds_ref, row, o, n_outer, r))
+        return index_map
+
+    def spec(shape, which, place):
+        return pl.BlockSpec(shape, at(which, place), memory_space=pltpu.VMEM)
+
+    return dict(
+        q=spec((1, block_q, d), "q", lambda i, b: (i, b, 0)),
+        kv=spec((1, block_k, d), "k", lambda i, b: (i // group, b, 0)),
+        # dk/dv leave per q head; the caller sums each GQA group
+        dkv=spec((1, block_k, d), "k", lambda i, b: (i, b, 0)),
+        lse=spec((1, 1, block_q, 1), "q", lambda i, b: (i, b, 0, 0)),
+        qseg=spec((1, 1, block_q), "q", lambda i, b: (i // heads, 0, b)),
+        kseg=spec((1, 1, block_k), "k", lambda i, b: (i // heads, 0, b)),
+    )
+
+
+def _fwd_call(
+    q, k, v, segs, bounds,
+    *, causal, block_q, block_k, group, heads, interpret,
+):
     bh, sq, d = q.shape
     sk = k.shape[1]
-    grid = (bh, sq // block_q, sk // block_k)
     segmented = segs is not None
-    # GQA lives in the index map: q-head row i reads KV row i // group, so the
-    # repeated [B,S,H,D] K/V never materialize in HBM (review finding r2);
-    # segment ids are per (batch, seq) — row i // heads — shared by all heads.
-    # They arrive as [B, 1, S]: Mosaic wants a block's last two dims to be
-    # (8, 128)-aligned or the array's full extent, which a (1, block) tile of
-    # [B, S] is only at B == 1
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, qi, ki: (i, qi, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, qi, ki: (i // group, ki, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, qi, ki: (i // group, ki, 0), memory_space=pltpu.VMEM),
-    ]
+    sp = _specs(block_q, block_k, d, group, heads, segmented, "q", sq // block_q)
+    in_specs = [sp["q"], sp["kv"], sp["kv"]]
     operands = [q, k, v]
     if segmented:
-        in_specs += [
-            pl.BlockSpec((1, 1, block_q), lambda i, qi, ki: (i // heads, 0, qi), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k), lambda i, qi, ki: (i // heads, 0, ki), memory_space=pltpu.VMEM),
-        ]
+        in_specs += [sp["qseg"], sp["kseg"]]
         operands += [segs, segs]
     return pl.pallas_call(
         functools.partial(
@@ -151,28 +274,27 @@ def _fwd_call(q, k, v, segs, *, causal, block_q, block_k, group, heads, interpre
             block_q=block_q,
             block_k=block_k,
             segmented=segmented,
+            heads=heads,
         ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, qi, ki: (i, qi, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(
-                (1, 1, block_q, 1), lambda i, qi, ki: (i, qi, 0, 0), memory_space=pltpu.VMEM
-            ),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, sq // block_q, sk // block_k),
+            in_specs=in_specs,
+            out_specs=[sp["q"], sp["lse"]],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq // block_q, block_q, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
         compiler_params=_COMPILER_PARAMS,
         name="flash_fwd",
         interpret=interpret,
-    )(*operands)
+    )(bounds, *operands)
 
 
 # -------------------------------------------------------------------- backward
@@ -207,8 +329,8 @@ def _recompute_p_ds(
 
 
 def _dq_kernel(
-    *refs,
-    scale, causal, block_q, block_k, segmented,
+    bounds_ref, *refs,
+    scale, causal, block_q, block_k, segmented, heads,
 ):
     if segmented:
         (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qseg_ref, kseg_ref,
@@ -223,16 +345,13 @@ def _dq_kernel(
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    needed = (k_start <= q_start + block_q - 1) if causal else (ki >= 0)
-
-    @pl.when(needed)
+    @pl.when(_visits(bounds_ref, heads if segmented else 0, ki))
     def _compute():
         k = k_ref[0]
         _, ds = _recompute_p_ds(
             q_ref[0], k, v_ref[0], o_ref[0], do_ref[0], lse_ref[0, 0],
-            scale=scale, causal=causal, q_start=q_start, k_start=k_start,
+            scale=scale, causal=causal,
+            q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
         )
@@ -247,8 +366,8 @@ def _dq_kernel(
 
 
 def _dkv_kernel(
-    *refs,
-    scale, causal, block_q, block_k, segmented,
+    bounds_ref, *refs,
+    scale, causal, block_q, block_k, segmented, heads,
 ):
     if segmented:
         (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qseg_ref, kseg_ref,
@@ -265,18 +384,16 @@ def _dkv_kernel(
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    # causal: a KV block only receives gradient from Q blocks at/after the diagonal
-    needed = (q_start + block_q - 1 >= k_start) if causal else (qi >= 0)
-
-    @pl.when(needed)
+    # a KV block receives gradient only from the Q blocks at or after the
+    # diagonal that share a document with it: its first-to-last needed block
+    @pl.when(_visits(bounds_ref, heads if segmented else 0, qi))
     def _compute():
         q = q_ref[0]
         do = do_ref[0]
         p, ds = _recompute_p_ds(
             q, k_ref[0], v_ref[0], o_ref[0], do, lse_ref[0, 0],
-            scale=scale, causal=causal, q_start=q_start, k_start=k_start,
+            scale=scale, causal=causal,
+            q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
         )
@@ -297,78 +414,61 @@ def _dkv_kernel(
 
 
 def _bwd_call(
-    q, k, v, o, do, lse, segs,
+    q, k, v, o, do, lse, segs, q_bounds, k_bounds,
     *, causal, block_q, block_k, group, heads, interpret,
 ):
+    """``q_bounds``: per q block the k blocks to visit (dq kernel);
+    ``k_bounds``: per k block the q blocks to visit (dkv kernel)."""
     bh, sq, d = q.shape
     sk = k.shape[1]
-    scale = 1.0 / d**0.5
     segmented = segs is not None
-    q_spec = pl.BlockSpec((1, block_q, d), lambda i, qi, ki: (i, qi, 0), memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, block_k, d), lambda i, qi, ki: (i // group, ki, 0), memory_space=pltpu.VMEM)
-    lse_spec = pl.BlockSpec(
-        (1, 1, block_q, 1), lambda i, qi, ki: (i, qi, 0, 0), memory_space=pltpu.VMEM
+    kw = dict(
+        scale=1.0 / d**0.5, causal=causal, block_q=block_q, block_k=block_k,
+        segmented=segmented, heads=heads,
     )
-    in_specs = [q_spec, k_spec, k_spec, q_spec, q_spec, lse_spec]
-    operands = [q, k, v, o, do, lse]
-    if segmented:
-        in_specs += [
-            pl.BlockSpec((1, 1, block_q), lambda i, qi, ki: (i // heads, 0, qi), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k), lambda i, qi, ki: (i // heads, 0, ki), memory_space=pltpu.VMEM),
-        ]
-        operands += [segs, segs]
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, segmented=segmented,
-        ),
-        grid=(bh, sq // block_q, sk // block_k),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
-        name="flash_dq",
-        interpret=interpret,
-    )(*operands)
 
+    def call(kernel, name, outer, grid, out_specs, out_shape, scratch_shapes):
+        sp = _specs(block_q, block_k, d, group, heads, segmented, outer, grid[1])
+        in_specs = [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"], sp["lse"]]
+        operands = [q, k, v, o, do, lse]
+        if segmented:
+            in_specs += [sp["qseg"], sp["kseg"]]
+            operands += [segs, segs]
+        return pl.pallas_call(
+            functools.partial(kernel, **kw),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=in_specs,
+                out_specs=[sp[o] for o in out_specs],
+                scratch_shapes=scratch_shapes,
+            ),
+            out_shape=out_shape,
+            compiler_params=_COMPILER_PARAMS,
+            name=name,
+            interpret=interpret,
+        )(q_bounds if outer == "q" else k_bounds, *operands)
+
+    (dq,) = call(
+        _dq_kernel, "flash_dq", "q", (bh, sq // block_q, sk // block_k),
+        ["q"], [jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
+        [pltpu.VMEM((block_q, d), jnp.float32)],
+    )
     # dkv grid: KV blocks outer, Q blocks inner (accumulate across Q). Outputs
     # are per *q-head* ([BH, S, D]); a KV block cannot accumulate across grid-i
     # revisits, so the group sum down to [B*Kh, S, D] happens in the caller.
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda i, ki, qi: (i, qi, 0), memory_space=pltpu.VMEM)
-    k_spec2 = pl.BlockSpec((1, block_k, d), lambda i, ki, qi: (i // group, ki, 0), memory_space=pltpu.VMEM)
-    o_spec2 = pl.BlockSpec((1, block_k, d), lambda i, ki, qi: (i, ki, 0), memory_space=pltpu.VMEM)
-    lse_spec2 = pl.BlockSpec(
-        (1, 1, block_q, 1), lambda i, ki, qi: (i, qi, 0, 0), memory_space=pltpu.VMEM
-    )
-    in_specs2 = [q_spec2, k_spec2, k_spec2, q_spec2, q_spec2, lse_spec2]
-    operands2 = [q, k, v, o, do, lse]
-    if segmented:
-        in_specs2 += [
-            pl.BlockSpec((1, 1, block_q), lambda i, ki, qi: (i // heads, 0, qi), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k), lambda i, ki, qi: (i // heads, 0, ki), memory_space=pltpu.VMEM),
-        ]
-        operands2 += [segs, segs]
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, segmented=segmented,
-        ),
-        grid=(bh, sk // block_k, sq // block_q),
-        in_specs=in_specs2,
-        out_specs=[o_spec2, o_spec2],
-        out_shape=[
+    dk, dv = call(
+        _dkv_kernel, "flash_dkv", "k", (bh, sk // block_k, sq // block_q),
+        ["dkv", "dkv"],
+        [
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
-        scratch_shapes=[
+        [
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
-        name="flash_dkv",
-        interpret=interpret,
-    )(*operands2)
+    )
     return dq, dk, dv
 
 
@@ -382,20 +482,34 @@ def _flash_core(
     (GQA group = H // Kh handled by kernel index maps — the repeated K/V
     never exist, in HBM or as residuals). With ``segmented``, a fourth
     [B, 1, S] int32 operand masks attention across packed-sequence
-    boundaries (zero cotangent). Backward tiles are independent of the
-    forward's — the dq/dkv kernels hold 6+ operands per tile, so their VMEM
-    sweet spot can differ (tools/tune_flash.py sweeps both on silicon)."""
+    boundaries (zero cotangent). Each of the three kernels gets its visit
+    bounds, computed here from what the call is given: they are data, so one
+    compiled step serves every packing. Backward tiles are independent of
+    the forward's — the dq/dkv kernels hold 6+ operands per tile, so their
+    VMEM sweet spot can differ (tools/tune_flash.py sweeps both on the chip)."""
 
     kw = dict(causal=causal, block_q=block_q, block_k=block_k, group=group,
               heads=heads, interpret=interpret)
     bwd_kw = dict(kw, block_q=bwd_block_q, block_k=bwd_block_k)
 
+    def bounds(q, k, segs, block_q, block_k, outer):
+        return jnp.asarray(visit_bounds(
+            segs if segmented else None, outer, causal=causal,
+            sq=q.shape[1], sk=k.shape[1], block_q=block_q, block_k=block_k,
+        ))
+
+    def forward(q, k, v, segs):
+        return _fwd_call(
+            q, k, v, segs if segmented else None,
+            bounds(q, k, segs, block_q, block_k, "q"), **kw,
+        )
+
     @jax.custom_vjp
     def core(q, k, v, segs):
-        return _fwd_call(q, k, v, segs if segmented else None, **kw)[0]
+        return forward(q, k, v, segs)[0]
 
     def core_fwd(q, k, v, segs):
-        o, lse = _fwd_call(q, k, v, segs if segmented else None, **kw)
+        o, lse = forward(q, k, v, segs)
         return o, (q, k, v, segs, o, lse)
 
     def core_bwd(res, g):
@@ -409,7 +523,10 @@ def _flash_core(
             lse = lse.reshape(bh_, sq_ // bwd_block_q, bwd_block_q, 1)
         dq, dk_h, dv_h = _bwd_call(
             q, k, v, o, g.astype(o.dtype), lse,
-            segs if segmented else None, **bwd_kw,
+            segs if segmented else None,
+            bounds(q, k, segs, bwd_block_q, bwd_block_k, "q"),
+            bounds(q, k, segs, bwd_block_q, bwd_block_k, "k"),
+            **bwd_kw,
         )
         if group > 1:
             # dkv kernel emits per-q-head grads; sum each GQA group in fp32
@@ -436,13 +553,26 @@ def _pick_divisor(s: int, cap: int) -> int:
     return max(b, 8)
 
 
-def _auto_blocks(sq: int, sk: int) -> tuple:
-    """Largest MXU-friendly tile sizes that divide the sequence. Measured in
-    the full train step on one v5e (round 2, 2026-07-29): 512-row q tiles are
-    ~2.7x faster than the FlashAttention-conventional 128 (66.9k vs 24.6k
-    tok/s at S=1024 — small tiles leave the MXU idle between grid steps);
-    k tiles of 512, widening to 1024 at long S, were best of the sweep."""
-    return _pick_divisor(sq, 512), _pick_divisor(sk, 1024 if sk >= 4096 else 512)
+def _auto_blocks(sq: int, sk: int, segmented: bool = False) -> tuple:
+    """(block_q, block_k, bwd_block_q, bwd_block_k): the tile sizes measured
+    fastest on one v5e that divide the sequence, chosen from what the call
+    can see (the lengths, and whether it carries segment ids).
+
+    Round 2 (2026-07-29, full train step): 512-row q tiles ~2.7x faster than
+    the FlashAttention-conventional 128 (66.9k vs 24.6k tok/s at S=1024 —
+    small tiles leave the MXU idle between grid steps); k tiles of 512,
+    widening to 1024 at long S. PR 25 (``tools/tune_flash.py --packed``, B 2,
+    S 4,096, 32/8 heads of 128, the packed4k rows, with the tile-visit table
+    in): a grid step costs more than the tiles a finer grid skips, so no
+    tile under 512 x 1,024 wins; the forward is fastest at 1,024 x 1,024
+    (3.10 ms a call against 3.36 at 512 x 1,024 and 4.37 at 512 x 512), the
+    backward the same within 0.5% at 512 x 1,024 and 1,024 x 1,024 (7.32,
+    7.28 ms), so it keeps the smaller. Calls with no segment ids keep round
+    2's tiles. PERF.md section 6 has the sweep."""
+    bq, bk = _pick_divisor(sq, 512), _pick_divisor(sk, 1024 if sk >= 4096 else 512)
+    if segmented and sq >= 4096:
+        return _pick_divisor(sq, 1024), bk, bq, bk
+    return bq, bk, bq, bk
 
 
 def _untileable(sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
@@ -485,12 +615,13 @@ def flash_attention(
     segment_ids=None,
 ) -> jax.Array:
     """q [B,S,H,D], k/v [B,S,Kh,D] → [B,S,H,D]. Differentiable (custom VJP).
-    ``block_q``/``block_k`` default to the measured-fastest tiling for the
-    sequence length (``_auto_blocks``); ``bwd_block_q``/``bwd_block_k``
-    default to the forward's and can be tuned independently (the backward
-    kernels carry 6+ operand tiles, so their VMEM sweet spot differs —
-    tools/tune_flash.py). ``segment_ids`` [B, S] masks attention across
-    packed-sequence boundaries in-kernel.
+    The four tile sizes default to the measured-fastest tiling for the
+    sequence lengths and for whether the call is segmented (``_auto_blocks``,
+    swept with tools/tune_flash.py); a forward tile the caller gives is the
+    backward's too unless it gives that as well (the backward kernels carry
+    6+ operand tiles, so their VMEM sweet spot differs). ``segment_ids``
+    [B, S] masks attention across packed-sequence boundaries in-kernel, and
+    the tiles it masks wholly are not visited (the module docstring).
 
     ``interpret`` defaults to the Pallas interpreter off-TPU and the compiled
     kernel on a TPU. Interpreted, a shape that does not tile falls back to
@@ -501,14 +632,16 @@ def flash_attention(
     b, sq, h, d = q.shape
     kh = k.shape[2]
     sk = k.shape[1]
-    auto_q, auto_k = _auto_blocks(sq, sk)
-    block_q = min(block_q, sq) if block_q else auto_q
-    block_k = min(block_k, sk) if block_k else auto_k
-    bwd_block_q = min(bwd_block_q, sq) if bwd_block_q else block_q
-    bwd_block_k = min(bwd_block_k, sk) if bwd_block_k else block_k
+    segmented = segment_ids is not None
+    auto = _auto_blocks(sq, sk, segmented)
+    # a forward tile the caller chose is the backward's too, unless it
+    # chooses that as well
+    bwd_block_q = min(bwd_block_q or block_q or auto[2], sq)
+    bwd_block_k = min(bwd_block_k or block_k or auto[3], sk)
+    block_q = min(block_q or auto[0], sq)
+    block_k = min(block_k or auto[1], sk)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    segmented = segment_ids is not None
     why = _untileable(
         sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
         segmented, compiled=not interpret,

@@ -34,6 +34,10 @@ GAUGES = frozenset(
         # input pipeline (train/prefetch.py)
         "input_wait_ms",
         "prefetch_depth",
+        # of the flash forward grid's tiles for a packed host batch, the share
+        # the kernel visits (ops/flash.py tiles_visited_share; recorded per
+        # batch in the prefetcher's thread by Trainer.fit)
+        "attention.tiles_visited_share",
         # checkpointing (train/checkpoint.py)
         "checkpoint_save_ms",
         # control plane (core/rpc.py, core/pod.py)
@@ -349,6 +353,7 @@ GAUGE_UNITS = {
     "resumed_step": "count",
     "input_wait_ms": "ms",
     "prefetch_depth": "count",
+    "attention.tiles_visited_share": "ratio",
     "checkpoint_save_ms": "ms",
     "heartbeat_rtt_ms": "ms",
     "data_plane_init_ms": "ms",

@@ -764,6 +764,25 @@ class Trainer:
             self._batch_shardings_memo = (key, shardings)
         return shardings
 
+    def _place_packed(self, tel):
+        """``shard_batch`` for ``fit``'s prefetcher thread, which has the host
+        batch in hand: a packed one also records the share of the flash grid's
+        tiles its segment ids leave to visit (``attention.tiles_visited_share``),
+        off the loop thread and with nothing read back from the device."""
+        import numpy as np
+
+        from maggy_tpu.ops.flash import tiles_visited_share
+
+        def put(batch):
+            seg = batch.get("segment_ids") if isinstance(batch, dict) else None
+            if isinstance(seg, np.ndarray) and seg.ndim == 2:
+                share = tiles_visited_share(seg)
+                if share is not None:
+                    tel.gauge("attention.tiles_visited_share", share)
+            return self.shard_batch(batch)
+
+        return put
+
     def shard_batch(self, batch, *, local: bool = False):
         """Place a host batch onto the mesh, batch axis over (data, fsdp).
 
@@ -1376,7 +1395,7 @@ class Trainer:
 
                 prefetcher = DevicePrefetcher(
                     data_iter,
-                    self.shard_batch,
+                    self._place_packed(tel),
                     depth=depth,
                     max_items=num_steps,
                     telemetry_recorder=tel,

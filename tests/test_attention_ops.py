@@ -115,13 +115,17 @@ def test_flash_kernel_matches_reference(causal):
 
 
 def test_flash_auto_blocks():
-    """Default tiles: measured-fastest MXU sizes that divide the sequence."""
+    """Default tiles (forward q, k, backward q, k): the sizes measured fastest
+    on one v5e that divide the sequence."""
     from maggy_tpu.ops.flash import _auto_blocks
 
-    assert _auto_blocks(1024, 1024) == (512, 512)
-    assert _auto_blocks(8192, 8192) == (512, 1024)  # wide k tiles at long S
-    assert _auto_blocks(1280, 1280) == (256, 256)  # halved until they divide
-    assert _auto_blocks(128, 128) == (128, 128)
+    assert _auto_blocks(1024, 1024) == (512, 512, 512, 512)
+    assert _auto_blocks(8192, 8192) == (512, 1024, 512, 1024)  # wide k tiles at long S
+    assert _auto_blocks(1280, 1280) == (256, 256, 256, 256)  # halved until they divide
+    assert _auto_blocks(128, 128) == (128, 128, 128, 128)
+    # segmented calls choose again from the same shapes; every choice divides
+    for s in (128, 1024, 1280, 4096, 8192):
+        assert all(s % b == 0 for b in _auto_blocks(s, s, True))
 
 
 def test_flash_default_blocks_match_reference():
@@ -222,6 +226,205 @@ def test_flash_under_remat():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-2, rtol=2e-2)
 
 
+# ------------------------------------------------ the tile-visit table (PR 25)
+
+_S = 256
+
+
+def _packing(name):
+    """[2, _S] segment ids: the packings the flash kernels' visit table has to
+    be right for. Ids restart at 1 in each row; 0 is the padded tail."""
+    rng = np.random.default_rng(7)
+
+    def row(*docs):
+        ids = np.zeros(_S, np.int32)
+        at = 0
+        for j, n in enumerate(docs):
+            ids[at:at + n] = j + 1
+            at += n
+        return ids
+
+    return np.stack({
+        "one_document": [row(_S), row(_S)],
+        "many_short": [row(*[32] * 8), row(17, 40, 9, 61, 30, 55, 44)],
+        "padded_tail": [row(70, 70, 70), row(200)],
+        "mixed_rows": [row(_S), row(20, 31, 45, 64, 50, 46)],
+        "all_padding_row": [row(), row(100, 100)],
+        # ids that go up and down: the table may only be a superset
+        "not_monotone": [rng.integers(0, 3, _S).astype(np.int32), row(90, 90)[::-1].copy()],
+    }[name])
+
+
+_PACKINGS = ("one_document", "many_short", "padded_tail", "mixed_rows", "all_padding_row", "not_monotone")
+
+
+def _brute_force_tiles(seg, causal, block_q, block_k):
+    """any() over each tile's full mask."""
+    s = seg.shape[1]
+    mask = seg[:, :, None] == seg[:, None, :]
+    if causal:
+        mask &= np.tril(np.ones((s, s), bool))[None]
+    return mask.reshape(-1, s // block_q, block_q, s // block_k, block_k).any(axis=(2, 4))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(32, 64), (64, 32), (128, 128)])
+@pytest.mark.parametrize("packing", _PACKINGS)
+def test_flash_tile_table_against_brute_force(packing, blocks, causal):
+    """``needed_tiles`` (block ranges of ids overlap, not above the diagonal)
+    equals a brute-force any() over each tile's mask for the ids packed rows
+    carry and holds every such tile for any other ids; the bounds the kernels
+    visit hold every needed tile, and every clamped block index lies inside
+    the row's first-to-last needed block."""
+    from maggy_tpu.ops.flash import _resident, needed_tiles, visit_bounds
+
+    seg = _packing(packing)
+    bq, bk = blocks
+    kw = dict(causal=causal, sq=_S, sk=_S, block_q=bq, block_k=bk)
+    truth = _brute_force_tiles(seg, causal, bq, bk)
+    need = needed_tiles(seg.reshape(2, 1, _S), **kw)
+    assert need.shape == truth.shape
+    if packing == "not_monotone":
+        assert (need | ~truth).all()  # a superset: no pair is lost
+    else:
+        np.testing.assert_array_equal(need, truth)
+    # traced ids give the same table as host ids
+    traced = jax.jit(lambda s: needed_tiles(s, **kw))(jnp.asarray(seg).reshape(2, 1, _S))
+    np.testing.assert_array_equal(np.asarray(traced), need)
+
+    for outer, tiles in (("q", need), ("k", need.swapaxes(1, 2))):
+        bounds = visit_bounds(seg.reshape(2, 1, _S), outer, **kw)
+        rows, n_outer, n_red = tiles.shape
+        first, last = bounds.reshape(rows, n_outer, 2).transpose(2, 0, 1)
+        red = np.arange(n_red)
+        visited = (first[..., None] <= red) & (red <= last[..., None])
+        assert (visited | ~tiles).all()
+        if packing != "not_monotone":
+            np.testing.assert_array_equal(visited, tiles)  # needed blocks are contiguous
+        for r in range(rows):
+            for o in range(n_outer):
+                at = [int(_resident(bounds, r, o, n_outer, i)) for i in red]
+                lo, hi = first[r, o], max(last[r, o], first[r, o])
+                assert all(lo <= a <= hi for a in at), (outer, r, o, at)
+                assert all(a == i for a, i in zip(at, red) if visited[r, o, i])
+
+
+def test_flash_tile_table_without_segments_is_the_causal_clamp():
+    from maggy_tpu.ops.flash import needed_tiles, visit_bounds
+
+    kw = dict(sq=_S, sk=_S, block_q=64, block_k=32)
+    assert needed_tiles(None, causal=True, **kw).shape == (1, 4, 8)  # one row for every batch row
+    np.testing.assert_array_equal(
+        visit_bounds(None, "q", causal=True, **kw).reshape(4, 2),
+        [[0, 1], [0, 3], [0, 5], [0, 7]],
+    )
+    np.testing.assert_array_equal(
+        visit_bounds(None, "k", causal=True, **kw).reshape(8, 2),
+        [[0, 3], [0, 3], [1, 3], [1, 3], [2, 3], [2, 3], [3, 3], [3, 3]],
+    )
+    assert needed_tiles(None, causal=False, **kw).all()
+
+
+def _kernels_with_table(q, k, v, do, seg, table_seg, fwd_blocks, bwd_blocks):
+    """o, lse, dq, dk, dv from the three kernels (interpreted), masking by
+    ``seg`` and visiting the tiles ``table_seg`` leaves."""
+    from maggy_tpu.ops import flash
+
+    b, s, h, d = q.shape
+    kh = k.shape[2]
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, d)
+    q, k, v, do = flat(q), flat(k), flat(v), flat(do)
+    segs = jnp.asarray(seg).reshape(b, 1, s)
+    table = jnp.asarray(table_seg).reshape(b, 1, s)
+
+    def bounds(blocks, outer):
+        return flash.visit_bounds(
+            table, outer, causal=True, sq=s, sk=s, block_q=blocks[0], block_k=blocks[1]
+        )
+
+    kw = dict(causal=True, group=h // kh, heads=h, interpret=True)
+    o, lse = flash._fwd_call(
+        q, k, v, segs, bounds(fwd_blocks, "q"),
+        block_q=fwd_blocks[0], block_k=fwd_blocks[1], **kw,
+    )
+    lse_b = lse.reshape(b * h, s // bwd_blocks[0], bwd_blocks[0], 1)
+    grads = flash._bwd_call(
+        q, k, v, o, do, lse_b, segs, bounds(bwd_blocks, "q"), bounds(bwd_blocks, "k"),
+        block_q=bwd_blocks[0], block_k=bwd_blocks[1], **kw,
+    )
+    return [np.asarray(x) for x in (o, lse, *grads)]
+
+
+@pytest.mark.parametrize(
+    "kh,fwd_blocks,bwd_blocks",
+    [(4, (64, 64), (64, 64)), (1, (64, 64), (64, 64)), (1, (32, 128), (64, 32))],
+    ids=["group1", "group4", "group4-bwd-tiles-unlike-fwd"],
+)
+@pytest.mark.parametrize("packing", _PACKINGS)
+def test_flash_skipped_tiles_change_no_bit(packing, kh, fwd_blocks, bwd_blocks):
+    """Output, LSE, dq, dk and dv with the visit table made from the segment
+    ids equal, bit for bit, the same kernels made to visit every causal tile
+    (a table made from ids that are all one document)."""
+    q, k, v = qkv(b=2, s=_S, h=4, kh=kh, d=128, seed=3)
+    do = jax.random.normal(jax.random.key(9), q.shape, q.dtype)
+    seg = _packing(packing)
+    skipping = _kernels_with_table(q, k, v, do, seg, seg, fwd_blocks, bwd_blocks)
+    every = _kernels_with_table(q, k, v, do, seg, np.ones_like(seg), fwd_blocks, bwd_blocks)
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), skipping, every):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    # and the public path, which makes its own table, is those kernels
+    out = flash_attention(
+        q, k, v, causal=True, segment_ids=jnp.asarray(seg), block_q=fwd_blocks[0],
+        block_k=fwd_blocks[1], bwd_block_q=bwd_blocks[0], bwd_block_k=bwd_blocks[1],
+        interpret=True,
+    )
+    np.testing.assert_array_equal(
+        np.asarray(out.transpose(0, 2, 1, 3).reshape(-1, _S, 128)), every[0]
+    )
+
+
+def test_flash_two_packings_one_trace():
+    """The visit table is data: batches of different packing run the jitted
+    function with one trace, each right against the dense reference."""
+    q, k, v = qkv(b=2, s=_S, h=2, d=128, seed=5)
+    traces = []
+
+    @jax.jit
+    def grads(q, k, v, seg):
+        traces.append(1)
+        return jax.grad(
+            lambda q, k, v: (
+                flash_attention(
+                    q, k, v, segment_ids=seg, block_q=64, block_k=64, interpret=True
+                ) ** 2
+            ).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    for packing in ("many_short", "padded_tail", "one_document"):
+        seg = jnp.asarray(_packing(packing))
+        ref = jax.grad(
+            lambda q, k, v: (default_attention(q, k, v, segment_ids=seg) ** 2).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        for a, b in zip(grads(q, k, v, seg), ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-2, rtol=2e-2)
+    assert len(traces) == 1
+
+
+def test_tiles_visited_share():
+    from maggy_tpu.ops.flash import tiles_visited_share
+
+    # 4 q blocks x 4 k blocks of 64: one document visits the 10 causal tiles
+    assert tiles_visited_share(_packing("one_document"), block_q=64, block_k=64) == 10 / 16
+    # four documents of 64 visit the diagonal only; a padded tail is one more document
+    four = np.repeat(np.arange(1, 5, dtype=np.int32), 64)[None]
+    assert tiles_visited_share(four, block_q=64, block_k=64) == 4 / 16
+    assert tiles_visited_share(np.where(four == 4, 0, four), block_q=64, block_k=64) == 4 / 16
+    assert tiles_visited_share(np.ones((1, 200), np.int32), block_q=64, block_k=64) is None
+    assert 0 < tiles_visited_share(_packing("many_short")) <= 1  # the automatic tiles
+
+
 def test_sharded_flash_matches_reference():
     """The shard_map wrap that auto_attention uses on multi-device meshes —
     a pallas_call has no GSPMD partitioning rule, so this is the only legal
@@ -300,6 +503,21 @@ def test_auto_attention_records_its_choice():
     assert event["attrs"]["kernel"] == "xla_dense"
     assert event["attrs"]["reason"] == "backend is cpu"
     assert event["attrs"]["q"] == [1, 128, 2, 128]
+    assert "block_q" not in event["attrs"]  # the dense path has no tiles
+
+
+def test_flash_kernel_event_carries_the_tiles():
+    from maggy_tpu import telemetry
+    from maggy_tpu.models.transformer import record_attention_kernel
+    from maggy_tpu.ops.flash import _auto_blocks
+
+    tel = telemetry.Telemetry(worker="t")
+    q, k, _ = qkv(b=1, s=4096, h=1, d=128)
+    with telemetry.current(tel):
+        record_attention_kernel("flash", q, k, jnp.ones((1, 4096), jnp.int32))
+    (event,) = [e for e in tel.drain_events() if e["name"] == "attention.kernel"]
+    tiles = tuple(event["attrs"][n] for n in ("block_q", "block_k", "bwd_block_q", "bwd_block_k"))
+    assert tiles == _auto_blocks(4096, 4096, True)
 
 
 @pytest.mark.slow

@@ -1,17 +1,23 @@
-"""Flash-attention tile sweep (fwd AND bwd grids) on real hardware.
+"""Flash-attention tile sweep, forward and backward grids, on the chip.
 
-Round-2 found 512-row forward q tiles ~2.7x faster than the conventional
-128; the backward kernels were left on the forward's tiles. This sweeps
-bwd_block_q/bwd_block_k independently on the bench geometry and prints a
-ranked table — run it on the chip, then bake the winner into _auto_blocks'
-backward variant.
+    python tools/tune_flash.py --packed        # the packed train cell's shape
+    python tools/tune_flash.py [--seq 4096]    # the same shape, no segment ids
 
-The tile grid is the autopilot knob registry's ``FLASH_TILE_CHOICES``
-(maggy_tpu/autopilot/knobs.py) — the manual sweep and the Planner's
-compute-bound recommendations draw candidates from the same table, so a
-tile this tool can measure is always one the autopilot may legally plan.
+Both modes time one attention call at B 2, 32 query and 8 key-value heads of
+128 (Mistral-7B's), bfloat16. ``--packed`` takes its segment ids from the
+benchmark's ``packed4k`` mix (``benchmark.traffic``, read only):
+every row of the pool, two rows a call, so a tile choice is timed on the
+packings the cell trains on and printed beside the share of the grid's tiles
+it visits. The forward is timed alone (it runs twice a step under full
+recomputation), the backward as forward plus backward less the forward at
+the same forward tiles. ``_auto_blocks`` in ``maggy_tpu/ops/flash.py``
+returns the winners; PERF.md has the table this printed when they were
+chosen.
 
-    python tools/tune_flash.py [--seq 1024] [--steps 10]
+The candidates are the autopilot knob registry's ``FLASH_TILE_CHOICES``
+(maggy_tpu/autopilot/knobs.py) from 256 up, so a tile this tool can measure
+is one the autopilot may plan. Off the chip it runs two toy choices under the
+Pallas interpreter, to show the command works; its times mean nothing there.
 """
 
 import argparse
@@ -24,64 +30,106 @@ import time
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
 
+def packed_segment_ids(seq_len):
+    """[calls, rows a call, seq_len] int32: the ``packed4k`` pool's batches
+    (ids 1.. in each row, 0 for the padded tail), every row of the mix."""
+    import numpy as np
+
+    from benchmark import traffic
+
+    mix = traffic.load_mix("packed4k")
+    if mix["seq_len"] != seq_len:
+        raise SystemExit(f"--packed runs at the mix's seq_len {mix['seq_len']}")
+    pool, _ = traffic.packed_pool(mix, seed=0, vocab=2)
+    return np.stack([batch["segment_ids"] for batch in pool])
+
+
 def main():
     parser = argparse.ArgumentParser()
-    parser.add_argument("--seq", type=int, default=1024)
-    parser.add_argument("--steps", type=int, default=10)
-    parser.add_argument("--quick", action="store_true")
+    parser.add_argument("--seq", type=int, default=4096)
+    parser.add_argument("--steps", type=int, default=5, help="timed passes over the calls")
+    parser.add_argument("--packed", action="store_true")
     args = parser.parse_args()
-
-    from bench import on_cpu
-
-    cpu = on_cpu()
 
     import jax
     import jax.numpy as jnp
-
-    from maggy_tpu.ops.flash import flash_attention
-
-    # bench-geometry attention shape: d_model 1024, 8 heads -> head_dim 128
-    B, S, H, D = (2, 256, 2, 128) if (cpu or args.quick) else (16, args.seq, 8, 128)
-    q = jax.random.normal(jax.random.key(1), (B, S, H, D), jnp.bfloat16)
-    k = jax.random.normal(jax.random.key(2), (B, S, H, D), jnp.bfloat16)
-    v = jax.random.normal(jax.random.key(3), (B, S, H, D), jnp.bfloat16)
+    import numpy as np
 
     from maggy_tpu.autopilot.knobs import FLASH_TILE_CHOICES
+    from maggy_tpu.ops.flash import _auto_blocks, flash_attention, tiles_visited_share
 
-    cands = [c for c in FLASH_TILE_CHOICES if c <= S] or [S]
-    if cpu or args.quick:
-        cands = cands[:2]
+    toy = jax.default_backend() != "tpu"
+    B, S, H, KH, D = (2, 512, 2, 1, 128) if toy else (2, args.seq, 32, 8, 128)
+    dt = jnp.bfloat16
+    q, do = (jax.random.normal(jax.random.key(i), (B, S, H, D), dt) for i in (1, 4))
+    k, v = (jax.random.normal(jax.random.key(i), (B, S, KH, D), dt) for i in (2, 3))
+    if args.packed and not toy:
+        segs = packed_segment_ids(S)
+    elif args.packed:
+        segs = np.repeat(np.arange(1, 5, dtype=np.int32), S // 4)[None, None].repeat(B, 1)
+    else:
+        segs = [None]
+    calls = [None if s is None else jnp.asarray(s) for s in segs]
+    cands = [c for c in FLASH_TILE_CHOICES if 256 <= c <= S] or [S]
+    pairs = list(itertools.product(cands, cands))[: 2 if toy else None]
 
-    def time_one(bq, bk, bbq, bbk):
-        def loss(q, k, v):
-            o = flash_attention(
-                q, k, v, causal=True, block_q=bq, block_k=bk,
-                bwd_block_q=bbq, bwd_block_k=bbk,
-            )
-            return (o.astype(jnp.float32) ** 2).sum()
+    def attend(tiles, q, k, v, seg):
+        bq, bk, bbq, bbk = tiles
+        return flash_attention(
+            q, k, v, causal=True, block_q=bq, block_k=bk,
+            bwd_block_q=bbq, bwd_block_k=bbk, segment_ids=seg,
+        )
 
-        g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        jax.block_until_ready(g(q, k, v))  # compile
+    def time_ms(fn, *arrays):
+        """Milliseconds a call, over every call of the pool ``--steps`` times."""
+        jax.block_until_ready([fn(seg, *arrays) for seg in calls])  # compile, warm
         t0 = time.perf_counter()
         for _ in range(args.steps):
-            out = g(q, k, v)
+            out = [fn(seg, *arrays) for seg in calls]
         jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / args.steps * 1e3
+        return (time.perf_counter() - t0) / (args.steps * len(calls)) * 1e3
 
-    rows = []
-    fwd_best = (512 if 512 in cands else cands[-1], 512 if 512 in cands else cands[-1])
-    for bbq, bbk in itertools.product(cands, cands):
-        try:
-            ms = time_one(fwd_best[0], fwd_best[1], bbq, bbk)
-            rows.append({"bwd_block_q": bbq, "bwd_block_k": bbk, "ms": round(ms, 3)})
-            print(f"bwd ({bbq:4d},{bbk:4d}): {ms:8.3f} ms")
-        except Exception as e:  # noqa: BLE001 - a tile that fails to lower is data
-            print(f"bwd ({bbq:4d},{bbk:4d}): FAILED {type(e).__name__}")
-    rows.sort(key=lambda r: r["ms"])
+    def forward(tiles):
+        return time_ms(jax.jit(lambda seg, q, k, v: attend(tiles, q, k, v, seg)), q, k, v)
+
+    def forward_backward(tiles):
+        def f(seg, q, k, v, do):
+            _, pull = jax.vjp(lambda q, k, v: attend(tiles, q, k, v, seg), q, k, v)
+            return pull(do)
+
+        return time_ms(jax.jit(f), q, k, v, do)
+
+    def visited(bq, bk):
+        if calls[0] is None:
+            return None
+        shares = [tiles_visited_share(s, block_q=bq, block_k=bk) for s in segs]
+        return round(float(np.mean(shares)), 4)
+
+    def sweep(what, run, tiles_of):
+        rows = []
+        for bq, bk in pairs:
+            try:
+                ms = run(tiles_of(bq, bk))
+            except Exception as e:  # noqa: BLE001 - a tile that fails to lower is data
+                print(f"{what} ({bq:4d},{bk:4d}): FAILED {type(e).__name__}: {str(e)[:120]}")
+                continue
+            rows.append({"block_q": bq, "block_k": bk, "ms": round(ms, 4), "visited": visited(bq, bk)})
+            print(f"{what} ({bq:4d},{bk:4d}): {ms:8.4f} ms a call, visits {rows[-1]['visited']}")
+        return sorted(rows, key=lambda r: r["ms"])
+
+    fwd = sweep("fwd", forward, lambda bq, bk: (bq, bk, bq, bk))
+    best = (fwd[0]["block_q"], fwd[0]["block_k"])
+    both = sweep("fwd+bwd", forward_backward, lambda bq, bk: best + (bq, bk))
+    for r in both:
+        r["bwd_ms"] = round(r["ms"] - fwd[0]["ms"], 4)
+    auto = _auto_blocks(S, S, args.packed)
     print(json.dumps({
-        "geometry": f"B={B} S={S} H={H} D={D}",
-        "fwd_tiles": fwd_best,
-        "ranking": rows[:5],
+        "geometry": f"B={B} S={S} H={H} KH={KH} D={D} packed={args.packed} calls={len(calls)}",
+        "forward": fwd,
+        "forward_tiles_under_backward": best,
+        "forward_plus_backward": both,
+        "auto_blocks": auto,
+        "auto_ms": {"fwd": round(forward(auto), 4), "fwd+bwd": round(forward_backward(auto), 4)},
         "device": str(jax.devices()[0]),
     }))
 
