@@ -6,6 +6,14 @@ top-k routing builds one-hot dispatch/combine tensors with a static per-expert
 capacity, expert FFNs are a single batched einsum over parameters laid out
 [experts, ...] and sharded on the ``expert`` mesh axis, so XLA inserts the
 token all-to-alls and the whole layer stays static-shaped for the MXU.
+
+The second form (``MoEConfig.experts_held`` > 0; DeepSeek-V3's layer as
+``glm4_moe_lite`` keeps it) is :class:`ExpertShareBlock`: a sigmoid router
+over all the published experts, a shared expert beside them, and the chip's
+share of the routed ones computed dropless by grouped matrix products over the
+slots that exist. The stack takes a layer pattern: ``n_dense_layers`` leading
+dense layers, then the expert layers under the scan, then (``mtp_depth``) the
+multi-token-prediction module.
 """
 
 from __future__ import annotations
@@ -16,15 +24,19 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from maggy_tpu.models.transformer import (
     REMAT_POLICIES,
-    Attention,
     DecoderConfig,
+    MLPBlock,
     RMSNorm,
     _dense,
     _parse_ablated,
     _partitioned,
+    _ScannedGatedLayer,
+    _ScannedLayer,
+    attention_module,
 )
 
 
@@ -37,6 +49,55 @@ class MoEConfig(DecoderConfig):
     # tokens are routed in fixed-size groups so the dispatch one-hot is
     # O(tokens * group_size), not O(tokens^2) — the GShard group axis
     group_size: int = 512
+    # the layer pattern: n_layers counts n_dense_layers leading dense layers
+    # (d_ff wide) and then the expert layers
+    n_dense_layers: int = 0
+    # the dropless share form (ExpertShareBlock) when > 0: of n_experts this
+    # chip holds experts_held, the share numbered expert_offset (experts
+    # [expert_offset * experts_held, (expert_offset + 1) * experts_held)).
+    # Under an ``expert`` mesh axis the two numbers would be the axis's size
+    # and index; the exchange across chips is not written yet
+    experts_held: int = 0
+    expert_offset: int = 0
+    moe_d_ff: int = 0  # width of one routed or shared expert
+    n_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    # the router's selection bias (``noaux_tc``): enters top-k only, gets no
+    # gradient, and is a constant here — N(0, select_bias_std) from
+    # select_bias_seed, a row a layer (its update between steps is per-step
+    # state outside the optimizer, which the program does not have)
+    select_bias_std: float = 0.0
+    select_bias_seed: int = 0
+    # multi-token prediction (DeepSeek-V3 section 2.2), depth 0 or 1: one more
+    # expert layer predicts the token two ahead through the shared embedding
+    # and head; Trainer adds mtp_weight times its loss
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.experts_held:
+            if self.n_experts % self.experts_held or not (
+                0 <= self.expert_offset < self.n_experts // self.experts_held
+            ):
+                raise ValueError(
+                    "experts_held must divide n_experts and expert_offset "
+                    "number one of the shares"
+                )
+            if not self.moe_d_ff:
+                raise ValueError("the share form needs moe_d_ff")
+            if self.ablated:
+                raise ValueError("the share form has no LOCO gates")
+        if not 0 <= self.n_dense_layers < self.n_layers:
+            raise ValueError("n_dense_layers must leave an expert layer")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError("mtp_depth is 0 or 1")
+
+    def select_bias(self) -> np.ndarray:
+        """[expert layers (+ the MTP module's), n_experts] float32."""
+        rows = self.n_layers - self.n_dense_layers + self.mtp_depth
+        rng = np.random.default_rng(self.select_bias_seed)
+        return (self.select_bias_std * rng.standard_normal((rows, self.n_experts))).astype(np.float32)
 
     @classmethod
     def mixtral_8x7b(cls, **overrides) -> "MoEConfig":
@@ -181,51 +242,213 @@ class MoEBlock(nn.Module):
         return y
 
 
+@jax.custom_vjp
+def _to_slots(x, order, inv, held):
+    """Rows of ``x`` [T, d] in slot order: row ``i`` is the token of slot
+    ``order[i]`` (slot ``t * k + j`` is token ``t``'s choice ``j``). A gather
+    forward and, with ``inv`` the inverse permutation, a gather backward too
+    (a scatter-add by token otherwise); slots on experts not held bring no
+    gradient back, whatever the products left in their rows."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _to_slots_fwd(x, order, inv, held):
+    return _to_slots(x, order, inv, held), (inv, held, x.shape[0])
+
+
+def _to_slots_bwd(res, g):
+    inv, held, t = res
+    g = jnp.where(held[:, None], g[inv], 0).reshape(t, -1, g.shape[-1])
+    return g.astype(jnp.float32).sum(1).astype(g.dtype), None, None, None
+
+
+_to_slots.defvjp(_to_slots_fwd, _to_slots_bwd)
+
+
+@jax.custom_vjp
+def _from_slots(y, order, inv, held):
+    """The inverse: rows of ``y`` (slot order) back at ``[T * k, d]`` by
+    token and choice, zero where the slot's expert is not held."""
+    return jnp.where(held[:, None], y[inv], 0)
+
+
+def _from_slots_fwd(y, order, inv, held):
+    return _from_slots(y, order, inv, held), (order, inv, held)
+
+
+def _from_slots_bwd(res, g):
+    order, inv, held = res
+    return jnp.where(held[:, None], g, 0)[order], None, None, None
+
+
+_from_slots.defvjp(_from_slots_fwd, _from_slots_bwd)
+
+
+def sigmoid_route(logits, select_bias, top_k: int, scaling: float):
+    """DeepSeek-V3's router (``noaux_tc`` without a group limit) from float32
+    logits [..., n_experts]: ``(sel [..., k] expert numbers, weights [..., k])``
+    with ``s = sigmoid(logits)``, ``sel = top_k(s + select_bias)`` (the bias
+    enters the selection only and gets no gradient) and the chosen scores
+    normalised over themselves and scaled."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    biased = scores if select_bias is None else scores + select_bias
+    _, sel = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)
+    chosen = jnp.take_along_axis(scores, sel, axis=-1)
+    return sel, scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+
+
+class ExpertShareBlock(nn.Module):
+    """One chip's share of a sigmoid-routed expert layer, dropless.
+
+    The router scores all ``n_experts`` in float32: ``s = sigmoid(x W_r)``,
+    ``sel = top_k(s + b)`` (``b`` the selection bias: no gradient),
+    ``w_e = routed_scaling * s_e / (sum of the chosen s + 1e-20)``. The result
+    is ``shared(x) + sum over chosen experts held here of w_e * expert_e(x)``:
+    what the absent experts would add is another chip's part. The (token,
+    choice) slots are sorted by held expert (those on absent experts last),
+    and three grouped products (``jax.lax.ragged_dot``: on a TPU a kernel that
+    visits the row tiles the group sizes cover) run over the slots that exist,
+    not over the buffer, which has a row for every slot (``T * top_k``), so
+    none on a held expert is ever cut: ``slots_dropped`` counts what a
+    smaller buffer would cut. Sows ``expert_load`` ([experts_held] slots an
+    expert) and ``slots_dropped`` for the trainer's step metrics."""
+
+    cfg: MoEConfig
+
+    @nn.compact
+    def __call__(self, x, select_bias=None):
+        cfg = self.cfg
+        b, s, d = x.shape
+        t, k, e, held, f = b * s, cfg.top_k, cfg.n_experts, cfg.experts_held, cfg.moe_d_ff
+        lo = cfg.expert_offset * held
+        tokens = x.reshape(t, d)
+
+        with jax.named_scope("moe.route"):
+            logits = nn.DenseGeneral(
+                features=e, use_bias=False, dtype=jnp.float32,
+                param_dtype=cfg.param_dtype, precision=jax.lax.Precision.HIGHEST,
+                kernel_init=_partitioned(nn.initializers.normal(0.02), ("embed", None), cfg),
+                name="router",
+            )(tokens.astype(jnp.float32))
+            sel, weights = sigmoid_route(logits, select_bias, k, cfg.routed_scaling)  # [t, k]
+
+        with jax.named_scope("moe.dispatch"):
+            local = sel.reshape(t * k) - lo
+            is_held = (local >= 0) & (local < held)
+            key = jnp.where(is_held, local, held)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=jnp.int32), unique_indices=True
+            )
+            load = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+            rows = t * k  # the buffer: a row for every slot, so dropless
+            slots_in = _to_slots(tokens, order, inv, is_held)
+
+        def experts(name, axes, shape):
+            w = self.param(
+                name, _partitioned(nn.initializers.normal(0.02), axes, cfg),
+                shape, cfg.param_dtype,
+            )
+            return jnp.asarray(w, cfg.dtype)
+
+        w_gate = experts("w_gate", ("expert", "embed", "mlp"), (held, d, f))
+        w_up = experts("w_up", ("expert", "embed", "mlp"), (held, d, f))
+        w_down = experts("w_down", ("expert", "mlp", "embed"), (held, f, d))
+        with jax.named_scope("moe.experts"):
+            hidden = nn.silu(jax.lax.ragged_dot(slots_in, w_gate, load)) * jax.lax.ragged_dot(
+                slots_in, w_up, load
+            )
+            slots_out = jax.lax.ragged_dot(hidden, w_down, load)
+
+        with jax.named_scope("moe.combine"):
+            routed = _from_slots(slots_out, order, inv, is_held).reshape(t, k, d)
+            y = jnp.einsum("tkd,tk->td", routed, weights.astype(routed.dtype))
+
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                y = y + MLPBlock(
+                    dataclasses.replace(cfg, d_ff=f * cfg.n_shared_experts),
+                    name="shared",
+                )(tokens)
+        self.sow("intermediates", "expert_load", load)
+        self.sow("intermediates", "slots_dropped", jnp.maximum(load.sum() - rows, 0))
+        return y.reshape(b, s, d)
+
+
 class MoELayer(nn.Module):
     cfg: MoEConfig
 
     @nn.compact
-    def __call__(self, x, positions, segment_ids=None, gates=None):
+    def __call__(self, x, positions, segment_ids=None, gates=None, select_bias=None):
         """``gates`` — optional [2] float (attn, moe) LOCO ablation gates,
         same semantics as DecoderLayer (zero gate = identity residual,
         zero grads, unchanged param tree). The gate also scales the sown
         router aux loss — an ablated expert block must not keep pushing
-        balancing gradients into its router."""
-        a = Attention(self.cfg, name="attn")(
+        balancing gradients into its router. ``select_bias`` — the share
+        form's [n_experts] selection bias of this layer."""
+        a = attention_module(self.cfg)(self.cfg, name="attn")(
             RMSNorm(self.cfg, name="attn_norm")(x), positions, segment_ids
         )
         x = x + (a if gates is None else a * gates[0].astype(a.dtype))
+        xn = RMSNorm(self.cfg, name="mlp_norm")(x)
+        if self.cfg.experts_held:
+            return x + ExpertShareBlock(self.cfg, name="moe")(xn, select_bias)
         m = MoEBlock(self.cfg, name="moe")(
-            RMSNorm(self.cfg, name="mlp_norm")(x),
-            aux_gate=None if gates is None else gates[1],
+            xn, aux_gate=None if gates is None else gates[1]
         )
         x = x + (m if gates is None else m * gates[1].astype(m.dtype))
         return x
 
 
+def _remat(cls, cfg):
+    """``cls`` recomputed in the backward pass where the configuration asks
+    for it (no gradients, hence no remat, in decode)."""
+    if cfg.remat and not cfg.decode:
+        return nn.remat(
+            cls, prevent_cse=not cfg.scan_layers, policy=REMAT_POLICIES[cfg.remat_policy]
+        )
+    return cls
+
+
 class _ScannedMoELayer(nn.Module):
-    cfg: MoEConfig
-
-    @nn.compact
-    def __call__(self, x, positions, segment_ids=None):
-        return MoELayer(self.cfg, name="layer")(x, positions, segment_ids), None
-
-
-class _ScannedGatedMoELayer(nn.Module):
-    """Scan body when LOCO gates are active (gates ride in_axes=0)."""
+    """Scan body. ``per_layer`` holds what rides the scan's in_axes=0: the
+    LOCO ``gates`` and the share form's ``select_bias``, each where there is
+    one."""
 
     cfg: MoEConfig
 
     @nn.compact
-    def __call__(self, x, positions, gates, segment_ids=None):
+    def __call__(self, x, positions, per_layer, segment_ids=None):
         return MoELayer(self.cfg, name="layer")(
-            x, positions, segment_ids, gates
+            x, positions, segment_ids, **per_layer
         ), None
+
+
+class MTPModule(nn.Module):
+    """Multi-token prediction, depth 1 (DeepSeek-V3 section 2.2): position
+    ``i``'s last hidden state (before the final norm) and the embedding of
+    token ``i+1``, each normed, concatenated and projected back to the model's
+    width, go through one more expert layer and a final norm of its own. The
+    caller applies the shared head: the logits predict token ``i+2``."""
+
+    cfg: MoEConfig
+
+    @nn.compact
+    def __call__(self, h, next_embed, positions, segment_ids, per_layer):
+        cfg = self.cfg
+        x = jnp.concatenate(
+            [RMSNorm(cfg, name="enorm")(next_embed), RMSNorm(cfg, name="hnorm")(h)], axis=-1
+        )
+        x = _dense(cfg.d_model, (None, "embed"), cfg, "eh_proj")(x)
+        x, _ = _remat(_ScannedMoELayer, cfg)(cfg, name="block")(x, positions, per_layer, segment_ids)
+        return RMSNorm(cfg, name="final_norm")(x)
 
 
 class MoEDecoder(nn.Module):
     """Sparse-MoE causal LM; same interface as
-    :class:`maggy_tpu.models.transformer.Decoder`."""
+    :class:`maggy_tpu.models.transformer.Decoder`. With ``mtp_depth`` it also
+    sows ``mtp_logits`` (float32, predicting the token two ahead) for the
+    trainer's loss."""
 
     cfg: MoEConfig
 
@@ -242,42 +465,52 @@ class MoEDecoder(nn.Module):
             (cfg.vocab_size, cfg.d_model),
             cfg.param_dtype,
         )
-        x = jnp.asarray(embed, cfg.dtype)[tokens]
+        embed = jnp.asarray(embed, cfg.dtype)
+        x = embed[tokens]
 
+        n_dense, n_moe = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
         gates = _parse_ablated(cfg.ablated, cfg.n_layers)
-        layer_cls = _ScannedMoELayer if gates is None else _ScannedGatedMoELayer
-        if cfg.remat and not cfg.decode:  # no gradients (hence no remat) in decode
-            layer_cls = nn.remat(
-                layer_cls,
-                prevent_cse=not cfg.scan_layers,
-                policy=REMAT_POLICIES[cfg.remat_policy],
-            )
+        bias = cfg.select_bias() if cfg.experts_held and cfg.select_bias_std else None
+
+        def per_layer(rows):
+            """What a run of expert layers takes from the scan's leading axis."""
+            out = {}
+            if gates is not None:
+                out["gates"] = jnp.asarray(gates[n_dense:][rows])
+            if bias is not None:
+                out["select_bias"] = jnp.asarray(bias[rows])
+            return out
+
+        # the leading dense layers, unrolled: there are few of them
+        for i in range(n_dense):
+            if gates is None:
+                x, _ = _remat(_ScannedLayer, cfg)(cfg, name=f"dense_{i}")(x, positions, segment_ids)
+            else:
+                x, _ = _remat(_ScannedGatedLayer, cfg)(cfg, name=f"dense_{i}")(
+                    x, positions, jnp.asarray(gates[i]), segment_ids
+                )
+        layer_cls = _remat(_ScannedMoELayer, cfg)
         if cfg.scan_layers:
-            scanned = nn.scan(
+            x, _ = nn.scan(
                 layer_cls,
                 variable_axes={"params": 0, "intermediates": 0, "cache": 0},
                 split_rngs={"params": True},
-                in_axes=(
-                    (nn.broadcast, nn.broadcast)
-                    if gates is None
-                    else (nn.broadcast, 0, nn.broadcast)
-                ),
-                length=cfg.n_layers,
+                in_axes=(nn.broadcast, 0, nn.broadcast),
+                length=n_moe,
                 metadata_params={nn.PARTITION_NAME: None},
-            )(cfg, name="layers")
-            if gates is None:
-                x, _ = scanned(x, positions, segment_ids)
-            else:
-                x, _ = scanned(x, positions, jnp.asarray(gates), segment_ids)
+            )(cfg, name="layers")(x, positions, per_layer(slice(0, n_moe)), segment_ids)
         else:
-            for i in range(cfg.n_layers):
-                if gates is None:
-                    x, _ = layer_cls(cfg, name=f"layers_{i}")(x, positions, segment_ids)
-                else:
-                    x, _ = layer_cls(cfg, name=f"layers_{i}")(
-                        x, positions, jnp.asarray(gates[i]), segment_ids
-                    )
+            for i in range(n_moe):
+                x, _ = layer_cls(cfg, name=f"layers_{i}")(x, positions, per_layer(i), segment_ids)
 
-        x = RMSNorm(cfg, name="final_norm")(x)
-        logits = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, "lm_head")(x)
+        head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, "lm_head")
+        logits = head(RMSNorm(cfg, name="final_norm")(x))
+        if cfg.mtp_depth:
+            # token i+1's embedding beside position i; the row's last position
+            # wraps and is never a target's predictor (its target is masked)
+            mtp = MTPModule(cfg, name="mtp")(
+                x, embed[jnp.roll(tokens, -1, axis=1)], positions, segment_ids,
+                per_layer(n_moe),
+            )
+            self.sow("intermediates", "mtp_logits", head(mtp).astype(jnp.float32))
         return logits.astype(jnp.float32)

@@ -142,13 +142,39 @@ class DecoderConfig:
     # ablated sublayers contribute nothing and receive zero gradients);
     # grammar in _parse_ablated, usually set via cfg.without(...)
     ablated: Any = frozenset()
+    # latent attention (DeepSeek-V2 MLA, as glm4_moe_lite keeps it): with
+    # kv_lora_rank > 0 the layers' attention is LatentAttention — low-rank
+    # query and key-value paths with an inner norm each, rope on a
+    # qk_rope_head_dim-wide part whose key is one head shared by all, and
+    # heads of qk_nope_head_dim + qk_rope_head_dim (= v_head_dim), whatever
+    # d_model / n_heads is. n_kv_heads is n_heads there
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_heads
 
     def __post_init__(self):
-        if self.d_model % self.n_heads:
+        if self.kv_lora_rank:
+            if not self.q_lora_rank or self.n_kv_heads != self.n_heads:
+                raise ValueError(
+                    "latent attention needs q_lora_rank and n_kv_heads == n_heads"
+                )
+            if self.v_head_dim != self.head_dim or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention takes v_head_dim == qk_nope_head_dim + "
+                    "qk_rope_head_dim (one width through the kernels) and an "
+                    "even qk_rope_head_dim"
+                )
+            if self.decode:
+                raise ValueError("latent attention has no decode cache yet")
+        elif self.d_model % self.n_heads:
             raise ValueError("d_model must be divisible by n_heads")
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be divisible by n_kv_heads")
@@ -292,7 +318,11 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
     """Why the automatic dispatch keeps a shape off the Pallas flash kernel
     (None: it tiles). head_dim must fill the 128 lanes and both sequence
     lengths be multiples of the 128 block, which is what guarantees that
-    ``ops/flash.py``'s auto-chosen tiles are ones Mosaic can compile."""
+    ``ops/flash.py``'s auto-chosen tiles are ones Mosaic can compile. Any
+    multiple of 128 is admitted; 128 (every dense model) and 256 (latent
+    attention's 192 + 64, PR 26: B 2, S 8,192, 20 heads, compiled and run on
+    one v5e, where 1,024 x 1,024 tiles do not fit VMEM and ``_auto_blocks``
+    chooses others) have run; wider heads have not."""
     if jax.default_backend() != "tpu":
         return f"backend is {jax.default_backend()}"
     if d % 128:
@@ -316,7 +346,7 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = ""):
 
         attrs = dict(zip(
             ("block_q", "block_k", "bwd_block_q", "bwd_block_k"),
-            _auto_blocks(q.shape[1], k.shape[1], segment_ids is not None),
+            _auto_blocks(q.shape[1], k.shape[1], segment_ids is not None, q.shape[3]),
         ))
     telemetry.get().event(
         "attention.kernel", kernel=kernel, reason=reason,
@@ -336,7 +366,11 @@ def auto_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     128x128 blocks lost to dense everywhere. With ``segment_ids`` the kernels
     also leave out the tiles a packed row masks wholly and choose their tiles
     again (PR 25: forward plus backward 10.7 ms against 14.1 at B 2, S 4,096
-    on the packed4k rows; PERF.md section 6). On a multi-device mesh the
+    on the packed4k rows; PERF.md section 6). Heads of width 256 (PR 26,
+    latent attention at B 2, S 8,192, 20 query and key heads, the packed8k
+    rows) take the same kernels at tiles chosen for the width: forward 7.5 ms
+    and backward 20.1 ms a call on one v5e, a quarter of that model's train
+    step. On a multi-device mesh the
     kernel runs per-shard under shard_map (a pallas_call has no GSPMD
     partitioning rule), each shard making its visit table from its own rows;
     incompatible layouts (sp/pp axes, non-divisible batch/heads) take the XLA
@@ -679,6 +713,66 @@ class Attention(nn.Module):
             return ops_attn.finalize(acc, l, q.dtype)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention, training form (module docstring of
+    :class:`DecoderConfig`'s ``kv_lora_rank``): ``c_q = norm(x W_qa)``,
+    ``q = c_q W_qb``; ``[c_kv ; k_r] = x W_kva``, ``[k_nope ; v] =
+    norm(c_kv) W_kvb``; each head's query is ``[q_nope ; rope(q_rope)]`` and
+    its key ``[k_nope ; rope(k_r)]`` with the one rope key broadcast over the
+    heads. The heads then go through the same dispatch as :class:`Attention`'s
+    (``auto_attention``: the flash kernels at the head's full width)."""
+
+    cfg: DecoderConfig
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids=None):
+        from jax.ad_checkpoint import checkpoint_name
+
+        cfg = self.cfg
+        h, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        # device-side scopes (telemetry/metrics.py SCOPES): metadata only
+        with jax.named_scope("mla.q"):
+            c_q = RMSNorm(cfg, name="q_norm")(
+                _dense(cfg.q_lora_rank, ("embed", None), cfg, "wq_a")(x)
+            )
+            q = _dense((h, dn + dr), (None, "heads", None), cfg, "wq_b")(c_q)
+        with jax.named_scope("mla.kv"):
+            c_kv = _dense(cfg.kv_lora_rank + dr, ("embed", None), cfg, "wkv_a")(x)
+            kv = _dense(
+                (h, dn + cfg.v_head_dim), (None, "heads", None), cfg, "wkv_b"
+            )(RMSNorm(cfg, name="kv_norm")(c_kv[..., : cfg.kv_lora_rank]))
+        with jax.named_scope("mla.rope"):
+            k_rope = rope(
+                c_kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta
+            )
+            q = jnp.concatenate(
+                [q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta)], axis=-1
+            )
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_rope, (*kv.shape[:-1], dr))],
+                axis=-1,
+            )
+        attn = cfg.attention_fn or auto_attention
+        out = attn(q, k, kv[..., dn:], causal=True, segment_ids=segment_ids)
+        out = checkpoint_name(out, "attn_out")  # as Attention: kept under "dots_attn"
+        return nn.DenseGeneral(
+            features=cfg.d_model,
+            axis=(-2, -1),
+            use_bias=False,
+            dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            kernel_init=_partitioned(
+                nn.initializers.normal(stddev=0.02), ("heads", None, "embed"), cfg
+            ),
+            name="wo",
+        )(out)
+
+
+def attention_module(cfg: DecoderConfig):
+    """The attention class a layer of this configuration takes."""
+    return LatentAttention if cfg.kv_lora_rank else Attention
+
+
 class MLPBlock(nn.Module):
     cfg: DecoderConfig
 
@@ -717,7 +811,7 @@ class DecoderLayer(nn.Module):
         zero gate removes that sublayer's contribution (residual becomes
         identity) and cuts its gradients, with an unchanged param tree.
         ``segment_ids`` — optional [B, S] packed-sequence ids."""
-        a = Attention(self.cfg, name="attn")(
+        a = attention_module(self.cfg)(self.cfg, name="attn")(
             RMSNorm(self.cfg, name="attn_norm")(x), positions, segment_ids
         )
         x = x + (a if gates is None else a * gates[0].astype(a.dtype))

@@ -132,14 +132,14 @@ def _resident(bounds_ref, row, outer, n_outer, red):
     return jnp.clip(red, first, jnp.maximum(last, first))
 
 
-def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None):
+def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None, head_dim=128):
     """Of the tiles in the forward kernel's grid for a packed host batch
     (``segment_ids`` [B, S], numpy), the share the kernel visits, at the
-    automatically chosen tile sizes unless others are given. None where the
-    tiles do not divide S."""
+    tile sizes chosen automatically for heads of ``head_dim`` unless others
+    are given. None where the tiles do not divide S."""
     seg = np.asarray(segment_ids)
     s = seg.shape[-1]
-    auto = _auto_blocks(s, s, True)
+    auto = _auto_blocks(s, s, True, head_dim)
     block_q, block_k = block_q or auto[0], block_k or auto[1]
     if s % block_q or s % block_k:
         return None
@@ -553,10 +553,11 @@ def _pick_divisor(s: int, cap: int) -> int:
     return max(b, 8)
 
 
-def _auto_blocks(sq: int, sk: int, segmented: bool = False) -> tuple:
+def _auto_blocks(sq: int, sk: int, segmented: bool = False, head_dim: int = 128) -> tuple:
     """(block_q, block_k, bwd_block_q, bwd_block_k): the tile sizes measured
     fastest on one v5e that divide the sequence, chosen from what the call
-    can see (the lengths, and whether it carries segment ids).
+    can see (the lengths, whether it carries segment ids, and the heads'
+    width).
 
     Round 2 (2026-07-29, full train step): 512-row q tiles ~2.7x faster than
     the FlashAttention-conventional 128 (66.9k vs 24.6k tok/s at S=1024 —
@@ -568,10 +569,20 @@ def _auto_blocks(sq: int, sk: int, segmented: bool = False) -> tuple:
     (3.10 ms a call against 3.36 at 512 x 1,024 and 4.37 at 512 x 512), the
     backward the same within 0.5% at 512 x 1,024 and 1,024 x 1,024 (7.32,
     7.28 ms), so it keeps the smaller. Calls with no segment ids keep round
-    2's tiles. PERF.md section 6 has the sweep."""
+    2's tiles. PR 26 (the same tool at B 2, S 8,192, 20/20 heads of 256, the
+    packed8k rows: latent attention's heads): 1,024 x 1,024 does not compile
+    at that width (16.74M of the 16M of VMEM a kernel may use, forward and
+    backward alike), so the width is part of what the choice sees; the
+    forward is fastest at 1,024 x 512 (7.53 ms a call against 7.65 at 512 x
+    1,024 and 8.40 at 512 x 512), the backward at 512 x 1,024 as at width 128
+    (20.14 ms against 20.49 at 1,024 x 512 and 20.58 at 512 x 512). Calls at
+    width 128 keep their tiles to the number. PERF.md section 6 has both
+    sweeps."""
     bq, bk = _pick_divisor(sq, 512), _pick_divisor(sk, 1024 if sk >= 4096 else 512)
     if segmented and sq >= 4096:
-        return _pick_divisor(sq, 1024), bk, bq, bk
+        if head_dim <= 128:
+            return _pick_divisor(sq, 1024), bk, bq, bk
+        return _pick_divisor(sq, 1024), _pick_divisor(sk, 512), bq, bk
     return bq, bk, bq, bk
 
 
@@ -633,7 +644,7 @@ def flash_attention(
     kh = k.shape[2]
     sk = k.shape[1]
     segmented = segment_ids is not None
-    auto = _auto_blocks(sq, sk, segmented)
+    auto = _auto_blocks(sq, sk, segmented, d)
     # a forward tile the caller chose is the backward's too, unless it
     # chooses that as well
     bwd_block_q = min(bwd_block_q or block_q or auto[2], sq)

@@ -38,6 +38,11 @@ GAUGES = frozenset(
         # the kernel visits (ops/flash.py tiles_visited_share; recorded per
         # batch in the prefetcher's thread by Trainer.fit)
         "attention.tiles_visited_share",
+        # an expert share model's step counters (models/moe.py
+        # ExpertShareBlock, read by Trainer.fit with the loss of its last step)
+        "moe.slots",  # (token, choice) slots on the experts this chip holds, all layers
+        "moe.slots_dropped",  # of them, cut by a buffer: the layer is dropless, so 0
+        "moe.load_max_over_mean",  # busiest held expert's slots over the mean one's, a mean over layers
         # checkpointing (train/checkpoint.py)
         "checkpoint_save_ms",
         # control plane (core/rpc.py, core/pod.py)
@@ -248,9 +253,15 @@ SCOPES = (
     "optimizer",  # optax update + apply, global gradient norm
     "grad_sync",  # bucketed / ZeRO gradient collectives (train/trainer.py overlap step)
     "moe.route",  # router logits, top-k, capacity positions, aux losses
-    "moe.dispatch",  # one-hot dispatch of tokens to expert buffers
+    "moe.dispatch",  # tokens to expert buffers (one-hot, or sorted slots in the share form)
     "moe.experts",  # the experts' feed-forward matmuls
     "moe.combine",  # weighted gather back to tokens
+    "moe.shared",  # the shared expert beside the routed ones (ExpertShareBlock)
+    "mla.q",  # latent attention: query down-projection, norm, up-projection
+    "mla.kv",  # latent attention: key-value down-projection, norm, up-projection
+    "mla.rope",  # latent attention: rope on the narrow part, heads put together
+    # (flax module names are scopes too and need no entry: attn, mlp, moe,
+    # and mtp, the multi-token-prediction module)
     "decode_attn",  # page/chunk gather + online softmax over the KV cache
     "kv_write",  # this step's K/V written into the cache
     "sample",  # logits -> token (serve/engine.py)
@@ -354,6 +365,9 @@ GAUGE_UNITS = {
     "input_wait_ms": "ms",
     "prefetch_depth": "count",
     "attention.tiles_visited_share": "ratio",
+    "moe.slots": "count",
+    "moe.slots_dropped": "count",
+    "moe.load_max_over_mean": "ratio",
     "checkpoint_save_ms": "ms",
     "heartbeat_rtt_ms": "ms",
     "data_plane_init_ms": "ms",

@@ -179,6 +179,12 @@ def decoder_pipeline_parts(
             "pp>1 with cfg.ablated is not supported: the stage chunks would "
             "silently ignore the LOCO gates. Ablate without pipeline stages."
         )
+    if getattr(cfg, "n_dense_layers", 0) or getattr(cfg, "mtp_depth", 0) or getattr(cfg, "experts_held", 0):
+        raise ValueError(
+            "pp>1 takes one kind of layer under one scan: a stack with leading "
+            "dense layers, the multi-token-prediction module or the expert "
+            "share form has no stage chunks yet"
+        )
     if cfg.tie_embeddings:
         raise ValueError(
             "tie_embeddings=True is not supported with pp>1: the input "
@@ -272,9 +278,9 @@ def decoder_pipeline_parts(
 
             positions, segment_ids = _side_inputs(x, raw)
             (y, _), mods = chunk.apply(
-                {"params": params["layers"]}, x, positions, segment_ids,
+                {"params": params["layers"]}, x, positions, {}, segment_ids,
                 mutable=["intermediates"],
-            )
+            )  # {}: nothing rides the scan per layer (no gates, no bias)
             # this stage's router balancing losses (shared collection rule)
             return y, collect_aux_losses(mods)
     else:

@@ -54,35 +54,40 @@ class TrainState(train_state.TrainState):
 
 
 def _lm_loss_parts(
-    logits: jax.Array, batch: Dict[str, jax.Array]
+    logits: jax.Array, batch: Dict[str, jax.Array], ahead: int = 1
 ) -> Tuple[jax.Array, jax.Array]:
     """``(masked log-likelihood sum, mask weight)`` for the LM objective —
     the sufficient statistics :func:`lm_loss_fn` normalizes. Split out so the
     bucketed-overlap step can psum the two parts across batch shards and
     reproduce the dense masked mean exactly (sum-of-sums / sum-of-weights),
-    instead of averaging per-shard means whose denominators differ."""
+    instead of averaging per-shard means whose denominators differ.
+    ``ahead``: position ``i``'s logits predict token ``i + ahead`` (2 for a
+    multi-token-prediction head); segments are runs, so a target in position
+    ``i``'s segment has every token between them in it too."""
     tokens = batch["tokens"]
-    targets = tokens[:, 1:]
-    logits = logits[:, :-1]
+    targets = tokens[:, ahead:]
+    logits = logits[:, :-ahead]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     mask = batch.get("loss_mask")
-    mask = None if mask is None else mask[:, 1:].astype(jnp.float32)
+    mask = None if mask is None else mask[:, ahead:].astype(jnp.float32)
     seg = batch.get("segment_ids")
     if seg is not None:
-        same = (seg[:, 1:] == seg[:, :-1]).astype(jnp.float32)
+        same = (seg[:, ahead:] == seg[:, :-ahead]).astype(jnp.float32)
         mask = same if mask is None else mask * same
     if mask is None:
         return ll.sum(), jnp.float32(ll.size)
     return (ll * mask).sum(), mask.sum()
 
 
-def lm_loss_fn(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
+def lm_loss_fn(
+    logits: jax.Array, batch: Dict[str, jax.Array], ahead: int = 1
+) -> jax.Array:
     """Next-token cross entropy over ``batch["tokens"]`` with optional
     ``batch["loss_mask"]``. With ``batch["segment_ids"]`` (packed sequences)
     the boundary positions — where the target token belongs to a different
     segment than its predictor — are masked out automatically."""
-    ll_sum, weight = _lm_loss_parts(logits, batch)
+    ll_sum, weight = _lm_loss_parts(logits, batch, ahead)
     return -ll_sum / jnp.maximum(weight, 1.0)
 
 
@@ -104,6 +109,42 @@ def collect_aux_losses(mods) -> jax.Array:
         if "aux_loss" in jax.tree_util.keystr(path):
             aux = aux + jnp.sum(leaf).astype(jnp.float32)
     return aux
+
+
+def _sown(mods, name: str) -> list:
+    """Every intermediate a model sowed under ``name``, wherever in it."""
+    return [
+        leaf
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+            mods.get("intermediates", {})
+        )[0]
+        if f"'{name}'" in jax.tree_util.keystr(path)
+    ]
+
+
+def mtp_loss(mods, batch: Dict[str, jax.Array]) -> Optional[jax.Array]:
+    """The multi-token-prediction loss of a model that sowed ``mtp_logits``
+    (models/moe.py): cross entropy of the token two ahead, counted where it
+    lies in the predictor's document. ``None`` for every other model."""
+    logits = _sown(mods, "mtp_logits")
+    return lm_loss_fn(logits[0], batch, ahead=2) if logits else None
+
+
+def expert_counters(mods) -> Dict[str, jax.Array]:
+    """The step's counters of a model with expert share layers
+    (``ExpertShareBlock`` sows ``expert_load`` and ``slots_dropped``): the
+    (token, choice) slots on held experts, those a buffer cut (dropless: 0),
+    and the busiest held expert's load over the mean one, a mean over the
+    layers. Empty for every other model."""
+    load = _sown(mods, "expert_load")
+    if not load:
+        return {}
+    load = jnp.concatenate([a.reshape(-1, a.shape[-1]) for a in load]).astype(jnp.float32)
+    return {
+        "moe_slots": load.sum(),
+        "moe_slots_dropped": sum(jnp.sum(a) for a in _sown(mods, "slots_dropped")).astype(jnp.float32),
+        "moe_load_max_over_mean": jnp.mean(load.max(-1) / jnp.maximum(load.mean(-1), 1.0)),
+    }
 
 
 def _prefetch_depth(prefetch: Optional[int]) -> int:
@@ -378,6 +419,11 @@ class Trainer:
                 {"params": params}, *_model_inputs(batch),
                 mutable=["intermediates"],
             )
+            if _sown(mods, "mtp_logits"):
+                raise NotImplementedError(
+                    "the bucketed/ZeRO overlap step has no multi-token-"
+                    "prediction loss: train this model with overlap off"
+                )
             aux_dev = collect_aux_losses(mods) / n_manual
             with jax.named_scope("loss"):
                 if is_lm:
@@ -773,10 +819,13 @@ class Trainer:
 
         from maggy_tpu.ops.flash import tiles_visited_share
 
+        head_dim = getattr(getattr(self.model, "cfg", None), "head_dim", None)
+        head_dim = head_dim if isinstance(head_dim, int) else 128  # tiles depend on the width
+
         def put(batch):
             seg = batch.get("segment_ids") if isinstance(batch, dict) else None
             if isinstance(seg, np.ndarray) and seg.ndim == 2:
-                share = tiles_visited_share(seg)
+                share = tiles_visited_share(seg, head_dim=head_dim)
                 if share is not None:
                     tel.gauge("attention.tiles_visited_share", share)
             return self.shard_batch(batch)
@@ -994,10 +1043,16 @@ class Trainer:
                 )
                 with jax.named_scope("loss"):
                     loss = self.loss_fn(logits, batch)
+                    mtp = mtp_loss(mods, batch)
                 aux = collect_aux_losses(mods)
-                return loss + aux, (loss, aux)
+                extra = expert_counters(mods)
+                total = loss + aux
+                if mtp is not None:
+                    total = total + self.model.cfg.mtp_weight * mtp
+                    extra["mtp_loss"] = mtp
+                return total, (loss, aux, extra)
 
-            (total, (loss, aux)), grads = jax.value_and_grad(loss_of, has_aux=True)(
+            (total, (loss, aux, extra)), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 state.params
             )
             with jax.named_scope("optimizer"):
@@ -1009,6 +1064,7 @@ class Trainer:
                 "total_loss": total,
                 "grad_norm": gnorm,
                 "step": state.step,
+                **extra,
             }
 
         return jax.jit(train_step, donate_argnums=(0,))
@@ -1627,6 +1683,10 @@ class Trainer:
         )
         with tel.span("train.drain", why="return"):
             out = {k: float(v) for k, v in metrics.items()}
+        if "moe_slots" in out:  # an expert share model's counters, read with the loss
+            tel.gauge("moe.slots", out["moe_slots"])
+            tel.gauge("moe.slots_dropped", out["moe_slots_dropped"])
+            tel.gauge("moe.load_max_over_mean", out["moe_load_max_over_mean"])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

@@ -3,9 +3,12 @@
     python tools/tune_flash.py --packed        # the packed train cell's shape
     python tools/tune_flash.py [--seq 4096]    # the same shape, no segment ids
 
-Both modes time one attention call at B 2, 32 query and 8 key-value heads of
-128 (Mistral-7B's), bfloat16. ``--packed`` takes its segment ids from the
-benchmark's ``packed4k`` mix (``benchmark.traffic``, read only):
+    python tools/tune_flash.py --packed --mix packed8k --heads 20 --kv-heads 20 --head-dim 256
+
+Both modes time one attention call at B 2, bfloat16, by default at 32 query
+and 8 key-value heads of 128 (Mistral-7B's; ``--heads``, ``--kv-heads``,
+``--head-dim`` give another model's). ``--packed`` takes its segment ids from
+a benchmark mix (``--mix``, default ``packed4k``; ``benchmark.traffic``, read only):
 every row of the pool, two rows a call, so a tile choice is timed on the
 packings the cell trains on and printed beside the share of the grid's tiles
 it visits. The forward is timed alone (it runs twice a step under full
@@ -30,16 +33,14 @@ import time
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
 
-def packed_segment_ids(seq_len):
-    """[calls, rows a call, seq_len] int32: the ``packed4k`` pool's batches
-    (ids 1.. in each row, 0 for the padded tail), every row of the mix."""
+def packed_segment_ids(mix_name):
+    """[calls, rows a call, seq_len] int32: the mix's pool of batches (ids
+    1.. in each row, 0 for the padded tail), every row of the mix."""
     import numpy as np
 
     from benchmark import traffic
 
-    mix = traffic.load_mix("packed4k")
-    if mix["seq_len"] != seq_len:
-        raise SystemExit(f"--packed runs at the mix's seq_len {mix['seq_len']}")
+    mix = traffic.load_mix(mix_name)
     pool, _ = traffic.packed_pool(mix, seed=0, vocab=2)
     return np.stack([batch["segment_ids"] for batch in pool])
 
@@ -49,6 +50,10 @@ def main():
     parser.add_argument("--seq", type=int, default=4096)
     parser.add_argument("--steps", type=int, default=5, help="timed passes over the calls")
     parser.add_argument("--packed", action="store_true")
+    parser.add_argument("--mix", default="packed4k", help="--packed: the benchmark mix whose rows are timed (its seq_len is the length)")
+    parser.add_argument("--heads", type=int, default=32)
+    parser.add_argument("--kv-heads", type=int, default=8)
+    parser.add_argument("--head-dim", type=int, default=128)
     args = parser.parse_args()
 
     import jax
@@ -59,15 +64,16 @@ def main():
     from maggy_tpu.ops.flash import _auto_blocks, flash_attention, tiles_visited_share
 
     toy = jax.default_backend() != "tpu"
-    B, S, H, KH, D = (2, 512, 2, 1, 128) if toy else (2, args.seq, 32, 8, 128)
+    if args.packed and not toy:
+        segs = packed_segment_ids(args.mix)
+        args.seq = segs.shape[-1]
+    B, S, H, KH, D = (2, 512, 2, 1, 128) if toy else (2, args.seq, args.heads, args.kv_heads, args.head_dim)
     dt = jnp.bfloat16
     q, do = (jax.random.normal(jax.random.key(i), (B, S, H, D), dt) for i in (1, 4))
     k, v = (jax.random.normal(jax.random.key(i), (B, S, KH, D), dt) for i in (2, 3))
-    if args.packed and not toy:
-        segs = packed_segment_ids(S)
-    elif args.packed:
+    if args.packed and toy:
         segs = np.repeat(np.arange(1, 5, dtype=np.int32), S // 4)[None, None].repeat(B, 1)
-    else:
+    elif not args.packed:
         segs = [None]
     calls = [None if s is None else jnp.asarray(s) for s in segs]
     cands = [c for c in FLASH_TILE_CHOICES if 256 <= c <= S] or [S]
@@ -82,10 +88,13 @@ def main():
 
     def time_ms(fn, *arrays):
         """Milliseconds a call, over every call of the pool ``--steps`` times."""
-        jax.block_until_ready([fn(seg, *arrays) for seg in calls])  # compile, warm
+        for seg in calls:  # compile, warm; one call's outputs held at a time
+            out = fn(seg, *arrays)
+        jax.block_until_ready(out)
         t0 = time.perf_counter()
         for _ in range(args.steps):
-            out = [fn(seg, *arrays) for seg in calls]
+            for seg in calls:
+                out = fn(seg, *arrays)
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / (args.steps * len(calls)) * 1e3
 
@@ -122,7 +131,7 @@ def main():
     both = sweep("fwd+bwd", forward_backward, lambda bq, bk: best + (bq, bk))
     for r in both:
         r["bwd_ms"] = round(r["ms"] - fwd[0]["ms"], 4)
-    auto = _auto_blocks(S, S, args.packed)
+    auto = _auto_blocks(S, S, args.packed, D)
     print(json.dumps({
         "geometry": f"B={B} S={S} H={H} KH={KH} D={D} packed={args.packed} calls={len(calls)}",
         "forward": fwd,
