@@ -19,6 +19,7 @@ multi-token-prediction module.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import flax.linen as nn
@@ -242,46 +243,195 @@ class MoEBlock(nn.Module):
         return y
 
 
-@jax.custom_vjp
-def _to_slots(x, order, inv, held):
-    """Rows of ``x`` [T, d] in slot order: row ``i`` is the token of slot
-    ``order[i]`` (slot ``t * k + j`` is token ``t``'s choice ``j``). A gather
-    forward and, with ``inv`` the inverse permutation, a gather backward too
-    (a scatter-add by token otherwise); slots on experts not held bring no
-    gradient back, whatever the products left in their rows."""
-    return x[order // (order.shape[0] // x.shape[0])]
+def counting_sort(key, n_keys: int):
+    """A stable sort of ``key`` [n] (whole numbers below ``n_keys``, a small
+    static count) without sorting: ``(inv, load)`` with ``load[v]`` the
+    entries of value ``v`` and ``inv[i]`` the place of entry ``i`` in sorted
+    order, its value's offset (the exclusive running sum of ``load``) plus
+    its rank among the entries of that value. ``order`` with
+    ``order[inv[i]] = i`` is ``jnp.argsort(key, stable=True)``."""
+    onehot = (key[:, None] == jnp.arange(n_keys, dtype=key.dtype)).astype(jnp.int32)
+    upto = jnp.cumsum(onehot, axis=0)  # [n, n_keys]: scalars a slot, never rows of width d
+    load = upto[-1] if key.shape[0] else jnp.zeros(n_keys, jnp.int32)
+    start = jnp.cumsum(load) - load
+    inv = ((upto - 1 + start) * onehot).sum(-1)
+    return inv.astype(jnp.int32), load
 
 
-def _to_slots_fwd(x, order, inv, held):
-    return _to_slots(x, order, inv, held), (inv, held, x.shape[0])
+def chunk_rows(slots: int, held: int, n_experts: int) -> int:
+    """Rows of one chunk of a share layer's buffer (``slots`` = ``T * top_k``
+    rows, which no load exceeds): the routed part runs chunk by chunk over as
+    many as the counted slots fill. A chip that holds every expert sees every
+    slot: one chunk. Otherwise the load is about ``slots * held / n_experts``
+    and wanders severalfold above that with the router, so an eighth of the
+    buffer: on one v5e at the GLM cell's size a quarter lost 1.2% of the
+    step to rows that hold no slot and a sixteenth won 0.4% (PERF.md section
+    6, PR 27)."""
+    return -(-slots // 8) if held < n_experts else slots
 
 
-def _to_slots_bwd(res, g):
-    inv, held, t = res
-    g = jnp.where(held[:, None], g[inv], 0).reshape(t, -1, g.shape[-1])
-    return g.astype(jnp.float32).sum(1).astype(g.dtype), None, None, None
+def _by_token(rows, inv, held, weights=None):
+    """``sum over the held choices j of weights[:, j] * rows[inv[:, j]]``
+    [T, d] in float32 from rows in slot order (``inv`` [T, k]: the place of
+    token ``t``'s choice ``j``; ``held`` [T, k]: whether its expert is held,
+    and then the place lies below the load): a token's choices gathered one
+    at a time, so nothing of ``T * k`` rows is built, and selected, not
+    multiplied by zero (a row past the load may hold anything)."""
+    at = jnp.minimum(inv, rows.shape[0] - 1)
+    total = 0.0
+    for j in range(inv.shape[1]):
+        row = rows[at[:, j]].astype(jnp.float32)
+        if weights is not None:
+            row = weights[:, j, None].astype(jnp.float32) * row
+        total = total + jnp.where(held[:, j, None], row, 0)
+    return total
 
 
-_to_slots.defvjp(_to_slots_fwd, _to_slots_bwd)
+# tiles (rows, reduction, columns) of the grouped product's kernel: the best of
+# seven on one v5e at the GLM cell's shapes (PERF.md section 6, PR 27)
+GROUPED_TILES = (512, 2048, 512)
 
 
-@jax.custom_vjp
-def _from_slots(y, order, inv, held):
-    """The inverse: rows of ``y`` (slot order) back at ``[T * k, d]`` by
-    token and choice, zero where the slot's expert is not held."""
-    return jnp.where(held[:, None], y[inv], 0)
+def grouped_kernel(lhs, rhs, group_sizes, interpret: bool = False):
+    """``grouped_dot`` as the Pallas kernels of ``megablox`` (``gmm`` forward
+    and for the rows' gradient, ``tgmm`` for the weights'; float32
+    accumulation, results in the operands' type), which visit the row tiles
+    the group sizes cover. Rows have to fill whole tiles."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    return megablox.gmm(
+        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=GROUPED_TILES,
+        interpret=interpret,
+    )
 
 
-def _from_slots_fwd(y, order, inv, held):
-    return _from_slots(y, order, inv, held), (order, inv, held)
+def grouped_dot(lhs, rhs, group_sizes):
+    """``lhs`` [C, k] by runs of rows, run ``e`` (``group_sizes[e]`` rows,
+    in order from row 0) times ``rhs[e]`` [k, n]; rows past the runs hold
+    anything. On a TPU, where the rows fill the kernel's tiles, the Pallas
+    kernel: ``jax.lax.ragged_dot`` becomes a kernel of the compiler's own
+    there, as fast to within a tenth, but one that drops the program's scope
+    names, so that a trace cannot put its time down to ``moe.experts``
+    (PERF.md section 6, PR 27). Elsewhere ``jax.lax.ragged_dot``."""
+    if (
+        jax.default_backend() == "tpu"
+        and lhs.shape[0] % GROUPED_TILES[0] == 0
+        and lhs.dtype == rhs.dtype == jnp.bfloat16
+    ):
+        return grouped_kernel(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
-def _from_slots_bwd(res, g):
-    order, inv, held = res
-    return jnp.where(held[:, None], g, 0)[order], None, None, None
+def _chunk_sizes(load, first_row, rows: int):
+    """Of the runs of rows that ``load`` gives (run ``e``: ``load[e]`` rows, in
+    order from row 0), what lies in ``[first_row, first_row + rows)``."""
+    ends = jnp.cumsum(load)
+    return jnp.clip(jnp.minimum(ends, first_row + rows) - jnp.maximum(ends - load, first_row), 0, rows)
 
 
-_from_slots.defvjp(_from_slots_fwd, _from_slots_bwd)
+@jax.jit
+def _chunk_experts(a, sizes, w_gate, w_up, w_down):
+    """The three grouped products over one chunk's rows ``a`` [rows, d].
+    Under ``jax.jit`` for its cache alone: every expert layer of a model and
+    every pass calls it at the same shapes, and tracing the kernels anew each
+    time took longer than loading the compiled step."""
+    with jax.named_scope("moe.experts"):
+        hidden = nn.silu(grouped_dot(a, w_gate, sizes)) * grouped_dot(a, w_up, sizes)
+        return grouped_dot(hidden, w_down, sizes)
+
+
+@jax.jit
+def _chunk_experts_back(a, sizes, w_gate, w_up, w_down, g_rows, scale):
+    """One chunk again and backward, from the rows ``g_rows`` of the result's
+    cotangent that belong to its slots and the slots' weights ``scale`` (zero
+    where the slot's expert is not held): the cotangent of ``a``, each slot's
+    dot product of its result with ``g_rows`` (the weights' gradient), and
+    the three expert weights' gradients."""
+    y, vjp = jax.vjp(
+        lambda a, w_gate, w_up, w_down: _chunk_experts(a, sizes, w_gate, w_up, w_down),
+        a, w_gate, w_up, w_down,
+    )
+    with jax.named_scope("moe.combine"):
+        dot = (y.astype(jnp.float32) * g_rows.astype(jnp.float32)).sum(-1)
+        d_y = scale[:, None] * g_rows
+    return (*vjp(d_y), dot)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(rows: int, tokens, weights, order, inv, held, load, w_gate, w_up, w_down):
+    """The routed part of the share layer: ``out[t] = sum over the held
+    choices j of weights[t, j] * expert(tokens[t])``, the buffer (slot order:
+    ``order`` [chunks * rows] names the slot of each row, ``inv`` [T, k] the
+    row of each slot) worked through in chunks of ``rows`` rows, as many as
+    the counted slots ``load.sum()`` fill and no more: a loop whose length is
+    read in the step, so one traced and compiled body serves every load. A
+    chunk gathers its rows of ``tokens``, runs the three grouped products and
+    writes its part of the buffer; then a token's choices are gathered back
+    and summed in float32. Its own VJP (a loop of unknown length has no
+    transpose): the forward pass keeps the arguments only; the backward pass
+    is a second loop whose chunk runs forward again and then backward,
+    the expert weights' gradients summed over the chunks in float32, and
+    both directions gather by token where a scatter-add would stand."""
+    k = inv.shape[1]
+
+    def chunk(i, y):
+        first = i * rows
+        with jax.named_scope("moe.dispatch"):
+            a = tokens[jax.lax.dynamic_slice(order, (first,), (rows,)) // k]
+        y_c = _chunk_experts(a, _chunk_sizes(load, first, rows), w_gate, w_up, w_down)
+        return jax.lax.dynamic_update_slice(y, y_c, (first, 0))
+
+    y = jax.lax.fori_loop(
+        0, (load.sum() + rows - 1) // rows, chunk,
+        jnp.zeros((order.shape[0], tokens.shape[1]), tokens.dtype),
+    )
+    with jax.named_scope("moe.combine"):
+        return _by_token(y, inv, held, weights).astype(tokens.dtype)
+
+
+def _routed_fwd(rows, *args):
+    return _routed(rows, *args), args
+
+
+def _routed_bwd(rows, res, g):
+    tokens, weights, order, inv, held, load, w_gate, w_up, w_down = res
+    k = inv.shape[1]
+    scale = jnp.where(held, weights, 0).reshape(-1)
+
+    def chunk(i, carry):
+        d_a, dot, *d_experts = carry
+        first = i * rows
+        slots = jax.lax.dynamic_slice(order, (first,), (rows,))
+        with jax.named_scope("moe.dispatch"):
+            a = tokens[slots // k]
+        with jax.named_scope("moe.combine"):
+            g_rows, scale_rows = g[slots // k], scale[slots]
+        d_a_c, *d_experts_c, dot_c = _chunk_experts_back(
+            a, _chunk_sizes(load, first, rows), w_gate, w_up, w_down, g_rows, scale_rows
+        )
+        return (
+            jax.lax.dynamic_update_slice(d_a, d_a_c, (first, 0)),
+            jax.lax.dynamic_update_slice(dot, dot_c, (first,)),
+            *(total + part.astype(jnp.float32) for total, part in zip(d_experts, d_experts_c)),
+        )
+
+    d_a, dot, *d_experts = jax.lax.fori_loop(
+        0, (load.sum() + rows - 1) // rows, chunk,
+        (
+            jnp.zeros((order.shape[0], tokens.shape[1]), tokens.dtype),
+            jnp.zeros(order.shape[0], jnp.float32),
+            *(jnp.zeros(w.shape, jnp.float32) for w in (w_gate, w_up, w_down)),
+        ),
+    )
+    with jax.named_scope("moe.dispatch"):
+        d_tokens = _by_token(d_a, inv, held).astype(tokens.dtype)
+    with jax.named_scope("moe.combine"):
+        d_weights = jnp.where(held, dot[jnp.minimum(inv, dot.shape[0] - 1)], 0).astype(weights.dtype)
+    d_experts = (d.astype(w.dtype) for d, w in zip(d_experts, (w_gate, w_up, w_down)))
+    return (d_tokens, d_weights, None, None, None, None, *d_experts)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 def sigmoid_route(logits, select_bias, top_k: int, scaling: float):
@@ -305,13 +455,17 @@ class ExpertShareBlock(nn.Module):
     ``w_e = routed_scaling * s_e / (sum of the chosen s + 1e-20)``. The result
     is ``shared(x) + sum over chosen experts held here of w_e * expert_e(x)``:
     what the absent experts would add is another chip's part. The (token,
-    choice) slots are sorted by held expert (those on absent experts last),
-    and three grouped products (``jax.lax.ragged_dot``: on a TPU a kernel that
-    visits the row tiles the group sizes cover) run over the slots that exist,
-    not over the buffer, which has a row for every slot (``T * top_k``), so
-    none on a held expert is ever cut: ``slots_dropped`` counts what a
-    smaller buffer would cut. Sows ``expert_load`` ([experts_held] slots an
-    expert) and ``slots_dropped`` for the trainer's step metrics."""
+    choice) slots are put in order of held expert by a counting sort (those
+    on absent experts last) and counted, and the routed part (``_routed``:
+    gather into slot order, three grouped products, the weighted sum back by
+    token) works through the buffer chunk by chunk (``chunk_rows``), as many
+    chunks as the counted slots fill, in a loop whose length is read in the
+    step: the rows of width ``d_model`` that move follow the load, not
+    ``T * top_k``. The buffer has a row for every slot, so none on a held
+    expert is ever cut: ``slots_dropped`` counts what the chunks that ran
+    left out, and reads 0. Sows ``expert_load`` ([experts_held] slots an
+    expert), ``slots_dropped`` and ``rows_visited`` ([2]: the rows of the
+    chunks that ran, of ``T * top_k``) for the trainer's step metrics."""
 
     cfg: MoEConfig
 
@@ -333,16 +487,18 @@ class ExpertShareBlock(nn.Module):
             sel, weights = sigmoid_route(logits, select_bias, k, cfg.routed_scaling)  # [t, k]
 
         with jax.named_scope("moe.dispatch"):
-            local = sel.reshape(t * k) - lo
-            is_held = (local >= 0) & (local < held)
-            key = jnp.where(is_held, local, held)
-            order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            inv = jnp.zeros_like(order).at[order].set(
+            local = sel - lo
+            is_held = (local >= 0) & (local < held)  # [t, k]
+            key = jnp.where(is_held, local, held).reshape(t * k)
+            inv, load = counting_sort(key, held + 1)
+            load = load[:held]
+            # a row of the buffer for every slot, in chunks: the last chunk may overhang
+            rows = chunk_rows(t * k, held, e)
+            chunks = -(-t * k // rows)
+            order = jnp.zeros(chunks * rows, jnp.int32).at[inv].set(
                 jnp.arange(t * k, dtype=jnp.int32), unique_indices=True
             )
-            load = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-            rows = t * k  # the buffer: a row for every slot, so dropless
-            slots_in = _to_slots(tokens, order, inv, is_held)
+            visited = jnp.minimum((load.sum() + rows - 1) // rows * rows, t * k)
 
         def experts(name, axes, shape):
             w = self.param(
@@ -354,15 +510,10 @@ class ExpertShareBlock(nn.Module):
         w_gate = experts("w_gate", ("expert", "embed", "mlp"), (held, d, f))
         w_up = experts("w_up", ("expert", "embed", "mlp"), (held, d, f))
         w_down = experts("w_down", ("expert", "mlp", "embed"), (held, f, d))
-        with jax.named_scope("moe.experts"):
-            hidden = nn.silu(jax.lax.ragged_dot(slots_in, w_gate, load)) * jax.lax.ragged_dot(
-                slots_in, w_up, load
-            )
-            slots_out = jax.lax.ragged_dot(hidden, w_down, load)
-
-        with jax.named_scope("moe.combine"):
-            routed = _from_slots(slots_out, order, inv, is_held).reshape(t, k, d)
-            y = jnp.einsum("tkd,tk->td", routed, weights.astype(routed.dtype))
+        y = _routed(
+            rows, tokens, weights.astype(tokens.dtype), order, inv.reshape(t, k),
+            is_held, load, w_gate, w_up, w_down,
+        )
 
         if cfg.n_shared_experts:
             with jax.named_scope("moe.shared"):
@@ -371,7 +522,8 @@ class ExpertShareBlock(nn.Module):
                     name="shared",
                 )(tokens)
         self.sow("intermediates", "expert_load", load)
-        self.sow("intermediates", "slots_dropped", jnp.maximum(load.sum() - rows, 0))
+        self.sow("intermediates", "slots_dropped", jnp.maximum(load.sum() - visited, 0))
+        self.sow("intermediates", "rows_visited", jnp.stack([visited, jnp.int32(t * k)]))
         return y.reshape(b, s, d)
 
 

@@ -43,6 +43,9 @@ GAUGES = frozenset(
         "moe.slots",  # (token, choice) slots on the experts this chip holds, all layers
         "moe.slots_dropped",  # of them, cut by a buffer: the layer is dropless, so 0
         "moe.load_max_over_mean",  # busiest held expert's slots over the mean one's, a mean over layers
+        # rows of the layers' buffers the chunks that ran visited over the rows
+        # the buffers hold (T * top_k a layer); 1.0 = the mechanism does nothing
+        "moe.rows_visited_share",
         # checkpointing (train/checkpoint.py)
         "checkpoint_save_ms",
         # control plane (core/rpc.py, core/pod.py)
@@ -368,6 +371,7 @@ GAUGE_UNITS = {
     "moe.slots": "count",
     "moe.slots_dropped": "count",
     "moe.load_max_over_mean": "ratio",
+    "moe.rows_visited_share": "ratio",
     "checkpoint_save_ms": "ms",
     "heartbeat_rtt_ms": "ms",
     "data_plane_init_ms": "ms",

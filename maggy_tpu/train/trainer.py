@@ -132,19 +132,26 @@ def mtp_loss(mods, batch: Dict[str, jax.Array]) -> Optional[jax.Array]:
 
 def expert_counters(mods) -> Dict[str, jax.Array]:
     """The step's counters of a model with expert share layers
-    (``ExpertShareBlock`` sows ``expert_load`` and ``slots_dropped``): the
-    (token, choice) slots on held experts, those a buffer cut (dropless: 0),
-    and the busiest held expert's load over the mean one, a mean over the
-    layers. Empty for every other model."""
+    (``ExpertShareBlock`` sows ``expert_load``, ``slots_dropped`` and
+    ``rows_visited``): the (token, choice) slots on held experts, those a
+    buffer cut (dropless: 0), the busiest held expert's load over the mean
+    one, a mean over the layers, and the rows of the layers' buffers that the
+    chunks that ran visited over the rows they hold (1.0: every layer worked
+    through its whole buffer). Empty for every other model."""
     load = _sown(mods, "expert_load")
     if not load:
         return {}
     load = jnp.concatenate([a.reshape(-1, a.shape[-1]) for a in load]).astype(jnp.float32)
-    return {
+    out = {
         "moe_slots": load.sum(),
         "moe_slots_dropped": sum(jnp.sum(a) for a in _sown(mods, "slots_dropped")).astype(jnp.float32),
         "moe_load_max_over_mean": jnp.mean(load.max(-1) / jnp.maximum(load.mean(-1), 1.0)),
     }
+    rows = _sown(mods, "rows_visited")  # [visited, of] a layer
+    if rows:
+        visited, of = jnp.concatenate([a.reshape(-1, 2) for a in rows]).sum(0)
+        out["moe_rows_visited_share"] = visited / of
+    return out
 
 
 def _prefetch_depth(prefetch: Optional[int]) -> int:
@@ -1687,6 +1694,8 @@ class Trainer:
             tel.gauge("moe.slots", out["moe_slots"])
             tel.gauge("moe.slots_dropped", out["moe_slots_dropped"])
             tel.gauge("moe.load_max_over_mean", out["moe_load_max_over_mean"])
+        if "moe_rows_visited_share" in out:
+            tel.gauge("moe.rows_visited_share", out["moe_rows_visited_share"])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

@@ -11,6 +11,7 @@ bfloat16, is the cell's (``benchmark/kinds/train_packed_ref.py``).
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -181,6 +182,192 @@ def test_token_with_no_held_expert_gets_the_shared_expert_only(tiny, seeded):
     assert int(mods["intermediates"]["expert_load"][0].sum()) == 0
 
 
+# ------------------------------------------- the buffer, chunk by chunk
+
+
+def as_reference(base):
+    """A block's parameters under the reference's leaf names."""
+    return {"router": base["router"]["kernel"],
+            **{f"experts_{n}": base[f"w_{n}"] for n in ("gate", "up", "down")},
+            **{f"shared_{n}": base["shared"][f"w_{n}"]["kernel"] for n in ("gate", "up", "down")}}
+
+
+# slots on held experts -> rows of the chunks that hold them: 128 tokens x
+# top-2 = 256 slots, chunks of 32 rows
+LOADS = {"one_chunk": (20, 32), "some_chunks": (100, 128), "on_the_edge": (64, 64), "one_past_the_edge": (65, 96),
+         "whole_buffer": (256, 256)}
+
+
+def steered(base, sizes, n_slots):
+    """[2, 64, d] inputs that put exactly ``n_slots`` slots on the two held
+    experts: each token is noise plus a push along the router's columns, so
+    that it chooses both held experts, the first alone, or neither."""
+    w = base["router"]["kernel"]
+    both, one = w[:, 0] + w[:, 1], w[:, 0] - w[:, 1]
+    t = 128
+    kinds = np.full(t, -1)
+    kinds[:n_slots // 2], kinds[n_slots // 2:n_slots // 2 + n_slots % 2] = 2, 1
+    kinds = np.random.default_rng(n_slots).permutation(kinds)
+    push = {2: 12 * both / (both @ both), 1: 12 * one / (one @ one), -1: -12 * both / (both @ both)}
+    x = 0.3 * jax.random.normal(jax.random.key(n_slots), (t, sizes["d_model"]), jnp.float32)
+    x = x - (x @ w[:, :2]) @ jnp.linalg.pinv(w[:, :2])  # the noise leaves the held experts' logits alone
+    return (x + jnp.stack([push[k] for k in kinds])).reshape(2, 64, -1)
+
+
+@pytest.fixture(scope="module", params=list(LOADS))
+def load_case(request, tiny, seeded):
+    _cfg, _ref, sizes, pcfg = tiny
+    base = block_params(seeded[2])
+    n_slots, rows = LOADS[request.param]
+    xn = steered(base, sizes, n_slots)
+    bias = jnp.zeros(sizes["n_experts"])
+    _, slots = mla_moe.expert_layer(xn, as_reference(base), bias, sizes)
+    assert int(slots) == n_slots  # the steering holds, by the reference's own router
+    return sizes, pcfg, base, xn, bias, n_slots, rows
+
+
+def test_every_load_agrees_with_the_plain_reference(load_case):
+    """Output, and the gradient of every leaf and of the input, at loads in
+    the first chunk, in some, on a chunk's edge, one slot past it and in the
+    whole buffer: every chunk is the same function. Gradients per leaf as
+    the norm of the difference over the leaf's norm."""
+    sizes, pcfg, base, xn, bias, _n, _rows = load_case
+    cot = jnp.cos(jnp.arange(xn.size, dtype=jnp.float32)).reshape(xn.shape)
+
+    def program(base, xn):
+        y = moe.ExpertShareBlock(pcfg).apply({"params": base}, xn, bias)
+        return (y * cot).sum(), y
+
+    def reference(base, xn):
+        y, _ = mla_moe.expert_layer(xn, as_reference(base), bias, sizes)
+        return (y * cot).sum(), y
+
+    (_, got), got_g = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(base, xn)
+    (_, want), want_g = jax.value_and_grad(reference, argnums=(0, 1), has_aux=True)(base, xn)
+    np.testing.assert_allclose(got, want, **TOL)
+    gaps = jax.tree.map(
+        lambda a, b: float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-6)), got_g, want_g
+    )
+    worst = max(jax.tree_util.tree_leaves_with_path(gaps), key=lambda kv: kv[1])
+    assert worst[1] < 1e-4, (jax.tree_util.keystr(worst[0]), worst[1])  # as test_gradient_of_every_leaf
+
+
+def test_every_load_drops_nothing_and_visits_its_chunks(load_case):
+    _sizes, pcfg, base, xn, bias, n_slots, rows = load_case
+    _, mods = moe.ExpertShareBlock(pcfg).apply({"params": base}, xn, bias, mutable=["intermediates"])
+    sown = mods["intermediates"]
+    assert int(sown["expert_load"][0].sum()) == n_slots
+    assert int(sown["slots_dropped"][0]) == 0
+    assert [int(v) for v in sown["rows_visited"][0]] == [rows, 256]
+
+
+@pytest.mark.parametrize("slots,held,n_experts,want", [
+    (65536, 8, 64, 8192),  # the GLM cell: an eighth of 16,384 x 4
+    (65536, 64, 64, 65536),  # every expert held: every slot is, one chunk
+    (100, 2, 8, 13),  # no multiple of 8: rounded up, the last chunk overhangs
+])
+def test_chunk_rows(slots, held, n_experts, want):
+    assert moe.chunk_rows(slots, held, n_experts) == want
+
+
+def test_a_buffer_that_is_no_whole_number_of_chunks(tiny, seeded):
+    """25 tokens x top-2 = 50 slots in chunks of 7: the eighth chunk overhangs
+    the buffer, and the layer still is the plain reference's."""
+    _cfg, _ref, sizes, pcfg = tiny
+    base = block_params(seeded[2])
+    xn = jax.random.normal(jax.random.key(21), (1, 25, sizes["d_model"]), jnp.float32)
+    bias = jnp.where(jnp.arange(sizes["n_experts"]) < sizes["held"], 10.0, 0.0)  # every slot on a held expert
+    got, mods = moe.ExpertShareBlock(pcfg).apply({"params": base}, xn, bias, mutable=["intermediates"])
+    want, slots = mla_moe.expert_layer(xn, as_reference(base), bias, sizes)
+    np.testing.assert_allclose(got, want, **TOL)
+    assert int(slots) == 50 and [int(v) for v in mods["intermediates"]["rows_visited"][0]] == [50, 50]
+
+
+@pytest.mark.parametrize("sizes", [(300, 0, 412), (0, 0, 0), (512, 256, 256)], ids=["an_empty_group", "no_slot", "full"])
+def test_grouped_kernel_in_the_interpreter_is_the_ragged_dot(sizes):
+    """The Pallas kernels a TPU runs the products through (``grouped_dot``),
+    in the interpreter at their own tiles: the rows of the groups and both
+    gradients as ``jax.lax.ragged_dot`` gives them (bfloat16 operands, float32
+    sums; one reduction tile, so the same sums), rows past the groups left
+    out of the comparison as the layer leaves them out."""
+    m, k, n = 2 * moe.GROUPED_TILES[0], 64, 48
+    x = jax.random.normal(jax.random.key(0), (m, k), jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(1), (len(sizes), k, n), jnp.bfloat16)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    live = (jnp.arange(m) < sum(sizes))[:, None]
+
+    def run(product):
+        def loss(x, w):
+            y = jnp.where(live, product(x, w, group_sizes), 0)
+            return (y.astype(jnp.float32) * jnp.cos(jnp.arange(n))).sum(), y
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(x, w)
+
+    (_, got), (got_x, got_w) = run(functools.partial(moe.grouped_kernel, interpret=True))
+    (_, want), (want_x, want_w) = run(jax.lax.ragged_dot)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(jnp.where(live, got_x, 0), jnp.where(live, want_x, 0))
+    np.testing.assert_array_equal(got_w, want_w)
+    assert moe.grouped_dot(x, w, group_sizes).shape == (m, n)  # off the TPU: ragged_dot itself
+
+
+@pytest.mark.parametrize("case", ["random", "ties_only", "empty_held_set", "one_key_absent", "single", "none"])
+def test_counting_sort_is_the_stable_argsort(case):
+    n_keys = 9
+    rng = np.random.default_rng(4)
+    key = {
+        "random": rng.integers(0, n_keys, 4096),
+        "ties_only": np.full(512, 3),
+        "empty_held_set": np.full(512, n_keys - 1),  # every slot on an absent expert
+        "one_key_absent": rng.choice([0, 1, 2, 4, 5, 6, 7, 8], 777),
+        "single": np.array([5]),
+        "none": np.zeros(0, np.int64),
+    }[case].astype(np.int32)
+    inv, load = jax.jit(moe.counting_sort, static_argnums=1)(jnp.asarray(key), n_keys)
+    order = np.argsort(key, kind="stable")
+    np.testing.assert_array_equal(np.asarray(inv)[order], np.arange(len(key)))
+    np.testing.assert_array_equal(order, jnp.argsort(jnp.asarray(key), stable=True))
+    np.testing.assert_array_equal(load, np.bincount(key, minlength=n_keys))
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in v if isinstance(v, (list, tuple)) else [v]:
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _makers(jaxpr, shapes, found):
+    """Names of the primitives, at any depth, that make a value of one of
+    ``shapes``."""
+    for eqn in jaxpr.eqns:
+        if any(tuple(getattr(v.aval, "shape", ())) in shapes for v in eqn.outvars):
+            found.add(eqn.primitive.name)
+        for sub in _sub_jaxprs(eqn):
+            _makers(sub, shapes, found)
+    return found
+
+
+def test_no_work_on_a_whole_buffer_of_rows(tiny, seeded):
+    """In the toy model's loss and gradient (every layer recomputed, as the
+    cell runs it) a value of ``[T * top_k, d_model]`` is the buffer the
+    chunks are written into, empty at first, and nothing else: no gather, no
+    ``where``, no sum makes one, nor one of ``[T, top_k, d_model]``. A
+    whole-buffer gather that comes back fails here, on the CPU, and not only
+    on the chip."""
+    _cfg, _ref, sizes, pcfg = tiny
+    _leaves, _model, params = seeded
+    model = moe.MoEDecoder(dataclasses.replace(pcfg, remat=True, remat_policy="nothing"))
+    b, s = 3, 16  # 48 tokens, 96 slots: [96, 64] is nothing else's shape
+    batch = {k: jnp.ones((b, s), jnp.int32) for k in ("tokens", "positions", "segment_ids", "loss_mask")}
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: program_loss(model, pcfg.mtp_weight, p, batch)[0]))(params)
+    t, k, d = b * s, sizes["top_k"], sizes["d_model"]
+    carriers = {"broadcast_in_dim", "dynamic_update_slice", "while", "pjit", "custom_vjp_call", "checkpoint",
+                "custom_vjp_call_jaxpr", "scan", "custom_jvp_call", "remat"}
+    assert _makers(jaxpr.jaxpr, {(t * k // 8, d)}, set()) - carriers >= {"gather", "mul"}  # the chunks' rows are seen
+    assert _makers(jaxpr.jaxpr, {(t * k, d), (t, k, d)}, set()) <= carriers
+
+
 def program_outputs(model, params, batch):
     logits, mods = model.apply(
         {"params": params}, batch["tokens"], batch["positions"], batch["segment_ids"],
@@ -271,8 +458,12 @@ def test_trainer_step_reports_mtp_loss_and_counters(tiny, batch):
     host = {k: np.asarray(v) for k, v in batch.items()}
     state = tr.make_state(jax.random.key(0), host)
     state, out = tr.fit(state, iter([host] * 3), num_steps=3)
-    assert {"loss", "mtp_loss", "total_loss", "moe_slots", "moe_slots_dropped", "moe_load_max_over_mean"} <= set(out)
+    assert {"loss", "mtp_loss", "total_loss", "moe_slots", "moe_slots_dropped", "moe_load_max_over_mean",
+            "moe_rows_visited_share"} <= set(out)
     assert out["moe_slots_dropped"] == 0 and out["moe_slots"] > 0
+    # three expert layers of 256 slots, each through the eighths that hold its load
+    assert 0 < out["moe_rows_visited_share"] <= 1 and (out["moe_rows_visited_share"] * 24) % 1 == 0
+    assert out["moe_rows_visited_share"] * 3 * 256 >= out["moe_slots"]
     np.testing.assert_allclose(out["total_loss"], out["loss"] + pcfg.mtp_weight * out["mtp_loss"], rtol=1e-5)
 
 
@@ -289,6 +480,36 @@ def test_dense_decoder_step_is_untouched():
     state = tr.make_state(jax.random.key(0), host)
     _, out = tr.fit(state, iter([host]), num_steps=1)
     assert set(out) - {"steps_per_sec"} == {"loss", "aux_loss", "total_loss", "grad_norm", "step"}
+    assert "moe_rows_visited_share" not in out
+
+
+def test_fit_publishes_the_rows_visited_gauge_for_a_share_model_only(tiny, batch):
+    import optax
+
+    from maggy_tpu import telemetry
+    from maggy_tpu.parallel.mesh import make_mesh
+    from maggy_tpu.parallel.spec import ShardingSpec
+
+    mesh = make_mesh(ShardingSpec(fsdp=1), jax.devices()[:1])
+    runs = [
+        (moe.MoEDecoder(tiny[3]), {k: np.asarray(v) for k, v in batch.items()}),
+        (transformer.Decoder(transformer.DecoderConfig.tiny()), {"tokens": np.ones((2, 16), np.int32)}),
+    ]
+    class Recorder(telemetry.Telemetry):
+        def gauge(self, name, value):
+            if name == "moe.rows_visited_share":
+                seen[-1].append(value)
+            super().gauge(name, value)
+
+    seen = []
+    with telemetry.current(Recorder(worker="t")):
+        for model, host in runs:
+            seen.append([])
+            tr = trainer_mod.Trainer(model, optax.adamw(1e-3), mesh)
+            _, out = tr.fit(tr.make_state(jax.random.key(0), host), iter([host]), num_steps=1)
+            if "moe_rows_visited_share" in out:
+                assert seen[-1] == [out["moe_rows_visited_share"]]
+    assert [len(v) for v in seen] == [1, 0]
 
 
 @pytest.mark.parametrize("bad", [

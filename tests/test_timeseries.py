@@ -294,6 +294,17 @@ def test_every_metric_has_a_unit():
     assert {u for u in M.UNITS.values()} <= set(M.VALID_UNITS)
 
 
+@pytest.mark.parametrize("name,unit", [
+    ("moe.slots", "count"), ("moe.slots_dropped", "count"), ("moe.load_max_over_mean", "ratio"),
+    ("moe.rows_visited_share", "ratio"),
+])
+def test_expert_share_gauges_are_registered_with_their_units(name, unit):
+    """What ``Trainer.fit`` publishes for a model with expert share layers."""
+    from maggy_tpu.telemetry import metrics as M
+
+    assert name in M.GAUGES and M.UNITS[name] == unit
+
+
 def test_lint_units_and_alert_registry_self_checks():
     mod = load_tool("check_telemetry_names")
     registry = mod.load_registry(REPO)
