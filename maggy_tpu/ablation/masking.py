@@ -1,4 +1,4 @@
-"""Factory-free model ablation (VERDICT r3 item 3).
+"""Factory-free model ablation.
 
 The reference ablates layers of *any* user Keras model by JSON surgery —
 ``model_from_json`` after deleting named layers (reference loco.py:82-136) —

@@ -39,7 +39,7 @@ class TuneConfig:
         no candidate is memory-pruned.
     :param measure: run the measured stage (short trials through the HPO
         driver + ASHA). ``False`` picks the winner from the static
-        flops/bytes ranking alone — the cheap mode bench.py uses.
+        flops/bytes ranking alone (the cheap mode).
     :param steps_per_unit: train steps per unit of ASHA budget; a trial at
         rung budget ``b`` runs ``b * steps_per_unit`` measured steps.
     :param asha_reduction_factor / asha_resource_min / asha_resource_max:

@@ -3,7 +3,7 @@ environments (core/environment/hopsworks.py:33, databricks.py:23).
 
 Backed by ``fsspec``: the filesystem protocol comes from the root URL's
 scheme (``gs://`` in production, ``memory://`` in tests — which is how this
-class is exercised for real without a bucket, VERDICT r3 item 8). Raises a
+class is exercised for real without a bucket). Raises a
 clear error at first use when the protocol's driver isn't importable, so
 local development never needs gcsfs.
 """

@@ -519,7 +519,7 @@ class Client:
         come fast (2 ms, doubling) and only a genuinely idle executor backs
         off to the full ``poll`` interval — "executors always busy" is the
         reference's one published claim (DistributedML'20), and a fixed
-        50 ms first retry measurably taxed it (tools/bench_async_vs_bsp.py)."""
+        50 ms first retry taxes it on every trial boundary."""
         delay = 0.002
         while True:
             reply = self._request({"type": "GET"})

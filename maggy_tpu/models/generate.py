@@ -186,7 +186,7 @@ def prefill(decode_model, params, tokens, positions, segment_ids=None,
     K/V (+ segment id) cache slot in a single forward instead of one apply
     per token. Returns ``(logits [B, T, V], cache)``; feed the cache to
     further single-token applies or :func:`generate_cached_packed`.
-    (VERDICT r4 item 4 — the reference has no decode path at all.)"""
+    (The reference has no decode path at all.)"""
     if cache is None:
         cache = init_cache(
             decode_model, tokens, mesh=mesh, packed=segment_ids is not None

@@ -47,8 +47,7 @@ REMAT_POLICIES = {
 
 
 def _parse_ablated(ablated, n_layers: int):
-    """Component-name grammar for factory-free LOCO ablation (VERDICT r3
-    item 3): "attn" / "mlp" (that sublayer in every layer), "layers.<i>"
+    """Component-name grammar for factory-free LOCO ablation: "attn" / "mlp" (that sublayer in every layer), "layers.<i>"
     (layer i entirely), "layers.<i>.attn" / "layers.<i>.mlp". Returns a
     [n_layers, 2] float gate array (attn, mlp) or None when nothing is
     ablated. Raises on unknown names so typos never silently train the full
@@ -107,10 +106,12 @@ class DecoderConfig:
     remat: bool = False
     # which intermediates remat keeps: "dots_attn" saves projection/MLP matmul
     # outputs (no-batch-dim dots) plus the attention kernel's output, so the
-    # backward recomputes only cheap elementwise work — measured fastest on
-    # v5e at every S (BENCH_NOTES round 2; "nothing" costs ~27% at S=1024).
-    # "dots" drops the attention output (re-runs the flash forward in the
-    # backward); "nothing" recomputes the whole layer (minimum HBM).
+    # backward recomputes only cheap elementwise work. "dots" drops the
+    # attention output (re-runs the flash forward in the backward); "nothing"
+    # recomputes the whole layer (minimum HBM). Not compared on the chip on
+    # the current code: both benchmark cells run "nothing", because their
+    # step fits one v5e no other way (PERF.md section 4: 13.51 GiB planned
+    # against 16.90 of 15.75 without), and read it as train.recompute_share.
     remat_policy: str = "dots_attn"
     logits_softcap: float = 0.0
     tie_embeddings: bool = False
@@ -130,8 +131,8 @@ class DecoderConfig:
     num_pages: int = 0
     # KV-cache read chunk: decode attends over ceil(written/chunk) chunks of
     # the cache instead of all max_seq_len slots — HBM traffic (the decode
-    # bottleneck, ~4x off roofline per BENCH_NOTES r1) tracks the ACTUAL
-    # prefix length. Rounded down to a divisor of max_seq_len at use
+    # bottleneck) tracks the ACTUAL prefix length. Rounded down to a divisor
+    # of max_seq_len at use
     decode_chunk: int = 256
     # False drops the nn.with_partitioning logical-axis annotations from every
     # param (identical values/tree). Used where params are placed manually —
@@ -461,14 +462,14 @@ class Attention(nn.Module):
         ``max_seq_len`` and attend the chunk's queries over everything cached
         so far (the KV-cache path the recompute-based generate() lacks).
 
-        Length-adaptive reads (VERDICT r3 item 7): the cache is consumed in
+        Length-adaptive reads: the cache is consumed in
         ``decode_chunk``-sized blocks under a dynamic-trip-count loop that
         stops after the last WRITTEN chunk, so per-step HBM traffic — the
         decode bottleneck — is proportional to the actual prefix, not
         ``max_seq_len``. Online-softmax across chunks (same recurrence as
         ops.attention) keeps the math exact.
 
-        Packed batches (VERDICT r4 item 4): with ``segment_ids`` a packed
+        Packed batches: with ``segment_ids`` a packed
         prompt prefills in ONE pass — the ids are cached alongside K/V and
         every read is masked to the query's segment, so segments cannot
         attend across their boundaries. Later single-token steps may omit

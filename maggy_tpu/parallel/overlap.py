@@ -55,7 +55,6 @@ __all__ = [
     "reflatten_opt_state",
     "opt_state_bytes_per_device",
     "latency_hiding_flags",
-    "measure_step_times",
     "record_overlap_gauges",
 ]
 
@@ -306,34 +305,6 @@ def latency_hiding_flags() -> Tuple[str, ...]:
         "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
         "--xla_tpu_overlap_compute_collective_tc=true",
     )
-
-
-def measure_step_times(
-    entries: Dict[str, Tuple[Any, Any]], batch, repeats: int = 5
-) -> Dict[str, float]:
-    """Min-of-``repeats`` wall time (ms) per labelled step variant.
-
-    ``entries`` maps label -> ``(step_fn, state)`` where ``step_fn(state,
-    batch) -> (state, metrics)`` is a compiled train step and ``state`` is
-    that variant's own TrainState (steps donate their input, so variants
-    must not share one). The first call per variant is the untimed
-    compile/warmup; timed calls feed the returned state back in."""
-    import time as _time
-
-    import jax
-
-    out = {}
-    for label, (fn, state) in entries.items():
-        state, metrics = fn(state, batch)
-        jax.block_until_ready(metrics)  # compile + warmup
-        best = float("inf")
-        for _ in range(max(1, int(repeats))):
-            t0 = _time.perf_counter()
-            state, metrics = fn(state, batch)
-            jax.block_until_ready((state, metrics))
-            best = min(best, (_time.perf_counter() - t0) * 1e3)
-        out[label] = best
-    return out
 
 
 def record_overlap_gauges(

@@ -423,7 +423,7 @@ def pipeline_forward_loss(
     stage_has_aux: bool = False,
 ):
     """Forward-only GPipe sweep returning ``(loss, aux)`` microbatch means —
-    the EVAL counterpart of :func:`pipeline_grads_1f1b` (VERDICT r4 item 9):
+    the EVAL counterpart of :func:`pipeline_grads_1f1b`:
     per-device live state is one stage's params plus a single microbatch
     activation, instead of unstacking the whole model replicated on every
     device (which OOMs exactly in the regime pipeline parallelism exists

@@ -15,7 +15,6 @@ schedule with blocks distributed over devices.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -92,64 +91,22 @@ def ring_attention(
     causal: bool = True,
     axis_name: str = AXIS_SEQ,
     segment_ids=None,
-    impl: str = "xla",
-    interpret=None,
 ):
     """Global-view ring attention: q [B,S,H,D], k/v [B,S,Kh,D] sharded on S.
 
     Call under ``jit`` with the mesh active; works as the Decoder's
-    ``attention_fn`` when the sharding spec has ``sp > 1``.
+    ``attention_fn`` when the sharding spec has ``sp > 1``. XLA schedules the
+    ``ppermute`` rotation against the block compute; fully differentiable.
 
-    :param impl: ``"xla"`` — the shard_map/ppermute ring (XLA schedules the
-        rotation; fully differentiable). ``"pallas"`` — the
-        :mod:`maggy_tpu.ops.ring_flash` kernel: the KV rotation is issued
-        in-kernel via ``make_async_remote_copy`` and explicitly overlapped
-        with the block compute, forward AND backward (the bwd ring rotates
-        (k, v, dk, dv) together, recomputing probabilities from the saved
-        LSE). ``"auto"`` — the XLA ring unless the mesh is on TPU *and*
-        ``MAGGY_TPU_RING_PALLAS=1`` is set: the RDMA kernel has not yet been
-        timed on real multi-chip ICI, so an untimed kernel is never the
-        silent default training path (it stays one env var away, and the
-        ``bench.py`` ring microbench records the comparison when hardware
-        allows).
-    :param interpret: pallas only — run under the TPU interpret machine
-        (defaults to True off-TPU so CPU meshes can test the kernel).
     :param segment_ids: optional [B, S] int ids for packed sequences (sharded
         on S like q/k/v); tokens only attend within their own segment. The
-        segment-id shard rotates around the ring with its KV shard. Supported
-        on the XLA ring; the Pallas kernel rejects it for now.
+        segment-id shard rotates around the ring with its KV shard.
     """
-    if impl == "auto":
-        # resolve from the mesh's devices, not the process default backend —
-        # a CPU mesh created on a TPU-capable host must not pick pallas
-        on_tpu = mesh.devices.flat[0].platform == "tpu"
-        opt_in = os.environ.get("MAGGY_TPU_RING_PALLAS") == "1"
-        impl = "pallas" if (on_tpu and opt_in and segment_ids is None) else "xla"
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"impl must be 'xla', 'pallas', or 'auto', got {impl!r}")
     num_shards = mesh.shape[axis_name]
     if num_shards == 1:
         return ops_attn.blockwise_attention(
             q, k, v, causal=causal, segment_ids=segment_ids
         )
-
-    if impl == "pallas":
-        if segment_ids is not None:
-            raise NotImplementedError(
-                "the Pallas RDMA ring kernel does not support segment_ids; "
-                "use impl='xla' (or 'auto', which routes packed batches there)"
-            )
-        return _pallas_ring(
-            q, k, v, mesh=mesh, causal=causal, axis_name=axis_name,
-            interpret=interpret,
-        )
-    return _xla_ring(
-        q, k, v, segment_ids, mesh=mesh, causal=causal, axis_name=axis_name
-    )
-
-
-def _xla_ring(q, k, v, segment_ids, *, mesh, causal, axis_name):
-    num_shards = mesh.shape[axis_name]
     spec = P(None, axis_name, None, None)
     seg_spec = P(None, axis_name)
     use_segments = segment_ids is not None
@@ -172,29 +129,14 @@ def _xla_ring(q, k, v, segment_ids, *, mesh, causal, axis_name):
     )(q, k, v, segment_ids)
 
 
-def _pallas_ring(q, k, v, *, mesh, causal, axis_name, interpret):
-    from maggy_tpu.ops.ring_flash import ring_flash_attention
-
-    if interpret is None:
-        interpret = mesh.devices.flat[0].platform != "tpu"
-    # the kernel carries its own custom_vjp (ring backward with rotating
-    # dk/dv accumulators) — nothing to wrap here
-    return ring_flash_attention(
-        q, k, v, mesh=mesh, causal=causal, axis_name=axis_name,
-        interpret=interpret,
-    )
-
-
-def make_ring_attention(mesh, axis_name: str = AXIS_SEQ, impl: str = "auto"):
+def make_ring_attention(mesh, axis_name: str = AXIS_SEQ):
     """Build an ``attention_fn`` for DecoderConfig: same signature as
-    ``default_attention``. ``impl="auto"`` trains through the XLA ppermute
-    ring; set ``MAGGY_TPU_RING_PALLAS=1`` on a TPU mesh to opt into the RDMA
-    Pallas kernel (fwd+bwd) once it has a recorded win on real ICI."""
+    ``default_attention``."""
 
     def attn(q, k, v, *, causal: bool = True, segment_ids=None):
         return ring_attention(
             q, k, v, mesh=mesh, causal=causal, axis_name=axis_name,
-            segment_ids=segment_ids, impl=impl,
+            segment_ids=segment_ids,
         )
 
     return attn

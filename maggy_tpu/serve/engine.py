@@ -198,8 +198,7 @@ class Engine:
         self.prefix_tokens_saved = 0
         self.prefill_calls = 0  # full (from-scratch) prefills
         # prompt tokens ACTUALLY computed by prefill (suffix-only on any
-        # reuse path) — the figure the fleet-KV bench compares across
-        # affinity settings (bench.py extra.fleetkv)
+        # reuse path) — the figure to compare across affinity settings
         self.prefill_tokens = 0
 
         # ---- paged KV cache (docs/serving.md "Paged KV cache")
@@ -219,7 +218,7 @@ class Engine:
         self.pages_per_row = self.max_seq_len // self.page_size
         # pool defaults to the dense capacity (num_slots full rows) plus the
         # reserved scratch page; pass num_pages to run UNDER the dense
-        # budget — that is the whole point (bench.py extra.paging)
+        # budget — that is the whole point
         self._num_pages_explicit = num_pages is not None
         self.num_pages = (
             int(num_pages)
@@ -1615,7 +1614,7 @@ class Engine:
 
     @property
     def tier_stats(self) -> Dict[str, Any]:
-        """Host-DRAM tier accounting for SSTATS/monitor/bench: pool
+        """Host-DRAM tier accounting for SSTATS and the monitor: pool
         occupancy plus the policy's spill/fill ledger. ``{"enabled":
         False}`` when the tier is off so panels can branch safely."""
         if self.tier is None:
@@ -1633,7 +1632,7 @@ class Engine:
 
     @property
     def paging_stats(self) -> Dict[str, Any]:
-        """Paged-cache accounting for SSTATS/monitor/bench: pool occupancy,
+        """Paged-cache accounting for SSTATS and the monitor: pool occupancy,
         sharing, and the per-request page cap. ``{"paged": False}`` on the
         dense fallback so panels can branch without key errors."""
         if not self.paged:

@@ -128,7 +128,7 @@ def diurnal_burst_spec(
     correlated burst pinned to the swell's crest.
 
     This is the offered load the fleet autoscaler is sized against
-    (``bench.py extra.autoscale``, ``tests/test_autoscale.py``): quiet
+    (``tests/test_autoscale.py``): quiet
     shoulders where scale-in should engage, a crest that demands
     scale-out, and a mid-crest burst that drives the brownout ladder to
     level >= 2. Two tenants (a standard-class majority with shared prefix
@@ -404,7 +404,7 @@ class TrafficReplay:
 def summarize(outcomes: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     """Per-class rollup of a replay's outcomes: counts by status, TTFT
     percentiles of completed requests, shed fraction — the shape the
-    overload acceptance test and ``bench.py extra.qos`` both assert on."""
+    overload acceptance test asserts on."""
     by_class: Dict[str, Dict[str, Any]] = {}
     for o in outcomes:
         cls = by_class.setdefault(

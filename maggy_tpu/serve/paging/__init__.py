@@ -10,8 +10,8 @@ pages. Consequences, in order of importance:
 
 * **Concurrency tracks actual lengths.** A request occupies
   ``ceil(tokens/page_size)`` pages, not ``max_seq_len`` rows, so the same
-  HBM admits several times more typical-length requests (``bench.py
-  extra.paging`` gates ≥2x at a fixed simulated budget).
+  HBM admits more typical-length requests (how many more is not
+  measured on the chip: ROADMAP W1, D3).
 * **Prefix sharing is aliasing, not copying.** Admitting a request whose
   prompt shares a resident prefix points its page-table entries at the
   source's pages (ref-counted; ``serve.pages_shared``) instead of copying

@@ -63,7 +63,7 @@ class BlockAllocator:
         # referenced pages; a page freed is a page forgotten.
         # guarded-by: the engine lock (all allocator mutation already is)
         self._last_access: Dict[int, int] = {}
-        # cumulative counters (monotonic; bench/stats)
+        # cumulative counters (monotonic; stats)
         self.allocs = 0
         self.shares = 0
 

@@ -1,7 +1,9 @@
 """FLOPs/MFU estimation for telemetry gauges.
 
-Same model as bench.py's headline metric: training FLOPs/token ≈ 6·params
-(fwd+bwd matmul estimate) against the chip's published peak, looked up by
+Training FLOPs/token ≈ 6·params (fwd+bwd matmul estimate: it counts
+embedding rows and every expert and leaves attention out, so the monitor's
+``mfu_est`` is an estimate; the benchmark's ``train.mfu`` counts from
+``benchmark/counts.py``) against the chip's published peak, looked up by
 ``device.device_kind`` in :data:`PEAK_BF16_FLOPS`. A device that is not in
 the table has no peak here — an "MFU" against a guessed peak would be noise,
 so the gauge is omitted (CPU test meshes, GPU hosts) and an unknown TPU kind

@@ -153,8 +153,7 @@ def decoder_pipeline_parts(
     """Build the 1F1B parts for a :class:`Decoder`.
 
     Raises loudly for anything the pipeline path cannot honor — a silently
-    replicated stage axis is the failure mode this replaces (VERDICT r3
-    item 2)."""
+    replicated stage axis is the failure mode this replaces."""
     from maggy_tpu.models.moe import MoEDecoder, _ScannedMoELayer
 
     is_moe = isinstance(model, MoEDecoder)
@@ -204,7 +203,7 @@ def decoder_pipeline_parts(
             f"ep={ep} under pp>1 needs an MoE model (got "
             f"{type(model).__name__}): a dense model has no expert dims, so "
             "the expert axis would silently replicate every stage param and "
-            "waste ep-1 of every ep devices (VERDICT r3 item 2 failure mode)"
+            "waste ep-1 of every ep devices"
         )
     if ep > 1 and getattr(cfg, "n_experts", 0) % ep:
         raise ValueError(

@@ -375,8 +375,7 @@ class Trainer:
         return mode
 
     def _build_overlap_train_step(
-        self, mode: str, manual: Tuple[str, ...], dz: int,
-        comm_axes: Optional[Tuple[str, ...]] = None, donate: bool = True,
+        self, mode: str, manual: Tuple[str, ...], dz: int
     ):
         """The bucketed-collective train step (docs/distributed.md "Gradient
         overlap & ZeRO").
@@ -392,10 +391,6 @@ class Trainer:
         optimizer update touches only the local shard (the optax state IS
         the flat shard layout — see ``_init_fn``), and an all-gather
         rebuilds the params; optimizer memory per device drops ~1/dz.
-
-        ``comm_axes`` (bench comm-probe only, bucket mode) restricts which
-        axes actually reduce — () strips every collective to time pure
-        compute; the resulting numerics are wrong on purpose.
         """
         from jax.sharding import PartitionSpec as P
 
@@ -403,11 +398,7 @@ class Trainer:
         from maggy_tpu.parallel import overlap
         from jax import shard_map as _shard_map
 
-        axes_comm = tuple(manual if comm_axes is None else comm_axes)
-        assert all(a in manual for a in axes_comm)
         assert mode in ("bucket", "zero") and (mode != "zero" or dz > 1)
-        if mode == "zero" and comm_axes is not None:
-            raise ValueError("comm-probe variants are bucket-mode only")
         mesh_shape = dict(self.mesh.shape)
         n_manual = 1
         for a in manual:
@@ -446,12 +437,12 @@ class Trainer:
             # slow cross-slice all-reduce overlaps it (and later buckets'
             # backward) independently
             with jax.named_scope("grad_sync"):
-                if AXIS_DATA in axes_comm:
+                if AXIS_DATA in manual:
                     if scatter:
                         vec = jax.lax.psum_scatter(vec, AXIS_DATA, tiled=True)
-                    elif AXIS_DATA in manual:
+                    else:
                         vec = jax.lax.psum(vec, AXIS_DATA)
-                if AXIS_SLICE in axes_comm:
+                if AXIS_SLICE in manual:
                     vec = jax.lax.psum(vec, AXIS_SLICE)
             return vec
 
@@ -561,25 +552,7 @@ class Trainer:
                 "step": state.step,
             }
 
-        return jax.jit(train_step, donate_argnums=(0,) if donate else ())
-
-    def overlap_step_variant(
-        self, comm_axes: Optional[Tuple[str, ...]] = None, donate: bool = True
-    ):
-        """A compiled bucketed step reducing only over ``comm_axes`` — the
-        bench's comm-probe (``()`` strips all collectives to time pure
-        compute). Timing-only: skipped reductions make the numerics wrong
-        on purpose. Requires an eligible bucket-mode (zero_stage=0)
-        trainer."""
-        mode, manual, dz = self._overlap_mode()
-        if mode != "bucket":
-            raise ValueError(
-                "overlap_step_variant needs an overlap-eligible "
-                f"zero_stage=0 trainer (resolved mode: {mode!r})"
-            )
-        return self._build_overlap_train_step(
-            mode, manual, dz, comm_axes=comm_axes, donate=donate
-        )
+        return jax.jit(train_step, donate_argnums=(0,))
 
     # ------------------------------------------------------------------ state
 
@@ -1211,7 +1184,7 @@ class Trainer:
         """Mean loss over ``num_batches`` held-out batches (no state update).
         The loss is computed inside jit so full logits never leave the
         device. Under pp>1 the loss flows through the pipeline stages
-        (forward-only GPipe sweep, VERDICT r4 item 9) — per-device live
+        (forward-only GPipe sweep) — per-device live
         bytes stay bounded by one stage's params + a microbatch activation,
         never the unstacked full model.
 

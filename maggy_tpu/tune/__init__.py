@@ -20,7 +20,7 @@ adds zero distributed machinery.
 
 Winners persist in a tuning cache on the env seam (local or ``gs://``
 identically), keyed by (model fingerprint, topology, dtype, search grid);
-``bench.py`` and the serve CLI consult it before falling back to defaults.
+the serve CLI consults it before falling back to defaults.
 
     from maggy_tpu.tune import tune, TuneConfig
     result = tune(Decoder(cfg), TuneConfig(presets=("dp", "fsdp", "2d")))

@@ -7,7 +7,7 @@
 Prints ONE JSON line (the TuneResult) on stdout; progress goes to stderr.
 The winner also lands in the tuning cache under the ambient experiment root
 (``MAGGY_TPU_LOG_ROOT``/``tune_cache``, local or ``gs://``), where
-``bench.py`` and ``python -m maggy_tpu.serve --mesh auto`` pick it up.
+``python -m maggy_tpu.serve --mesh auto`` picks it up.
 """
 
 from __future__ import annotations

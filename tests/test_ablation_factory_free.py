@@ -1,4 +1,4 @@
-"""Factory-free model ablation (VERDICT r3 item 3): DecoderConfig.without
+"""Factory-free model ablation: DecoderConfig.without
 gating, the generic param-subtree masking fallback, and the driver's
 auto-derivation — reference parity with Keras-JSON layer surgery
 (loco.py:82-136) minus the user plumbing."""

@@ -58,16 +58,43 @@ def test_blockwise_grads_match():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
 
+@pytest.mark.parametrize("h,kh", [(4, 4), (4, 2)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_ring_matches_reference(causal):
+def test_ring_matches_reference(causal, h, kh):
     mesh = make_mesh(ShardingSpec(sp=4, dp=2))
-    q, k, v = qkv(b=2, s=64, h=4, d=16)
+    q, k, v = qkv(b=2, s=64, h=h, kh=kh, d=16)
     ref = default_attention(q, k, v, causal=causal)
     with mesh:
         out = jax.jit(
             lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=causal)
         )(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("h,kh", [(4, 4), (4, 2)])
+def test_ring_grads_match_reference(h, kh):
+    """dq, dk and dv through the ppermute ring, with grouped heads too: k and
+    v rotate at their own head count and are repeated on the compute side, so
+    their cotangents must sum over the group on the way back."""
+    mesh = make_mesh(ShardingSpec(sp=4, dp=2))
+    q, k, v = qkv(b=2, s=64, h=h, kh=kh, d=16)
+    # a weighted sum, so every output element carries its own cotangent
+    w = jax.random.normal(jax.random.key(7), q.shape, q.dtype)
+
+    def loss_ring(q, k, v):
+        return (ring_attention(q, k, v, mesh=mesh, causal=True) * w).sum()
+
+    def loss_ref(q, k, v):
+        return (default_attention(q, k, v, causal=True) * w).sum()
+
+    with mesh:
+        g = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), g, g_ref):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name
+        )
 
 
 @pytest.mark.slow

@@ -432,8 +432,8 @@ def test_forced_regression_rolls_back(tmp_env):
 
 def test_controller_observe_overhead_budget():
     """The per-step controller cost (window append + amortized
-    diagnose/plan) stays far under 2% of any realistic step — the CI
-    mirror of bench.py extra.autopilot's gate."""
+    diagnose/plan) stays far under 2% of any realistic step (a host
+    cost, asserted loosely; no device number)."""
     target = KnobTarget(knobs={"train.prefetch_depth": 2, "train.metrics_window": 2})
     c = Controller(
         target,

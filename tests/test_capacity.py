@@ -461,18 +461,3 @@ def test_engine_registers_accounts_and_capacity_surfaces():
     assert engine.pages_held_peak(slot) == 0
     assert engine.prefix_stats["prefix_residency"]["resident_prefixes"] == 0
     engine.allocator.check_invariants()
-
-
-# ----------------------------------------------------------- bench gate
-
-
-def test_bench_capacity_gate():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    out = bench.bench_capacity(quick=True)
-    assert out["within_budget"] is True
-    assert 0.0 < out["mem_headroom_pct"] <= 1.0
-    assert out["accounts"] == 4

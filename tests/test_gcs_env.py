@@ -1,4 +1,4 @@
-"""GcsEnv exercised for real over fsspec ``memory://`` (VERDICT r3 item 8):
+"""GcsEnv exercised for real over fsspec ``memory://``:
 dump/load, directory layout, the driver-registry round-trip in both secret
 modes, remote sharded-dataset streaming through the env seam, and a full
 lagom experiment writing every artifact into the object store."""

@@ -1,4 +1,4 @@
-"""The PRODUCTION gs:// path (VERDICT r4 item 7): the gcsfs driver is
+"""The PRODUCTION gs:// path: the gcsfs driver is
 actually instantiated — no longer dead code behind the memory:// CI seam —
 with error paths for a missing driver, and live read/write coverage that
 engages whenever the environment can reach GCS (env-gated on a bucket for

@@ -127,7 +127,7 @@ def test_lagom_hyperband_e2e(tmp_env):
 
 @pytest.mark.slow
 def test_hyperband_fleet_scale_stress():
-    """VERDICT r4 item 6: 16 simulated executors, ~264 trials, 5%
+    """Hyperband at fleet scale: 16 simulated executors, ~264 trials, 5%
     stragglers, through the REAL controllers (the driver's one-decision-
     at-a-time discipline). Locks three facts: concurrent cycles
     (iterations=N) beat the pre-knob serial-cycle behavior on both idle

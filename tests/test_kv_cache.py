@@ -48,7 +48,7 @@ def test_multi_chunk_cache_reads_match_full_forward():
     """Length-adaptive chunked cache reads (decode_chunk < max_seq_len): the
     cross-chunk online-softmax recurrence must reproduce the full forward —
     geometry chosen so 4 chunks are live and the prefix crosses chunk
-    boundaries mid-decode (VERDICT r3 item 7 path, multi-chunk case)."""
+    boundaries mid-decode (the length-adaptive read, multi-chunk case)."""
     cfg = DecoderConfig.tiny(max_seq_len=64, decode_chunk=16, dtype=jnp.float32)
     model = Decoder(cfg)
     tokens = jnp.asarray(
@@ -191,7 +191,7 @@ def test_tp_decode_cache_sharded():
 
 @pytest.mark.slow
 def test_packed_prefill_logits_match_per_sequence(setup):
-    """VERDICT r4 item 4: a packed prompt batch prefills in ONE pass, and the
+    """A packed prompt batch prefills in ONE pass, and the
     segment mask isolates each segment — every segment's prefill logits
     equal a plain forward over that sequence alone."""
     from maggy_tpu.models.generate import prefill

@@ -244,6 +244,63 @@ def test_lagom_injects_train_context(tmp_env):
     assert isinstance(seen["ctx"], TrainContext)
 
 
+# trial durations long enough that the one-time driver bring-up (~0.4 s) does
+# not distort the steady-state comparison. heavy_tail: most trials fast, a few
+# 10x slower (the regime the paper's upper band comes from: a BSP wave is as
+# slow as its slowest member)
+_DURATION_OF = {
+    "uniform": lambda x: 0.1 + 0.9 * x,  # 0.1-1.0 s
+    "heavy_tail": lambda x: 0.1 + 1.5 * x**3,  # 0.1-1.6 s, skewed
+}
+
+
+def _run_async(num_trials, num_executors, dist, seed=0):
+    """One real lagom() run; trial duration rides the searchspace so the
+    driver's scheduling order decides which executor sleeps how long."""
+    durations = []
+    duration_of = _DURATION_OF[dist]
+
+    def train(hparams, reporter):
+        d = duration_of(float(hparams["x"]))
+        reporter.broadcast(float(hparams["x"]), step=0)
+        t0 = time.perf_counter()
+        time.sleep(d)
+        # (start, ACTUAL elapsed): elapsed so sleep overshoot on a loaded host
+        # taxes the BSP baseline too; start so BSP waves form in ASSIGNMENT
+        # order (completion order is roughly ascending, and similar-duration
+        # waves would understate what a submission-ordered barrier pays)
+        durations.append((t0, time.perf_counter() - t0))
+        return {"metric": float(hparams["x"])}
+
+    t0 = time.perf_counter()
+    result = experiment.lagom(
+        train,
+        HyperparameterOptConfig(
+            num_trials=num_trials,
+            optimizer="randomsearch",
+            searchspace=Searchspace(x=("DOUBLE", [0.0, 1.0])),
+            direction="max",
+            es_policy="none",
+            num_executors=num_executors,
+            hb_interval=0.05,
+            seed=seed,
+        ),
+    )
+    wall = time.perf_counter() - t0
+    assert result["num_trials"] == num_trials, result
+    durations.sort(key=lambda sd: sd[0])
+    return wall, [elapsed for _, elapsed in durations]
+
+
+def _bsp_wall(durations, num_executors):
+    """Synchronous BSP cost of the SAME trials: waves of num_executors, each
+    as slow as its slowest trial (the Spark stage barrier)."""
+    return sum(
+        max(durations[i : i + num_executors])
+        for i in range(0, len(durations), num_executors)
+    )
+
+
 @pytest.mark.slow
 def test_async_beats_bsp_wallclock(tmp_env):
     """The reference's ONE published benchmark (DistributedML'20): async
@@ -252,16 +309,11 @@ def test_async_beats_bsp_wallclock(tmp_env):
     control plane (driver + RPC + executor threads) against the BSP cost of
     the SAME per-trial durations. Conservative bounds: heavy-tailed trials
     (the paper's regime) must clear 25%; even uniform durations must show
-    a double-digit win."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
-    from bench_async_vs_bsp import bsp_wall, run_async
-
-    wall_u, durs_u = run_async(48, 8, "uniform", seed=1)
-    red_u = 1.0 - wall_u / bsp_wall(durs_u, 8)
-    wall_h, durs_h = run_async(48, 8, "heavy_tail", seed=1)
-    red_h = 1.0 - wall_h / bsp_wall(durs_h, 8)
+    a double-digit win. The trials sleep: this is a property of the
+    scheduler, not a timing of compute."""
+    wall_u, durs_u = _run_async(48, 8, "uniform", seed=1)
+    red_u = 1.0 - wall_u / _bsp_wall(durs_u, 8)
+    wall_h, durs_h = _run_async(48, 8, "heavy_tail", seed=1)
+    red_h = 1.0 - wall_h / _bsp_wall(durs_h, 8)
     assert red_h > 0.25, (red_h, wall_h)
     assert red_u > 0.10, (red_u, wall_u)

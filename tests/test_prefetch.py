@@ -4,6 +4,7 @@ metrics drain's broadcast contract, evaluate's single host sync, and the
 check_host_sync lint."""
 
 import textwrap
+import threading
 import time
 
 import jax
@@ -174,36 +175,64 @@ def test_fit_resume_skips_without_materializing(tmp_path):
     assert fresh.batches_materialized == 6, fresh.batches_materialized
 
 
-# ------------------------------------------------------------- fit overlap win
+# ---------------------------------------------------------- fit overlap order
 
 
-def test_fit_overlap_wall_clock_is_max_not_sum():
-    """ACCEPTANCE: with a sleep-based loader, fit through the prefetcher
-    approaches max(loader, step) per step instead of loader + step."""
-    trainer, state, data = _tiny_trainer()
-    # compile once so neither timed run pays it
-    state, _ = trainer.fit(state, data, num_steps=1, prefetch=0)
+def _logged_fit(trainer, state, data, n, prefetch):
+    """fit() with every pull from the loader and every dispatch of a step
+    logged into one list. With a prefetcher, a dispatch first waits (bounded)
+    for the next batch's pull: the producer thread owes it without the loop's
+    help. Returns (state, log, whether every such wait was met)."""
+    log, cond = [], threading.Condition()
 
-    sleep_s = 0.04
-
-    def slow(src):
+    def loader(src):
+        k = 0
         while True:
-            time.sleep(sleep_s)
+            with cond:
+                log.append(("pull", k))
+                cond.notify_all()
             yield next(src)
+            k += 1
 
-    n = 10
-    t0 = time.perf_counter()
-    state, _ = trainer.fit(state, slow(data), num_steps=n, prefetch=0)
-    t_sync = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    state, _ = trainer.fit(state, slow(data), num_steps=n, prefetch=2)
-    t_over = time.perf_counter() - t0
-    # sync pays sleep + step serially every step; overlapped pays ~max of
-    # the two. Demand a 1.25x margin — loose enough for CI noise, far above
-    # anything a non-overlapping implementation can produce when the sleep
-    # alone is >= 40ms/step of the budget.
-    assert t_over < t_sync / 1.25, (t_sync, t_over)
-    assert t_over < n * sleep_s * 1.8, (t_sync, t_over)
+    step = trainer.step
+    met = []
+
+    def logged_step(state, batch):
+        with cond:
+            i = sum(1 for kind, _ in log if kind == "step")
+            if prefetch and i + 1 < n:
+                met.append(cond.wait_for(lambda: ("pull", i + 1) in log, timeout=30))
+            log.append(("step", i))
+        return step(state, batch)
+
+    trainer.step = logged_step
+    try:
+        state, _ = trainer.fit(state, loader(data), num_steps=n, prefetch=prefetch)
+    finally:
+        del trainer.step
+    return state, log, all(met)
+
+
+def test_fit_prefetch_pulls_next_batch_before_dispatch():
+    """ACCEPTANCE: through the prefetcher batch i+1 is pulled (and placed, by
+    the producer thread) before step i is dispatched, so the loader's time
+    hides behind the step's; without it the loop pulls batch i+1 only after
+    it dispatched step i. Either way exactly ``num_steps`` batches are
+    consumed. No clock: what the overlap buys on the chip is the benchmark's
+    ``train.idle_in_input_share``."""
+    trainer, state, data = _tiny_trainer()
+    n = 6
+    pulls = [("pull", k) for k in range(n)]
+
+    state, log, _ = _logged_fit(trainer, state, data, n, prefetch=0)
+    assert log == [e for k in range(n) for e in (("pull", k), ("step", k))]
+
+    state, log, met = _logged_fit(trainer, state, data, n, prefetch=2)
+    assert met, log
+    assert [e for e in log if e[0] == "pull"] == pulls  # no more than n
+    assert [e for e in log if e[0] == "step"] == [("step", k) for k in range(n)]
+    for i in range(n - 1):
+        assert log.index(("pull", i + 1)) < log.index(("step", i)), (i, log)
 
 
 # -------------------------------------------------------- lagged metrics drain

@@ -1,4 +1,4 @@
-"""Experiment resume after a hard driver kill (VERDICT r1 item 10).
+"""Experiment resume after a hard driver kill.
 
 A subprocess runs a seeded random-search HPO and SIGKILLs ITSELF (driver,
 server, and executor threads all die — the ungraceful crash) once enough

@@ -80,8 +80,8 @@ def test_asha_256_trials_scale(tmp_env):
     """BASELINE config-2 shape at control-plane scale: 256 ASHA trials with a
     small REAL train step (jitted ridge-regression GD, compiled once) through
     the full driver/RPC/executor path. Asserts completion without deadlock,
-    no leaked executor/heartbeat threads, and monotone trial completion
-    (VERDICT r1 item 9). Runs in well under 3 minutes on the CI CPU mesh."""
+    no leaked executor/heartbeat threads, and monotone trial completion.
+    Runs in well under 3 minutes on the CI CPU mesh."""
     import time
 
     import jax

@@ -303,8 +303,8 @@ def test_check_telemetry_names_lint():
 def test_trace_overhead_recorder_hot_path():
     """The full per-record observability cost — span + gauge + event +
     histogram, trace-tagged, flight-teed — stays far under any realistic
-    step budget (bench.py extra.trace_overhead tracks the engine-level A/B;
-    2% of even a 5 ms step is 100 us, asserted loosely here)."""
+    step budget (2% of even a 5 ms step is 100 us, asserted loosely here;
+    a host cost, no device number)."""
     tel = Telemetry(worker="bench")
     n = 2000
     with tracing.scope("hot"):

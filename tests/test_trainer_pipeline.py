@@ -1,4 +1,4 @@
-"""Trainer-integrated pipeline parallelism (VERDICT r3 item 2): a mesh with
+"""Trainer-integrated pipeline parallelism: a mesh with
 stage>1 must actually train the real Decoder under 1F1B — same numbers as the
 dense path — or raise loudly, never silently replicate the stage axis."""
 
@@ -307,7 +307,7 @@ def test_pp_raises_loudly_for_unsupported():
 
 
 def test_pp_tp_matches_dense_loss_and_grads():
-    """pp=2 x tp=2 x dp=2 (VERDICT r4 item 2): stage params carry
+    """pp=2 x tp=2 x dp=2: stage params carry
     tensor-sharded dims (attn heads / mlp hidden / vocab — the model's own
     logical axes resolved through the Trainer rules), the pipeline shard_map
     stays manual over stage/data/fsdp with `tensor` in GSPMD-auto mode, and
@@ -399,7 +399,7 @@ def test_pp_tp_moe_trains():
 
 
 def test_pp_pipelined_eval_loss_bounded_memory():
-    """VERDICT r4 item 9: evaluate() under pp computes the loss THROUGH the
+    """evaluate() under pp computes the loss THROUGH the
     pipeline stages (forward-only sweep) — matching the dense loss, with
     compiled temp memory well under the unstack-everything eval it
     replaced (at scale the dominant win is never materializing the full

@@ -64,9 +64,8 @@ def marker_lines(
 def iter_py_files(roots: Union[str, Iterable[str]]) -> Iterator[str]:
     """Every ``.py`` file under ``roots`` (deterministic order).
 
-    A root that is itself a file is yielded as-is (``bench.py`` in the
-    chaos-kind lint); directories are walked with :data:`PRUNE_PREFIXES`
-    applied at every level.
+    A root that is itself a file is yielded as-is; directories are walked
+    with :data:`PRUNE_PREFIXES` applied at every level.
     """
     if isinstance(roots, str):
         roots = [roots]

@@ -11,7 +11,7 @@ same way ``check_telemetry_names`` closes the metric set:
   frozenset (``Chaos.parse`` also rejects unknown kinds at runtime; this
   tool catches the static sites, including ``.fire`` calls that bypass
   parse).
-* This tool AST-walks ``maggy_tpu/``, ``tests/``, and ``bench.py`` for
+* This tool AST-walks ``maggy_tpu/`` and ``tests/`` for
   - ``.fire("kind", ...)`` calls on chaos-ish receivers (an identifier in
     the chain containing ``chaos``, or ``self``/``ch`` — the codebase's
     spellings), whose literal first argument must be a declared kind;
@@ -167,7 +167,6 @@ def main(argv=None) -> int:
     roots = args or [
         os.path.join(repo, "maggy_tpu"),
         os.path.join(repo, "tests"),
-        os.path.join(repo, "bench.py"),
     ]
     kinds = load_kinds(repo)
     return report(check_tree(roots, kinds))

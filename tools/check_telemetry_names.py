@@ -177,7 +177,7 @@ def check_capacity_rules(alerts) -> List[str]:
 # the host-DRAM tier observability contract (docs/serving.md "Host-DRAM
 # page tier"): the tier.* series the scheduler tick and engine emit must
 # stay registered under exactly these kinds with these units — consumers
-# (monitor tier line, bench extra.fleetkv, fleet capacity aggregation) key
+# (monitor tier line, fleet capacity aggregation) key
 # on them, and a silent re-kind (gauge -> counter) breaks every one.
 TIER_SERIES = {
     "tier.host_pages_free": ("gauge", "count"),
@@ -222,8 +222,8 @@ def check_tier_series(registry) -> List[str]:
 
 # the autoscaler observability contract (docs/fleet.md "Autoscaling"): the
 # fleet.* capacity-loop series must stay registered under exactly these
-# kinds with these units — the bench autoscale gate, the monitor autoscale
-# line, and alert.fleet_at_capacity all key on them.
+# kinds with these units — the monitor autoscale line and
+# alert.fleet_at_capacity key on them.
 AUTOSCALE_SERIES = {
     "fleet.replicas": ("gauge", "count"),
     "fleet.draining": ("gauge", "count"),

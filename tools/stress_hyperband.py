@@ -1,4 +1,4 @@
-"""Controller-level scheduling stress (VERDICT r4 item 6): does Hyperband
+"""Controller-level scheduling stress: does Hyperband
 keep a 16-executor fleet busy at 256-trial scale, with stragglers?
 
 Simulates the driver's scheduling loop against the REAL controllers (the
@@ -155,7 +155,7 @@ def asha_factory(num_trials: int, seed: int = 0):
 
 def run_suite(n_executors: int = 16, straggler: float = 0.05, cycles: int = 12,
               seed: int = 0):
-    """The VERDICT r4 item 6 comparison; ~22 trials/cycle x 12 = 264 ≈ the
+    """Concurrent against serial cycles against ASHA; ~22 trials/cycle x 12 = 264 ≈ the
     256-trial bar."""
     concurrent = simulate(
         hyperband_factory(iterations=cycles, seed=seed), n_executors, straggler,
