@@ -38,7 +38,7 @@ FLASH_TILE_CHOICES = (128, 256, 512, 1024)
 
 # remat policy names mirrored from models/transformer.py REMAT_POLICIES
 # (kept literal here so the registry stays stdlib-importable)
-REMAT_POLICY_CHOICES = (None, "nothing", "dots", "dots_attn")
+REMAT_POLICY_CHOICES = (None, "nothing", "dots")
 
 # paged KV cache page sizes (tokens): powers of two that divide every
 # supported max_seq_len; the engine snaps incompatible values down

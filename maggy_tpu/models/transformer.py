@@ -26,21 +26,37 @@ import jax
 import jax.numpy as jnp
 
 from maggy_tpu.ops import attention as ops_attn
+from maggy_tpu.ops.flash import (
+    FLASH_RESIDUALS,
+    flash_attention,
+    sharded_flash_attention,
+)
 
 Dtype = Any
 
-# remat policies by name so configs stay JSON-friendly/hashable.
-# "dots_attn" = "dots" plus the tensor tagged `checkpoint_name(.., "attn_out")`
-# (the attention kernel's output): it trades ~2 bytes/token/layer of HBM for
-# not re-running the flash forward in the backward. Measured a wash at S=1024
-# on v5e (65.3k vs 66.9k tok/s, within noise) — it becomes the right trade
-# when attention dominates (long S with remat still on).
+# Recompute policies by name, so configs stay JSON-friendly and hashable: what
+# a layer under ``nn.remat`` keeps from its forward pass for its backward.
+# Every policy that recomputes keeps the flash kernel's two results
+# (``ops.flash.FLASH_RESIDUALS``: the output and the per-row log-sum-exp, which
+# the kernel's backward rule reads), so a recomputed layer runs ``flash_fwd``
+# once a step and its replay rebuilds only q, k and v around it.
+# - "nothing": no XLA intermediate is kept; the attention kernel's two results
+#   are. A layer costs its input plus 2 * n_heads * head_dim_v + 4 * n_heads
+#   bytes a token (as much again as the input where heads * width = d_model).
+# - "dots": the same, plus the outputs of matmuls with no batch dimension (the
+#   projections and the feed-forward), so the replay is elementwise work.
+# - "everything": nothing is recomputed.
+# There is no policy that replays the kernel: a step that does not fit with
+# its results kept is one the autotuner (``tune/static.py``) prunes by its
+# compiled footprint, and the remedy is the one it proposes, a smaller batch.
+# Close to the device's memory the compiler makes the room itself, by
+# computing other values twice (PERF.md section 6, PR 29: three matmuls, 14 ms
+# of the 30 the kernel's replay had cost in the GLM cell).
 REMAT_POLICIES = {
-    "nothing": jax.checkpoint_policies.nothing_saveable,
-    "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    "dots_attn": jax.checkpoint_policies.save_from_both_policies(
+    "nothing": jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS),
+    "dots": jax.checkpoint_policies.save_from_both_policies(
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        jax.checkpoint_policies.save_only_these_names("attn_out"),
+        jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS),
     ),
     "everything": jax.checkpoint_policies.everything_saveable,
 }
@@ -104,15 +120,13 @@ class DecoderConfig:
     param_dtype: Any = jnp.float32
     scan_layers: bool = True
     remat: bool = False
-    # which intermediates remat keeps: "dots_attn" saves projection/MLP matmul
-    # outputs (no-batch-dim dots) plus the attention kernel's output, so the
-    # backward recomputes only cheap elementwise work. "dots" drops the
-    # attention output (re-runs the flash forward in the backward); "nothing"
-    # recomputes the whole layer (minimum HBM). Not compared on the chip on
-    # the current code: both benchmark cells run "nothing", because their
-    # step fits one v5e no other way (PERF.md section 4: 13.51 GiB planned
-    # against 16.90 of 15.75 without), and read it as train.recompute_share.
-    remat_policy: str = "dots_attn"
+    # what a recomputed layer keeps (``REMAT_POLICIES``): "dots" the matmul
+    # outputs and the attention kernel's results, so the backward recomputes
+    # elementwise work only; "nothing" the kernel's results alone (least
+    # HBM). Both benchmark cells run "nothing", because their step fits one
+    # v5e no other way (PERF.md section 4), and read what is replayed as
+    # train.recompute_share; "dots" is not measured on the chip.
+    remat_policy: str = "dots"
     logits_softcap: float = 0.0
     tie_embeddings: bool = False
     attention_fn: Optional[Callable] = None
@@ -377,10 +391,6 @@ def auto_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     incompatible layouts (sp/pp axes, non-divisible batch/heads) take the XLA
     path. The choice is recorded (:func:`record_attention_kernel`), never
     silent."""
-    from maggy_tpu.ops.flash import (  # late: avoid import cycle
-        flash_attention,
-        sharded_flash_attention,
-    )
     from maggy_tpu.parallel.mesh import ambient_mesh
 
     why = flash_tileable(q.shape[1], k.shape[1], q.shape[3])
@@ -438,12 +448,6 @@ class Attention(nn.Module):
         else:
             attn = cfg.attention_fn or auto_attention
             out = attn(q, k, v, causal=True, segment_ids=segment_ids)
-            # under remat="dots_attn" this tag saves the kernel output so the
-            # backward reads it instead of re-running the flash forward
-            # (plain "dots" ignores the tag and recomputes)
-            from jax.ad_checkpoint import checkpoint_name
-
-            out = checkpoint_name(out, "attn_out")
         out = nn.DenseGeneral(
             features=cfg.d_model,
             axis=(-2, -1),
@@ -727,8 +731,6 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
-        from jax.ad_checkpoint import checkpoint_name
-
         cfg = self.cfg
         h, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         # device-side scopes (telemetry/metrics.py SCOPES): metadata only
@@ -755,7 +757,6 @@ class LatentAttention(nn.Module):
             )
         attn = cfg.attention_fn or auto_attention
         out = attn(q, k, kv[..., dn:], causal=True, segment_ids=segment_ids)
-        out = checkpoint_name(out, "attn_out")  # as Attention: kept under "dots_attn"
         return nn.DenseGeneral(
             features=cfg.d_model,
             axis=(-2, -1),

@@ -8,8 +8,9 @@ recurrence): a dQ kernel accumulating over KV blocks and a dK/dV kernel
 accumulating over Q blocks, both recomputing the probabilities from the saved
 per-row log-sum-exp instead of storing them. ``delta = rowsum(dO * O)`` is
 recomputed per tile from the O/dO blocks so the only extra residual is the
-[BH, S] LSE (stored in column layout ``[BH, n_q, block_q, 1]`` so neither
-direction ever needs a sublane<->lane relayout).
+[BH, S] LSE (the kernels read and write it as a column, ``[BH, n_q, block_q,
+1]``, so none needs a sublane<->lane relayout inside; between the passes it
+is kept as rows of 128, see ``FLASH_RESIDUALS``).
 
 **The tile-visit table.** The grid is static (shapes decide it); which of its
 tiles hold an unmasked pair is data. Before each of the three kernels
@@ -41,12 +42,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from maggy_tpu.ops.attention import NEG_INF, _repeat_kv, blockwise_attention
 
 _LANES = 128
+
+# the forward kernel's two results as the backward rule keeps them, named
+# (``checkpoint_name``) so that a recompute policy can keep them too, as all of
+# ``models.transformer.REMAT_POLICIES`` do: a layer under ``nn.remat`` then
+# runs ``flash_fwd`` once a step and not again inside its replayed forward
+FLASH_RESIDUALS = ("flash_o", "flash_lse")
 
 # every kernel's grid is (batch*heads, outer blocks, reduction blocks): only
 # the last axis carries the VMEM accumulators from one step to the next
@@ -510,17 +518,22 @@ def _flash_core(
 
     def core_fwd(q, k, v, segs):
         o, lse = forward(q, k, v, segs)
+        # q, k, v stay unnamed: three times o's size, and a replay rebuilds
+        # them from the layer's input without the kernel. The LSE as the
+        # kernels have it, a column, is padded to 128 lanes by a TPU layout
+        # (as many bytes as ``o`` for 1/128 of the numbers): kept as rows of
+        # 128, which the chip reshapes faster than [BH, S] (PERF.md section 6,
+        # PR 29; S that 128 does not divide is interpreter-only)
+        sq = q.shape[1]
+        lanes = _LANES if sq % _LANES == 0 else 1
+        o = checkpoint_name(o, FLASH_RESIDUALS[0])
+        lse = checkpoint_name(lse.reshape(-1, sq // lanes, lanes), FLASH_RESIDUALS[1])
         return o, (q, k, v, segs, o, lse)
 
     def core_bwd(res, g):
         q, k, v, segs, o, lse = res
-        if bwd_block_q != block_q:
-            # the LSE residual is stored chunked by the FORWARD's q tile
-            # ([BH, n_q, block_q, 1], contiguous in sq) — re-chunk for the
-            # backward's tiling
-            bh_, _, _, _ = lse.shape
-            sq_ = q.shape[1]
-            lse = lse.reshape(bh_, sq_ // bwd_block_q, bwd_block_q, 1)
+        # back to a column, chunked by the backward's q tile
+        lse = lse.reshape(-1, q.shape[1] // bwd_block_q, bwd_block_q, 1)
         dq, dk_h, dv_h = _bwd_call(
             q, k, v, o, g.astype(o.dtype), lse,
             segs if segmented else None,

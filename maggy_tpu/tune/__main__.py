@@ -36,7 +36,7 @@ def main(argv=None) -> int:
                         help="comma-separated n_microbatches options (pp meshes)")
     parser.add_argument("--remat", default="",
                         help="comma-separated remat policies to try "
-                             "(nothing/dots/dots_attn/everything)")
+                             "(nothing/dots/everything)")
     parser.add_argument("--seq-len", type=int, default=128)
     parser.add_argument("--budget-gb", type=float,
                         help="per-device HBM budget for the AOT prune "

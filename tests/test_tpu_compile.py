@@ -5,14 +5,18 @@ tiling) fails here and not on the chip. One file, and the topology described
 inside a fixture: only the worker that runs this file loads the TPU's library.
 """
 
+import functools
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from maggy_tpu.models import moe
+from maggy_tpu.models.transformer import REMAT_POLICIES, DecoderConfig, LatentAttention
+from maggy_tpu.ops.flash import flash_attention
 
 
 @pytest.fixture(scope="module")
@@ -46,3 +50,32 @@ def test_grouped_kernels_compile_at_the_glm_widths(one_chip, k, n):
     )
     text = jax.jit(step).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_recomputed_latent_attention_compiles_one_flash_forward(one_chip):
+    """A recomputed ``LatentAttention`` at glm-4.7-flash's widths, the GLM
+    cell's rows (2 x 8,192, packed), its loss and gradient: Mosaic takes the
+    three kernels at width 256, and the policy keeps the forward kernel's
+    results, so the compiled program calls ``flash_fwd`` once (a replay would
+    be a second call: ``nn.remat`` holds XLA back from merging the two)."""
+    cfg = DecoderConfig(
+        d_model=2048, n_heads=20, n_kv_heads=20, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, max_seq_len=8192, partition_params=False,
+        attention_fn=functools.partial(flash_attention, interpret=False),
+    )
+    layer = nn.remat(LatentAttention, policy=REMAT_POLICIES["nothing"])(cfg)
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.key(0), x, ids, ids),
+    )
+
+    def step(params, x, positions, segment_ids):
+        return jax.value_and_grad(
+            lambda p, x: layer.apply(p, x, positions, segment_ids).astype(jnp.float32).sum(), argnums=(0, 1)
+        )(params, x)
+
+    text = jax.jit(step).lower(params, x, ids, ids).compile().as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert [sum(name in line for line in calls) for name in ("flash_fwd", "flash_dq", "flash_dkv")] == [1, 1, 1]
