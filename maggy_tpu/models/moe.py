@@ -13,7 +13,9 @@ over all the published experts, a shared expert beside them, and the chip's
 share of the routed ones computed dropless by grouped matrix products over the
 slots that exist. The stack takes a layer pattern: ``n_dense_layers`` leading
 dense layers, then the expert layers under the scan, then (``mtp_depth``) the
-multi-token-prediction module.
+multi-token-prediction module. Where the layers' operators differ
+(``DecoderConfig.layer_types``: attention or the gated short convolution) the
+scan's body is one period of the pattern (``layer_plan``).
 """
 
 from __future__ import annotations
@@ -37,7 +39,7 @@ from maggy_tpu.models.transformer import (
     _partitioned,
     _ScannedGatedLayer,
     _ScannedLayer,
-    attention_module,
+    layer_operator,
 )
 
 
@@ -63,6 +65,9 @@ class MoEConfig(DecoderConfig):
     moe_d_ff: int = 0  # width of one routed or shared expert
     n_shared_experts: int = 0
     routed_scaling: float = 1.0
+    # what the sum of the chosen scores gets before it divides them (the
+    # published modelling codes differ: 1e-20 glm4_moe_lite, 1e-6 lfm2_moe)
+    route_norm_eps: float = 1e-20
     # the router's selection bias (``noaux_tc``): enters top-k only, gets no
     # gradient, and is a constant here — N(0, select_bias_std) from
     # select_bias_seed, a row a layer (its update between steps is per-step
@@ -434,17 +439,17 @@ def _routed_bwd(rows, res, g):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-def sigmoid_route(logits, select_bias, top_k: int, scaling: float):
+def sigmoid_route(logits, select_bias, top_k: int, scaling: float, norm_eps: float = 1e-20):
     """DeepSeek-V3's router (``noaux_tc`` without a group limit) from float32
     logits [..., n_experts]: ``(sel [..., k] expert numbers, weights [..., k])``
     with ``s = sigmoid(logits)``, ``sel = top_k(s + select_bias)`` (the bias
     enters the selection only and gets no gradient) and the chosen scores
-    normalised over themselves and scaled."""
+    normalised over themselves (their sum plus ``norm_eps``) and scaled."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     biased = scores if select_bias is None else scores + select_bias
     _, sel = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)
     chosen = jnp.take_along_axis(scores, sel, axis=-1)
-    return sel, scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    return sel, scaling * chosen / (chosen.sum(-1, keepdims=True) + norm_eps)
 
 
 class ExpertShareBlock(nn.Module):
@@ -452,8 +457,8 @@ class ExpertShareBlock(nn.Module):
 
     The router scores all ``n_experts`` in float32: ``s = sigmoid(x W_r)``,
     ``sel = top_k(s + b)`` (``b`` the selection bias: no gradient),
-    ``w_e = routed_scaling * s_e / (sum of the chosen s + 1e-20)``. The result
-    is ``shared(x) + sum over chosen experts held here of w_e * expert_e(x)``:
+    ``w_e = routed_scaling * s_e / (sum of the chosen s + route_norm_eps)``. The result
+    is ``shared(x)`` (where there is a shared expert) ``+ sum over chosen experts held here of w_e * expert_e(x)``:
     what the absent experts would add is another chip's part. The (token,
     choice) slots are put in order of held expert by a counting sort (those
     on absent experts last) and counted, and the routed part (``_routed``:
@@ -484,7 +489,9 @@ class ExpertShareBlock(nn.Module):
                 kernel_init=_partitioned(nn.initializers.normal(0.02), ("embed", None), cfg),
                 name="router",
             )(tokens.astype(jnp.float32))
-            sel, weights = sigmoid_route(logits, select_bias, k, cfg.routed_scaling)  # [t, k]
+            sel, weights = sigmoid_route(
+                logits, select_bias, k, cfg.routed_scaling, cfg.route_norm_eps
+            )  # [t, k]
 
         with jax.named_scope("moe.dispatch"):
             local = sel - lo
@@ -529,6 +536,7 @@ class ExpertShareBlock(nn.Module):
 
 class MoELayer(nn.Module):
     cfg: MoEConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, gates=None, select_bias=None):
@@ -538,9 +546,7 @@ class MoELayer(nn.Module):
         router aux loss — an ablated expert block must not keep pushing
         balancing gradients into its router. ``select_bias`` — the share
         form's [n_experts] selection bias of this layer."""
-        a = attention_module(self.cfg)(self.cfg, name="attn")(
-            RMSNorm(self.cfg, name="attn_norm")(x), positions, segment_ids
-        )
+        a = layer_operator(self.cfg, self.kind, x, positions, segment_ids)
         x = x + (a if gates is None else a * gates[0].astype(a.dtype))
         xn = RMSNorm(self.cfg, name="mlp_norm")(x)
         if self.cfg.experts_held:
@@ -552,12 +558,22 @@ class MoELayer(nn.Module):
         return x
 
 
-def _remat(cls, cfg):
+def _remat(cls, cfg, prevent_cse=None):
     """``cls`` recomputed in the backward pass where the configuration asks
-    for it (no gradients, hence no remat, in decode)."""
+    for it (no gradients, hence no remat, in decode). ``prevent_cse`` None:
+    ``not cfg.scan_layers``, right for the body of a scan over many layers,
+    where no replay can be merged with a forward in another loop. A layer
+    outside the scan, or in a scan of one period, which XLA unrolls, takes
+    True: merged with its forward a replay keeps every intermediate, and at
+    the hybrid cell's size XLA then plans 14.54 GiB and computes nine large
+    products again to stay under its limit, against 8.93 GiB with the replays
+    kept apart (compiled for a v5e, PERF.md section 6, PR 30). The stacks of
+    one kind of layer keep PR 26's merged replays of ``dense_<i>`` and ``mtp``
+    until a ``perf_opt`` issue measures the other way (PERF.md section 7)."""
     if cfg.remat and not cfg.decode:
         return nn.remat(
-            cls, prevent_cse=not cfg.scan_layers, policy=REMAT_POLICIES[cfg.remat_policy]
+            cls, prevent_cse=(not cfg.scan_layers) if prevent_cse is None else prevent_cse,
+            policy=REMAT_POLICIES[cfg.remat_policy],
         )
     return cls
 
@@ -568,12 +584,42 @@ class _ScannedMoELayer(nn.Module):
     one."""
 
     cfg: MoEConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions, per_layer, segment_ids=None):
-        return MoELayer(self.cfg, name="layer")(
+        return MoELayer(self.cfg, self.kind, name="layer")(
             x, positions, segment_ids, **per_layer
         ), None
+
+
+class _ScannedPeriod(nn.Module):
+    """Scan body of a stack whose layers' operators differ: one period of the
+    pattern, ``layer_<j>`` of ``kinds[j]``, each recomputed on its own (the
+    flash kernel's kept results matter in the attention layers only).
+    ``per_layer`` arrives with a leading axis over the period's layers."""
+
+    cfg: MoEConfig
+    kinds: tuple
+
+    @nn.compact
+    def __call__(self, x, positions, per_layer, segment_ids=None):
+        for j, kind in enumerate(self.kinds):
+            x, _ = _remat(_ScannedMoELayer, self.cfg, True)(self.cfg, kind, name=f"layer_{j}")(
+                x, positions, {k: v[j] for k, v in per_layer.items()}, segment_ids
+            )
+        return x, None
+
+
+def layer_plan(kinds, n_dense: int):
+    """``(period, n_periods, tail)`` of the layers after the ``n_dense``
+    leading ones: the shortest run of kinds whose repetition gives them, how
+    many whole runs there are, and the layers over (a prefix of the run)."""
+    rest = tuple(kinds[n_dense:])
+    for p in range(1, len(rest) + 1):
+        if all(rest[i] == rest[i % p] for i in range(len(rest))):
+            return rest[:p], len(rest) // p, rest[len(rest) // p * p:]
+    return (), 0, ()
 
 
 class MTPModule(nn.Module):
@@ -600,7 +646,10 @@ class MoEDecoder(nn.Module):
     """Sparse-MoE causal LM; same interface as
     :class:`maggy_tpu.models.transformer.Decoder`. With ``mtp_depth`` it also
     sows ``mtp_logits`` (float32, predicting the token two ahead) for the
-    trainer's loss."""
+    trainer's loss. ``layer_types`` gives every layer its operator: the
+    leading dense layers and the layers over after the last whole period are
+    unrolled (``dense_<i>``, ``tail_<i>``), the whole periods scanned
+    (``layers``: one kind of layer as ``layer``, several as ``layer_<j>``)."""
 
     cfg: MoEConfig
 
@@ -621,6 +670,10 @@ class MoEDecoder(nn.Module):
         x = embed[tokens]
 
         n_dense, n_moe = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
+        kinds = cfg.layer_kinds()
+        period, n_periods, tail = layer_plan(kinds, n_dense)
+        n_scanned = n_periods * len(period)
+        apart = True if cfg.layer_types else None  # ``_remat``: replays kept from their forwards
         gates = _parse_ablated(cfg.ablated, cfg.n_layers)
         bias = cfg.select_bias() if cfg.experts_held and cfg.select_bias_std else None
 
@@ -636,27 +689,46 @@ class MoEDecoder(nn.Module):
         # the leading dense layers, unrolled: there are few of them
         for i in range(n_dense):
             if gates is None:
-                x, _ = _remat(_ScannedLayer, cfg)(cfg, name=f"dense_{i}")(x, positions, segment_ids)
+                x, _ = _remat(_ScannedLayer, cfg, apart)(cfg, kinds[i], name=f"dense_{i}")(x, positions, segment_ids)
             else:
-                x, _ = _remat(_ScannedGatedLayer, cfg)(cfg, name=f"dense_{i}")(
+                x, _ = _remat(_ScannedGatedLayer, cfg)(cfg, kinds[i], name=f"dense_{i}")(
                     x, positions, jnp.asarray(gates[i]), segment_ids
                 )
         layer_cls = _remat(_ScannedMoELayer, cfg)
         if cfg.scan_layers:
+            scanned = per_layer(slice(0, n_scanned))
+            body, kind = layer_cls, period[0]
+            if len(period) > 1:  # the period's layers are recomputed one by one inside the body
+                body, kind = _ScannedPeriod, period
+                scanned = {
+                    k: v.reshape(n_periods, len(period), *v.shape[1:]) for k, v in scanned.items()
+                }
             x, _ = nn.scan(
-                layer_cls,
+                body,
                 variable_axes={"params": 0, "intermediates": 0, "cache": 0},
                 split_rngs={"params": True},
                 in_axes=(nn.broadcast, 0, nn.broadcast),
-                length=n_moe,
+                length=n_periods,
                 metadata_params={nn.PARTITION_NAME: None},
-            )(cfg, name="layers")(x, positions, per_layer(slice(0, n_moe)), segment_ids)
+            )(cfg, kind, name="layers")(x, positions, scanned, segment_ids)
+            for i, kind in enumerate(tail):
+                x, _ = _remat(_ScannedMoELayer, cfg, apart)(cfg, kind, name=f"tail_{i}")(
+                    x, positions, per_layer(n_scanned + i), segment_ids
+                )
         else:
             for i in range(n_moe):
-                x, _ = layer_cls(cfg, name=f"layers_{i}")(x, positions, per_layer(i), segment_ids)
+                x, _ = layer_cls(cfg, kinds[n_dense + i], name=f"layers_{i}")(
+                    x, positions, per_layer(i), segment_ids
+                )
 
+        x_norm = RMSNorm(cfg, name="final_norm")(x)
+        if cfg.tie_embeddings:
+            if cfg.mtp_depth:
+                raise ValueError("the multi-token-prediction module takes an untied head")
+            with jax.named_scope("lm_head"):  # the scope the untied head's module gives
+                return jnp.einsum("bsd,vd->bsv", x_norm, embed).astype(jnp.float32)
         head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, "lm_head")
-        logits = head(RMSNorm(cfg, name="final_norm")(x))
+        logits = head(x_norm)
         if cfg.mtp_depth:
             # token i+1's embedding beside position i; the row's last position
             # wraps and is never a target's predictor (its target is masked)

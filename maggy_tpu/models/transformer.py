@@ -29,6 +29,7 @@ from maggy_tpu.ops import attention as ops_attn
 from maggy_tpu.ops.flash import (
     FLASH_RESIDUALS,
     flash_attention,
+    lane_fill,
     sharded_flash_attention,
 )
 
@@ -60,6 +61,9 @@ REMAT_POLICIES = {
     ),
     "everything": jax.checkpoint_policies.everything_saveable,
 }
+
+
+LAYER_KINDS = ("full_attention", "conv")
 
 
 def _parse_ablated(ablated, n_layers: int):
@@ -168,12 +172,26 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # the operator each layer takes before its feed-forward, one name a layer
+    # (``LAYER_KINDS``); empty: attention everywhere. "conv" is the gated
+    # short convolution (:class:`ShortConv`, ``lfm2``'s) with ``conv_kernel``
+    # taps; it has no decode state yet (its tail of conv_kernel - 1 positions
+    # would live beside the KV pages: ROADMAP M4)
+    layer_types: tuple = ()
+    conv_kernel: int = 3
+    # an RMSNorm over the head's width on every query and key head before the
+    # rotary embedding (:class:`Attention` only)
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_heads
+
+    def layer_kinds(self) -> tuple:
+        """The operator of every layer, ``n_layers`` names."""
+        return self.layer_types or ("full_attention",) * self.n_layers
 
     def __post_init__(self):
         if self.kv_lora_rank:
@@ -193,6 +211,17 @@ class DecoderConfig:
             raise ValueError("d_model must be divisible by n_heads")
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be divisible by n_kv_heads")
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.n_layers or set(self.layer_types) - set(LAYER_KINDS):
+                raise ValueError(
+                    f"layer_types names each of the {self.n_layers} layers one of {LAYER_KINDS}"
+                )
+            if self.decode and "conv" in self.layer_types:
+                raise ValueError(
+                    "a conv layer has no decode state yet (the convolution's tail "
+                    "beside the KV cache): this model trains and scores, it does not serve"
+                )
         if self.remat_policy not in REMAT_POLICIES:
             raise ValueError(
                 f"remat_policy must be one of {sorted(REMAT_POLICIES)}"
@@ -331,17 +360,19 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
     """Why the automatic dispatch keeps a shape off the Pallas flash kernel
-    (None: it tiles). head_dim must fill the 128 lanes and both sequence
-    lengths be multiples of the 128 block, which is what guarantees that
-    ``ops/flash.py``'s auto-chosen tiles are ones Mosaic can compile. Any
-    multiple of 128 is admitted; 128 (every dense model) and 256 (latent
-    attention's 192 + 64, PR 26: B 2, S 8,192, 20 heads, compiled and run on
-    one v5e, where 1,024 x 1,024 tiles do not fit VMEM and ``_auto_blocks``
-    chooses others) have run; wider heads have not."""
+    (None: it tiles). head_dim must fill the 128 lanes, or half of them, and
+    both sequence lengths be multiples of the 128 block, which is what
+    guarantees that ``ops/flash.py``'s auto-chosen tiles are ones Mosaic can
+    compile. Any multiple of 128 is admitted; 128 (every dense model) and 256
+    (latent attention's 192 + 64, PR 26: B 2, S 8,192, 20 heads, compiled and
+    run on one v5e, where 1,024 x 1,024 tiles do not fit VMEM and
+    ``_auto_blocks`` chooses others) have run; wider heads have not. Width 64
+    (PR 30: 32 query heads over 8 key-value heads, B 4, S 8,192) runs the same
+    kernels with half-filled lanes (``ops/flash.py`` ``lane_fill``)."""
     if jax.default_backend() != "tpu":
         return f"backend is {jax.default_backend()}"
-    if d % 128:
-        return f"head_dim {d} is not a multiple of 128"
+    if lane_fill(d) is None:
+        return f"head_dim {d} is not a multiple of 128 (nor 64)"
     if sq % 128 or sk % 128:
         return f"sequence lengths ({sq}, {sk}) are not multiples of 128"
     return None
@@ -350,16 +381,18 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
 def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = ""):
     """Journal which kernel the automatic dispatch chose for this shape as
     one ``attention.kernel`` event; for the flash kernels also the tiles they
-    run at (forward q, k, backward q, k). The dispatch runs at trace time, so
+    run at (forward q, k, backward q, k), and always the head width with, for
+    the flash kernels, how it fills the 128 lanes. The dispatch runs at trace time, so
     events count traces (init, forward, a rematerialized backward), never
     steps."""
     from maggy_tpu import telemetry
 
-    attrs = {}
+    attrs = {"head_dim": int(q.shape[3])}
     if kernel.startswith("flash"):
         from maggy_tpu.ops.flash import _auto_blocks
 
-        attrs = dict(zip(
+        attrs["lanes"] = lane_fill(q.shape[3])
+        attrs.update(zip(
             ("block_q", "block_k", "bwd_block_q", "bwd_block_k"),
             _auto_blocks(q.shape[1], k.shape[1], segment_ids is not None, q.shape[3]),
         ))
@@ -441,6 +474,9 @@ class Attention(nn.Module):
         q = _dense((cfg.n_heads, hd), ("embed", "heads", None), cfg, "wq")(x)
         k = _dense((cfg.n_kv_heads, hd), ("embed", "kv", None), cfg, "wk")(x)
         v = _dense((cfg.n_kv_heads, hd), ("embed", "kv", None), cfg, "wv")(x)
+        if cfg.qk_norm:  # over the head's width, one scale for all heads
+            q = RMSNorm(cfg, name="q_norm")(q)
+            k = RMSNorm(cfg, name="k_norm")(k)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         if cfg.decode:
@@ -770,9 +806,75 @@ class LatentAttention(nn.Module):
         )(out)
 
 
+class ShortConv(nn.Module):
+    """The gated short convolution of ``lfm2``: ``[B, C, x] = split3(u W_in)``,
+    ``z = B * x``, ``c_t = sum over j < K of w[K-1-j] * z[t-j]`` (depthwise and
+    causal, one weight a channel and tap, the last tap on the current
+    position as a left-padded ``Conv1d`` has it; no bias, no activation),
+    ``y = (C * c) W_out``. No positions enter it. With ``segment_ids`` a tap
+    that would reach into the previous document of a packed row is zero, as
+    the padding before a row's start is: a document gives what it gives alone.
+    Plain ``jax.numpy`` under three named scopes; ``conv.mix`` holds the two
+    gates and the taps (float32 inside the fusion, no product). Sows
+    ``taps_masked`` ([2]: taps zeroed at row and document starts, of all
+    ``B * S * K``) for the trainer's step metrics."""
+
+    cfg: DecoderConfig
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids=None):
+        cfg = self.cfg
+        if cfg.decode:
+            raise NotImplementedError("ShortConv has no decode state (DecoderConfig refuses it)")
+        d, taps = cfg.d_model, cfg.conv_kernel
+        s = x.shape[1]
+        with jax.named_scope("conv.in_proj"):
+            bcx = _dense((3, d), ("embed", None, "channels"), cfg, "in_proj")(x)
+        w = self.param(
+            "conv",
+            _partitioned(nn.initializers.normal(stddev=0.02), (None, "channels"), cfg),
+            (taps, d),
+            cfg.param_dtype,
+        )
+        with jax.named_scope("conv.mix"):
+            gate_in, gate_out, u = (bcx[..., i, :].astype(jnp.float32) for i in range(3))
+            z = gate_in * u
+            w32 = w.astype(jnp.float32)
+            c = w32[taps - 1] * z
+            masked = jnp.int32(0)
+            for j in range(1, min(taps, s)):
+                back = jnp.pad(z[:, : s - j], ((0, 0), (j, 0), (0, 0)))
+                inside = jnp.arange(s)[None, :] >= j
+                if segment_ids is not None:
+                    inside = inside & (
+                        jnp.pad(segment_ids[:, : s - j], ((0, 0), (j, 0))) == segment_ids
+                    )
+                    back = jnp.where(inside[..., None], back, 0.0)
+                masked = masked + jnp.sum(~jnp.broadcast_to(inside, x.shape[:2]))
+                c = c + w32[taps - 1 - j] * back
+            y = (gate_out * c).astype(cfg.dtype)
+        self.sow(
+            "intermediates", "taps_masked",
+            jnp.stack([masked, jnp.int32(x.shape[0] * s * taps)]),
+        )
+        with jax.named_scope("conv.out_proj"):
+            return _dense(d, ("channels", "embed"), cfg, "out_proj")(y)
+
+
 def attention_module(cfg: DecoderConfig):
-    """The attention class a layer of this configuration takes."""
+    """The attention class an attention layer of this configuration takes."""
     return LatentAttention if cfg.kv_lora_rank else Attention
+
+
+def layer_operator(cfg: DecoderConfig, kind: str, x, positions, segment_ids):
+    """The operator a layer of ``kind`` takes (``LAYER_KINDS``) on the normed
+    residual: ``attn`` under ``attn_norm`` or ``conv`` under ``conv_norm``.
+    Called inside the layer's ``nn.compact`` method."""
+    if kind == "conv":
+        return ShortConv(cfg, name="conv")(RMSNorm(cfg, name="conv_norm")(x), positions, segment_ids)
+    return attention_module(cfg)(cfg, name="attn")(
+        RMSNorm(cfg, name="attn_norm")(x), positions, segment_ids
+    )
 
 
 class MLPBlock(nn.Module):
@@ -806,6 +908,7 @@ def _constrain_residual(x):
 
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions, gates=None, segment_ids=None):
@@ -813,9 +916,7 @@ class DecoderLayer(nn.Module):
         zero gate removes that sublayer's contribution (residual becomes
         identity) and cuts its gradients, with an unchanged param tree.
         ``segment_ids`` — optional [B, S] packed-sequence ids."""
-        a = attention_module(self.cfg)(self.cfg, name="attn")(
-            RMSNorm(self.cfg, name="attn_norm")(x), positions, segment_ids
-        )
+        a = layer_operator(self.cfg, self.kind, x, positions, segment_ids)
         x = x + (a if gates is None else a * gates[0].astype(a.dtype))
         m = MLPBlock(self.cfg, name="mlp")(RMSNorm(self.cfg, name="mlp_norm")(x))
         x = x + (m if gates is None else m * gates[1].astype(m.dtype))
@@ -824,10 +925,11 @@ class DecoderLayer(nn.Module):
 
 class _ScannedLayer(nn.Module):
     cfg: DecoderConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
-        return DecoderLayer(self.cfg, name="layer")(
+        return DecoderLayer(self.cfg, self.kind, name="layer")(
             x, positions, None, segment_ids
         ), None
 
@@ -837,10 +939,11 @@ class _ScannedGatedLayer(nn.Module):
     so each layer sees its own (attn, mlp) pair."""
 
     cfg: DecoderConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions, gates, segment_ids=None):
-        return DecoderLayer(self.cfg, name="layer")(
+        return DecoderLayer(self.cfg, self.kind, name="layer")(
             x, positions, gates, segment_ids
         ), None
 
@@ -871,6 +974,12 @@ class Decoder(nn.Module):
         x = _constrain_residual(jnp.asarray(embed, cfg.dtype)[tokens])
 
         gates = _parse_ablated(cfg.ablated, cfg.n_layers)
+        kinds = cfg.layer_kinds()
+        if cfg.scan_layers and len(set(kinds)) > 1:
+            raise ValueError(
+                "Decoder scans layers of one kind: a mixed layer_types takes "
+                "scan_layers=False here, or MoEDecoder, which scans whole periods"
+            )
         layer_cls = _ScannedLayer if gates is None else _ScannedGatedLayer
         if cfg.remat and not cfg.decode:  # no gradients (hence no remat) in decode
             layer_cls = nn.remat(
@@ -892,7 +1001,7 @@ class Decoder(nn.Module):
                 ),
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: None},
-            )(cfg, name="layers")
+            )(cfg, kinds[0], name="layers")
             if gates is None:
                 x, _ = scanned(x, positions, segment_ids)
             else:
@@ -900,11 +1009,11 @@ class Decoder(nn.Module):
         else:
             for i in range(cfg.n_layers):
                 if gates is None:
-                    x, _ = layer_cls(cfg, name=f"layers_{i}")(
+                    x, _ = layer_cls(cfg, kinds[i], name=f"layers_{i}")(
                         x, positions, segment_ids
                     )
                 else:
-                    x, _ = layer_cls(cfg, name=f"layers_{i}")(
+                    x, _ = layer_cls(cfg, kinds[i], name=f"layers_{i}")(
                         x, positions, jnp.asarray(gates[i]), segment_ids
                     )
 

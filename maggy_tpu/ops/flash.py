@@ -50,6 +50,23 @@ from maggy_tpu.ops.attention import NEG_INF, _repeat_kv, blockwise_attention
 
 _LANES = 128
 
+
+def lane_fill(head_dim: int) -> Optional[str]:
+    """How heads of this width fill the kernels' 128 lanes; None: a width the
+    kernels do not take. A multiple of 128 fills them ("full"). Width 64 runs
+    "half": each tile of q, k, v, o and the
+    accumulators is ``[rows, 64]`` in half-filled vector registers, the two
+    products of a tile contract over or produce 64 of the MXU's 128 columns.
+    Two heads to a tile would fill the registers and HBM tiles of q and o, but
+    not the MXU (the heads' scores are separate products whichever way they
+    are laid, and a pair's ``[rows, 128]`` operand against a 64-wide key block
+    is the same half-filled pass), and the kernels' time is in the
+    ``[block_q, block_k]`` score tile, whose exponentials and masks do not
+    depend on the width: PERF.md section 6, PR 30."""
+    if head_dim % _LANES == 0:
+        return "full"
+    return "half" if head_dim == _LANES // 2 else None
+
 # the forward kernel's two results as the backward rule keeps them, named
 # (``checkpoint_name``) so that a recompute policy can keep them too, as all of
 # ``models.transformer.REMAT_POLICIES`` do: a layer under ``nn.remat`` then
@@ -603,10 +620,12 @@ def _untileable(sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
                 segmented, compiled):
     """Why Mosaic cannot tile this call, or None: blocks must divide the
     sequence and stay sublane-aligned (multiples of 8 rows), head_dim must
-    fill the 128 lanes, and compiled segmented runs put the segment-id block
-    in the lane dim, so they need 128-aligned blocks too."""
-    if d % _LANES:
-        return f"head_dim {d} is not a multiple of {_LANES}"
+    fill the 128 lanes or half of them (``lane_fill``: a block's last
+    dimension is then the array's whole extent, which Mosaic admits), and
+    compiled segmented runs put the segment-id block in the lane dim, so they
+    need 128-aligned blocks too."""
+    if lane_fill(d) is None:
+        return f"head_dim {d} is not a multiple of {_LANES} (nor {_LANES // 2}, which half fills the lanes)"
     need = _LANES if (segmented and compiled) else 8
     for name, blk, s in (
         ("block_q", block_q, sq), ("block_k", block_k, sk),

@@ -33,6 +33,10 @@ DEFAULT_RULES: Tuple[Tuple[str, Any], ...] = (
     ("embed", AXIS_FSDP),
     ("mlp", AXIS_TENSOR),
     ("heads", AXIS_TENSOR),
+    # a short convolution's channels (models/transformer.py ShortConv): its
+    # three weights lie as the attention projections beside them do, the
+    # model's width over fsdp ("embed") and the operator's own over tensor
+    ("channels", AXIS_TENSOR),
     ("kv", None),
     ("vocab", AXIS_TENSOR),
     ("expert", AXIS_EXPERT),

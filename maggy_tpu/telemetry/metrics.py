@@ -46,6 +46,10 @@ GAUGES = frozenset(
         # rows of the layers' buffers the chunks that ran visited over the rows
         # the buffers hold (T * top_k a layer); 1.0 = the mechanism does nothing
         "moe.rows_visited_share",
+        # a model with short-convolution layers (models/transformer.py
+        # ShortConv): taps zeroed at row and document starts over all taps of
+        # the step's conv layers; above 0 the batch's packing reached the operator
+        "conv.taps_masked_share",
         # checkpointing (train/checkpoint.py)
         "checkpoint_save_ms",
         # control plane (core/rpc.py, core/pod.py)
@@ -263,6 +267,9 @@ SCOPES = (
     "mla.q",  # latent attention: query down-projection, norm, up-projection
     "mla.kv",  # latent attention: key-value down-projection, norm, up-projection
     "mla.rope",  # latent attention: rope on the narrow part, heads put together
+    "conv.in_proj",  # short convolution: the product that makes the two gates and the input
+    "conv.mix",  # short convolution: the gates and the taps between the two products (no product)
+    "conv.out_proj",  # short convolution: the product back to the model's width
     # (flax module names are scopes too and need no entry: attn, mlp, moe,
     # and mtp, the multi-token-prediction module)
     "decode_attn",  # page/chunk gather + online softmax over the KV cache
@@ -372,6 +379,7 @@ GAUGE_UNITS = {
     "moe.slots_dropped": "count",
     "moe.load_max_over_mean": "ratio",
     "moe.rows_visited_share": "ratio",
+    "conv.taps_masked_share": "ratio",
     "checkpoint_save_ms": "ms",
     "heartbeat_rtt_ms": "ms",
     "data_plane_init_ms": "ms",

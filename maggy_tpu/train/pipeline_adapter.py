@@ -224,9 +224,12 @@ def decoder_pipeline_parts(
         )
     else:
         local_attn = _pp_local_attention
+    if len(set(cfg.layer_kinds())) > 1:
+        raise NotImplementedError("a pipeline stage scans layers of one kind: layer_types mixes them")
     stage_cfg = dataclasses.replace(
         cfg,
         n_layers=l_per,
+        layer_types=cfg.layer_kinds()[:l_per] if cfg.layer_types else (),
         attention_fn=cfg.attention_fn or local_attn,
         # no logical-axis boxes inside the shard_map: placement is manual
         # (P('stage') on the stacked tree), and flax would otherwise try to

@@ -154,6 +154,18 @@ def expert_counters(mods) -> Dict[str, jax.Array]:
     return out
 
 
+def conv_counters(mods) -> Dict[str, jax.Array]:
+    """The step's counter of a model with short-convolution layers
+    (``ShortConv`` sows ``taps_masked``): the taps zeroed at row and document
+    starts over all taps of the step's conv layers, which says that the
+    batch's packing reached the operator. Empty for every other model."""
+    taps = _sown(mods, "taps_masked")  # [masked, of] a layer
+    if not taps:
+        return {}
+    masked, of = jnp.concatenate([a.reshape(-1, 2) for a in taps]).sum(0)
+    return {"conv_taps_masked_share": masked / of}
+
+
 def _prefetch_depth(prefetch: Optional[int]) -> int:
     """Resolve an input-prefetch depth: an explicit argument wins, else the
     ``MAGGY_TPU_PREFETCH`` env knob, else 2 (double-buffered). 0 disables."""
@@ -1025,7 +1037,7 @@ class Trainer:
                     loss = self.loss_fn(logits, batch)
                     mtp = mtp_loss(mods, batch)
                 aux = collect_aux_losses(mods)
-                extra = expert_counters(mods)
+                extra = {**expert_counters(mods), **conv_counters(mods)}
                 total = loss + aux
                 if mtp is not None:
                     total = total + self.model.cfg.mtp_weight * mtp
@@ -1669,6 +1681,8 @@ class Trainer:
             tel.gauge("moe.load_max_over_mean", out["moe_load_max_over_mean"])
         if "moe_rows_visited_share" in out:
             tel.gauge("moe.rows_visited_share", out["moe_rows_visited_share"])
+        if "conv_taps_masked_share" in out:
+            tel.gauge("conv.taps_masked_share", out["conv_taps_masked_share"])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

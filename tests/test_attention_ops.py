@@ -132,10 +132,11 @@ def test_ulysses_head_divisibility():
             ulysses_attention(q, k, v, mesh=mesh)
 
 
+@pytest.mark.parametrize("d", [128, 64])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_kernel_matches_reference(causal):
-    # d must be a multiple of 128 lanes for the kernel path
-    q, k, v = qkv(b=1, s=256, h=2, d=128)
+def test_flash_kernel_matches_reference(causal, d):
+    # d must be a multiple of the 128 lanes for the kernel path, or half of them
+    q, k, v = qkv(b=1, s=256, h=2, d=d)
     ref = default_attention(q, k, v, causal=causal)
     out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-3)
@@ -167,11 +168,13 @@ def test_flash_default_blocks_match_reference():
     np.testing.assert_allclose(np.asarray(g_fl), np.asarray(g_ref), atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("d", [128, 64])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_backward_matches_reference(causal):
+def test_flash_backward_matches_reference(causal, d):
     """The Pallas backward kernels (dQ + dK/dV split) against jax.grad through
-    the XLA dense path — the round-1 gap (forward-only kernel)."""
-    q, k, v = qkv(b=1, s=256, h=2, d=128)
+    the XLA dense path — the round-1 gap (forward-only kernel). Width 64: the
+    same kernels with half-filled lanes (``ops.flash.lane_fill``)."""
+    q, k, v = qkv(b=1, s=256, h=2, d=d)
 
     def loss_ref(q, k, v):
         return (default_attention(q, k, v, causal=causal) ** 2).sum()
@@ -383,16 +386,17 @@ def _kernels_with_table(q, k, v, do, seg, table_seg, fwd_blocks, bwd_blocks):
 
 
 @pytest.mark.parametrize(
-    "kh,fwd_blocks,bwd_blocks",
-    [(4, (64, 64), (64, 64)), (1, (64, 64), (64, 64)), (1, (32, 128), (64, 32))],
-    ids=["group1", "group4", "group4-bwd-tiles-unlike-fwd"],
+    "kh,fwd_blocks,bwd_blocks,d",
+    [(4, (64, 64), (64, 64), 128), (1, (64, 64), (64, 64), 128), (1, (32, 128), (64, 32), 128),
+     (1, (64, 64), (64, 64), 64)],
+    ids=["group1", "group4", "group4-bwd-tiles-unlike-fwd", "group4-width64"],
 )
 @pytest.mark.parametrize("packing", _PACKINGS)
-def test_flash_skipped_tiles_change_no_bit(packing, kh, fwd_blocks, bwd_blocks):
+def test_flash_skipped_tiles_change_no_bit(packing, kh, fwd_blocks, bwd_blocks, d):
     """Output, LSE, dq, dk and dv with the visit table made from the segment
     ids equal, bit for bit, the same kernels made to visit every causal tile
     (a table made from ids that are all one document)."""
-    q, k, v = qkv(b=2, s=_S, h=4, kh=kh, d=128, seed=3)
+    q, k, v = qkv(b=2, s=_S, h=4, kh=kh, d=d, seed=3)
     do = jax.random.normal(jax.random.key(9), q.shape, q.dtype)
     seg = _packing(packing)
     skipping = _kernels_with_table(q, k, v, do, seg, seg, fwd_blocks, bwd_blocks)
@@ -406,35 +410,39 @@ def test_flash_skipped_tiles_change_no_bit(packing, kh, fwd_blocks, bwd_blocks):
         interpret=True,
     )
     np.testing.assert_array_equal(
-        np.asarray(out.transpose(0, 2, 1, 3).reshape(-1, _S, 128)), every[0]
+        np.asarray(out.transpose(0, 2, 1, 3).reshape(-1, _S, d)), every[0]
     )
 
 
-def test_flash_two_packings_one_trace():
+@pytest.mark.parametrize("d,kh", [(128, 2), (64, 1)], ids=["width128", "width64-gqa"])
+def test_flash_two_packings_one_trace(d, kh):
     """The visit table is data: batches of different packing run the jitted
-    function with one trace, each right against the dense reference."""
-    q, k, v = qkv(b=2, s=_S, h=2, d=128, seed=5)
+    function with one trace, each right against the dense reference with the
+    same segment ids: the output and the gradients of q, k and v."""
+    q, k, v = qkv(b=2, s=_S, h=2, kh=kh, d=d, seed=5)
     traces = []
 
+    def square(attn, seg):
+        def f(q, k, v):
+            out = attn(q, k, v, segment_ids=seg)
+            return (out ** 2).sum(), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
     @jax.jit
-    def grads(q, k, v, seg):
+    def flash(q, k, v, seg):
         traces.append(1)
-        return jax.grad(
-            lambda q, k, v: (
-                flash_attention(
-                    q, k, v, segment_ids=seg, block_q=64, block_k=64, interpret=True
-                ) ** 2
-            ).sum(),
-            argnums=(0, 1, 2),
-        )(q, k, v)
+        kernel = lambda q, k, v, segment_ids: flash_attention(
+            q, k, v, segment_ids=segment_ids, block_q=64, block_k=64, interpret=True
+        )
+        return square(kernel, seg)(q, k, v)
 
     for packing in ("many_short", "padded_tail", "one_document"):
         seg = jnp.asarray(_packing(packing))
-        ref = jax.grad(
-            lambda q, k, v: (default_attention(q, k, v, segment_ids=seg) ** 2).sum(),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        for a, b in zip(grads(q, k, v, seg), ref):
+        (_, out), grads = flash(q, k, v, seg)
+        (_, want), ref = square(default_attention, seg)(q, k, v)
+        real = np.asarray(seg > 0)[..., None, None]  # a padded position attends to padding: nobody reads it
+        np.testing.assert_allclose(np.asarray(out) * real, np.asarray(want) * real, atol=2e-3, rtol=2e-3)
+        for a, b in zip(grads, ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-2, rtol=2e-2)
     assert len(traces) == 1
 
@@ -545,6 +553,13 @@ def test_flash_kernel_event_carries_the_tiles():
     (event,) = [e for e in tel.drain_events() if e["name"] == "attention.kernel"]
     tiles = tuple(event["attrs"][n] for n in ("block_q", "block_k", "bwd_block_q", "bwd_block_k"))
     assert tiles == _auto_blocks(4096, 4096, True)
+    assert (event["attrs"]["head_dim"], event["attrs"]["lanes"]) == (128, "full")
+    q, k, _ = qkv(b=1, s=4096, h=4, kh=1, d=64)
+    with telemetry.current(tel):
+        record_attention_kernel("flash", q, k, jnp.ones((1, 4096), jnp.int32))
+    (event,) = [e for e in tel.drain_events() if e["name"] == "attention.kernel"]
+    assert (event["attrs"]["head_dim"], event["attrs"]["lanes"]) == (64, "half")
+    assert event["attrs"]["block_q"] == _auto_blocks(4096, 4096, True, 64)[0]
 
 
 @pytest.mark.slow
