@@ -173,7 +173,7 @@ def phase_kernels(run: Run) -> dict:
     import jax.numpy as jnp
 
     from maggy_tpu.ops.attention import blockwise_attention
-    from maggy_tpu.ops.flash import flash_attention
+    from maggy_tpu.ops.flash import BACKWARD_KERNELS, backward_form, flash_attention
 
     # Tolerance: relative Frobenius error against the float32 reference.
     # bf16 carries 8 mantissa bits (2^-8 = 0.4% per rounding); the kernels
@@ -236,11 +236,12 @@ def phase_kernels(run: Run) -> dict:
             t0 = time.perf_counter()
             lowered = flash_step.lower(q, k, v, w, seg)
             if not interpret:
-                # the program about to run holds the three Mosaic kernels: it
-                # is neither interpreted nor the blockwise fallback (which,
-                # with interpret=False, would have raised instead)
+                # the program about to run holds the Mosaic kernels, the
+                # backward in the form this row and width take: it is neither
+                # interpreted nor the blockwise fallback (which, with
+                # interpret=False, would have raised instead)
                 text = lowered.as_text()
-                for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+                for name in ("flash_fwd", *BACKWARD_KERNELS[backward_form(s, d)]):
                     check(name in text, f"kernel {name} missing from the lowered step")
             compiled = lowered.compile()
             setup_s += time.perf_counter() - t0

@@ -382,16 +382,19 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = ""):
     """Journal which kernel the automatic dispatch chose for this shape as
     one ``attention.kernel`` event; for the flash kernels also the tiles they
     run at (forward q, k, backward q, k), and always the head width with, for
-    the flash kernels, how it fills the 128 lanes. The dispatch runs at trace time, so
-    events count traces (init, forward, a rematerialized backward), never
-    steps."""
+    the flash kernels, how it fills the 128 lanes and which backward the row
+    and the width take (``backward``: ``fused``, one kernel, or ``split``,
+    two: ``ops.flash.backward_form``, which the kernels' call asks too). The
+    dispatch runs at trace time, so events count traces (init, forward, a
+    rematerialized backward), never steps."""
     from maggy_tpu import telemetry
 
     attrs = {"head_dim": int(q.shape[3])}
     if kernel.startswith("flash"):
-        from maggy_tpu.ops.flash import _auto_blocks
+        from maggy_tpu.ops.flash import _auto_blocks, backward_form
 
         attrs["lanes"] = lane_fill(q.shape[3])
+        attrs["backward"] = backward_form(q.shape[1], q.shape[3])
         attrs.update(zip(
             ("block_q", "block_k", "bwd_block_q", "bwd_block_k"),
             _auto_blocks(q.shape[1], k.shape[1], segment_ids is not None, q.shape[3]),
@@ -418,7 +421,11 @@ def auto_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     latent attention at B 2, S 8,192, 20 query and key heads, the packed8k
     rows) take the same kernels at tiles chosen for the width: forward 7.5 ms
     and backward 20.1 ms a call on one v5e, a quarter of that model's train
-    step. On a multi-device mesh the
+    step. Since PR 31 the backward is one kernel that visits a score tile
+    once (``ops/flash.py`` ``flash_bwd``; a row too long to keep a head's dq
+    in VMEM, past S 32,768 at width 128, keeps the two-kernel split,
+    ``backward_form``): 13.4 ms a call at that shape, 4.9 against 7.8 at B 2,
+    S 4,096, 32/8 heads of 128. On a multi-device mesh the
     kernel runs per-shard under shard_map (a pallas_call has no GSPMD
     partitioning rule), each shard making its visit table from its own rows;
     incompatible layouts (sp/pp axes, non-divisible batch/heads) take the XLA

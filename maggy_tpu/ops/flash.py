@@ -3,17 +3,26 @@
 Same online-softmax math as :mod:`maggy_tpu.ops.attention`, hand-tiled for the
 MXU. The forward runs grid (batch*heads, q_blocks, k_blocks) with fp32 running
 statistics in VMEM scratch; the [S, S] score matrix never leaves VMEM tiles.
-The backward is the standard TPU two-kernel split (FlashAttention-2
-recurrence): a dQ kernel accumulating over KV blocks and a dK/dV kernel
-accumulating over Q blocks, both recomputing the probabilities from the saved
-per-row log-sum-exp instead of storing them. ``delta = rowsum(dO * O)`` is
+The backward (FlashAttention-2's recurrence) recomputes the probabilities
+from the saved per-row log-sum-exp instead of storing them, and visits a
+score tile once: one kernel, ``flash_bwd``, on the grid (batch*heads, k
+blocks, q blocks) computes p and dS of a tile and adds ``p^T dO`` to dV and
+``dS^T q`` to dK, accumulated across the inner axis, and ``dS k`` to dQ,
+which for the whole row of the head stays in float32 in VMEM across both
+axes and leaves once a head in ``q.dtype`` (``_bwd_kernel``; five products
+and one pass of vector work a tile). A row too long for that
+(``backward_form``: by ``sq`` and the head's width alone, past S 32,768 at
+width 128) takes the two-kernel split whose VMEM does not grow with S: a dQ
+kernel accumulating over KV blocks and a dK/dV kernel accumulating over Q
+blocks, each recomputing p and dS (seven products, two passes); at equal
+tiles both forms give the same bits. ``delta = rowsum(dO * O)`` is
 recomputed per tile from the O/dO blocks so the only extra residual is the
 [BH, S] LSE (the kernels read and write it as a column, ``[BH, n_q, block_q,
 1]``, so none needs a sublane<->lane relayout inside; between the passes it
 is kept as rows of 128, see ``FLASH_RESIDUALS``).
 
 **The tile-visit table.** The grid is static (shapes decide it); which of its
-tiles hold an unmasked pair is data. Before each of the three kernels
+tiles hold an unmasked pair is data. Before each kernel
 ``needed_tiles`` marks, for every batch row and outer block, the reduction
 blocks that are not wholly above the causal diagonal and whose range of
 segment ids overlaps the outer block's, and ``visit_bounds`` keeps the first
@@ -62,7 +71,9 @@ def lane_fill(head_dim: int) -> Optional[str]:
     are laid, and a pair's ``[rows, 128]`` operand against a 64-wide key block
     is the same half-filled pass), and the kernels' time is in the
     ``[block_q, block_k]`` score tile, whose exponentials and masks do not
-    depend on the width: PERF.md section 6, PR 30."""
+    depend on the width: PERF.md section 6, PR 30. The fused backward passes
+    over that tile once for all three gradients (PR 31); its resident dq is
+    counted at 128 lanes a row at this width."""
     if head_dim % _LANES == 0:
         return "full"
     return "half" if head_dim == _LANES // 2 else None
@@ -73,8 +84,9 @@ def lane_fill(head_dim: int) -> Optional[str]:
 # runs ``flash_fwd`` once a step and not again inside its replayed forward
 FLASH_RESIDUALS = ("flash_o", "flash_lse")
 
-# every kernel's grid is (batch*heads, outer blocks, reduction blocks): only
-# the last axis carries the VMEM accumulators from one step to the next
+# the grid is (batch*heads, outer blocks, reduction blocks): in the forward and
+# the split backward kernels only the last axis carries the VMEM accumulators
+# from one step to the next (the fused backward's dq lives across both)
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary")
 )
@@ -119,9 +131,9 @@ def needed_tiles(segs, *, causal, sq, sk, block_q, block_k):
 def visit_bounds(segs, outer, **tiles):
     """int32 [rows * outer blocks * 2], flat for SMEM: the first and the last
     needed reduction block of every (row, outer block), q blocks outermost
-    (``outer="q"``: forward, dq) or k blocks (``"k"``: dkv); ``tiles`` as
-    ``needed_tiles`` takes them. The kernels visit first..last and nothing
-    else; a row with no needed block reads (0, -1)."""
+    (``outer="q"``: forward, dq) or k blocks (``"k"``: the fused backward,
+    dkv); ``tiles`` as ``needed_tiles`` takes them. The kernels visit
+    first..last and nothing else; a row with no needed block reads (0, -1)."""
     need = needed_tiles(segs, **tiles)
     if outer == "k":
         need = need.swapaxes(1, 2)
@@ -245,9 +257,10 @@ def _fwd_kernel(
 
 def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer):
     """BlockSpecs of one kernel's grid (batch*heads, outer blocks, reduction
-    blocks) with q rows (``outer="q"``: forward, dq) or k rows (``"k"``: dkv)
-    outermost. Every index map also gets the visit bounds (scalar prefetch):
-    the reduction side names ``_resident``'s block, the outer side its own.
+    blocks) with q rows (``outer="q"``: forward, dq) or k rows (``"k"``: the
+    fused backward, dkv) outermost. Every index map also gets the visit
+    bounds (scalar prefetch): the reduction side names ``_resident``'s block,
+    the outer side its own.
 
     GQA lives in the index map: q-head row i reads KV row i // group, so the
     repeated [B,S,H,D] K/V never materialize in HBM (review finding r2);
@@ -438,63 +451,224 @@ def _dkv_kernel(
         dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
-def _bwd_call(
-    q, k, v, o, do, lse, segs, q_bounds, k_bounds,
+def _bwd_kernel(
+    bounds_ref, *refs,
+    scale, causal, block_q, block_k, segmented, heads,
+):
+    """dq, dk and dv from one visit of a tile: the grid is ``_dkv_kernel``'s
+    (k blocks outer, q blocks inner, dk and dv accumulated across the inner
+    axis), and dq for the whole row of the head, ``[n_q, block_q, d]``
+    float32, stays in VMEM across both axes: q block ``qi`` is zeroed at the
+    first k block, gets ``ds k`` from every visited tile (k blocks in
+    ascending order, as ``_dq_kernel`` adds them) and is cast into the
+    head's output block at the last."""
+    if segmented:
+        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qseg_ref, kseg_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref) = refs
+    else:
+        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref) = refs
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    nk = pl.num_programs(1)
+    nq = pl.num_programs(2)
+
+    @pl.when(qi == 0)
+    def _init_kv():
+        dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
+        dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
+
+    @pl.when(ki == 0)
+    def _init_q():
+        dq_acc_ref[qi] = jnp.zeros(dq_acc_ref.shape[1:], dq_acc_ref.dtype)
+
+    @pl.when(_visits(bounds_ref, heads if segmented else 0, qi))
+    def _compute():
+        q = q_ref[0]
+        k = k_ref[0]
+        do = do_ref[0]
+        p, ds = _recompute_p_ds(
+            q, k, v_ref[0], o_ref[0], do, lse_ref[0, 0],
+            scale=scale, causal=causal,
+            q_start=qi * block_q, k_start=ki * block_k,
+            qseg=qseg_ref[0, 0] if segmented else None,
+            kseg=kseg_ref[0, 0] if segmented else None,
+        )
+        ds = ds.astype(q.dtype)
+        dv_acc_ref[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk_acc_ref[:] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_acc_ref[qi] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(qi == nq - 1)
+    def _finalize_kv():
+        dk_ref[0] = dk_acc_ref[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == nk - 1)
+    def _finalize_q():
+        dq_ref[0, qi] = dq_acc_ref[qi].astype(dq_ref.dtype)
+
+
+# The fused backward keeps a head's whole dq in VMEM: a float32 accumulator of
+# ``sq * max(d, 128)`` numbers (width 64 pads to the lanes) and the output
+# block it is cast into, which the pipeline holds twice. Counted at its worst
+# (float32 out: 12 bytes a number) that may take 48 MiB of a v5e's 128 MiB of
+# VMEM, which leaves the tiles (41 MiB as ``_fused_vmem_bytes`` counts them at
+# 1,024 x 1,024, width 256, float32) and the compiler their room: rows up to S 32,768 at width 128 and
+# 16,384 at 256, so every shape the benchmark's cells, the serve prefill and
+# the tests run. A longer row takes the two split kernels, whose VMEM does
+# not grow with S.
+_FUSED_DQ_VMEM_BYTES = 48 * 2**20
+
+
+# the kernels each form launches, by the names the trace and the lowered
+# program carry
+BACKWARD_KERNELS = {"fused": ("flash_bwd",), "split": ("flash_dq", "flash_dkv")}
+
+
+def _resident_dq_bytes(sq, d, itemsize=4):
+    """VMEM a head's dq holds in ``flash_bwd``: the float32 accumulator and
+    the output block's two buffers (``itemsize``: float32 unless given)."""
+    return sq * max(d, _LANES) * (4 + 2 * itemsize)
+
+
+def backward_form(sq: int, head_dim: int) -> str:
+    """Which backward a call of this row length and head width runs:
+    ``"fused"`` (``flash_bwd``: one kernel, a tile visited once) where the
+    head's dq fits ``_FUSED_DQ_VMEM_BYTES``, else ``"split"`` (``flash_dq``
+    and ``flash_dkv``). Nothing else decides it."""
+    return "fused" if _resident_dq_bytes(sq, head_dim) <= _FUSED_DQ_VMEM_BYTES else "split"
+
+
+def _fused_vmem_bytes(sq, d, block_q, block_k, itemsize):
+    """What ``flash_bwd`` may use of VMEM (Mosaic's default is 16 MiB), from
+    the sizes the call sees: the resident dq and beside it a step's tiles. At the GLM cell's
+    shape and tiles this counts 36 MiB; Mosaic took the kernel at 32 and not
+    at 24 (ISSUE 31's sketch)."""
+    dpad = max(d, _LANES)
+    tiles = (
+        6 * block_q * block_k * 4  # s, p, dp, ds and the masks and casts between them
+        + 2 * (3 * block_q + 4 * block_k) * dpad * itemsize  # q, o, do; k, v, dk, dv: two buffers each
+        + 2 * block_k * dpad * 4  # the dk and dv accumulators
+        + 2 * block_q * _LANES * 4  # the LSE column, padded to the lanes
+    )
+    return _resident_dq_bytes(sq, d, itemsize) + tiles
+
+
+def _bwd_pallas(
+    kernel, name, outer, grid, outs, scratch_shapes, compiler_params,
+    q, k, v, o, do, lse, segs, bounds,
     *, causal, block_q, block_k, group, heads, interpret,
 ):
-    """``q_bounds``: per q block the k blocks to visit (dq kernel);
-    ``k_bounds``: per k block the q blocks to visit (dkv kernel)."""
+    """One backward kernel over ``grid`` with q rows (``outer="q"``) or k rows
+    (``"k"``) outermost; ``outs`` pairs each result's BlockSpec (a name of
+    ``_specs`` or a spec) with its shape."""
+    d = q.shape[2]
+    segmented = segs is not None
+    sp = _specs(block_q, block_k, d, group, heads, segmented, outer, grid[1])
+    in_specs = [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"], sp["lse"]]
+    operands = [q, k, v, o, do, lse]
+    if segmented:
+        in_specs += [sp["qseg"], sp["kseg"]]
+        operands += [segs, segs]
+    return pl.pallas_call(
+        functools.partial(
+            kernel, scale=1.0 / d**0.5, causal=causal, block_q=block_q,
+            block_k=block_k, segmented=segmented, heads=heads,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[sp[spec] if isinstance(spec, str) else spec for spec, _ in outs],
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=[shape for _, shape in outs],
+        compiler_params=compiler_params,
+        name=name,
+        interpret=interpret,
+    )(bounds, *operands)
+
+
+def _bwd_split(q, k, v, o, do, lse, segs, bounds, *, block_q, block_k, **kw):
+    """The two-kernel backward (FlashAttention-2's): ``flash_dq`` sums over k
+    blocks, ``flash_dkv`` over q blocks, each recomputing p and ds on every
+    tile it visits. Its VMEM does not grow with the row's length."""
     bh, sq, d = q.shape
     sk = k.shape[1]
-    segmented = segs is not None
-    kw = dict(
-        scale=1.0 / d**0.5, causal=causal, block_q=block_q, block_k=block_k,
-        segmented=segmented, heads=heads,
-    )
-
-    def call(kernel, name, outer, grid, out_specs, out_shape, scratch_shapes):
-        sp = _specs(block_q, block_k, d, group, heads, segmented, outer, grid[1])
-        in_specs = [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"], sp["lse"]]
-        operands = [q, k, v, o, do, lse]
-        if segmented:
-            in_specs += [sp["qseg"], sp["kseg"]]
-            operands += [segs, segs]
-        return pl.pallas_call(
-            functools.partial(kernel, **kw),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=grid,
-                in_specs=in_specs,
-                out_specs=[sp[o] for o in out_specs],
-                scratch_shapes=scratch_shapes,
-            ),
-            out_shape=out_shape,
-            compiler_params=_COMPILER_PARAMS,
-            name=name,
-            interpret=interpret,
-        )(q_bounds if outer == "q" else k_bounds, *operands)
-
-    (dq,) = call(
+    kw = dict(kw, block_q=block_q, block_k=block_k)
+    (dq,) = _bwd_pallas(
         _dq_kernel, "flash_dq", "q", (bh, sq // block_q, sk // block_k),
-        ["q"], [jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
-        [pltpu.VMEM((block_q, d), jnp.float32)],
+        [("q", jax.ShapeDtypeStruct((bh, sq, d), q.dtype))],
+        [pltpu.VMEM((block_q, d), jnp.float32)], _COMPILER_PARAMS,
+        q, k, v, o, do, lse, segs, bounds("q"), **kw,
     )
-    # dkv grid: KV blocks outer, Q blocks inner (accumulate across Q). Outputs
-    # are per *q-head* ([BH, S, D]); a KV block cannot accumulate across grid-i
-    # revisits, so the group sum down to [B*Kh, S, D] happens in the caller.
-    dk, dv = call(
+    dk, dv = _bwd_pallas(
         _dkv_kernel, "flash_dkv", "k", (bh, sk // block_k, sq // block_q),
-        ["dkv", "dkv"],
         [
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            ("dkv", jax.ShapeDtypeStruct((bh, sk, d), k.dtype)),
+            ("dkv", jax.ShapeDtypeStruct((bh, sk, d), v.dtype)),
         ],
-        [
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+        [pltpu.VMEM((block_k, d), jnp.float32)] * 2, _COMPILER_PARAMS,
+        q, k, v, o, do, lse, segs, bounds("k"), **kw,
     )
     return dq, dk, dv
+
+
+def _bwd_fused(q, k, v, o, do, lse, segs, bounds, *, block_q, block_k, **kw):
+    """``flash_bwd``: the grid and visit table of ``flash_dkv``, dq besides
+    (``_bwd_kernel``). dq leaves as ``[BH, n_q, block_q, D]`` in ``q.dtype``,
+    one block a head, which is ``[BH, S, D]`` read another way: no buffer the
+    split form does not have."""
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    n_q = sq // block_q
+    dq, dk, dv = _bwd_pallas(
+        _bwd_kernel, "flash_bwd", "k", (bh, sk // block_k, n_q),
+        [
+            (
+                pl.BlockSpec(
+                    (1, n_q, block_q, d), lambda i, o, r, bounds_ref: (i, 0, 0, 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                jax.ShapeDtypeStruct((bh, n_q, block_q, d), q.dtype),
+            ),
+            ("dkv", jax.ShapeDtypeStruct((bh, sk, d), k.dtype)),
+            ("dkv", jax.ShapeDtypeStruct((bh, sk, d), v.dtype)),
+        ],
+        [
+            pltpu.VMEM((n_q, block_q, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        # dq lives across both inner axes, so neither may be split or reordered
+        pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_fused_vmem_bytes(sq, d, block_q, block_k, q.dtype.itemsize),
+        ),
+        q, k, v, o, do, lse, segs, bounds("k"),
+        block_q=block_q, block_k=block_k, **kw,
+    )
+    return dq.reshape(bh, sq, d), dk, dv
+
+
+def _bwd_call(q, k, v, o, do, lse, segs, bounds, **kw):
+    """dq and, per q head, dk and dv (the caller sums each GQA group: a KV
+    block cannot accumulate across grid rows). ``bounds(outer)`` gives the
+    visit table with q or k blocks outermost; the form chosen from the row's
+    length and the head's width (``backward_form``) asks for the one or two
+    it reads."""
+    fused = backward_form(q.shape[1], q.shape[2]) == "fused"
+    return (_bwd_fused if fused else _bwd_split)(q, k, v, o, do, lse, segs, bounds, **kw)
 
 
 @functools.lru_cache(maxsize=None)
@@ -507,11 +681,14 @@ def _flash_core(
     (GQA group = H // Kh handled by kernel index maps — the repeated K/V
     never exist, in HBM or as residuals). With ``segmented``, a fourth
     [B, 1, S] int32 operand masks attention across packed-sequence
-    boundaries (zero cotangent). Each of the three kernels gets its visit
-    bounds, computed here from what the call is given: they are data, so one
-    compiled step serves every packing. Backward tiles are independent of
-    the forward's — the dq/dkv kernels hold 6+ operands per tile, so their
-    VMEM sweet spot can differ (tools/tune_flash.py sweeps both on the chip)."""
+    boundaries (zero cotangent). Each kernel gets its visit bounds, computed
+    here from what the call is given: they are data, so one compiled step
+    serves every packing. The backward is ``_bwd_call``'s: one fused kernel
+    reading the k-outer table, or for a row over the VMEM budget the split
+    pair reading one table each; only the tables the form reads are built.
+    Backward tiles are independent of the forward's — the backward holds 6+
+    operands per tile, so its VMEM sweet spot can differ (tools/tune_flash.py
+    sweeps both on the chip)."""
 
     kw = dict(causal=causal, block_q=block_q, block_k=block_k, group=group,
               heads=heads, interpret=interpret)
@@ -554,12 +731,11 @@ def _flash_core(
         dq, dk_h, dv_h = _bwd_call(
             q, k, v, o, g.astype(o.dtype), lse,
             segs if segmented else None,
-            bounds(q, k, segs, bwd_block_q, bwd_block_k, "q"),
-            bounds(q, k, segs, bwd_block_q, bwd_block_k, "k"),
+            functools.partial(bounds, q, k, segs, bwd_block_q, bwd_block_k),
             **bwd_kw,
         )
         if group > 1:
-            # dkv kernel emits per-q-head grads; sum each GQA group in fp32
+            # the kernel emits dk, dv per q head; sum each GQA group in fp32
             bh, sk, d = dk_h.shape
 
             def gsum(x, dtype):
@@ -606,13 +782,26 @@ def _auto_blocks(sq: int, sk: int, segmented: bool = False, head_dim: int = 128)
     forward is fastest at 1,024 x 512 (7.53 ms a call against 7.65 at 512 x
     1,024 and 8.40 at 512 x 512), the backward at 512 x 1,024 as at width 128
     (20.14 ms against 20.49 at 1,024 x 512 and 20.58 at 512 x 512). Calls at
-    width 128 keep their tiles to the number. PERF.md section 6 has both
-    sweeps."""
+    width 128 keep their tiles to the number. PR 31 (the fused backward, the
+    same tool at the three cells' shapes and rows; backward ms a call, the
+    split pair at the same tiles beside it): width 128 (B 2, S 4,096, 32/8)
+    4.88 at 512 x 1,024 (split 7.77), 4.97 at 512 x 512, 5.02 at 1,024 x
+    512, 5.07 at 1,024 x 1,024; width 256 (B 2, S 8,192, 20/20) 13.38 at
+    512 x 1,024 (split 20.14), 13.53 at 1,024 x 1,024, which the raised VMEM
+    limit now admits, 13.57 at 512 x 512; both keep 512 x 1,024. Width 64 (B
+    4, S 8,192, 32/8) 25.89 at 1,024 x 1,024 against 27.05 at 512 x 1,024
+    (split 41.36) and 27.46 at 1,024 x 512: half-wide operands make a grid
+    step cheaper to fetch and no cheaper to start, so the backward takes the
+    forward's larger q tile there. No tile under 512 wins anywhere. PERF.md
+    section 6 has the sweeps."""
     bq, bk = _pick_divisor(sq, 512), _pick_divisor(sk, 1024 if sk >= 4096 else 512)
     if segmented and sq >= 4096:
-        if head_dim <= 128:
-            return _pick_divisor(sq, 1024), bk, bq, bk
-        return _pick_divisor(sq, 1024), _pick_divisor(sk, 512), bq, bk
+        fq = _pick_divisor(sq, 1024)
+        if head_dim < 128:
+            return fq, bk, fq, bk
+        if head_dim == 128:
+            return fq, bk, bq, bk
+        return fq, _pick_divisor(sk, 512), bq, bk
     return bq, bk, bq, bk
 
 
@@ -661,8 +850,8 @@ def flash_attention(
     The four tile sizes default to the measured-fastest tiling for the
     sequence lengths and for whether the call is segmented (``_auto_blocks``,
     swept with tools/tune_flash.py); a forward tile the caller gives is the
-    backward's too unless it gives that as well (the backward kernels carry
-    6+ operand tiles, so their VMEM sweet spot differs). ``segment_ids``
+    backward's too unless it gives that as well (the backward carries 6+
+    operand tiles, so its VMEM sweet spot differs). ``segment_ids``
     [B, S] masks attention across packed-sequence boundaries in-kernel, and
     the tiles it masks wholly are not visited (the module docstring).
 

@@ -302,7 +302,9 @@ EVENTS = frozenset(
         "train.run_start",
         "train.run_end",
         # which attention kernel the automatic dispatch took for a traced
-        # shape, and why not flash (models/transformer.py auto_attention)
+        # shape, and why not flash (models/transformer.py auto_attention);
+        # for the flash kernels their tiles, ``lanes`` and ``backward``
+        # (``fused`` or ``split``: ops/flash.py backward_form)
         "attention.kernel",
         # autopilot decisions (autopilot/controller.py, serve/scheduler.py):
         # the auditable telemetry→config loop — diagnosis verdicts, applied

@@ -2,6 +2,8 @@
 seq-sharded mesh, Ulysses == reference, flash kernel (interpret mode) ==
 reference, and gradients flow through blockwise/ring."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -151,6 +153,10 @@ def test_flash_auto_blocks():
     assert _auto_blocks(8192, 8192) == (512, 1024, 512, 1024)  # wide k tiles at long S
     assert _auto_blocks(1280, 1280) == (256, 256, 256, 256)  # halved until they divide
     assert _auto_blocks(128, 128) == (128, 128, 128, 128)
+    # segmented long rows choose by the heads' width too (the sweeps of PR 25, 26, 31)
+    assert _auto_blocks(8192, 8192, True, 128) == (1024, 1024, 512, 1024)
+    assert _auto_blocks(8192, 8192, True, 256) == (1024, 512, 512, 1024)
+    assert _auto_blocks(8192, 8192, True, 64) == (1024, 1024, 1024, 1024)
     # segmented calls choose again from the same shapes; every choice divides
     for s in (128, 1024, 1280, 4096, 8192):
         assert all(s % b == 0 for b in _auto_blocks(s, s, True))
@@ -355,42 +361,88 @@ def test_flash_tile_table_without_segments_is_the_causal_clamp():
     assert needed_tiles(None, causal=False, **kw).all()
 
 
-def _kernels_with_table(q, k, v, do, seg, table_seg, fwd_blocks, bwd_blocks):
-    """o, lse, dq, dk, dv from the three kernels (interpreted), masking by
-    ``seg`` and visiting the tiles ``table_seg`` leaves."""
+def _kernels_with_table(q, k, v, do, seg, table_seg, fwd_blocks, bwd_blocks, *, causal=True, backward="_bwd_call"):
+    """o, lse, dq, dk, dv from the kernels (interpreted), masking by ``seg``
+    (None: no segment ids) and visiting the tiles ``table_seg`` leaves; the
+    gradients from ``backward``: ``_bwd_call``, which chooses the form as the
+    public path does, or ``_bwd_fused`` / ``_bwd_split``."""
     from maggy_tpu.ops import flash
 
     b, s, h, d = q.shape
     kh = k.shape[2]
     flat = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, d)
     q, k, v, do = flat(q), flat(k), flat(v), flat(do)
-    segs = jnp.asarray(seg).reshape(b, 1, s)
-    table = jnp.asarray(table_seg).reshape(b, 1, s)
+    segs = None if seg is None else jnp.asarray(seg).reshape(b, 1, s)
+    table = None if table_seg is None else jnp.asarray(table_seg).reshape(b, 1, s)
 
     def bounds(blocks, outer):
-        return flash.visit_bounds(
-            table, outer, causal=True, sq=s, sk=s, block_q=blocks[0], block_k=blocks[1]
-        )
+        return jnp.asarray(flash.visit_bounds(
+            table, outer, causal=causal, sq=s, sk=s, block_q=blocks[0], block_k=blocks[1]
+        ))
 
-    kw = dict(causal=True, group=h // kh, heads=h, interpret=True)
+    kw = dict(causal=causal, group=h // kh, heads=h, interpret=True)
     o, lse = flash._fwd_call(
         q, k, v, segs, bounds(fwd_blocks, "q"),
         block_q=fwd_blocks[0], block_k=fwd_blocks[1], **kw,
     )
     lse_b = lse.reshape(b * h, s // bwd_blocks[0], bwd_blocks[0], 1)
-    grads = flash._bwd_call(
-        q, k, v, o, do, lse_b, segs, bounds(bwd_blocks, "q"), bounds(bwd_blocks, "k"),
+    grads = getattr(flash, backward)(
+        q, k, v, o, do, lse_b, segs, functools.partial(bounds, bwd_blocks),
         block_q=bwd_blocks[0], block_k=bwd_blocks[1], **kw,
     )
     return [np.asarray(x) for x in (o, lse, *grads)]
 
 
-@pytest.mark.parametrize(
+_KERNEL_GEOMETRIES = pytest.mark.parametrize(
     "kh,fwd_blocks,bwd_blocks,d",
     [(4, (64, 64), (64, 64), 128), (1, (64, 64), (64, 64), 128), (1, (32, 128), (64, 32), 128),
      (1, (64, 64), (64, 64), 64)],
     ids=["group1", "group4", "group4-bwd-tiles-unlike-fwd", "group4-width64"],
 )
+
+
+@_KERNEL_GEOMETRIES
+@pytest.mark.parametrize("packing", _PACKINGS + ("unsegmented", "non_causal"))
+def test_flash_fused_backward_equals_the_split_kernels(packing, kh, fwd_blocks, bwd_blocks, d):
+    """At equal tiles ``flash_bwd`` gives the dq, dk and dv of ``flash_dq`` and
+    ``flash_dkv``, bit for bit: it visits by the k-outer table alone, a tile
+    in only one of the two tables is wholly masked and adds zero, and every q
+    block gets its k blocks in ascending order. Over the packings, with no
+    segment ids (the causal diagonal alone) and non-causal (documents alone)."""
+    q, k, v = qkv(b=2, s=_S, h=4, kh=kh, d=d, seed=3)
+    do = jax.random.normal(jax.random.key(9), q.shape, q.dtype)
+    seg = None if packing == "unsegmented" else _packing("mixed_rows" if packing == "non_causal" else packing)
+    run = functools.partial(
+        _kernels_with_table, q, k, v, do, seg, seg, fwd_blocks, bwd_blocks, causal=packing != "non_causal"
+    )
+    fused, split = run(backward="_bwd_fused"), run(backward="_bwd_split")
+    for name, a, b in zip(("dq", "dk", "dv"), fused[2:], split[2:]):
+        assert np.abs(b).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize(
+    "s,d,form",
+    [(8192, 256, "fused"), (4096, 128, "fused"), (8192, 64, "fused"), (32768, 128, "fused"),
+     (65536, 128, "split"), (32768, 256, "split")],
+    ids=["glm-cell", "mistral-cell", "lfm2-cell", "at-the-budget", "over-the-budget", "over-the-budget-width256"],
+)
+def test_flash_backward_form_follows_the_row_and_the_width(s, d, form):
+    """A head's dq stays in VMEM where it fits the stated budget, and a longer
+    row keeps the two split kernels: chosen by ``sq`` and the width alone,
+    and counted by kernel name in the jaxpr of a gradient (nothing runs)."""
+    from maggy_tpu.ops.flash import BACKWARD_KERNELS, backward_form
+    from tests.test_flash_residuals import count, kernels
+
+    assert backward_form(s, d) == form
+    assert BACKWARD_KERNELS == {"fused": ("flash_bwd",), "split": ("flash_dq", "flash_dkv")}
+    x = jax.ShapeDtypeStruct((1, s, 1, d), jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v: flash_attention(q, k, v, interpret=True).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    launched = kernels(count(jax.make_jaxpr(grad)(x, x, x).jaxpr))
+    assert launched == {"flash_fwd": 1, **dict.fromkeys(BACKWARD_KERNELS[form], 1)}
+
+
+@_KERNEL_GEOMETRIES
 @pytest.mark.parametrize("packing", _PACKINGS)
 def test_flash_skipped_tiles_change_no_bit(packing, kh, fwd_blocks, bwd_blocks, d):
     """Output, LSE, dq, dk and dv with the visit table made from the segment
@@ -554,6 +606,7 @@ def test_flash_kernel_event_carries_the_tiles():
     tiles = tuple(event["attrs"][n] for n in ("block_q", "block_k", "bwd_block_q", "bwd_block_k"))
     assert tiles == _auto_blocks(4096, 4096, True)
     assert (event["attrs"]["head_dim"], event["attrs"]["lanes"]) == (128, "full")
+    assert event["attrs"]["backward"] == "fused"  # what ``_bwd_call`` asks: ``backward_form``
     q, k, _ = qkv(b=1, s=4096, h=4, kh=1, d=64)
     with telemetry.current(tel):
         record_attention_kernel("flash", q, k, jnp.ones((1, 4096), jnp.int32))

@@ -19,7 +19,7 @@ from maggy_tpu.ops.flash import FLASH_RESIDUALS, flash_attention, sharded_flash_
 
 S = 128
 LATENT = dict(q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128)
-ONCE = {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+ONCE = {"flash_fwd": 1, "flash_bwd": 1}
 
 
 def count(jaxpr, found=None):

@@ -527,8 +527,8 @@ def test_config_refuses_what_the_layers_cannot_do(tiny, bad):
 
 @pytest.mark.parametrize("packed", [False, True])
 def test_flash_at_head_width_256_against_the_dense_path(packed):
-    """The three kernels in the Pallas interpreter at the width latent
-    attention gives them (192 + 64 = 256 = v), 128-row tiles, against
+    """The kernels (forward and fused backward) in the Pallas interpreter at
+    the width latent attention gives them (192 + 64 = 256 = v), 128-row tiles, against
     ``default_attention``; packed rows mask across documents and skip tiles."""
     b, s, h, d = 2, 256, 2, 256
     q, k, v = (0.5 * jax.random.normal(jax.random.key(i), (b, s, h, d), jnp.float32) for i in range(3))

@@ -16,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from maggy_tpu.models import moe
 from maggy_tpu.models.transformer import REMAT_POLICIES, DecoderConfig, LatentAttention
-from maggy_tpu.ops.flash import flash_attention
+from maggy_tpu.ops.flash import backward_form, flash_attention
 
 
 @pytest.fixture(scope="module")
@@ -29,6 +29,12 @@ def one_chip():
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+def flash_calls(text):
+    """The flash kernels a compiled program calls, by name."""
+    calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    return {name: sum(name in line for line in calls) for name in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv")}
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)], ids=["gate_and_up", "down"])
@@ -55,9 +61,10 @@ def test_grouped_kernels_compile_at_the_glm_widths(one_chip, k, n):
 def test_recomputed_latent_attention_compiles_one_flash_forward(one_chip):
     """A recomputed ``LatentAttention`` at glm-4.7-flash's widths, the GLM
     cell's rows (2 x 8,192, packed), its loss and gradient: Mosaic takes the
-    three kernels at width 256, and the policy keeps the forward kernel's
-    results, so the compiled program calls ``flash_fwd`` once (a replay would
-    be a second call: ``nn.remat`` holds XLA back from merging the two)."""
+    forward and the fused backward kernel at width 256, and the policy keeps
+    the forward kernel's results, so the compiled program calls ``flash_fwd``
+    once (a replay would be a second call: ``nn.remat`` holds XLA back from
+    merging the two)."""
     cfg = DecoderConfig(
         d_model=2048, n_heads=20, n_kv_heads=20, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
         qk_rope_head_dim=64, v_head_dim=256, max_seq_len=8192, partition_params=False,
@@ -77,5 +84,30 @@ def test_recomputed_latent_attention_compiles_one_flash_forward(one_chip):
         )(params, x)
 
     text = jax.jit(step).lower(params, x, ids, ids).compile().as_text()
-    calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
-    assert [sum(name in line for line in calls) for name in ("flash_fwd", "flash_dq", "flash_dkv")] == [1, 1, 1]
+    assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
+
+
+@pytest.mark.parametrize(
+    "b,s,h,kh,d",
+    [(2, 8192, 20, 20, 256), (2, 4096, 32, 8, 128), (4, 8192, 32, 8, 64)],
+    ids=["glm-40x8192x256", "mistral-64x4096x128", "lfm2-128x8192x64"],
+)
+def test_fused_flash_backward_compiles_at_the_cells_shapes(one_chip, b, s, h, kh, d):
+    """``flash_bwd`` at the three training cells' rows, heads and widths,
+    segmented, bfloat16, at the tiles ``_auto_blocks`` gives: a head's whole
+    dq in VMEM beside the tiles needs more than Mosaic's default 16 MiB, so a
+    ``vmem_limit_bytes`` counted too low is refused here, not on the chip."""
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, kh, d), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+
+    def step(q, k, v, segment_ids):
+        return jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, segment_ids=segment_ids, interpret=False)
+            .astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    text = jax.jit(step).lower(q, kv, kv, ids).compile().as_text()
+    assert backward_form(s, d) == "fused"
+    assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}

@@ -5,17 +5,19 @@
 
     python tools/tune_flash.py --packed --mix packed8k --heads 20 --kv-heads 20 --head-dim 256
 
-Both modes time one attention call at B 2, bfloat16, by default at 32 query
+    python tools/tune_flash.py --packed --mix packed8k-r4 --heads 32 --kv-heads 8 --head-dim 64
+
+Both modes time one attention call in bfloat16, by default at B 2 and 32 query
 and 8 key-value heads of 128 (Mistral-7B's; ``--heads``, ``--kv-heads``,
 ``--head-dim`` give another model's). ``--packed`` takes its segment ids from
 a benchmark mix (``--mix``, default ``packed4k``; ``benchmark.traffic``, read only):
-every row of the pool, two rows a call, so a tile choice is timed on the
-packings the cell trains on and printed beside the share of the grid's tiles
-it visits. The forward is timed alone (it runs twice a step under full
-recomputation), the backward as forward plus backward less the forward at
-the same forward tiles. ``_auto_blocks`` in ``maggy_tpu/ops/flash.py``
-returns the winners; PERF.md has the table this printed when they were
-chosen.
+every row of the pool, the mix's rows a step a call, so a tile choice is timed
+on the packings the cell trains on and printed beside the share of the grid's
+tiles it visits. The forward is timed alone, the backward (one fused kernel
+where ``ops.flash.backward_form`` says so, else two) as forward plus backward
+less the forward at the same forward tiles. ``_auto_blocks`` in
+``maggy_tpu/ops/flash.py`` returns the winners; PERF.md has the table this
+printed when they were chosen.
 
 The candidates are the autopilot knob registry's ``FLASH_TILE_CHOICES``
 (maggy_tpu/autopilot/knobs.py) from 256 up, so a tile this tool can measure
@@ -61,13 +63,15 @@ def main():
     import numpy as np
 
     from maggy_tpu.autopilot.knobs import FLASH_TILE_CHOICES
-    from maggy_tpu.ops.flash import _auto_blocks, flash_attention, tiles_visited_share
+    from maggy_tpu.ops.flash import _auto_blocks, backward_form, flash_attention, tiles_visited_share
 
     toy = jax.default_backend() != "tpu"
     if args.packed and not toy:
         segs = packed_segment_ids(args.mix)
         args.seq = segs.shape[-1]
-    B, S, H, KH, D = (2, 512, 2, 1, 128) if toy else (2, args.seq, args.heads, args.kv_heads, args.head_dim)
+    B, S, H, KH, D = (2, 512, 2, 1, 128) if toy else (
+        segs.shape[1] if args.packed else 2, args.seq, args.heads, args.kv_heads, args.head_dim
+    )
     dt = jnp.bfloat16
     q, do = (jax.random.normal(jax.random.key(i), (B, S, H, D), dt) for i in (1, 4))
     k, v = (jax.random.normal(jax.random.key(i), (B, S, KH, D), dt) for i in (2, 3))
@@ -139,6 +143,7 @@ def main():
         "forward_plus_backward": both,
         "auto_blocks": auto,
         "auto_ms": {"fwd": round(forward(auto), 4), "fwd+bwd": round(forward_backward(auto), 4)},
+        "backward": backward_form(S, D),
         "device": str(jax.devices()[0]),
     }))
 
