@@ -7,7 +7,9 @@ a user calls, at the widths of ``DecoderConfig.llama3_8b()`` with the depth
 (and, on one chip, the vocabulary) cut to fit and seeded random weights:
 
 1. kernels  ops/flash.py forward+backward, compiled (``interpret=False``),
-            plain and packed, against the float32 blockwise reference;
+            plain and packed, against the float32 blockwise reference; then
+            ops/sparse_select.py's ``index_scores`` against float32 products
+            and the flash pair under the mask of an exact selection;
 2. trainer  ``experiment.lagom(train_fn, DistributedConfig(...))`` with the
             README's train_fn (``ctx.trainer`` -> ``make_state`` -> ``fit``);
 3. server   the stack ``python -m maggy_tpu.serve`` builds, answering
@@ -62,6 +64,7 @@ class Sizes:
     serve_cfg: dict  # ... and for the served model
     kernel_heads: tuple  # (q heads, kv heads, head_dim)
     kernel_cases: tuple  # (batch, seq) pairs
+    index_heads: tuple  # (index heads, their width) of the selected-key case
     train_seq: int
     train_steps: int
     prompt_lens: tuple
@@ -90,6 +93,7 @@ def chip_sizes(n_chips: int, bytes_limit: int) -> Sizes:
         serve_cfg={"n_layers": 2},
         kernel_heads=(32, 8, 128),
         kernel_cases=((2, 2048), (1, 8192)),
+        index_heads=(16, 64),
         train_seq=2048,
         train_steps=6,
         prompt_lens=(6, 7, 24, 30, 100, 120, 400, 500),
@@ -112,6 +116,7 @@ def toy_sizes() -> Sizes:
         serve_cfg=toy,
         kernel_heads=(4, 2, 128),
         kernel_cases=((2, 256),),
+        index_heads=(4, 16),
         train_seq=128,
         train_steps=4,
         prompt_lens=(3, 5, 9, 12, 20, 26, 40, 50),
@@ -260,6 +265,67 @@ def phase_kernels(run: Run) -> dict:
                 f"flash {label} off the float32 reference: {errs} > {tol}",
             )
             cases.append({"case": label, **errs})
+
+    # attention over selected keys (ops/sparse_select.py): the index scores'
+    # kernel against float32 products of the same bfloat16 numbers, the exact
+    # selection of a quarter of the row's keys a query, and the flash pair
+    # with the selection's tile as an operand against float32 attention under
+    # the same mask, at the first case's size, packed
+    from maggy_tpu.models.transformer import default_attention
+    from maggy_tpu.ops import sparse_select
+
+    b, s = run.sizes.kernel_cases[0]
+    heads_i, width_i = run.sizes.index_heads
+    topk = s // 4
+    q, k, v, w, segs = make_inputs(b, s)
+
+    @jax.jit
+    def make_index():
+        keys = jax.random.split(jax.random.key(7), 3)
+        return (
+            jax.random.normal(keys[0], (b, heads_i, s, width_i), jnp.bfloat16),
+            jax.random.normal(keys[1], (b, s, width_i), jnp.bfloat16),
+            jax.random.normal(keys[2], (b, s, heads_i), f32) * (heads_i * width_i) ** -0.5,
+        )
+
+    @jax.jit
+    def selection(qi, ki, wi, segs):
+        seg3 = segs[:, None]
+        got = sparse_select.index_scores(qi, ki, wi, seg3, 0, s, interpret=interpret)
+        z = jnp.einsum("bjqd,bsd->bqjs", qi.astype(f32), ki.astype(f32), precision="highest")
+        want = (wi[..., None] * jnp.maximum(z, 0.0)).sum(2)
+        seen = got > -jnp.inf
+        at = jnp.arange(s)
+        same = (seen == ((at[:, None] >= at[None]) & (segs[:, :, None] == segs[:, None, :]))).all()
+        err = jnp.linalg.norm(jnp.where(seen, got - want, 0.0)) / jnp.linalg.norm(jnp.where(seen, want, 0.0))
+        mask, counts = sparse_select.select(qi, ki, wi, seg3, topk, interpret=interpret)
+        by_hand = jnp.minimum(seen.sum(-1), topk).sum()
+        return mask, err, same, counts, by_hand
+
+    t0 = time.perf_counter()
+    mask, err, same, counts, by_hand = selection(*make_index(), segs)
+    label = f"B={b} S={s} packed, {topk} keys a query of {heads_i} x {width_i} index heads"
+    run.say(f"  index_scores {label}: rel err {float(err):.2e}; selected {int(counts[0])} of {int(counts[1])} pairs, "
+            f"{int(counts[2])} queries off their count")
+    check(bool(same), "index_scores: a pair outside a query's document or after it is not -inf, or one inside is")
+    check(float(err) <= tol, f"index_scores off the float32 products: {float(err)} > {tol}")
+    check(int(counts[2]) == 0 and int(counts[0]) == int(by_hand), "the selection is not min(topk, visible) keys a query")
+    masked_step = jax.jit(lambda q, k, v, w, seg, m: fwd_bwd(functools.partial(flash, selected=m), q, k, v, w, seg))
+    masked_reference = jax.jit(
+        lambda q, k, v, w, seg, m: fwd_bwd(
+            functools.partial(default_attention, selected=m), q.astype(f32), k.astype(f32), v.astype(f32), w, seg
+        )
+    )
+    got = masked_step(q, k, v, w, segs, mask)
+    setup_s += time.perf_counter() - t0
+    with jax.default_matmul_precision("highest"):
+        want = masked_reference(q, k, v, w, segs, mask)
+    rel, finite = compare(got, want)
+    check(bool(finite), f"flash under a selection {label}: a value is not finite")
+    errs = dict(zip(("out", "dq", "dk", "dv"), (float(e) for e in rel)))
+    run.say(f"  flash under a selection {label}: rel err " + " ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+    check(max(errs.values()) <= tol, f"flash under a selection off the float32 reference: {errs} > {tol}")
+    cases.append({"case": "selected " + label, "index_scores": float(err), **errs})
     return {
         "setup_s": setup_s, "heads": [h, kh, d], "tolerance": tol, "cases": cases,
         "compiled": not interpret,

@@ -68,6 +68,15 @@ class MoEConfig(DecoderConfig):
     # what the sum of the chosen scores gets before it divides them (the
     # published modelling codes differ: 1e-20 glm4_moe_lite, 1e-6 lfm2_moe)
     route_norm_eps: float = 1e-20
+    # how the share form's router scores the experts: "sigmoid"
+    # (``sigmoid_route``: DeepSeek-V3's, with the selection bias) or "softmax"
+    # (``softmax_route``: ``qwen3_moe``'s, the chosen probabilities
+    # renormalised over themselves; no bias, no scaling)
+    router: str = "sigmoid"
+    # a chunk of the share layer's buffer as a fraction of the expected load
+    # ``T * top_k * experts_held / n_experts`` (``chunk_rows``); 0: an eighth
+    # of the buffer
+    chunk_of_load: float = 0.0
     # the router's selection bias (``noaux_tc``): enters top-k only, gets no
     # gradient, and is a constant here — N(0, select_bias_std) from
     # select_bias_seed, a row a layer (its update between steps is per-step
@@ -94,6 +103,12 @@ class MoEConfig(DecoderConfig):
                 raise ValueError("the share form needs moe_d_ff")
             if self.ablated:
                 raise ValueError("the share form has no LOCO gates")
+            if self.router not in ("sigmoid", "softmax"):
+                raise ValueError("router is 'sigmoid' or 'softmax'")
+            if self.router == "softmax" and (self.select_bias_std or self.routed_scaling != 1.0):
+                raise ValueError("the softmax router takes no selection bias and no scaling")
+        if self.chunk_of_load < 0 or (self.chunk_of_load and not self.experts_held):
+            raise ValueError("chunk_of_load is a fraction of the share form's expected load")
         if not 0 <= self.n_dense_layers < self.n_layers:
             raise ValueError("n_dense_layers must leave an expert layer")
         if self.mtp_depth not in (0, 1):
@@ -263,7 +278,7 @@ def counting_sort(key, n_keys: int):
     return inv.astype(jnp.int32), load
 
 
-def chunk_rows(slots: int, held: int, n_experts: int) -> int:
+def chunk_rows(slots: int, held: int, n_experts: int, of_load: float = 0.0) -> int:
     """Rows of one chunk of a share layer's buffer (``slots`` = ``T * top_k``
     rows, which no load exceeds): the routed part runs chunk by chunk over as
     many as the counted slots fill. A chip that holds every expert sees every
@@ -271,8 +286,15 @@ def chunk_rows(slots: int, held: int, n_experts: int) -> int:
     and wanders severalfold above that with the router, so an eighth of the
     buffer: on one v5e at the GLM cell's size a quarter lost 1.2% of the
     step to rows that hold no slot and a sixteenth won 0.4% (PERF.md section
-    6, PR 27)."""
-    return -(-slots // 8) if held < n_experts else slots
+    6, PR 27). ``of_load`` (``MoEConfig.chunk_of_load``) above 0 asks for
+    that fraction of the expected load instead: where a chip holds an eighth
+    of the experts an eighth of the buffer is the expected load itself, and
+    a layer lands on one chunk or on two by a few hundred slots."""
+    if held >= n_experts:
+        return slots
+    if of_load > 0:
+        return math.ceil(of_load * slots * held / n_experts)
+    return -(-slots // 8)
 
 
 def _by_token(rows, inv, held, weights=None):
@@ -452,8 +474,20 @@ def sigmoid_route(logits, select_bias, top_k: int, scaling: float, norm_eps: flo
     return sel, scaling * chosen / (chosen.sum(-1, keepdims=True) + norm_eps)
 
 
+def softmax_route(logits, top_k: int):
+    """``qwen3_moe``'s router (``norm_topk_prob``) from float32 logits
+    [..., n_experts]: ``(sel [..., k] expert numbers, weights [..., k])`` with
+    ``p = softmax(logits)`` over all the experts, ``sel = top_k(p)`` and the
+    chosen probabilities divided by their sum."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, sel = jax.lax.top_k(jax.lax.stop_gradient(probs), top_k)
+    chosen = jnp.take_along_axis(probs, sel, axis=-1)
+    return sel, chosen / chosen.sum(-1, keepdims=True)
+
+
 class ExpertShareBlock(nn.Module):
-    """One chip's share of a sigmoid-routed expert layer, dropless.
+    """One chip's share of a sigmoid-routed expert layer, dropless
+    (``router="softmax"``: of a softmax-routed one, ``softmax_route``).
 
     The router scores all ``n_experts`` in float32: ``s = sigmoid(x W_r)``,
     ``sel = top_k(s + b)`` (``b`` the selection bias: no gradient),
@@ -489,9 +523,12 @@ class ExpertShareBlock(nn.Module):
                 kernel_init=_partitioned(nn.initializers.normal(0.02), ("embed", None), cfg),
                 name="router",
             )(tokens.astype(jnp.float32))
-            sel, weights = sigmoid_route(
-                logits, select_bias, k, cfg.routed_scaling, cfg.route_norm_eps
-            )  # [t, k]
+            if cfg.router == "softmax":
+                sel, weights = softmax_route(logits, k)
+            else:
+                sel, weights = sigmoid_route(
+                    logits, select_bias, k, cfg.routed_scaling, cfg.route_norm_eps
+                )  # [t, k]
 
         with jax.named_scope("moe.dispatch"):
             local = sel - lo
@@ -500,7 +537,7 @@ class ExpertShareBlock(nn.Module):
             inv, load = counting_sort(key, held + 1)
             load = load[:held]
             # a row of the buffer for every slot, in chunks: the last chunk may overhang
-            rows = chunk_rows(t * k, held, e)
+            rows = chunk_rows(t * k, held, e, cfg.chunk_of_load)
             chunks = -(-t * k // rows)
             order = jnp.zeros(chunks * rows, jnp.int32).at[inv].set(
                 jnp.arange(t * k, dtype=jnp.int32), unique_indices=True

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from maggy_tpu.ops import attention as ops_attn
+from maggy_tpu.ops import sparse_select
 from maggy_tpu.ops.flash import (
     FLASH_RESIDUALS,
     flash_attention,
@@ -47,17 +48,22 @@ Dtype = Any
 # - "dots": the same, plus the outputs of matmuls with no batch dimension (the
 #   projections and the feed-forward), so the replay is elementwise work.
 # - "everything": nothing is recomputed.
+# A selected-key attention layer (``sparse_topk``) keeps two things more
+# (``ops.sparse_select.SPARSE_RESIDUALS``): each query's threshold, so that the
+# replay rebuilds the selection's mask and does not select again, and the
+# indexer's gradients, which its loss's one pass already gave.
 # There is no policy that replays the kernel: a step that does not fit with
 # its results kept is one the autotuner (``tune/static.py``) prunes by its
 # compiled footprint, and the remedy is the one it proposes, a smaller batch.
 # Close to the device's memory the compiler makes the room itself, by
 # computing other values twice (PERF.md section 6, PR 29: three matmuls, 14 ms
 # of the 30 the kernel's replay had cost in the GLM cell).
+KEPT_RESIDUALS = (*FLASH_RESIDUALS, *sparse_select.SPARSE_RESIDUALS)
 REMAT_POLICIES = {
-    "nothing": jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS),
+    "nothing": jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS),
     "dots": jax.checkpoint_policies.save_from_both_policies(
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS),
+        jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS),
     ),
     "everything": jax.checkpoint_policies.everything_saveable,
 }
@@ -182,12 +188,24 @@ class DecoderConfig:
     # an RMSNorm over the head's width on every query and key head before the
     # rotary embedding (:class:`Attention` only)
     qk_norm: bool = False
+    # the width of a head where it is not d_model / n_heads (``qwen3_moe``'s
+    # family: 32 heads of 128 over a model of 2,048); 0: d_model / n_heads
+    head_width: int = 0
+    # attention over the keys an indexer selects (:class:`Attention` only,
+    # ``ops/sparse_select.py``): each query attends its ``sparse_topk`` keys of
+    # largest index score, ``index_heads`` heads of ``index_head_dim`` over one
+    # key head; 0: every key (today's path, bit for bit). A row of at most
+    # ``sparse_topk`` positions selects everything and takes that path too.
+    # Training and scoring only: the serve engine has no indexer cache
+    sparse_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
 
     @property
     def head_dim(self) -> int:
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
 
     def layer_kinds(self) -> tuple:
         """The operator of every layer, ``n_layers`` names."""
@@ -226,6 +244,16 @@ class DecoderConfig:
             raise ValueError(
                 f"remat_policy must be one of {sorted(REMAT_POLICIES)}"
             )
+        if self.sparse_topk:
+            if not (self.index_heads and self.index_head_dim) or self.index_head_dim % 2:
+                raise ValueError("sparse_topk needs index_heads and an even index_head_dim")
+            if self.kv_lora_rank or self.attention_fn is not None:
+                raise ValueError("selected-key attention is Attention's, through the automatic dispatch")
+            if self.decode:
+                raise ValueError(
+                    "selected-key attention has a training form only: no indexer "
+                    "cache beside the KV cache, no selection inside decode"
+                )
         if self.paged:
             if not self.decode:
                 raise ValueError("paged=True requires decode=True")
@@ -341,6 +369,23 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.cfg.dtype)
 
 
+class LayerNorm(nn.Module):
+    """Mean and variance over the last dimension, a scale and a bias (the
+    indexer's key norm); float32 inside."""
+
+    cfg: DecoderConfig
+
+    @nn.compact
+    def __call__(self, x):
+        width, cfg = x.shape[-1], self.cfg
+        scale = self.param("scale", _partitioned(nn.initializers.ones_init(), ("norm",), cfg), (width,), cfg.param_dtype)
+        bias = self.param("bias", _partitioned(nn.initializers.zeros_init(), ("norm",), cfg), (width,), cfg.param_dtype)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + cfg.norm_eps) * scale + bias).astype(cfg.dtype)
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary position embedding over the last dim of [B, S, H, D] arrays.
 
@@ -378,18 +423,21 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
     return None
 
 
-def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = ""):
+def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", selected: int = 0):
     """Journal which kernel the automatic dispatch chose for this shape as
     one ``attention.kernel`` event; for the flash kernels also the tiles they
     run at (forward q, k, backward q, k), and always the head width with, for
     the flash kernels, how it fills the 128 lanes and which backward the row
     and the width take (``backward``: ``fused``, one kernel, or ``split``,
-    two: ``ops.flash.backward_form``, which the kernels' call asks too). The
+    two: ``ops.flash.backward_form``, which the kernels' call asks too), and
+    ``selected``, the keys a query keeps where a selection masks the call. The
     dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
 
     attrs = {"head_dim": int(q.shape[3])}
+    if selected:
+        attrs["selected"] = int(selected)
     if kernel.startswith("flash"):
         from maggy_tpu.ops.flash import _auto_blocks, backward_form
 
@@ -406,7 +454,9 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = ""):
     )
 
 
-def auto_attention(q, k, v, *, causal: bool = True, segment_ids=None):
+def auto_attention(
+    q, k, v, *, causal: bool = True, segment_ids=None, selected=None, topk: int = 0, return_lse: bool = False
+):
     """Pick the fastest correct kernel for the backend/shape: the Pallas flash
     kernel (fwd+bwd) on TPU when the geometry tiles onto the MXU
     (:func:`flash_tileable`), otherwise the XLA dense path. Tile size is the
@@ -430,29 +480,36 @@ def auto_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     partitioning rule), each shard making its visit table from its own rows;
     incompatible layouts (sp/pp axes, non-divisible batch/heads) take the XLA
     path. The choice is recorded (:func:`record_attention_kernel`), never
-    silent."""
+    silent. ``selected`` (int8 [B, Sq, Sk], the ``topk`` keys a query keeps:
+    ``ops/sparse_select.py``) masks every path the same way; the kernels take
+    a tile of it as an operand. ``return_lse``: ``(out, lse)`` with the rows'
+    log-sum-exp [B, H, Sq] where the one-chip kernels ran, which keep it
+    anyway, and None on every other path (the caller normalises by itself)."""
     from maggy_tpu.parallel.mesh import ambient_mesh
 
+    sel = {} if selected is None else {"selected": selected}
     why = flash_tileable(q.shape[1], k.shape[1], q.shape[3])
     if why is None:
         mesh = ambient_mesh()
         if mesh is None or mesh.size == 1:
-            record_attention_kernel("flash", q, k, segment_ids)
-            return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+            record_attention_kernel("flash", q, k, segment_ids, selected=topk)
+            return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids, return_lse=return_lse, **sel)
         out = sharded_flash_attention(
-            q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids
+            q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids, **sel
         )
         if out is not None:
-            record_attention_kernel("flash_sharded", q, k, segment_ids)
-            return out
+            record_attention_kernel("flash_sharded", q, k, segment_ids, selected=topk)
+            return (out, None) if return_lse else out
         why = f"mesh {dict(mesh.shape)} does not divide batch/heads or uses seq/stage axes"
-    record_attention_kernel("xla_dense", q, k, segment_ids, why)
-    return default_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+    record_attention_kernel("xla_dense", q, k, segment_ids, why, selected=topk)
+    out = default_attention(q, k, v, causal=causal, segment_ids=segment_ids, **sel)
+    return (out, None) if return_lse else out
 
 
-def default_attention(q, k, v, *, causal: bool = True, segment_ids=None):
+def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selected=None):
     """Reference soft-max attention: q [B,S,H,D], k/v [B,S,Kh,D] with GQA
-    head-group broadcast. fp32 logits/softmax for stability."""
+    head-group broadcast. fp32 logits/softmax for stability. ``selected``
+    [B, Sq, Sk]: the pairs a selection keeps (nonzero)."""
     b, sq, h, d = q.shape
     kh = k.shape[2]
     group = h // kh
@@ -466,6 +523,8 @@ def default_attention(q, k, v, *, causal: bool = True, segment_ids=None):
     if segment_ids is not None:
         seg_mask = segment_ids[:, None, None, :, None] == segment_ids[:, None, None, None, :]
         logits = jnp.where(seg_mask, logits, -1e30)
+    if selected is not None:
+        logits = jnp.where((selected != 0)[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
     return out.reshape(b, sq, h, d)
@@ -488,6 +547,8 @@ class Attention(nn.Module):
         k = rope(k, positions, cfg.rope_theta)
         if cfg.decode:
             out = self._cached_attention(q, k, v, positions, segment_ids)
+        elif cfg.sparse_topk:
+            out = self._selected_attention(x, q, k, v, positions, segment_ids)
         else:
             attn = cfg.attention_fn or auto_attention
             out = attn(q, k, v, causal=True, segment_ids=segment_ids)
@@ -502,6 +563,50 @@ class Attention(nn.Module):
             ),
             name="wo",
         )(out)
+        return out
+
+    def _selected_attention(self, x, q, k, v, positions, segment_ids):
+        """Attention over each query's ``sparse_topk`` keys of largest index
+        score (``ops/sparse_select.py``). The indexer reads the layer's normed
+        input behind a stop-gradient: ``index_heads`` query heads and one key
+        head (a LayerNorm on it) of ``index_head_dim``, the rotary embedding
+        on their whole width, and a float32 weight a head; it learns from
+        ``index_aux_loss`` alone (sown: the KL from the heads' mean
+        probabilities on the selected keys to the index's softmax there), and
+        the rest of the model from the cross entropy alone. Sows
+        ``sparse_counts`` ([3]: pairs selected, pairs visible, queries whose
+        set is not ``min(sparse_topk, visible)`` keys) where it selects: a row
+        of at most ``sparse_topk`` positions takes the dense path whole."""
+        cfg = self.cfg
+        heads, width, topk = cfg.index_heads, cfg.index_head_dim, cfg.sparse_topk
+        s = x.shape[1]
+        with jax.named_scope("sparse.index"):
+            u = jax.lax.stop_gradient(x)
+            qi = rope(_dense((heads, width), ("embed", None, None), cfg, "index_q")(u), positions, cfg.rope_theta)
+            ki = LayerNorm(cfg, name="index_k_norm")(_dense(width, ("embed", None), cfg, "index_k")(u))
+            ki = rope(ki[:, :, None], positions, cfg.rope_theta)[:, :, 0]
+            w = nn.DenseGeneral(
+                features=heads, use_bias=False, dtype=jnp.float32, param_dtype=cfg.param_dtype,
+                precision=jax.lax.Precision.HIGHEST,
+                kernel_init=_partitioned(nn.initializers.normal(0.02), ("embed", None), cfg),
+                name="index_w",
+            )(u.astype(jnp.float32)) * (heads**-0.5 * width**-0.5)
+            qi = qi.transpose(0, 2, 1, 3)  # [B, J, S, Dj]: a head's rows together
+        segs = None if segment_ids is None else segment_ids.astype(jnp.int32)[:, None]
+        mask = None
+        if s > topk:
+            mask, counts = sparse_select.select(qi, ki, w, segs, topk)
+            self.sow("intermediates", "sparse_counts", counts)
+        out, lse = auto_attention(
+            q, k, v, causal=True, segment_ids=segment_ids, selected=mask, topk=topk if s > topk else 0,
+            return_lse=True,
+        )
+        with jax.named_scope("sparse.index_loss"):
+            real = jnp.ones(x.shape[:2], bool) if segment_ids is None else segment_ids > 0
+            self.sow(
+                "intermediates", "index_aux_loss",
+                sparse_select.index_loss(qi, ki, w, q, k, lse, mask, segs, real),
+            )
         return out
 
     def _cached_attention(self, q, k, v, positions, segment_ids=None):
@@ -997,7 +1102,7 @@ class Decoder(nn.Module):
         if cfg.scan_layers:
             scanned = nn.scan(
                 layer_cls,
-                variable_axes={"params": 0, "cache": 0},
+                variable_axes={"params": 0, "cache": 0, "intermediates": 0},
                 split_rngs={"params": True},
                 # positions/segment_ids are the same for every layer; LOCO
                 # gates are per-layer

@@ -90,6 +90,12 @@ FLASH_RESIDUALS = ("flash_o", "flash_lse")
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary")
 )
+# with a selection's tile beside the others (int8, two buffers, and its int32
+# form beside the scores) 1,024 x 1,024 tiles pass Mosaic's default 16 MiB
+_MASKED_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=48 * 2**20,
+)
 
 
 def _tile_mask(q_start, k_start, block_q, block_k):
@@ -101,7 +107,7 @@ def _tile_mask(q_start, k_start, block_q, block_k):
 # ------------------------------------------------------------- tile-visit table
 
 
-def needed_tiles(segs, *, causal, sq, sk, block_q, block_k):
+def needed_tiles(segs, *, causal, sq, sk, block_q, block_k, selected=None):
     """bool [rows, sq // block_q, sk // block_k]: the tiles that can hold an
     unmasked pair. A tile is needed when it is not wholly above the causal
     diagonal and the two blocks' ranges of segment ids (minimum and maximum
@@ -109,12 +115,16 @@ def needed_tiles(segs, *, causal, sq, sk, block_q, block_k):
     exactly the tiles with an unmasked pair; with any other ids it is a
     superset, so no pair is lost. Padding (id 0) closes a packed row, so it
     is ordered last. ``segs`` is [B, 1, S] (numpy on the host, or traced) or
-    None, which gives one row that every batch row shares."""
+    None, which gives one row that every batch row shares. ``selected``
+    ([B, sq, sk], traced: the pairs a selection keeps, nonzero) drops the
+    tiles that hold no selected pair, a row of the table a batch row."""
     nq, nk = sq // block_q, sk // block_k
     need = np.ones((1, nq, nk), bool)
     if causal:
         q_last = np.arange(nq)[:, None] * block_q + block_q - 1
         need = (np.arange(nk)[None, :] * block_k <= q_last)[None]
+    if selected is not None:
+        need = need & (selected.reshape(-1, nq, block_q, nk, block_k) != 0).any(axis=(2, 4))
     if segs is None:
         return need
     xp = np if isinstance(segs, np.ndarray) else jnp
@@ -154,7 +164,7 @@ def _visits(bounds_ref, heads, red):
     its first-to-last needed block. A tile outside is wholly masked (above
     the diagonal, or between two documents), so skipping it leaves every
     accumulator as it was. ``heads`` grid rows share a table row; 0 means the
-    table has one row (no segment ids)."""
+    table has one row (no segment ids and no selection)."""
     row = pl.program_id(0) // heads if heads else 0
     first, last = _bounds_at(bounds_ref, row, pl.program_id(1), pl.num_programs(1))
     return (first <= red) & (red <= last)
@@ -190,15 +200,27 @@ def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None,
 # --------------------------------------------------------------------- forward
 
 
+def _optional_refs(refs, n, segmented, masked):
+    """A kernel's references apart: its ``n`` fixed operands, then the two
+    segment-id blocks and the selection's tile, each where the call has one,
+    then results and scratch."""
+    ins, rest = refs[:n], list(refs[n:])
+    qseg_ref, kseg_ref = (rest.pop(0), rest.pop(0)) if segmented else (None, None)
+    sel_ref = rest.pop(0) if masked else None
+    return ins, qseg_ref, kseg_ref, sel_ref, rest
+
+
+def _selected(sel_ref):
+    """The selection's tile as a mask: int8 in HBM and VMEM, compared as int32."""
+    return sel_ref[0].astype(jnp.int32) != 0
+
+
 def _fwd_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads,
+    scale, causal, block_q, block_k, segmented, heads, masked=False,
 ):
-    if segmented:
-        (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    (q_ref, k_ref, v_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(refs, 3, segmented, masked)
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -213,7 +235,7 @@ def _fwd_kernel(
     k_start = ki * block_k
 
     # a skipped tile leaves m, l and the accumulator as they were (corr = 1, p = 0)
-    @pl.when(_visits(bounds_ref, heads if segmented else 0, ki))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, ki))
     def _compute():
         q = q_ref[0]
         k = k_ref[0]
@@ -225,6 +247,8 @@ def _fwd_kernel(
         if segmented:
             smask = qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
             mask = smask if mask is None else (mask & smask)
+        if masked:
+            mask = _selected(sel_ref) if mask is None else (mask & _selected(sel_ref))
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[:, :1]
@@ -255,7 +279,7 @@ def _fwd_kernel(
         )
 
 
-def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer):
+def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer, masked=False):
     """BlockSpecs of one kernel's grid (batch*heads, outer blocks, reduction
     blocks) with q rows (``outer="q"``: forward, dq) or k rows (``"k"``: the
     fused backward, dkv) outermost. Every index map also gets the visit
@@ -267,15 +291,21 @@ def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer):
     segment ids are per (batch, seq) — row i // heads — shared by all heads.
     They arrive as [B, 1, S]: Mosaic wants a block's last two dims to be
     (8, 128)-aligned or the array's full extent, which a (1, block) tile of
-    [B, S] is only at B == 1."""
+    [B, S] is only at B == 1. A selection's tile (``sel``, int8
+    ``[B, sq, sk]``, shared by a batch row's heads) lies at both blocks."""
+    rows = segmented or masked  # the visit table has a row a batch row
 
     def at(which, place):
         def index_map(i, o, r, bounds_ref):
             if which == outer:
                 return place(i, o)
-            row = i // heads if segmented else 0
+            row = i // heads if rows else 0
             return place(i, _resident(bounds_ref, row, o, n_outer, r))
         return index_map
+
+    def sel_map(i, o, r, bounds_ref):
+        red = _resident(bounds_ref, i // heads, o, n_outer, r)
+        return (i // heads, o, red) if outer == "q" else (i // heads, red, o)
 
     def spec(shape, which, place):
         return pl.BlockSpec(shape, at(which, place), memory_space=pltpu.VMEM)
@@ -288,22 +318,26 @@ def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer):
         lse=spec((1, 1, block_q, 1), "q", lambda i, b: (i, b, 0, 0)),
         qseg=spec((1, 1, block_q), "q", lambda i, b: (i // heads, 0, b)),
         kseg=spec((1, 1, block_k), "k", lambda i, b: (i // heads, 0, b)),
+        sel=pl.BlockSpec((1, block_q, block_k), sel_map, memory_space=pltpu.VMEM),
     )
 
 
 def _fwd_call(
-    q, k, v, segs, bounds,
+    q, k, v, segs, bounds, sel=None,
     *, causal, block_q, block_k, group, heads, interpret,
 ):
     bh, sq, d = q.shape
     sk = k.shape[1]
-    segmented = segs is not None
-    sp = _specs(block_q, block_k, d, group, heads, segmented, "q", sq // block_q)
+    segmented, masked = segs is not None, sel is not None
+    sp = _specs(block_q, block_k, d, group, heads, segmented, "q", sq // block_q, masked)
     in_specs = [sp["q"], sp["kv"], sp["kv"]]
     operands = [q, k, v]
     if segmented:
         in_specs += [sp["qseg"], sp["kseg"]]
         operands += [segs, segs]
+    if masked:
+        in_specs.append(sp["sel"])
+        operands.append(sel)
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel,
@@ -313,6 +347,7 @@ def _fwd_call(
             block_k=block_k,
             segmented=segmented,
             heads=heads,
+            masked=masked,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -329,7 +364,7 @@ def _fwd_call(
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq // block_q, block_q, 1), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=_MASKED_COMPILER_PARAMS if masked else _COMPILER_PARAMS,
         name="flash_fwd",
         interpret=interpret,
     )(bounds, *operands)
@@ -339,7 +374,7 @@ def _fwd_call(
 
 
 def _recompute_p_ds(
-    q, k, v, o, do, lse, *, scale, causal, q_start, k_start, qseg=None, kseg=None
+    q, k, v, o, do, lse, *, scale, causal, q_start, k_start, qseg=None, kseg=None, sel=None
 ):
     """Shared tile math: probabilities from the saved LSE, then
     dS = P * (dP - delta) * scale with delta recomputed from the O/dO tiles.
@@ -354,6 +389,8 @@ def _recompute_p_ds(
     if qseg is not None:
         smask = qseg[:, None] == kseg[None, :]
         mask = smask if mask is None else (mask & smask)
+    if sel is not None:
+        mask = sel if mask is None else (mask & sel)
     if mask is not None:
         p = jnp.where(mask, p, 0.0)
     dp = jax.lax.dot_general(
@@ -368,13 +405,12 @@ def _recompute_p_ds(
 
 def _dq_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads,
+    scale, causal, block_q, block_k, segmented, heads, masked=False,
 ):
-    if segmented:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qseg_ref, kseg_ref,
-         dq_ref, acc_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, acc_ref = refs
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
+        refs, 6, segmented, masked
+    )
+    dq_ref, acc_ref = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -383,7 +419,7 @@ def _dq_kernel(
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_visits(bounds_ref, heads if segmented else 0, ki))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, ki))
     def _compute():
         k = k_ref[0]
         _, ds = _recompute_p_ds(
@@ -392,6 +428,7 @@ def _dq_kernel(
             q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
+            sel=_selected(sel_ref) if masked else None,
         )
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
@@ -405,14 +442,12 @@ def _dq_kernel(
 
 def _dkv_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads,
+    scale, causal, block_q, block_k, segmented, heads, masked=False,
 ):
-    if segmented:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qseg_ref, kseg_ref,
-         dk_ref, dv_ref, dk_acc_ref, dv_acc_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-         dk_ref, dv_ref, dk_acc_ref, dv_acc_ref) = refs
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
+        refs, 6, segmented, masked
+    )
+    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = rest
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -424,7 +459,7 @@ def _dkv_kernel(
 
     # a KV block receives gradient only from the Q blocks at or after the
     # diagonal that share a document with it: its first-to-last needed block
-    @pl.when(_visits(bounds_ref, heads if segmented else 0, qi))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, qi))
     def _compute():
         q = q_ref[0]
         do = do_ref[0]
@@ -434,6 +469,7 @@ def _dkv_kernel(
             q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
+            sel=_selected(sel_ref) if masked else None,
         )
         # dV += P^T dO ; dK += dS^T Q — contract the q dim of both operands
         dv_acc_ref[:] += jax.lax.dot_general(
@@ -453,7 +489,7 @@ def _dkv_kernel(
 
 def _bwd_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads,
+    scale, causal, block_q, block_k, segmented, heads, masked=False,
 ):
     """dq, dk and dv from one visit of a tile: the grid is ``_dkv_kernel``'s
     (k blocks outer, q blocks inner, dk and dv accumulated across the inner
@@ -462,12 +498,10 @@ def _bwd_kernel(
     first k block, gets ``ds k`` from every visited tile (k blocks in
     ascending order, as ``_dq_kernel`` adds them) and is cast into the
     head's output block at the last."""
-    if segmented:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qseg_ref, kseg_ref,
-         dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-         dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref) = refs
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
+        refs, 6, segmented, masked
+    )
+    dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref = rest
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nk = pl.num_programs(1)
@@ -482,7 +516,7 @@ def _bwd_kernel(
     def _init_q():
         dq_acc_ref[qi] = jnp.zeros(dq_acc_ref.shape[1:], dq_acc_ref.dtype)
 
-    @pl.when(_visits(bounds_ref, heads if segmented else 0, qi))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, qi))
     def _compute():
         q = q_ref[0]
         k = k_ref[0]
@@ -493,6 +527,7 @@ def _bwd_kernel(
             q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
+            sel=_selected(sel_ref) if masked else None,
         )
         ds = ds.astype(q.dtype)
         dv_acc_ref[:] += jax.lax.dot_general(
@@ -549,7 +584,7 @@ def backward_form(sq: int, head_dim: int) -> str:
     return "fused" if _resident_dq_bytes(sq, head_dim) <= _FUSED_DQ_VMEM_BYTES else "split"
 
 
-def _fused_vmem_bytes(sq, d, block_q, block_k, itemsize):
+def _fused_vmem_bytes(sq, d, block_q, block_k, itemsize, masked=False):
     """What ``flash_bwd`` may use of VMEM (Mosaic's default is 16 MiB), from
     the sizes the call sees: the resident dq and beside it a step's tiles. At the GLM cell's
     shape and tiles this counts 36 MiB; Mosaic took the kernel at 32 and not
@@ -561,29 +596,34 @@ def _fused_vmem_bytes(sq, d, block_q, block_k, itemsize):
         + 2 * block_k * dpad * 4  # the dk and dv accumulators
         + 2 * block_q * _LANES * 4  # the LSE column, padded to the lanes
     )
+    if masked:  # a selection's tile: int8 in two buffers, and its int32 form
+        tiles += block_q * block_k * (2 + 4)
     return _resident_dq_bytes(sq, d, itemsize) + tiles
 
 
 def _bwd_pallas(
     kernel, name, outer, grid, outs, scratch_shapes, compiler_params,
-    q, k, v, o, do, lse, segs, bounds,
+    q, k, v, o, do, lse, segs, bounds, sel=None,
     *, causal, block_q, block_k, group, heads, interpret,
 ):
     """One backward kernel over ``grid`` with q rows (``outer="q"``) or k rows
     (``"k"``) outermost; ``outs`` pairs each result's BlockSpec (a name of
     ``_specs`` or a spec) with its shape."""
     d = q.shape[2]
-    segmented = segs is not None
-    sp = _specs(block_q, block_k, d, group, heads, segmented, outer, grid[1])
+    segmented, masked = segs is not None, sel is not None
+    sp = _specs(block_q, block_k, d, group, heads, segmented, outer, grid[1], masked)
     in_specs = [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"], sp["lse"]]
     operands = [q, k, v, o, do, lse]
     if segmented:
         in_specs += [sp["qseg"], sp["kseg"]]
         operands += [segs, segs]
+    if masked:
+        in_specs.append(sp["sel"])
+        operands.append(sel)
     return pl.pallas_call(
         functools.partial(
             kernel, scale=1.0 / d**0.5, causal=causal, block_q=block_q,
-            block_k=block_k, segmented=segmented, heads=heads,
+            block_k=block_k, segmented=segmented, heads=heads, masked=masked,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -599,18 +639,19 @@ def _bwd_pallas(
     )(bounds, *operands)
 
 
-def _bwd_split(q, k, v, o, do, lse, segs, bounds, *, block_q, block_k, **kw):
+def _bwd_split(q, k, v, o, do, lse, segs, bounds, sel=None, *, block_q, block_k, **kw):
     """The two-kernel backward (FlashAttention-2's): ``flash_dq`` sums over k
     blocks, ``flash_dkv`` over q blocks, each recomputing p and ds on every
     tile it visits. Its VMEM does not grow with the row's length."""
     bh, sq, d = q.shape
     sk = k.shape[1]
     kw = dict(kw, block_q=block_q, block_k=block_k)
+    params = _COMPILER_PARAMS if sel is None else _MASKED_COMPILER_PARAMS
     (dq,) = _bwd_pallas(
         _dq_kernel, "flash_dq", "q", (bh, sq // block_q, sk // block_k),
         [("q", jax.ShapeDtypeStruct((bh, sq, d), q.dtype))],
-        [pltpu.VMEM((block_q, d), jnp.float32)], _COMPILER_PARAMS,
-        q, k, v, o, do, lse, segs, bounds("q"), **kw,
+        [pltpu.VMEM((block_q, d), jnp.float32)], params,
+        q, k, v, o, do, lse, segs, bounds("q"), sel, **kw,
     )
     dk, dv = _bwd_pallas(
         _dkv_kernel, "flash_dkv", "k", (bh, sk // block_k, sq // block_q),
@@ -618,13 +659,13 @@ def _bwd_split(q, k, v, o, do, lse, segs, bounds, *, block_q, block_k, **kw):
             ("dkv", jax.ShapeDtypeStruct((bh, sk, d), k.dtype)),
             ("dkv", jax.ShapeDtypeStruct((bh, sk, d), v.dtype)),
         ],
-        [pltpu.VMEM((block_k, d), jnp.float32)] * 2, _COMPILER_PARAMS,
-        q, k, v, o, do, lse, segs, bounds("k"), **kw,
+        [pltpu.VMEM((block_k, d), jnp.float32)] * 2, params,
+        q, k, v, o, do, lse, segs, bounds("k"), sel, **kw,
     )
     return dq, dk, dv
 
 
-def _bwd_fused(q, k, v, o, do, lse, segs, bounds, *, block_q, block_k, **kw):
+def _bwd_fused(q, k, v, o, do, lse, segs, bounds, sel=None, *, block_q, block_k, **kw):
     """``flash_bwd``: the grid and visit table of ``flash_dkv``, dq besides
     (``_bwd_kernel``). dq leaves as ``[BH, n_q, block_q, D]`` in ``q.dtype``,
     one block a head, which is ``[BH, S, D]`` read another way: no buffer the
@@ -653,35 +694,40 @@ def _bwd_fused(q, k, v, o, do, lse, segs, bounds, *, block_q, block_k, **kw):
         # dq lives across both inner axes, so neither may be split or reordered
         pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_fused_vmem_bytes(sq, d, block_q, block_k, q.dtype.itemsize),
+            vmem_limit_bytes=_fused_vmem_bytes(sq, d, block_q, block_k, q.dtype.itemsize, sel is not None),
         ),
-        q, k, v, o, do, lse, segs, bounds("k"),
+        q, k, v, o, do, lse, segs, bounds("k"), sel,
         block_q=block_q, block_k=block_k, **kw,
     )
     return dq.reshape(bh, sq, d), dk, dv
 
 
-def _bwd_call(q, k, v, o, do, lse, segs, bounds, **kw):
+def _bwd_call(q, k, v, o, do, lse, segs, bounds, sel=None, **kw):
     """dq and, per q head, dk and dv (the caller sums each GQA group: a KV
     block cannot accumulate across grid rows). ``bounds(outer)`` gives the
     visit table with q or k blocks outermost; the form chosen from the row's
     length and the head's width (``backward_form``) asks for the one or two
     it reads."""
     fused = backward_form(q.shape[1], q.shape[2]) == "fused"
-    return (_bwd_fused if fused else _bwd_split)(q, k, v, o, do, lse, segs, bounds, **kw)
+    return (_bwd_fused if fused else _bwd_split)(q, k, v, o, do, lse, segs, bounds, sel, **kw)
 
 
 @functools.lru_cache(maxsize=None)
 def _flash_core(
     causal: bool, block_q: int, block_k: int, bwd_block_q: int,
     bwd_block_k: int, group: int, heads: int, interpret: bool,
-    segmented: bool,
+    segmented: bool, masked: bool = False, with_lse: bool = False,
 ):
     """Differentiable flash attention on q [B*H, S, D], k/v [B*Kh, S, D]
     (GQA group = H // Kh handled by kernel index maps — the repeated K/V
     never exist, in HBM or as residuals). With ``segmented``, a fourth
     [B, 1, S] int32 operand masks attention across packed-sequence
-    boundaries (zero cotangent). Each kernel gets its visit bounds, computed
+    boundaries (zero cotangent), and with ``masked`` a fifth, int8
+    [B, S, S], the pairs a selection keeps (nonzero; no cotangent either: a
+    selection is no function of the scores it masks). ``with_lse``: the
+    result is ``(o, lse)``, the rows' log-sum-exp as the backward keeps it
+    (``[BH, S / 128, 128]`` float32, a constant to whoever reads it: its
+    cotangent is dropped). Each kernel gets its visit bounds, computed
     here from what the call is given: they are data, so one compiled step
     serves every packing. The backward is ``_bwd_call``'s: one fused kernel
     reading the k-outer table, or for a row over the VMEM budget the split
@@ -694,45 +740,49 @@ def _flash_core(
               heads=heads, interpret=interpret)
     bwd_kw = dict(kw, block_q=bwd_block_q, block_k=bwd_block_k)
 
-    def bounds(q, k, segs, block_q, block_k, outer):
-        return jnp.asarray(visit_bounds(
-            segs if segmented else None, outer, causal=causal,
-            sq=q.shape[1], sk=k.shape[1], block_q=block_q, block_k=block_k,
-        ))
+    def bounds(q, k, segs, sel, block_q, block_k, outer):
+        tiles = dict(causal=causal, sq=q.shape[1], sk=k.shape[1], block_q=block_q, block_k=block_k)
+        if sel:
+            tiles["selected"] = sel[0]
+        return jnp.asarray(visit_bounds(segs if segmented else None, outer, **tiles))
 
-    def forward(q, k, v, segs):
+    def forward(q, k, v, segs, *sel):
         return _fwd_call(
             q, k, v, segs if segmented else None,
-            bounds(q, k, segs, block_q, block_k, "q"), **kw,
+            bounds(q, k, segs, sel, block_q, block_k, "q"), *sel, **kw,
         )
 
-    @jax.custom_vjp
-    def core(q, k, v, segs):
-        return forward(q, k, v, segs)[0]
+    def rows_of_lanes(lse, sq):
+        lanes = _LANES if sq % _LANES == 0 else 1
+        return lse.reshape(-1, sq // lanes, lanes)
 
-    def core_fwd(q, k, v, segs):
-        o, lse = forward(q, k, v, segs)
+    @jax.custom_vjp
+    def core(q, k, v, segs, *sel):
+        o, lse = forward(q, k, v, segs, *sel)
+        return (o, rows_of_lanes(lse, q.shape[1])) if with_lse else o
+
+    def core_fwd(q, k, v, segs, *sel):
+        o, lse = forward(q, k, v, segs, *sel)
         # q, k, v stay unnamed: three times o's size, and a replay rebuilds
         # them from the layer's input without the kernel. The LSE as the
         # kernels have it, a column, is padded to 128 lanes by a TPU layout
         # (as many bytes as ``o`` for 1/128 of the numbers): kept as rows of
         # 128, which the chip reshapes faster than [BH, S] (PERF.md section 6,
         # PR 29; S that 128 does not divide is interpreter-only)
-        sq = q.shape[1]
-        lanes = _LANES if sq % _LANES == 0 else 1
         o = checkpoint_name(o, FLASH_RESIDUALS[0])
-        lse = checkpoint_name(lse.reshape(-1, sq // lanes, lanes), FLASH_RESIDUALS[1])
-        return o, (q, k, v, segs, o, lse)
+        lse = checkpoint_name(rows_of_lanes(lse, q.shape[1]), FLASH_RESIDUALS[1])
+        return ((o, lse) if with_lse else o), (q, k, v, segs, o, lse, *sel)
 
     def core_bwd(res, g):
-        q, k, v, segs, o, lse = res
+        q, k, v, segs, o, lse, *sel = res
+        g = g[0] if with_lse else g
         # back to a column, chunked by the backward's q tile
         lse = lse.reshape(-1, q.shape[1] // bwd_block_q, bwd_block_q, 1)
         dq, dk_h, dv_h = _bwd_call(
             q, k, v, o, g.astype(o.dtype), lse,
             segs if segmented else None,
-            functools.partial(bounds, q, k, segs, bwd_block_q, bwd_block_k),
-            **bwd_kw,
+            functools.partial(bounds, q, k, segs, sel, bwd_block_q, bwd_block_k),
+            *sel, **bwd_kw,
         )
         if group > 1:
             # the kernel emits dk, dv per q head; sum each GQA group in fp32
@@ -743,7 +793,7 @@ def _flash_core(
                 return x.sum(axis=1).astype(dtype)
 
             dk_h, dv_h = gsum(dk_h, k.dtype), gsum(dv_h, v.dtype)
-        return dq, dk_h, dv_h, None  # int segment ids: no cotangent
+        return (dq, dk_h, dv_h, None, *(None for _ in sel))  # int segment ids, int8 selection: no cotangent
 
     core.defvjp(core_fwd, core_bwd)
     return core
@@ -830,7 +880,7 @@ def _untileable(sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "block_q", "block_k", "bwd_block_q", "bwd_block_k", "interpret",
+        "causal", "block_q", "block_k", "bwd_block_q", "bwd_block_k", "interpret", "return_lse",
     ),
 )
 def flash_attention(
@@ -845,6 +895,8 @@ def flash_attention(
     bwd_block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     segment_ids=None,
+    selected=None,
+    return_lse: bool = False,
 ) -> jax.Array:
     """q [B,S,H,D], k/v [B,S,Kh,D] → [B,S,H,D]. Differentiable (custom VJP).
     The four tile sizes default to the measured-fastest tiling for the
@@ -854,6 +906,14 @@ def flash_attention(
     operand tiles, so its VMEM sweet spot differs). ``segment_ids``
     [B, S] masks attention across packed-sequence boundaries in-kernel, and
     the tiles it masks wholly are not visited (the module docstring).
+    ``selected`` [B, Sq, Sk] (int8, nonzero: kept) is a selection of pairs
+    made outside, one for all the heads of a batch row
+    (``ops/sparse_select.py``): a pair counts where the causal and the segment
+    masks and the selection all keep it, a tile of it is an operand of every
+    kernel, and a tile with no selected pair is not visited. ``return_lse``:
+    ``(out, lse)`` with the rows' log-sum-exp over the pairs kept, [B, H, Sq]
+    float32 (+inf on a row that keeps none), as a constant; from the kernels
+    only (a shape that falls back raises).
 
     ``interpret`` defaults to the Pallas interpreter off-TPU and the compiled
     kernel on a TPU. Interpreted, a shape that does not tile falls back to
@@ -879,7 +939,7 @@ def flash_attention(
         segmented, compiled=not interpret,
     )
     if why is not None:
-        if not interpret:
+        if not interpret or selected is not None or return_lse:
             raise ValueError(
                 f"flash_attention cannot compile for q{q.shape} k{k.shape}: "
                 f"{why}. Pad the sequence, pass tiles that fit, or call "
@@ -898,10 +958,14 @@ def flash_attention(
         if segmented
         else jnp.zeros((b, 1, sq), jnp.int32)  # placeholder, never read
     )
+    sel = () if selected is None else (selected.astype(jnp.int8),)
     out = _flash_core(
         causal, block_q, block_k, bwd_block_q, bwd_block_k, h // kh, h,
-        interpret, segmented,
-    )(qr, kr, vr, segs)
+        interpret, segmented, selected is not None, return_lse,
+    )(qr, kr, vr, segs, *sel)
+    if return_lse:
+        out, lse = out
+        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), jax.lax.stop_gradient(lse.reshape(b, h, sq))
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 
 
@@ -914,6 +978,7 @@ def sharded_flash_attention(
     causal: bool = True,
     interpret: Optional[bool] = None,
     segment_ids: Optional[jax.Array] = None,
+    selected: Optional[jax.Array] = None,
 ):
     """Run the Pallas kernel per-shard under ``shard_map`` over ``mesh``.
 
@@ -947,16 +1012,14 @@ def sharded_flash_attention(
         or kh % tp
     ):
         return None
-    spec = P((AXIS_DATA, AXIS_FSDP), None, AXIS_TENSOR, None)
+    batch = (AXIS_DATA, AXIS_FSDP)
+    spec = P(batch, None, AXIS_TENSOR, None)
     fn = functools.partial(flash_attention, causal=causal, interpret=interpret)
-    if segment_ids is None:
-        return shard_map(
-            fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False,
-        )(q, k, v)
-    seg_spec = P((AXIS_DATA, AXIS_FSDP), None)
+    # what follows its batch row, where the call has it
+    rows = {"segment_ids": (segment_ids, P(batch, None)), "selected": (selected, P(batch, None, None))}
+    rows = {name: row for name, row in rows.items() if row[0] is not None}
     return shard_map(
-        lambda q, k, v, s: fn(q, k, v, segment_ids=s),
-        mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
+        lambda q, k, v, *more: fn(q, k, v, **dict(zip(rows, more))),
+        mesh=mesh, in_specs=(spec, spec, spec, *(s for _, s in rows.values())), out_specs=spec,
         check_vma=False,
-    )(q, k, v, segment_ids)
+    )(q, k, v, *(a for a, _ in rows.values()))

@@ -50,6 +50,11 @@ GAUGES = frozenset(
         # ShortConv): taps zeroed at row and document starts over all taps of
         # the step's conv layers; above 0 the batch's packing reached the operator
         "conv.taps_masked_share",
+        # a model with selected-key attention layers (models/transformer.py
+        # Attention with sparse_topk, ops/sparse_select.py)
+        "sparse.selected_share",  # pairs the selections keep over the pairs visible inside documents
+        "sparse.rows_off_k",  # queries whose set is not min(sparse_topk, visible) keys: exact, so 0
+        "sparse.index_loss",  # the indexer's KL loss, summed over the layers
         # checkpointing (train/checkpoint.py)
         "checkpoint_save_ms",
         # control plane (core/rpc.py, core/pod.py)
@@ -270,6 +275,9 @@ SCOPES = (
     "conv.in_proj",  # short convolution: the product that makes the two gates and the input
     "conv.mix",  # short convolution: the gates and the taps between the two products (no product)
     "conv.out_proj",  # short convolution: the product back to the model's width
+    "sparse.index",  # selected-key attention: the indexer's projections, the index scores, the mask from the thresholds
+    "sparse.select",  # selected-key attention: each query's top-k threshold
+    "sparse.index_loss",  # selected-key attention: the indexer's loss and its gradient, one pass
     # (flax module names are scopes too and need no entry: attn, mlp, moe,
     # and mtp, the multi-token-prediction module)
     "decode_attn",  # page/chunk gather + online softmax over the KV cache
@@ -304,7 +312,8 @@ EVENTS = frozenset(
         # which attention kernel the automatic dispatch took for a traced
         # shape, and why not flash (models/transformer.py auto_attention);
         # for the flash kernels their tiles, ``lanes`` and ``backward``
-        # (``fused`` or ``split``: ops/flash.py backward_form)
+        # (``fused`` or ``split``: ops/flash.py backward_form), and ``selected``,
+        # the keys a query keeps, where a selection masks the call
         "attention.kernel",
         # autopilot decisions (autopilot/controller.py, serve/scheduler.py):
         # the auditable telemetry→config loop — diagnosis verdicts, applied
@@ -382,6 +391,9 @@ GAUGE_UNITS = {
     "moe.load_max_over_mean": "ratio",
     "moe.rows_visited_share": "ratio",
     "conv.taps_masked_share": "ratio",
+    "sparse.selected_share": "ratio",
+    "sparse.rows_off_k": "count",
+    "sparse.index_loss": "ratio",  # nats, like a loss: no unit of its own in the vocabulary
     "checkpoint_save_ms": "ms",
     "heartbeat_rtt_ms": "ms",
     "data_plane_init_ms": "ms",

@@ -166,6 +166,25 @@ def conv_counters(mods) -> Dict[str, jax.Array]:
     return {"conv_taps_masked_share": masked / of}
 
 
+def sparse_counters(mods) -> Dict[str, jax.Array]:
+    """The step's counters of a model with selected-key attention layers
+    (``Attention`` sows ``index_aux_loss`` and, where it selects,
+    ``sparse_counts``: pairs selected, pairs visible, queries off their
+    count, a layer): the selected pairs over the visible ones, the queries
+    whose set is not ``min(sparse_topk, visible)`` keys (an exact selection:
+    0), and the indexer's loss summed over the layers. Empty for every other
+    model."""
+    loss = _sown(mods, "index_aux_loss")
+    if not loss:
+        return {}
+    out = {"index_loss": sum(jnp.sum(a) for a in loss).astype(jnp.float32)}
+    counts = _sown(mods, "sparse_counts")
+    if counts:
+        selected, visible, off = jnp.concatenate([a.reshape(-1, 3) for a in counts]).astype(jnp.float32).sum(0)
+        out.update(sparse_selected_share=selected / jnp.maximum(visible, 1.0), sparse_rows_off_k=off)
+    return out
+
+
 def _prefetch_depth(prefetch: Optional[int]) -> int:
     """Resolve an input-prefetch depth: an explicit argument wins, else the
     ``MAGGY_TPU_PREFETCH`` env knob, else 2 (double-buffered). 0 disables."""
@@ -1037,7 +1056,7 @@ class Trainer:
                     loss = self.loss_fn(logits, batch)
                     mtp = mtp_loss(mods, batch)
                 aux = collect_aux_losses(mods)
-                extra = {**expert_counters(mods), **conv_counters(mods)}
+                extra = {**expert_counters(mods), **conv_counters(mods), **sparse_counters(mods)}
                 total = loss + aux
                 if mtp is not None:
                     total = total + self.model.cfg.mtp_weight * mtp
@@ -1683,6 +1702,11 @@ class Trainer:
             tel.gauge("moe.rows_visited_share", out["moe_rows_visited_share"])
         if "conv_taps_masked_share" in out:
             tel.gauge("conv.taps_masked_share", out["conv_taps_masked_share"])
+        if "index_loss" in out:  # a selected-key attention model's counters
+            tel.gauge("sparse.index_loss", out["index_loss"])
+        if "sparse_rows_off_k" in out:
+            tel.gauge("sparse.selected_share", out["sparse_selected_share"])
+            tel.gauge("sparse.rows_off_k", out["sparse_rows_off_k"])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

@@ -261,13 +261,23 @@ def test_every_load_drops_nothing_and_visits_its_chunks(load_case):
     assert [int(v) for v in sown["rows_visited"][0]] == [rows, 256]
 
 
-@pytest.mark.parametrize("slots,held,n_experts,want", [
-    (65536, 8, 64, 8192),  # the GLM cell: an eighth of 16,384 x 4
-    (65536, 64, 64, 65536),  # every expert held: every slot is, one chunk
-    (100, 2, 8, 13),  # no multiple of 8: rounded up, the last chunk overhangs
+@pytest.mark.parametrize("slots,held,n_experts,of_load,want", [
+    (65536, 8, 64, 0.0, 8192),  # the GLM cell: an eighth of 16,384 x 4
+    (65536, 64, 64, 0.0, 65536),  # every expert held: every slot is, one chunk
+    (100, 2, 8, 0.0, 13),  # no multiple of 8: rounded up, the last chunk overhangs
+    (131072, 8, 64, 0.0, 16384),  # the LFM2 cell: an eighth of 32,768 x 4
+    (262144, 16, 128, 0.0, 32768),  # an eighth of the experts held: an eighth is the expected load itself
+    (262144, 16, 128, 0.75, 24576),  # three quarters of the expected load
+    (100, 2, 8, 0.75, 19),  # 18.75 rows: rounded up
+    (65536, 64, 64, 0.75, 65536),  # every expert held: one chunk whatever the fraction
 ])
-def test_chunk_rows(slots, held, n_experts, want):
-    assert moe.chunk_rows(slots, held, n_experts) == want
+def test_chunk_rows(slots, held, n_experts, of_load, want):
+    assert moe.chunk_rows(slots, held, n_experts, of_load) == want
+
+
+def test_chunk_of_load_belongs_to_the_share_form():
+    with pytest.raises(ValueError, match="chunk_of_load"):
+        moe.MoEConfig(chunk_of_load=0.75)
 
 
 def test_a_buffer_that_is_no_whole_number_of_chunks(tiny, seeded):

@@ -111,3 +111,44 @@ def test_fused_flash_backward_compiles_at_the_cells_shapes(one_chip, b, s, h, kh
     text = jax.jit(step).lower(q, kv, kv, ids).compile().as_text()
     assert backward_form(s, d) == "fused"
     assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
+
+
+def test_selected_key_kernels_compile_at_the_keye_cells_shape(one_chip):
+    """The three kernels that keye-vl-2.0-30b-a3b's cell adds, at its row (1 x
+    32,768, one document, 32 query heads over 4 of width 128, 16 index heads of
+    64, 2,048 keys a query): ``index_scores`` for a block of 1,024 queries from
+    a traced offset, ``sparse_select`` holding 128 x 32,768 scores in VMEM, and
+    the flash pair with a selection's int8 tile as an operand, the backward
+    fused with a head's whole dq resident (the longest row that keeps it)."""
+    from maggy_tpu.ops import sparse_select
+
+    s, h, kh, d, heads, width, rows = 32768, 32, 4, 128, 16, 64, 1024
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def select(qi, ki, w, seg, at):
+        scores = sparse_select.index_scores(qi, ki, w, seg, at, rows, interpret=False)
+        return scores, sparse_select.topk_thresholds(scores, at, 2048, interpret=False)
+
+    text = jax.jit(select).lower(
+        sds((1, heads, s, width), jnp.bfloat16), sds((1, s, width), jnp.bfloat16), sds((1, s, heads), jnp.float32),
+        sds((1, 1, s), jnp.int32), sds((), jnp.int32),
+    ).compile().as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    made = [line.split("=")[0].strip().lstrip("%") for line in calls]  # the result's name leads with the kernel's
+    assert sorted(name.split(".")[0] for name in made) == ["index_scores", "sparse_select"]
+
+    def step(q, k, v, segment_ids, selected):
+        return jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, segment_ids=segment_ids, selected=selected, interpret=False)
+            .astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    text = jax.jit(step).lower(
+        sds((1, s, h, d), jnp.bfloat16), sds((1, s, kh, d), jnp.bfloat16), sds((1, s, kh, d), jnp.bfloat16),
+        sds((1, s), jnp.int32), sds((1, s, s), jnp.int8),
+    ).compile().as_text()
+    assert backward_form(s, d) == "fused"
+    assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
