@@ -8,8 +8,9 @@ a user calls, at the widths of ``DecoderConfig.llama3_8b()`` with the depth
 
 1. kernels  ops/flash.py forward+backward, compiled (``interpret=False``),
             plain and packed, against the float32 blockwise reference; then
-            ops/sparse_select.py's ``index_scores`` against float32 products
-            and the flash pair under the mask of an exact selection;
+            ops/sparse_select.py's ``index_scores`` against float32 products,
+            the flash pair under the mask of an exact selection, and the
+            ``index_loss`` kernel against the loss written whole in float32;
 2. trainer  ``experiment.lagom(train_fn, DistributedConfig(...))`` with the
             README's train_fn (``ctx.trainer`` -> ``make_state`` -> ``fit``);
 3. server   the stack ``python -m maggy_tpu.serve`` builds, answering
@@ -65,6 +66,7 @@ class Sizes:
     kernel_heads: tuple  # (q heads, kv heads, head_dim)
     kernel_cases: tuple  # (batch, seq) pairs
     index_heads: tuple  # (index heads, their width) of the selected-key case
+    loss_case: tuple  # (seq, kv heads) of the indexer's loss: a row twice the keys a query keeps
     train_seq: int
     train_steps: int
     prompt_lens: tuple
@@ -94,6 +96,7 @@ def chip_sizes(n_chips: int, bytes_limit: int) -> Sizes:
         kernel_heads=(32, 8, 128),
         kernel_cases=((2, 2048), (1, 8192)),
         index_heads=(16, 64),
+        loss_case=(4096, 4),
         train_seq=2048,
         train_steps=6,
         prompt_lens=(6, 7, 24, 30, 100, 120, 400, 500),
@@ -117,6 +120,7 @@ def toy_sizes() -> Sizes:
         kernel_heads=(4, 2, 128),
         kernel_cases=((2, 256),),
         index_heads=(4, 16),
+        loss_case=(256, 2),
         train_seq=128,
         train_steps=4,
         prompt_lens=(3, 5, 9, 12, 20, 26, 40, 50),
@@ -298,7 +302,7 @@ def phase_kernels(run: Run) -> dict:
         at = jnp.arange(s)
         same = (seen == ((at[:, None] >= at[None]) & (segs[:, :, None] == segs[:, None, :]))).all()
         err = jnp.linalg.norm(jnp.where(seen, got - want, 0.0)) / jnp.linalg.norm(jnp.where(seen, want, 0.0))
-        mask, counts = sparse_select.select(qi, ki, wi, seg3, topk, interpret=interpret)
+        mask, counts, _ = sparse_select.select(qi, ki, wi, seg3, topk, interpret=interpret)
         by_hand = jnp.minimum(seen.sum(-1), topk).sum()
         return mask, err, same, counts, by_hand
 
@@ -326,6 +330,61 @@ def phase_kernels(run: Run) -> dict:
     run.say(f"  flash under a selection {label}: rel err " + " ".join(f"{n}={e:.2e}" for n, e in errs.items()))
     check(max(errs.values()) <= tol, f"flash under a selection off the float32 reference: {errs} > {tol}")
     cases.append({"case": "selected " + label, "index_scores": float(err), **errs})
+
+    # the indexer's loss: the ``index_loss`` kernel (loss and the gradients of
+    # the indexer's three inputs from one pass, the heads' log-sum-exp from the
+    # flash kernel under the selection) against the loss written whole on
+    # float32 ``[S, S]`` arrays and differentiated by jax, one row of twice the
+    # keys a query keeps, the heads over ``loss_case``'s key heads
+    s, kh_l = run.sizes.loss_case
+    topk = s // 2
+
+    @jax.jit
+    def loss_inputs():
+        keys = jax.random.split(jax.random.key(11), 5)
+        return (
+            jax.random.normal(keys[0], (1, s, h, d), jnp.bfloat16),
+            jax.random.normal(keys[1], (1, s, kh_l, d), jnp.bfloat16),
+            jax.random.normal(keys[2], (1, heads_i, s, width_i), jnp.bfloat16),
+            jax.random.normal(keys[3], (1, s, width_i), jnp.bfloat16),
+            jax.random.normal(keys[4], (1, s, heads_i), f32) * (heads_i * width_i) ** -0.5,
+        )
+
+    @jax.jit
+    def loss_by_kernel(q, k, qi, ki, wi):
+        mask, _counts, lse_i = sparse_select.select(qi, ki, wi, None, topk, interpret=interpret)
+        _out, lse = flash(q, k, k, selected=mask, return_lse=True)
+        real = jnp.ones((1, s), bool)
+        loss = lambda qi, ki, wi: sparse_select.index_loss(qi, ki, wi, q, k, lse, mask, None, real, lse_i)
+        return mask, jax.value_and_grad(loss, (0, 1, 2))(qi, ki, wi)
+
+    @jax.jit
+    def loss_whole(q, k, qi, ki, wi, mask):
+        keep = mask != 0
+        sc = jnp.einsum("bqhd,bshd->bhqs", q.astype(f32), jnp.repeat(k.astype(f32), h // kh_l, 2)) / d**0.5
+        target = jnp.where(keep, jax.nn.softmax(jnp.where(keep[:, None], sc, -1e30), -1).mean(1), 0.0)
+
+        def loss(qi, ki, wi):
+            z = jnp.einsum("bjqd,bsd->bqjs", qi, ki)
+            logq = jax.nn.log_softmax(jnp.where(keep, (wi[..., None] * jnp.maximum(z, 0.0)).sum(2), -1e30), -1)
+            return jnp.where(target > 0, target * (jnp.log(jnp.maximum(target, 1e-37)) - logq), 0.0).sum(-1).mean()
+
+        return jax.value_and_grad(loss, (0, 1, 2))(qi.astype(f32), ki.astype(f32), wi)
+
+    t0 = time.perf_counter()
+    inputs = loss_inputs()
+    mask, (loss, grads) = loss_by_kernel(*inputs)
+    jax.block_until_ready(grads)
+    setup_s += time.perf_counter() - t0
+    with jax.default_matmul_precision("highest"):
+        loss_want, grads_want = loss_whole(*inputs, mask)
+    rel, finite = compare((loss[None], *grads), (loss_want[None], *grads_want))
+    errs = dict(zip(("loss", "d_qi", "d_ki", "d_w"), (float(e) for e in rel)))
+    label = f"B=1 S={s}, {topk} keys a query, {h} heads over {kh_l} of {d}, {heads_i} x {width_i} index heads"
+    run.say(f"  index_loss {label}: loss {float(loss):.6f}, rel err " + " ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+    check(bool(finite), f"index_loss {label}: a value is not finite")
+    check(max(errs.values()) <= tol, f"index_loss off the float32 formula: {errs} > {tol}")
+    cases.append({"case": "index_loss " + label, **errs})
     return {
         "setup_s": setup_s, "heads": [h, kh, d], "tolerance": tol, "cases": cases,
         "compiled": not interpret,

@@ -430,14 +430,18 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
     the flash kernels, how it fills the 128 lanes and which backward the row
     and the width take (``backward``: ``fused``, one kernel, or ``split``,
     two: ``ops.flash.backward_form``, which the kernels' call asks too), and
-    ``selected``, the keys a query keeps where a selection masks the call. The
-    dispatch runs at trace time, so events count traces (init, forward, a
+    ``selected``, the keys a query keeps where a selection masks the call,
+    with the form the indexer's loss then takes (``index_loss``: ``kernel``
+    where this call returns the heads' log-sum-exp, which only the one-chip
+    flash kernels do, else ``blockwise``: ``ops.sparse_select.index_loss``).
+    The dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
 
     attrs = {"head_dim": int(q.shape[3])}
     if selected:
         attrs["selected"] = int(selected)
+        attrs["index_loss"] = "kernel" if kernel == "flash" else "blockwise"
     if kernel.startswith("flash"):
         from maggy_tpu.ops.flash import _auto_blocks, backward_form
 
@@ -593,9 +597,9 @@ class Attention(nn.Module):
             )(u.astype(jnp.float32)) * (heads**-0.5 * width**-0.5)
             qi = qi.transpose(0, 2, 1, 3)  # [B, J, S, Dj]: a head's rows together
         segs = None if segment_ids is None else segment_ids.astype(jnp.int32)[:, None]
-        mask = None
+        mask = index_lse = None
         if s > topk:
-            mask, counts = sparse_select.select(qi, ki, w, segs, topk)
+            mask, counts, index_lse = sparse_select.select(qi, ki, w, segs, topk)
             self.sow("intermediates", "sparse_counts", counts)
         out, lse = auto_attention(
             q, k, v, causal=True, segment_ids=segment_ids, selected=mask, topk=topk if s > topk else 0,
@@ -605,7 +609,7 @@ class Attention(nn.Module):
             real = jnp.ones(x.shape[:2], bool) if segment_ids is None else segment_ids > 0
             self.sow(
                 "intermediates", "index_aux_loss",
-                sparse_select.index_loss(qi, ki, w, q, k, lse, mask, segs, real),
+                sparse_select.index_loss(qi, ki, w, q, k, lse, mask, segs, real, index_lse),
             )
         return out
 

@@ -172,47 +172,91 @@ def test_selected_sets_are_lax_top_ks(indexer, k, ties):
 
 def test_select_counts_its_pairs_and_leaves_no_row_off_k(indexer):
     qi, ki, w, seg = indexer
-    mask, counts = sparse_select.select(qi, ki, w, seg[:, None], 32)
+    mask, counts, lse = sparse_select.select(qi, ki, w, seg[:, None], 32)
     want = top_k_by_hand(scores_by_hand(qi, ki, w, seg), 32)
     np.testing.assert_array_equal(mask != 0, want)
+    by_hand = jax.nn.logsumexp(jnp.where(want, scores_by_hand(qi, ki, w, seg), -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse, by_hand, rtol=1e-5)
+    np.testing.assert_allclose(sparse_select.index_lse(qi, ki, w, seg[:, None], mask), by_hand, rtol=1e-5)
     visible = int((scores_by_hand(qi, ki, w, seg) > -jnp.inf).sum())
     assert [int(c) for c in counts] == [int(want.sum()), visible, 0] and mask.dtype == jnp.int8
 
 
+def heads_scores(q, k):
+    return jnp.einsum("bqhd,bshd->bhqs", q, jnp.repeat(k, q.shape[2] // k.shape[2], 2), precision="highest") / np.sqrt(q.shape[3])
+
+
+def loss_written_whole(qi, ki, w, q, k, seg, keep, real):
+    """The indexer's loss as the module states it, on explicit ``[S, S]`` arrays."""
+    index = scores_by_hand(qi, ki, w, seg)
+    target = jnp.where(keep, jax.nn.softmax(jnp.where(keep[:, None], heads_scores(q, k), -1e30), -1).mean(1), 0.0)
+    logq = jax.nn.log_softmax(jnp.where(keep, index, -1e30), -1)
+    kl = jnp.where(target > 0, target * (jnp.log(jnp.maximum(target, 1e-37)) - logq), 0.0).sum(-1)
+    return (kl * real).sum() / real.sum()
+
+
+@pytest.fixture(scope="module")
+def heads():
+    b, s, h, kh, d = 2, 256, 4, 2, 32
+    return jax.random.normal(jax.random.key(3), (b, s, h, d)), jax.random.normal(jax.random.key(4), (b, s, kh, d))
+
+
 @pytest.mark.parametrize("given", [False, True], ids=["softmax-here", "lse-given"])
 @pytest.mark.parametrize("selected", [True, False], ids=["selected", "every-visible-key"])
-def test_index_loss_and_its_gradient_against_autodiff(indexer, monkeypatch, selected, given):
+def test_index_loss_and_its_gradient_against_autodiff(indexer, heads, monkeypatch, selected, given):
     """The one pass that gives the loss and the gradients of the indexer's
-    three inputs (blocks of 32 queries here, so four bands of keys) against
-    the same loss written whole and differentiated by jax; with the heads'
-    log-sum-exp given (what the flash kernels keep) and without."""
+    three inputs against the same loss written whole and differentiated by
+    jax: with the heads' log-sum-exp given (what the flash kernels keep: the
+    ``index_loss`` kernel, interpreted, tiles of 32 x 128) and without (the
+    blockwise form with its own softmax, four bands of keys)."""
     monkeypatch.setattr(sparse_select, "LOSS_BLOCK", 32)
+    monkeypatch.setattr(sparse_select, "LOSS_TILE", (32, 128))
     qi, ki, w, seg = indexer
-    b, s, h, kh, d = 2, 256, 4, 2, 32
-    q = jax.random.normal(jax.random.key(3), (b, s, h, d))
-    k = jax.random.normal(jax.random.key(4), (b, s, kh, d))
+    q, k = heads
     real = seg > 0
     scores_all = scores_by_hand(qi, ki, w, seg)
     keep = top_k_by_hand(scores_all, 32) if selected else scores_all > -jnp.inf
-
-    def whole(qi, ki, w):
-        index = scores_by_hand(qi, ki, w, seg)
-        sc = jnp.einsum("bqhd,bshd->bhqs", q, jnp.repeat(k, h // kh, 2), precision="highest") / np.sqrt(d)
-        target = jnp.where(keep, jax.nn.softmax(jnp.where(keep[:, None], sc, -1e30), -1).mean(1), 0.0)
-        logq = jax.nn.log_softmax(jnp.where(keep, index, -1e30), -1)
-        kl = jnp.where(target > 0, target * (jnp.log(jnp.maximum(target, 1e-37)) - logq), 0.0).sum(-1)
-        return (kl * real).sum() / real.sum()
-
+    whole = lambda qi, ki, w: loss_written_whole(qi, ki, w, q, k, seg, keep, real)
     mask = keep.astype(jnp.int8) if selected else None
-    sc = jnp.einsum("bqhd,bshd->bhqs", q, jnp.repeat(k, h // kh, 2), precision="highest") / np.sqrt(d)
-    lse = jax.nn.logsumexp(jnp.where(keep[:, None], sc, -jnp.inf), axis=-1) if given else None
+    lse = jax.nn.logsumexp(jnp.where(keep[:, None], heads_scores(q, k), -jnp.inf), axis=-1) if given else None
     one_pass = lambda qi, ki, w: sparse_select.index_loss(qi, ki, w, q, k, lse, mask, seg[:, None], real)
+    assert count(jax.make_jaxpr(one_pass)(qi, ki, w).jaxpr)["index_loss"] == int(given)
     np.testing.assert_allclose(one_pass(qi, ki, w), whole(qi, ki, w), rtol=1e-5)
     got, want = jax.grad(one_pass, (0, 1, 2))(qi, ki, w), jax.grad(whole, (0, 1, 2))(qi, ki, w)
     for a, w_ in zip(got, want):
         np.testing.assert_allclose(a, w_, rtol=1e-4, atol=1e-5 * float(jnp.abs(w_).max()))
     assert all(g is None or float(jnp.abs(g).max()) == 0 for g in jax.grad(
         lambda q, k: sparse_select.index_loss(qi, ki, w, q, k, None, mask, seg[:, None], real), (0, 1))(q, k))
+
+
+@pytest.mark.parametrize("segmented", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("selected", [True, False], ids=["selected", "every-visible-key"])
+def test_index_loss_kernel_over_several_blocks_and_tiles(indexer, heads, selected, segmented):
+    """The kernel by itself at 32 x 64 tiles over rows of 256: eight blocks of
+    queries against up to four tiles of keys each, so ``d ki`` sums across
+    blocks and tiles, a tile above a block's diagonal computes nothing, the
+    first queries of a document see fewer than the 32 keys a query keeps, and
+    a tail of the row weighs nothing (padding where the rows are packed)."""
+    qi, ki, w, seg = indexer
+    q, k = heads
+    real = seg > 0 if segmented else jnp.arange(256)[None] < jnp.asarray([[256], [200]])
+    seg = seg if segmented else None
+    scores_all = scores_by_hand(qi, ki, w, seg)
+    keep = top_k_by_hand(scores_all, 32) if selected else scores_all > -jnp.inf
+    assert (keep.sum(-1) < 32).any() and (keep.sum(-1) > 32).any() != selected
+    weight = real / real.sum()
+    lse = jax.nn.logsumexp(jnp.where(keep[:, None], heads_scores(q, k), -jnp.inf), axis=-1)
+    lse_i = jax.nn.logsumexp(jnp.where(keep, scores_all, -jnp.inf), axis=-1)
+    loss, *got = sparse_select._loss_kernel_pass(
+        q, k, lse, qi, ki, w, lse_i, keep.astype(jnp.int8) if selected else None, None if seg is None else seg[:, None],
+        weight, block_q=32, block_k=64,
+    )
+    whole = lambda qi, ki, w: loss_written_whole(qi, ki, w, q, k, seg, keep, real)
+    np.testing.assert_allclose(loss, whole(qi, ki, w), rtol=1e-5)
+    for a, w_ in zip(got, jax.grad(whole, (0, 1, 2))(qi, ki, w)):
+        np.testing.assert_allclose(a, w_, rtol=1e-4, atol=1e-5 * float(jnp.abs(w_).max()))
+    if not segmented:  # nothing reaches a query that weighs nothing
+        assert float(jnp.abs(got[0][1, :, 200:]).max()) == 0 and float(jnp.abs(got[2][1, 200:]).max()) == 0
 
 
 # ------------------------------------------------------- the kernels with a mask
@@ -391,11 +435,13 @@ def test_gradient_of_every_leaf_and_the_change_after_two_steps(tiny, batch, seed
 
 
 @pytest.mark.parametrize("policy", ["nothing", "dots"])
-def test_recomputed_layer_selects_once_and_keeps_the_thresholds(tiny, batch, seeded, policy):
+def test_recomputed_layer_selects_once_and_keeps_the_thresholds(tiny, batch, seeded, policy, monkeypatch):
     """The thresholds and the indexer's gradients are named residuals that
     every recompute policy keeps: the layer's body launches one selection
     (the replay rebuilds the mask with a third pass of ``index_scores``), and
-    the loss and every gradient are the unrecomputed model's."""
+    the loss and every gradient are the unrecomputed model's. Where the flash
+    kernels run (heads of 128, the interpreter told that the shape tiles) the
+    loss is the ``index_loss`` kernel, and the backward holds no call of it."""
     _cfg, _ref, _sizes, pcfg = tiny
     _leaves, model, params = seeded
     assert set(sparse_select.SPARSE_RESIDUALS) <= set(transformer.KEPT_RESIDUALS)
@@ -407,6 +453,28 @@ def test_recomputed_layer_selects_once_and_keeps_the_thresholds(tiny, batch, see
     plain = count(jax.make_jaxpr(jax.value_and_grad(lambda p: program_objective(model, p, batch)[0]))(params).jaxpr)
     assert plain["sparse_select"] == 1 and plain["index_scores"] == 2
     got, want = jax.jit(fn)(params), jax.jit(jax.value_and_grad(lambda p: program_objective(model, p, batch)[0]))(params)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+
+    cfg = transformer.DecoderConfig(
+        vocab_size=64, d_model=128, n_layers=2, n_heads=2, n_kv_heads=1, head_width=128, d_ff=64, max_seq_len=256,
+        dtype=jnp.float32, sparse_topk=64, index_heads=2, index_head_dim=16, remat=True, remat_policy=policy,
+    )
+    tokens = jnp.asarray(np.arange(256)[None] % 64, jnp.int32)
+    params = nn.meta.unbox(transformer.Decoder(cfg).init(jax.random.key(0), tokens)["params"])
+
+    def objective(cfg):
+        def fn(p):
+            logits, mods = transformer.Decoder(cfg).apply({"params": p}, tokens, mutable=["intermediates"])
+            return jnp.square(logits).mean() + trainer_mod.collect_aux_losses(mods)
+        return jax.value_and_grad(fn)
+
+    monkeypatch.setattr(transformer, "flash_tileable", lambda *a: None)
+    counted = count(jax.make_jaxpr(objective(cfg))(params).jaxpr)  # the scan's body once forward, once backward
+    assert counted["index_loss"] == counted["flash_fwd"] == counted["flash_bwd"] == counted["sparse_select"] == 1
+    assert counted["index_scores"] == 3 and counted["name:sparse_index_grads"] == 3
+    got, want = objective(cfg)(params), objective(dataclasses.replace(cfg, remat=False))(params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
@@ -440,12 +508,16 @@ def test_attention_dispatch_hands_the_selection_to_the_flash_kernels(monkeypatch
     assert count(jaxpr.jaxpr)["flash_fwd"] == 1
     kernel = [a for n, a in events if n == "attention.kernel"][-1]
     assert kernel["kernel"] == "flash" and kernel["selected"] == 64 and kernel["backward"] == "fused"
+    assert kernel["index_loss"] == "kernel" and count(jaxpr.jaxpr)["index_loss"] == 1
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     # the indexer's loss reads the kernels' log-sum-exp there, and its own softmax on the XLA path
     objective = lambda p: sum(jax.tree.leaves(layer.apply(p, x, pos, mutable=["intermediates"])[1]["intermediates"]["index_aux_loss"]))
     with_lse = jax.value_and_grad(objective)(params)
     monkeypatch.undo()
-    by_softmax = jax.value_and_grad(objective)(params)
+    with telemetry.current(Recorder(worker="t")):
+        by_softmax = jax.value_and_grad(objective)(params)
+    kernel = [a for n, a in events if n == "attention.kernel"][-1]
+    assert kernel["kernel"] == "xla_dense" and kernel["selected"] == 64 and kernel["index_loss"] == "blockwise"
     np.testing.assert_allclose(with_lse[0], by_softmax[0], rtol=1e-5)
     for a, b in zip(jax.tree.leaves(with_lse[1]), jax.tree.leaves(by_softmax[1])):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-6 * float(jnp.abs(b).max()) + 1e-9)

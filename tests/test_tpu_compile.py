@@ -152,3 +152,32 @@ def test_selected_key_kernels_compile_at_the_keye_cells_shape(one_chip):
     ).compile().as_text()
     assert backward_form(s, d) == "fused"
     assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
+
+
+def test_index_loss_kernel_compiles_at_the_keye_cells_shape(one_chip):
+    """``index_loss`` at the cell's row (1 x 32,768, 32 heads over 4 of 128, 16
+    index heads of 64), under a selection's int8 mask and, as a row of at most
+    2,048 positions takes it, over every visible key of a packed row: the 16
+    index heads' products of a 512 x 1,024 tile and ``d ki`` for the whole row
+    resident, past Mosaic's default 16 MiB of VMEM."""
+    from maggy_tpu.ops import sparse_select
+
+    h, kh, d, heads, width = 32, 4, 128, 16, 64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for s, masked in ((32768, True), (2048, False)):
+        def loss(q, k, lse, qi, ki, w, lse_i, mask, segs, weight):
+            return sparse_select._loss_kernel_pass(
+                q, k, lse, qi, ki, w, lse_i, mask if masked else None, None if masked else segs, weight, interpret=False
+            )
+
+        text = jax.jit(loss).lower(
+            sds((1, s, h, d), jnp.bfloat16), sds((1, s, kh, d), jnp.bfloat16), sds((1, h, s), jnp.float32),
+            sds((1, heads, s, width), jnp.bfloat16), sds((1, s, width), jnp.bfloat16), sds((1, s, heads), jnp.float32),
+            sds((1, s), jnp.float32), sds((1, s, s), jnp.int8), sds((1, 1, s), jnp.int32), sds((1, s), jnp.float32),
+        ).compile().as_text()
+        calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+        assert len(calls) == 1 and "index_loss" in calls[0].split("=")[0]
+
