@@ -71,7 +71,6 @@ def initialize_data_plane(
     # resolved platform is TPU (the knob only affects the CPU backend), and the
     # platform cannot be resolved before initialize without starting a backend
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    t0 = time.perf_counter()
     with tel.span("data_plane_init", coordinator=coordinator, rank=process_id):
         jax.distributed.initialize(
             coordinator, num_processes=num_processes, process_id=process_id
@@ -82,7 +81,6 @@ def initialize_data_plane(
         # touching jax before its RPC server is up while workers wait on that
         # server before touching jax — a circular wait only broken by a timeout.
         jax.devices()
-    tel.gauge("data_plane_init_ms", (time.perf_counter() - t0) * 1e3)
     return True
 
 

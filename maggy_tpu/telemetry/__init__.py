@@ -6,8 +6,10 @@ structured layer every tier threads through:
 
 * :mod:`maggy_tpu.telemetry.recorder` — a process-local :class:`Telemetry`
   recorder with ``span(name)`` context managers and typed counters/gauges,
-  buffered lock-free per worker. ``MAGGY_TPU_TELEMETRY=0`` swaps in a no-op
-  recorder so the hot path carries zero instrumentation cost.
+  buffered lock-free per worker; a span names its ``parent``, and the compile
+  pipeline's stages (``jax.monitoring``) are journaled as ``compile.*`` spans
+  under the program span whose call compiled. ``MAGGY_TPU_TELEMETRY=0`` swaps
+  in a no-op recorder so the hot path carries zero instrumentation cost.
 * :mod:`maggy_tpu.telemetry.sink` — a JSONL sink on the env storage seam, so
   records land under ``<exp_dir>/telemetry/worker_<pid>.jsonl`` identically on
   a local disk or ``gs://``.

@@ -23,7 +23,6 @@ GAUGES = frozenset(
     {
         # training loop (train/trainer.py)
         "step_time_ms",  # host wall clock per step
-        "step_time_ms_mean",  # mean over the run, compile step excluded
         "compile_time_ms",  # a step whose call traced the program, synced to cover the XLA compile
         "steps_per_sec",
         "tokens_per_sec",
@@ -59,7 +58,6 @@ GAUGES = frozenset(
         "checkpoint_save_ms",
         # control plane (core/rpc.py, core/pod.py)
         "heartbeat_rtt_ms",
-        "data_plane_init_ms",
         "driver_connect_ms",
         # serving engine + scheduler (serve/)
         "serve.ttft_ms",
@@ -164,6 +162,11 @@ COUNTERS = frozenset(
         "resilience.ckpt_zero_reshards",  # optimizer states converted across zero layouts
         "tune.cache_hits",
         "tune.cache_misses",
+        # the persistent compile cache's verdict on each backend compile it was
+        # asked about (telemetry/recorder.py, from jax.monitoring): loaded, or
+        # compiled anew (written back or not)
+        "compile.cache_hits",
+        "compile.cache_misses",
         "flightrec.dumps",  # stall watchdog dumps written (telemetry/flightrec.py)
         # series-only SLO attainment counters (telemetry/timeseries.py):
         # ingested into the time-series store from scheduler/router SLO
@@ -216,6 +219,16 @@ HISTOGRAMS = frozenset(
 # on the host plane of the trace, on the device events' clock.
 SPANS = frozenset(
     {
+        # the stages of a program's compilation, as jax.monitoring reports them
+        # on the calling thread (telemetry/recorder.py): journaled after the
+        # fact, ``parent`` the program span whose call compiled, ``fun_name``
+        # the program; nothing on the profiler's trace
+        "compile.trace",  # Python to jaxpr (an inner jit's trace lies inside its caller's)
+        "compile.lower",  # jaxpr to MLIR
+        "compile.backend",  # XLA compile or cache load: ``cache`` hit/miss/off, ``cache_load_ms``
+        # Trainer.make_state (train/trainer.py): shape inference for the
+        # shardings, then the jitted init's compile and dispatch
+        "train.make_state",
         # Trainer.fit step loop (train/trainer.py), one per phase
         "train.fit_setup",  # fit entry -> first step (resume, ledger, prefetcher)
         "train.input_wait",  # the loop thread's blocked pull of the next batch
@@ -375,7 +388,6 @@ VALID_UNITS = frozenset({"ms", "count", "bytes", "ratio", "per_s"})
 # adding a gauge means adding its unit here too.
 GAUGE_UNITS = {
     "step_time_ms": "ms",
-    "step_time_ms_mean": "ms",
     "compile_time_ms": "ms",
     "steps_per_sec": "per_s",
     "tokens_per_sec": "per_s",
@@ -396,7 +408,6 @@ GAUGE_UNITS = {
     "sparse.index_loss": "ratio",  # nats, like a loss: no unit of its own in the vocabulary
     "checkpoint_save_ms": "ms",
     "heartbeat_rtt_ms": "ms",
-    "data_plane_init_ms": "ms",
     "driver_connect_ms": "ms",
     "serve.ttft_ms": "ms",
     "serve.tokens_per_sec": "per_s",

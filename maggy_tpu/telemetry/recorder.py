@@ -24,6 +24,15 @@ profiler session runs (``Trainer.fit(profile_dir=...)``, ``profcap``, the
 benchmark's ``--trace 1``) the span lies on the host plane of the
 ``.xplane.pb``, on the device events' clock; outside one it is a flag check.
 
+A span record names the span that caused it: ``parent`` is the innermost
+span open on the same thread when this one was opened (absent at the top), so
+a span's self time is its duration less its children's. ``record_span``
+journals the same record from a start and an end that something else measured
+on ``time.time()``; the compile pipeline's stages come that way, from
+``jax.monitoring`` (:func:`install_compile_listeners`): ``compile.trace``,
+``compile.lower`` and ``compile.backend`` under the program span whose call
+compiled, with the persistent cache's verdict on the last.
+
 ``MAGGY_TPU_TELEMETRY=0`` disables recording globally: :func:`get` then
 returns the shared :data:`NULL` no-op recorder, whose ``span`` hands back one
 reusable null context manager — the instrumented code paths stay in place at
@@ -40,6 +49,7 @@ import weakref
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from maggy_tpu.core import lockdebug
@@ -90,6 +100,7 @@ class Telemetry:
         # the heartbeat thread (per beat); serialize so JSONL lines never tear
         self._flush_lock = lockdebug.lock("telemetry._flush_lock")
         _instances.add(self)
+        install_compile_listeners()
 
     # ------------------------------------------------------------------ spans
 
@@ -107,23 +118,43 @@ class Telemetry:
     def span(self, name: str, **attrs) -> Iterator[None]:
         """Time a block; records wall-clock start + duration on exit, and
         annotates the profiler's trace with it when one is being taken."""
+        stack = _open_spans()
+        parent = stack[-1] if stack else None
+        stack.append(name)
         ts = time.time()
         t0 = time.perf_counter()
         try:
             with TraceAnnotation(name, **attrs):
                 yield
         finally:
-            rec = {
-                "kind": "span",
-                "name": name,
-                "ts": ts,
-                "dur_ms": (time.perf_counter() - t0) * 1e3,
-                "worker": self.worker,
-                "tid": threading.get_ident() & 0xFFFF,
-            }
-            if attrs:
-                rec["attrs"] = attrs
-            self._append(rec)
+            dur_ms = (time.perf_counter() - t0) * 1e3
+            stack.pop()
+            self._append(self._span_record(name, ts, dur_ms, parent, attrs))
+
+    def _span_record(self, name, ts, dur_ms, parent, attrs) -> Dict[str, Any]:
+        rec = {
+            "kind": "span",
+            "name": name,
+            "ts": ts,
+            "dur_ms": dur_ms,
+            "worker": self.worker,
+            "tid": threading.get_ident() & 0xFFFF,
+        }
+        if parent is not None:
+            rec["parent"] = parent
+        if attrs:
+            rec["attrs"] = attrs
+        return rec
+
+    def record_span(self, name: str, start: float, end: float, **attrs) -> None:
+        """Journal a span after the fact, from a start and an end on
+        ``time.time()``'s clock: the record ``span`` writes, with the calling
+        thread's innermost open span as ``parent``, and no annotation of the
+        profiler's trace (the interval is over)."""
+        stack = _open_spans()
+        self._append(
+            self._span_record(name, start, (end - start) * 1e3, stack[-1] if stack else None, attrs)
+        )
 
     # ------------------------------------------------------- gauges / counters
 
@@ -262,6 +293,9 @@ class NullTelemetry:
     def span(self, name: str, **attrs):
         return self._NULL_CTX
 
+    def record_span(self, name: str, start: float, end: float, **attrs) -> None:
+        pass
+
     def gauge(self, name: str, value: float) -> None:
         pass
 
@@ -311,10 +345,100 @@ def flight_snapshots() -> List[Dict[str, Any]]:
     return out
 
 
+# per thread: the ambient recorder (``get``), the names of the spans open on
+# the thread, outermost first (whichever recorder opened them: the enclosing
+# span is a fact about the thread), and what the persistent compile cache said
+# of the compile in progress on it
+_tls = threading.local()
+
+
+def _open_spans() -> List[str]:
+    stack = getattr(_tls, "spans", None)
+    if stack is None:
+        stack = _tls.spans = []
+    return stack
+
+
+# ------------------------------------------------------- the compile pipeline
+# jax.monitoring reports each stage of a program's compilation on the thread
+# that called the program, with the start and end it measured on time.time().
+# A listener compares the event's name and records: an event not named here is
+# dropped at the comparison, and listeners fire on compile events only, never
+# per step.
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+# JAX also reports a trace for every jitted function called inside another's
+# trace and for every eager operation's lookup of a program it has: tens of
+# microseconds each, a thousand and more a set-up. Journaled they would push
+# everything else out of the flight ring and tell nothing (an inner trace lies
+# inside its caller's), so a trace shorter than this is not recorded
+_MIN_TRACE_S = 1e-3
+# inside the backend stage, in this order: the cache is asked, and on a hit
+# the hit and the retrieval time are reported before the stage ends. JAX asks
+# with no directory to ask in too; that, like a disabled cache, reads "off"
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _tls.cache = "hit"
+    elif event == _CACHE_ASKED and jax.config.jax_compilation_cache_dir:
+        _tls.cache = "miss"
+
+
+def _on_compile_duration(event: str, duration_secs: float, **_kw) -> None:
+    if event == _CACHE_LOAD:
+        _tls.cache_load_ms = duration_secs * 1e3
+
+
+def _on_compile_stage(event: str, start: float, end: float, fun_name: str = "", **_kw) -> None:
+    if event == _TRACE:
+        if end - start >= _MIN_TRACE_S:
+            tel = get()
+            tel.record_span("compile.trace", start, end, fun_name=fun_name)
+    elif event == _LOWER:
+        tel = get()
+        tel.record_span("compile.lower", start, end, fun_name=fun_name)
+    elif event == _BACKEND:
+        tel = get()
+        cache = getattr(_tls, "cache", "off")
+        _tls.cache = "off"
+        attrs = {"fun_name": fun_name, "cache": cache}
+        if cache == "hit":
+            tel.count("compile.cache_hits")
+            attrs["cache_load_ms"] = getattr(_tls, "cache_load_ms", 0.0)
+        elif cache == "miss":
+            tel.count("compile.cache_misses")
+        tel.record_span("compile.backend", start, end, **attrs)
+
+
+_listening = False
+_listening_lock = threading.Lock()
+
+
+def install_compile_listeners() -> None:
+    """Register the three listeners with ``jax.monitoring``, once a process
+    and never with telemetry disabled. Called where a real recorder is built
+    (``Telemetry.__init__``)."""
+    global _listening
+    if _listening or not enabled():
+        return
+    with _listening_lock:
+        if _listening:
+            return
+        jax.monitoring.register_event_listener(_on_compile_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+        jax.monitoring.register_event_time_span_listener(_on_compile_stage)
+        _listening = True
+
+
 # thread-ambient recorder: executors are THREADS in one process (like the
 # Reporter print tee), so the current recorder is thread-local, with one lazy
 # process-wide default for standalone Trainer.fit use outside any experiment
-_tls = threading.local()
 _default_lock = threading.Lock()
 _default: Optional[Telemetry] = None
 

@@ -741,15 +741,19 @@ class Trainer:
         (jit + out_shardings — no host-side full materialization). Under a
         ``stage`` mesh axis > 1 the params are born in the stage-stacked
         pipeline layout (see :mod:`maggy_tpu.train.pipeline_adapter`)."""
+        from maggy_tpu import telemetry
+
         inputs = _model_inputs(sample_batch)
-        init = jax.jit(
-            self._init_fn(),
-            out_shardings=self.state_shardings_for(sample_batch, rng),
-        )
-        # np (not jnp): host values enter a multi-process jit as replicated
-        # inputs instead of arrays committed to one process's local device
-        with self.mesh:
-            return init(rng, *jax.tree.map(np.asarray, inputs))
+        # the host's time only: the init is dispatched, not waited for
+        with telemetry.get().span("train.make_state"):
+            init = jax.jit(
+                self._init_fn(),
+                out_shardings=self.state_shardings_for(sample_batch, rng),
+            )
+            # np (not jnp): host values enter a multi-process jit as replicated
+            # inputs instead of arrays committed to one process's local device
+            with self.mesh:
+                return init(rng, *jax.tree.map(np.asarray, inputs))
 
     def adopt_state(self, state: TrainState, sample_batch: Dict[str, Any]) -> TrainState:
         """Place a foreign/host TrainState onto THIS trainer's mesh layout —
@@ -1489,8 +1493,6 @@ class Trainer:
             last_bcast = -1  # last loop index broadcast (monotonic step guard)
             fit_t0 = time.perf_counter()
             tokens_per_batch = 0
-            step_ms_sum = 0.0
-            steps_timed = 0  # steps in step_ms_sum: every one that did not compile
             # one trace per fit run: every span/gauge the loop records carries
             # it, and the run's start/end land as lifecycle events — the
             # training-side analogue of a serving request's lane
@@ -1585,8 +1587,6 @@ class Trainer:
                 if compiled:
                     tel.gauge("compile_time_ms", dt_ms)
                 else:
-                    step_ms_sum += dt_ms
-                    steps_timed += 1
                     tel.gauge("step_time_ms", dt_ms)
                 if self._expect_recompile:
                     sentinel.expect("train_step")
@@ -1717,8 +1717,6 @@ class Trainer:
         if num_steps > 0 and wall > 0:
             out["steps_per_sec"] = num_steps / wall
             tel.gauge("steps_per_sec", out["steps_per_sec"])
-            if steps_timed and step_ms_sum > 0:
-                tel.gauge("step_time_ms_mean", step_ms_sum / steps_timed)
             if tokens_per_batch and tel.active:
                 tok_per_sec = tokens_per_batch * num_steps / wall
                 tel.gauge("tokens_per_sec", tok_per_sec)

@@ -678,7 +678,7 @@ def test_fit_autopilot_integration(tmp_env):
 
     def starved(src):
         while True:
-            time.sleep(0.03)  # loader far slower than the tiny step
+            time.sleep(0.1)  # loader far slower than the tiny step, under six test workers too
             yield next(src)
 
     tel = Telemetry(worker="fit-ap")

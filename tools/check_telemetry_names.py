@@ -11,7 +11,7 @@ data. This lint makes the name set closed:
   ``DYNAMIC_PREFIXES`` for the few f-string names whose tail is a bounded
   runtime enum (request terminal states, RPC verbs).
 * This tool AST-walks ``maggy_tpu/`` for ``.gauge(`` / ``.count(`` /
-  ``.histogram(`` / ``.event(`` / ``.span(`` calls on telemetry-ish receivers (any name
+  ``.histogram(`` / ``.event(`` / ``.span(`` / ``.record_span(`` calls on telemetry-ish receivers (any name
   in the receiver chain containing ``tel`` — ``tel``, ``telemetry``,
   ``self.telemetry``, ``telemetry.get()`` — so ``str.count`` is never
   flagged) and checks:
@@ -53,7 +53,11 @@ from analysis import (  # noqa: E402
     walk_sources,
 )
 
-TELEMETRY_METHODS = ("gauge", "count", "histogram", "event", "span")
+# recorder method -> the registry kind its first argument names
+TELEMETRY_METHODS = {
+    "gauge": "gauge", "count": "count", "histogram": "histogram", "event": "event",
+    "span": "span", "record_span": "span",
+}
 
 
 def load_registry(repo: str):
@@ -325,7 +329,7 @@ def check_source(source: str, path: str, registry, alert_names=None) -> List[Tup
         if not node.args:
             continue
         arg = node.args[0]
-        allowed = registry.BY_KIND[fn.attr]
+        allowed = registry.BY_KIND[TELEMETRY_METHODS[fn.attr]]
         if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
             name = arg.value
             if name not in allowed:
