@@ -67,6 +67,7 @@ class Sizes:
     kernel_cases: tuple  # (batch, seq) pairs
     index_heads: tuple  # (index heads, their width) of the selected-key case
     loss_case: tuple  # (seq, kv heads) of the indexer's loss: a row twice the keys a query keeps
+    select_cases: tuple  # (seq, keys a query) at which one block of scores is held against two passes
     train_seq: int
     train_steps: int
     prompt_lens: tuple
@@ -97,6 +98,7 @@ def chip_sizes(n_chips: int, bytes_limit: int) -> Sizes:
         kernel_cases=((2, 2048), (1, 8192)),
         index_heads=(16, 64),
         loss_case=(4096, 4),
+        select_cases=((4096, 2048), (32768, 2048)),  # the second is the keye-vl-2.0-30b-a3b cell's row
         train_seq=2048,
         train_steps=6,
         prompt_lens=(6, 7, 24, 30, 100, 120, 400, 500),
@@ -121,6 +123,7 @@ def toy_sizes() -> Sizes:
         kernel_cases=((2, 256),),
         index_heads=(4, 16),
         loss_case=(256, 2),
+        select_cases=((256, 64),),
         train_seq=128,
         train_steps=4,
         prompt_lens=(3, 5, 9, 12, 20, 26, 40, 50),
@@ -302,7 +305,7 @@ def phase_kernels(run: Run) -> dict:
         at = jnp.arange(s)
         same = (seen == ((at[:, None] >= at[None]) & (segs[:, :, None] == segs[:, None, :]))).all()
         err = jnp.linalg.norm(jnp.where(seen, got - want, 0.0)) / jnp.linalg.norm(jnp.where(seen, want, 0.0))
-        mask, counts, _ = sparse_select.select(qi, ki, wi, seg3, topk, interpret=interpret)
+        mask, counts, *_ = sparse_select.select(qi, ki, wi, seg3, topk, interpret=interpret)
         by_hand = jnp.minimum(seen.sum(-1), topk).sum()
         return mask, err, same, counts, by_hand
 
@@ -352,7 +355,7 @@ def phase_kernels(run: Run) -> dict:
 
     @jax.jit
     def loss_by_kernel(q, k, qi, ki, wi):
-        mask, _counts, lse_i = sparse_select.select(qi, ki, wi, None, topk, interpret=interpret)
+        mask, _counts, lse_i, _thresholds = sparse_select.select(qi, ki, wi, None, topk, interpret=interpret)
         _out, lse = flash(q, k, k, selected=mask, return_lse=True)
         real = jnp.ones((1, s), bool)
         loss = lambda qi, ki, wi: sparse_select.index_loss(qi, ki, wi, q, k, lse, mask, None, real, lse_i)
@@ -385,6 +388,37 @@ def phase_kernels(run: Run) -> dict:
     check(bool(finite), f"index_loss {label}: a value is not finite")
     check(max(errs.values()) <= tol, f"index_loss off the float32 formula: {errs} > {tol}")
     cases.append({"case": "index_loss " + label, **errs})
+
+    # the selection from one block of scores a block of queries (``select``)
+    # against a pass for the thresholds and a pass for the mask, which is also
+    # what the flash kernels' backward runs (``selection_mask``): equal bits
+    @functools.partial(jax.jit, static_argnames=("s", "k"))
+    def select_both_ways(s, k):
+        keys = jax.random.split(jax.random.key(13), 3)
+        qi = jax.random.normal(keys[0], (1, heads_i, s, width_i), jnp.bfloat16)
+        ki = jax.random.normal(keys[1], (1, s, width_i), jnp.bfloat16)
+        wi = jax.random.normal(keys[2], (1, s, heads_i), f32) * (heads_i * width_i) ** -0.5
+        mask, counts, _lse, thresholds = sparse_select.select(qi, ki, wi, None, k, interpret=interpret)
+        rows = sparse_select._query_block(s)
+
+        def one(at):
+            scores = sparse_select.index_scores(qi, ki, wi, None, at, rows, interpret=interpret)
+            return sparse_select.topk_thresholds(scores, at, k, interpret=interpret)
+
+        first = jnp.moveaxis(sparse_select._each_block(one, s), 0, 2).reshape(1, 3, s)
+        again = sparse_select.selection_mask(qi, ki, wi, None, first, interpret=interpret)
+        return (thresholds != first).sum(), (mask != again).sum(), (mask != 0).sum(dtype=jnp.int32) - counts[0], counts
+
+    for s, k in run.sizes.select_cases:
+        t0 = time.perf_counter()
+        off_thresholds, off_mask, off_count, counts = jax.block_until_ready(select_both_ways(s, k))
+        setup_s += time.perf_counter() - t0
+        label = f"B=1 S={s}, {k} keys a query"
+        run.say(f"  select {label}: selected {int(counts[0])} of {int(counts[1])} pairs; against two passes "
+                f"{int(off_thresholds)} thresholds and {int(off_mask)} bytes of the mask differ")
+        check(int(off_thresholds) == int(off_mask) == int(off_count) == int(counts[2]) == 0,
+              f"select {label}: one block of scores and two passes disagree")
+        cases.append({"case": "select " + label, "selected": int(counts[0]), "visible": int(counts[1])})
     return {
         "setup_s": setup_s, "heads": [h, kh, d], "tolerance": tol, "cases": cases,
         "compiled": not interpret,

@@ -49,9 +49,10 @@ Dtype = Any
 #   projections and the feed-forward), so the replay is elementwise work.
 # - "everything": nothing is recomputed.
 # A selected-key attention layer (``sparse_topk``) keeps two things more
-# (``ops.sparse_select.SPARSE_RESIDUALS``): each query's threshold, so that the
-# replay rebuilds the selection's mask and does not select again, and the
-# indexer's gradients, which its loss's one pass already gave.
+# (``ops.sparse_select.SPARSE_RESIDUALS``): each query's threshold, from which
+# the flash kernels' backward makes the selection's mask again (the replay
+# makes no index score and selects nothing), and the indexer's gradients,
+# which its loss's one pass already gave.
 # There is no policy that replays the kernel: a step that does not fit with
 # its results kept is one the autotuner (``tune/static.py``) prunes by its
 # compiled footprint, and the remedy is the one it proposes, a smaller batch.
@@ -433,7 +434,12 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
     ``selected``, the keys a query keeps where a selection masks the call,
     with the form the indexer's loss then takes (``index_loss``: ``kernel``
     where this call returns the heads' log-sum-exp, which only the one-chip
-    flash kernels do, else ``blockwise``: ``ops.sparse_select.index_loss``).
+    flash kernels do, else ``blockwise``: ``ops.sparse_select.index_loss``)
+    and ``index_passes``, the ``index_scores`` launches a block of queries,
+    layer and training step as this call builds them: the forward's, from
+    which thresholds and mask both come, and one more where the kernels'
+    backward makes the mask again (``reselect``: 2); the XLA attention keeps
+    the mask (1, and what a recomputed layer's replay runs again).
     The dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
@@ -442,6 +448,7 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
     if selected:
         attrs["selected"] = int(selected)
         attrs["index_loss"] = "kernel" if kernel == "flash" else "blockwise"
+        attrs["index_passes"] = 2 if kernel.startswith("flash") else 1
     if kernel.startswith("flash"):
         from maggy_tpu.ops.flash import _auto_blocks, backward_form
 
@@ -459,7 +466,8 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
 
 
 def auto_attention(
-    q, k, v, *, causal: bool = True, segment_ids=None, selected=None, topk: int = 0, return_lse: bool = False
+    q, k, v, *, causal: bool = True, segment_ids=None, selected=None, reselect=None, topk: int = 0,
+    return_lse: bool = False,
 ):
     """Pick the fastest correct kernel for the backend/shape: the Pallas flash
     kernel (fwd+bwd) on TPU when the geometry tiles onto the MXU
@@ -486,7 +494,8 @@ def auto_attention(
     path. The choice is recorded (:func:`record_attention_kernel`), never
     silent. ``selected`` (int8 [B, Sq, Sk], the ``topk`` keys a query keeps:
     ``ops/sparse_select.py``) masks every path the same way; the kernels take
-    a tile of it as an operand. ``return_lse``: ``(out, lse)`` with the rows'
+    a tile of it as an operand, and their backward makes it again by
+    ``reselect`` where the XLA path keeps the array. ``return_lse``: ``(out, lse)`` with the rows'
     log-sum-exp [B, H, Sq] where the one-chip kernels ran, which keep it
     anyway, and None on every other path (the caller normalises by itself)."""
     from maggy_tpu.parallel.mesh import ambient_mesh
@@ -497,9 +506,11 @@ def auto_attention(
         mesh = ambient_mesh()
         if mesh is None or mesh.size == 1:
             record_attention_kernel("flash", q, k, segment_ids, selected=topk)
-            return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids, return_lse=return_lse, **sel)
+            return flash_attention(
+                q, k, v, causal=causal, segment_ids=segment_ids, return_lse=return_lse, reselect=reselect, **sel
+            )
         out = sharded_flash_attention(
-            q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids, **sel
+            q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids, reselect=reselect, **sel
         )
         if out is not None:
             record_attention_kernel("flash_sharded", q, k, segment_ids, selected=topk)
@@ -597,13 +608,14 @@ class Attention(nn.Module):
             )(u.astype(jnp.float32)) * (heads**-0.5 * width**-0.5)
             qi = qi.transpose(0, 2, 1, 3)  # [B, J, S, Dj]: a head's rows together
         segs = None if segment_ids is None else segment_ids.astype(jnp.int32)[:, None]
-        mask = index_lse = None
+        mask = index_lse = reselect = None
         if s > topk:
-            mask, counts, index_lse = sparse_select.select(qi, ki, w, segs, topk)
+            mask, counts, index_lse, thresholds = sparse_select.select(qi, ki, w, segs, topk)
+            reselect = jax.tree_util.Partial(sparse_select.selection_mask, qi, ki, w, segs, thresholds)
             self.sow("intermediates", "sparse_counts", counts)
         out, lse = auto_attention(
-            q, k, v, causal=True, segment_ids=segment_ids, selected=mask, topk=topk if s > topk else 0,
-            return_lse=True,
+            q, k, v, causal=True, segment_ids=segment_ids, selected=mask, reselect=reselect,
+            topk=topk if s > topk else 0, return_lse=True,
         )
         with jax.named_scope("sparse.index_loss"):
             real = jnp.ones(x.shape[:2], bool) if segment_ids is None else segment_ids > 0

@@ -716,7 +716,7 @@ def _bwd_call(q, k, v, o, do, lse, segs, bounds, sel=None, **kw):
 def _flash_core(
     causal: bool, block_q: int, block_k: int, bwd_block_q: int,
     bwd_block_k: int, group: int, heads: int, interpret: bool,
-    segmented: bool, masked: bool = False, with_lse: bool = False,
+    segmented: bool, masked: bool = False, with_lse: bool = False, reselects: bool = False,
 ):
     """Differentiable flash attention on q [B*H, S, D], k/v [B*Kh, S, D]
     (GQA group = H // Kh handled by kernel index maps — the repeated K/V
@@ -724,7 +724,10 @@ def _flash_core(
     [B, 1, S] int32 operand masks attention across packed-sequence
     boundaries (zero cotangent), and with ``masked`` a fifth, int8
     [B, S, S], the pairs a selection keeps (nonzero; no cotangent either: a
-    selection is no function of the scores it masks). ``with_lse``: the
+    selection is no function of the scores it masks), and with ``reselects``
+    a sixth, what gives that selection again when called
+    (``flash_attention``'s ``reselect``): the backward calls it, and the
+    mask is no residual. ``with_lse``: the
     result is ``(o, lse)``, the rows' log-sum-exp as the backward keeps it
     (``[BH, S / 128, 128]`` float32, a constant to whoever reads it: its
     cotangent is dropped). Each kernel gets its visit bounds, computed
@@ -747,6 +750,7 @@ def _flash_core(
         return jnp.asarray(visit_bounds(segs if segmented else None, outer, **tiles))
 
     def forward(q, k, v, segs, *sel):
+        sel = sel[:1]  # the selection itself; what may follow it is the backward's (``reselect``)
         return _fwd_call(
             q, k, v, segs if segmented else None,
             bounds(q, k, segs, sel, block_q, block_k, "q"), *sel, **kw,
@@ -768,13 +772,18 @@ def _flash_core(
         # kernels have it, a column, is padded to 128 lanes by a TPU layout
         # (as many bytes as ``o`` for 1/128 of the numbers): kept as rows of
         # 128, which the chip reshapes faster than [BH, S] (PERF.md section 6,
-        # PR 29; S that 128 does not divide is interpreter-only)
+        # PR 29; S that 128 does not divide is interpreter-only). Of a
+        # selection that came with the means to make it again, those are kept
+        # and the mask is not: a byte a pair of the row, which a recomputed
+        # layer's replay would have to select again to have
         o = checkpoint_name(o, FLASH_RESIDUALS[0])
         lse = checkpoint_name(rows_of_lanes(lse, q.shape[1]), FLASH_RESIDUALS[1])
-        return ((o, lse) if with_lse else o), (q, k, v, segs, o, lse, *sel)
+        return ((o, lse) if with_lse else o), (q, k, v, segs, o, lse, *(sel[1:] if reselects else sel))
 
     def core_bwd(res, g):
         q, k, v, segs, o, lse, *sel = res
+        if reselects:
+            sel = [sel[0]()]
         g = g[0] if with_lse else g
         # back to a column, chunked by the backward's q tile
         lse = lse.reshape(-1, q.shape[1] // bwd_block_q, bwd_block_q, 1)
@@ -793,7 +802,8 @@ def _flash_core(
                 return x.sum(axis=1).astype(dtype)
 
             dk_h, dv_h = gsum(dk_h, k.dtype), gsum(dv_h, v.dtype)
-        return (dq, dk_h, dv_h, None, *(None for _ in sel))  # int segment ids, int8 selection: no cotangent
+        # int segment ids, an int8 selection and what makes it again: no cotangent
+        return (dq, dk_h, dv_h, None, *(None,) * (masked + reselects))
 
     core.defvjp(core_fwd, core_bwd)
     return core
@@ -896,6 +906,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     segment_ids=None,
     selected=None,
+    reselect=None,
     return_lse: bool = False,
 ) -> jax.Array:
     """q [B,S,H,D], k/v [B,S,Kh,D] → [B,S,H,D]. Differentiable (custom VJP).
@@ -910,7 +921,11 @@ def flash_attention(
     made outside, one for all the heads of a batch row
     (``ops/sparse_select.py``): a pair counts where the causal and the segment
     masks and the selection all keep it, a tile of it is an operand of every
-    kernel, and a tile with no selected pair is not visited. ``return_lse``:
+    kernel, and a tile with no selected pair is not visited. ``reselect``, a
+    ``jax.tree_util.Partial`` over arrays that gives ``selected`` again when
+    called: the backward calls it for its mask (and differentiates nothing
+    there) where it would else keep the forward's, [B, Sq, Sk] bytes that a
+    recomputed layer's replay would have to make once more. ``return_lse``:
     ``(out, lse)`` with the rows' log-sum-exp over the pairs kept, [B, H, Sq]
     float32 (+inf on a row that keeps none), as a constant; from the kernels
     only (a shape that falls back raises).
@@ -959,9 +974,11 @@ def flash_attention(
         else jnp.zeros((b, 1, sq), jnp.int32)  # placeholder, never read
     )
     sel = () if selected is None else (selected.astype(jnp.int8),)
+    if sel and reselect is not None:
+        sel += (reselect,)
     out = _flash_core(
         causal, block_q, block_k, bwd_block_q, bwd_block_k, h // kh, h,
-        interpret, segmented, selected is not None, return_lse,
+        interpret, segmented, selected is not None, return_lse, len(sel) == 2,
     )(qr, kr, vr, segs, *sel)
     if return_lse:
         out, lse = out
@@ -979,6 +996,7 @@ def sharded_flash_attention(
     interpret: Optional[bool] = None,
     segment_ids: Optional[jax.Array] = None,
     selected: Optional[jax.Array] = None,
+    reselect=None,
 ):
     """Run the Pallas kernel per-shard under ``shard_map`` over ``mesh``.
 
@@ -1015,9 +1033,10 @@ def sharded_flash_attention(
     batch = (AXIS_DATA, AXIS_FSDP)
     spec = P(batch, None, AXIS_TENSOR, None)
     fn = functools.partial(flash_attention, causal=causal, interpret=interpret)
-    # what follows its batch row, where the call has it
-    rows = {"segment_ids": (segment_ids, P(batch, None)), "selected": (selected, P(batch, None, None))}
-    rows = {name: row for name, row in rows.items() if row[0] is not None}
+    # what follows its batch row, where the call has it (``reselect``: every array it holds leads with the batch)
+    by_row = lambda a: P(batch, *(None,) * (a.ndim - 1))
+    rows = {"segment_ids": segment_ids, "selected": selected, "reselect": reselect}
+    rows = {name: (row, jax.tree.map(by_row, row)) for name, row in rows.items() if row is not None}
     return shard_map(
         lambda q, k, v, *more: fn(q, k, v, **dict(zip(rows, more))),
         mesh=mesh, in_specs=(spec, spec, spec, *(s for _, s in rows.values())), out_specs=spec,

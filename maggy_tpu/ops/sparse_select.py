@@ -17,10 +17,16 @@ largest score exactly, by bisection over the bits of the scores' order-keeping
 integer form, 32 counting passes over a block held in VMEM, the position of
 the last tie it admits, and the log-sum-exp of the scores so selected), and
 the selection leaves as what the flash kernels take (``ops/flash.py``
-``selected``): an int8 ``[B, S, S]`` mask, one for all the heads of a layer.
-The three numbers a query are the residual a recomputed layer keeps
-(``SPARSE_RESIDUALS``): the replay rebuilds the mask from them with one more
-pass of ``index_scores`` and does not select again.
+``selected``): an int8 ``[B, S, S]`` mask, one for all the heads of a layer,
+compared out of the very block of scores its thresholds were found in
+(``select``: one pass of ``index_scores`` in the forward). The three numbers
+a query are the residual a layer keeps (``SPARSE_RESIDUALS``), recomputed or
+not, and the mask is none: the flash kernels' backward makes it again from
+them with a second pass of ``index_scores`` (``selection_mask``, handed over
+as ``reselect``), the same kernel on the same operands and so the same bits,
+and a recomputed layer's replay neither scores nor selects. Two passes a
+layer and step; one would need the mask itself to live until the backward, a
+byte a pair.
 
 ``index_loss`` is the indexer's objective, ``mean over real t of KL(mean over
 heads of the attention's probabilities on S_t || softmax over S_t of I[t, .])``,
@@ -278,9 +284,25 @@ def _query_block(s: int) -> int:
     return _divisor(s, 1024)
 
 
-def select_thresholds(qi, ki, w, segs, k: int, *, interpret=None):
-    """Each query's threshold, ``[B, 3, S]`` int32 (``topk_thresholds``), a
-    block of queries at a time."""
+def _each_block(one, s: int):
+    """``one(q_start)`` for every block of ``_query_block(s)`` queries in turn,
+    the results stacked on a new leading axis."""
+    q = _query_block(s)
+    return jax.lax.map(one, jnp.arange(s // q, dtype=jnp.int32) * q)
+
+
+def select(qi, ki, w, segs, k: int, *, interpret=None):
+    """The top ``k`` keys a query, from one block of scores a block of
+    queries: ``(mask int8 [B, S, S], counts int32 [3], lse [B, S] float32,
+    thresholds [B, 3, S] int32)``. ``counts``: the pairs selected, the pairs
+    visible (at or before the query, in its document) and the queries whose
+    set is not ``min(k, visible)`` keys; ``lse``: the log-sum-exp of each
+    query's selected scores (``index_loss`` wants it); ``thresholds``:
+    ``topk_thresholds``', named so that a recompute policy keeps them
+    (``SPARSE_RESIDUALS``) and ``selection_mask`` can give the mask again.
+    Nothing here has a gradient: the indexer learns from ``index_loss``
+    alone."""
+    qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
     b, _, s, _ = qi.shape
     q = _query_block(s)
 
@@ -288,17 +310,28 @@ def select_thresholds(qi, ki, w, segs, k: int, *, interpret=None):
         with jax.named_scope("sparse.index"):
             scores = index_scores(qi, ki, w, segs, q_start, q, interpret=interpret)
         with jax.named_scope("sparse.select"):
-            return topk_thresholds(scores, q_start, k, interpret=interpret)
+            thresholds = topk_thresholds(scores, q_start, k, interpret=interpret)
+        with jax.named_scope("sparse.index"):
+            keep = selection_from(scores, thresholds)
+            n_keep = keep.sum(-1, dtype=jnp.int32)
+            n_visible = (scores > _NEG_INF).sum(-1, dtype=jnp.int32)
+            counts = jnp.stack([
+                n_keep.sum(), n_visible.sum(), (n_keep != jnp.minimum(n_visible, k)).sum(dtype=jnp.int32),
+            ])
+        return keep.astype(jnp.int8), counts, thresholds
 
-    out = jax.lax.map(one, jnp.arange(s // q, dtype=jnp.int32) * q)  # [n, B, 3, Q]
-    return jnp.moveaxis(out, 0, 2).reshape(b, 3, s)
+    mask, counts, thresholds = _each_block(one, s)  # [n, B, Q, S], [n, 3], [n, B, 3, Q]
+    thresholds = checkpoint_name(jnp.moveaxis(thresholds, 0, 2).reshape(b, 3, s), SPARSE_RESIDUALS[0])
+    lse = jax.lax.bitcast_convert_type(thresholds[:, 2], jnp.float32)
+    return jnp.moveaxis(mask, 0, 1).reshape(b, s, s), counts.sum(0), lse, thresholds
 
 
-def selection_mask(qi, ki, w, segs, thresholds, k: int, *, interpret=None):
-    """``(mask int8 [B, S, S], counts int32 [3])`` from the thresholds, with
-    the index scores made again: the pairs selected, the pairs visible (at or
-    before the query, in its document) and the queries whose set is not
-    ``min(k, visible)`` keys."""
+def selection_mask(qi, ki, w, segs, thresholds, *, interpret=None):
+    """``select``'s mask again from its thresholds, with the index scores made
+    again and nothing selected: the same kernel on the same operands, so the
+    same bits. The backward of an attention under the selection calls it
+    (``ops/flash.py`` ``reselect``), so that no forward's mask has to live
+    until then, nor be made by a recomputed layer's replay."""
     b, _, s, _ = qi.shape
     q = _query_block(s)
 
@@ -306,28 +339,9 @@ def selection_mask(qi, ki, w, segs, thresholds, k: int, *, interpret=None):
         with jax.named_scope("sparse.index"):
             scores = index_scores(qi, ki, w, segs, q_start, q, interpret=interpret)
             keep = selection_from(scores, jax.lax.dynamic_slice_in_dim(thresholds, q_start, q, axis=2))
-            n_keep = keep.sum(-1, dtype=jnp.int32)
-            n_visible = (scores > _NEG_INF).sum(-1, dtype=jnp.int32)
-            counts = jnp.stack([
-                n_keep.sum(), n_visible.sum(), (n_keep != jnp.minimum(n_visible, k)).sum(dtype=jnp.int32),
-            ])
-            return keep.astype(jnp.int8), counts
+            return keep.astype(jnp.int8)
 
-    mask, counts = jax.lax.map(one, jnp.arange(s // q, dtype=jnp.int32) * q)  # [n, B, Q, S]
-    return jnp.moveaxis(mask, 0, 1).reshape(b, s, s), counts.sum(0)
-
-
-def select(qi, ki, w, segs, k: int, *, interpret=None):
-    """``(mask, counts)`` of ``selection_mask`` for the top ``k`` keys a query
-    and ``lse`` [B, S] float32, the log-sum-exp of each query's selected
-    scores (``index_loss`` wants it): the thresholds first, named so that a
-    recompute policy keeps them (``SPARSE_RESIDUALS``), then the mask from
-    them. Nothing here has a gradient: the indexer learns from ``index_loss``
-    alone."""
-    qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
-    thresholds = checkpoint_name(select_thresholds(qi, ki, w, segs, k, interpret=interpret), SPARSE_RESIDUALS[0])
-    lse = jax.lax.bitcast_convert_type(thresholds[:, 2], jnp.float32)
-    return (*selection_mask(qi, ki, w, segs, thresholds, k, interpret=interpret), lse)
+    return jnp.moveaxis(_each_block(one, s), 0, 1).reshape(b, s, s)
 
 
 # ------------------------------------------------------------- the indexer's loss
@@ -443,8 +457,7 @@ def index_lse(qi, ki, w, segs, mask=None, *, interpret=None):
             scores = jnp.where(jax.lax.dynamic_slice_in_dim(mask, q_start, q, axis=1) != 0, scores, _NEG_INF)
         return jax.nn.logsumexp(scores, axis=-1)
 
-    out = jax.lax.map(one, jnp.arange(s // q, dtype=jnp.int32) * q)  # [n, B, Q]
-    return jnp.moveaxis(out, 0, 1).reshape(b, s)
+    return jnp.moveaxis(_each_block(one, s), 0, 1).reshape(b, s)  # from [n, B, Q]
 
 
 def _column(tile, lane, j):

@@ -34,7 +34,7 @@ from maggy_tpu.models import moe, transformer  # noqa: E402
 from maggy_tpu.ops import sparse_select  # noqa: E402
 from maggy_tpu.ops.flash import backward_form, flash_attention  # noqa: E402
 from maggy_tpu.train import trainer as trainer_mod  # noqa: E402
-from test_flash_residuals import count  # noqa: E402  (Pallas kernels and checkpoint names in a jaxpr)
+from test_flash_residuals import count, kernels  # noqa: E402  (Pallas kernels and checkpoint names in a jaxpr)
 
 KIND = "train_packed_ref"
 SEED = 13
@@ -172,7 +172,7 @@ def test_selected_sets_are_lax_top_ks(indexer, k, ties):
 
 def test_select_counts_its_pairs_and_leaves_no_row_off_k(indexer):
     qi, ki, w, seg = indexer
-    mask, counts, lse = sparse_select.select(qi, ki, w, seg[:, None], 32)
+    mask, counts, lse, _thresholds = sparse_select.select(qi, ki, w, seg[:, None], 32)
     want = top_k_by_hand(scores_by_hand(qi, ki, w, seg), 32)
     np.testing.assert_array_equal(mask != 0, want)
     by_hand = jax.nn.logsumexp(jnp.where(want, scores_by_hand(qi, ki, w, seg), -jnp.inf), axis=-1)
@@ -434,47 +434,60 @@ def test_gradient_of_every_leaf_and_the_change_after_two_steps(tiny, batch, seed
 # ------------------------------------------------------- what a replay keeps
 
 
+def selecting_decoder(**fields):
+    """Two scanned selected-key layers whose heads the flash kernels tile
+    (width 128), one row of 256 under 64 keys a query, and their objective."""
+    cfg = transformer.DecoderConfig(**{**dict(
+        vocab_size=64, d_model=128, n_layers=2, n_heads=2, n_kv_heads=1, head_width=128, d_ff=64, max_seq_len=256,
+        dtype=jnp.float32, sparse_topk=64, index_heads=2, index_head_dim=16,
+    ), **fields})
+    tokens = jnp.asarray(np.arange(256)[None] % 64, jnp.int32)
+    params = nn.meta.unbox(transformer.Decoder(cfg).init(jax.random.key(0), tokens)["params"])
+
+    def fn(p):
+        logits, mods = transformer.Decoder(cfg).apply({"params": p}, tokens, mutable=["intermediates"])
+        return jnp.square(logits).mean() + trainer_mod.collect_aux_losses(mods)
+
+    return jax.value_and_grad(fn), params
+
+
 @pytest.mark.parametrize("policy", ["nothing", "dots"])
 def test_recomputed_layer_selects_once_and_keeps_the_thresholds(tiny, batch, seeded, policy, monkeypatch):
     """The thresholds and the indexer's gradients are named residuals that
-    every recompute policy keeps: the layer's body launches one selection
-    (the replay rebuilds the mask with a third pass of ``index_scores``), and
-    the loss and every gradient are the unrecomputed model's. Where the flash
-    kernels run (heads of 128, the interpreter told that the shape tiles) the
-    loss is the ``index_loss`` kernel, and the backward holds no call of it."""
+    every recompute policy keeps, and the loss and every gradient are the
+    unrecomputed model's. Where the flash kernels run (heads of 128, the
+    interpreter told that the shape tiles) a layer's body launches
+    ``index_scores`` twice a step and selects once: the forward takes
+    thresholds and mask from one block of scores, the kernels' backward rule
+    makes the mask again from the thresholds (``reselect``), and nothing the
+    backward reads needs the forward's mask, so the replay drops the whole
+    selection; recomputed or not. The loss is the ``index_loss`` kernel there,
+    and the backward holds no call of it. The XLA attention (the tiny model's
+    heads of 32) keeps the mask as a residual of its own: unrecomputed it
+    selects once and has one pass, and its replay selects again."""
     _cfg, _ref, _sizes, pcfg = tiny
     _leaves, model, params = seeded
     assert set(sparse_select.SPARSE_RESIDUALS) <= set(transformer.KEPT_RESIDUALS)
     remat = moe.MoEDecoder(dataclasses.replace(pcfg, remat=True, remat_policy=policy))
     fn = jax.value_and_grad(lambda p: program_objective(remat, p, batch)[0])
     counted = count(jax.make_jaxpr(fn)(params).jaxpr)
-    assert counted["sparse_select"] == 1 and counted["index_scores"] == 3
-    assert counted["name:sparse_threshold"] == 1 and counted["name:sparse_index_grads"] == 3
+    assert counted["sparse_select"] == 2 and counted["index_scores"] == 2
+    assert counted["name:sparse_threshold"] == 0 and counted["name:sparse_index_grads"] == 3  # no reader of the thresholds there
     plain = count(jax.make_jaxpr(jax.value_and_grad(lambda p: program_objective(model, p, batch)[0]))(params).jaxpr)
-    assert plain["sparse_select"] == 1 and plain["index_scores"] == 2
+    assert plain["sparse_select"] == 1 and plain["index_scores"] == 1
     got, want = jax.jit(fn)(params), jax.jit(jax.value_and_grad(lambda p: program_objective(model, p, batch)[0]))(params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
 
-    cfg = transformer.DecoderConfig(
-        vocab_size=64, d_model=128, n_layers=2, n_heads=2, n_kv_heads=1, head_width=128, d_ff=64, max_seq_len=256,
-        dtype=jnp.float32, sparse_topk=64, index_heads=2, index_head_dim=16, remat=True, remat_policy=policy,
-    )
-    tokens = jnp.asarray(np.arange(256)[None] % 64, jnp.int32)
-    params = nn.meta.unbox(transformer.Decoder(cfg).init(jax.random.key(0), tokens)["params"])
-
-    def objective(cfg):
-        def fn(p):
-            logits, mods = transformer.Decoder(cfg).apply({"params": p}, tokens, mutable=["intermediates"])
-            return jnp.square(logits).mean() + trainer_mod.collect_aux_losses(mods)
-        return jax.value_and_grad(fn)
-
     monkeypatch.setattr(transformer, "flash_tileable", lambda *a: None)
-    counted = count(jax.make_jaxpr(objective(cfg))(params).jaxpr)  # the scan's body once forward, once backward
-    assert counted["index_loss"] == counted["flash_fwd"] == counted["flash_bwd"] == counted["sparse_select"] == 1
-    assert counted["index_scores"] == 3 and counted["name:sparse_index_grads"] == 3
-    got, want = objective(cfg)(params), objective(dataclasses.replace(cfg, remat=False))(params)
+    launches = dict(index_scores=2, sparse_select=1, index_loss=1, flash_fwd=1, flash_bwd=1)
+    (fn, params), (plain, _) = selecting_decoder(remat=True, remat_policy=policy), selecting_decoder(remat=False)
+    counted = count(jax.make_jaxpr(fn)(params).jaxpr)  # the scan's body once forward, once backward
+    assert kernels(counted) == launches
+    assert counted["name:sparse_threshold"] == 1 and counted["name:sparse_index_grads"] == 3
+    assert kernels(count(jax.make_jaxpr(plain)(params).jaxpr)) == launches
+    got, want = fn(params), plain(params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
@@ -509,6 +522,7 @@ def test_attention_dispatch_hands_the_selection_to_the_flash_kernels(monkeypatch
     kernel = [a for n, a in events if n == "attention.kernel"][-1]
     assert kernel["kernel"] == "flash" and kernel["selected"] == 64 and kernel["backward"] == "fused"
     assert kernel["index_loss"] == "kernel" and count(jaxpr.jaxpr)["index_loss"] == 1
+    assert kernel["index_passes"] == 2  # the forward's here, the backward's in the gradient below
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     # the indexer's loss reads the kernels' log-sum-exp there, and its own softmax on the XLA path
     objective = lambda p: sum(jax.tree.leaves(layer.apply(p, x, pos, mutable=["intermediates"])[1]["intermediates"]["index_aux_loss"]))
@@ -518,6 +532,7 @@ def test_attention_dispatch_hands_the_selection_to_the_flash_kernels(monkeypatch
         by_softmax = jax.value_and_grad(objective)(params)
     kernel = [a for n, a in events if n == "attention.kernel"][-1]
     assert kernel["kernel"] == "xla_dense" and kernel["selected"] == 64 and kernel["index_loss"] == "blockwise"
+    assert kernel["index_passes"] == 1
     np.testing.assert_allclose(with_lse[0], by_softmax[0], rtol=1e-5)
     for a, b in zip(jax.tree.leaves(with_lse[1]), jax.tree.leaves(by_softmax[1])):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-6 * float(jnp.abs(b).max()) + 1e-9)

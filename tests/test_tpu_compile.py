@@ -154,6 +154,45 @@ def test_selected_key_kernels_compile_at_the_keye_cells_shape(one_chip):
     assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
 
 
+def test_recomputed_selected_key_layer_compiles_two_index_passes(one_chip, monkeypatch):
+    """A recomputed ``Attention`` with keye-vl-2.0-30b-a3b's widths at its
+    cell's row (1 x 32,768, one document), its output's sum and the indexer's
+    loss and their gradient: the compiled program calls ``index_scores`` twice
+    (the forward's block of scores gives thresholds and mask; the flash
+    kernels' backward makes the mask again from the kept thresholds) and every
+    other kernel once: the replay neither selects nor scores. The dispatch
+    asks the backend for its kernels; here it is told "tpu"."""
+    from maggy_tpu.models.transformer import Attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = 32768
+    cfg = DecoderConfig(
+        d_model=2048, n_heads=32, n_kv_heads=4, head_width=128, qk_norm=True, rope_theta=1e7, max_seq_len=s,
+        sparse_topk=2048, index_heads=16, index_head_dim=64, partition_params=False,
+    )
+    layer = nn.remat(Attention, policy=REMAT_POLICIES["nothing"])(cfg)
+    x = jax.ShapeDtypeStruct((1, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.key(0), x, ids, ids),
+    )
+
+    def step(params, x, positions, segment_ids):
+        def objective(p, x):
+            out, mods = layer.apply(p, x, positions, segment_ids, mutable=["intermediates"])
+            return out.astype(jnp.float32).sum() + sum(jax.tree.leaves(mods["intermediates"]["index_aux_loss"]))
+
+        return jax.value_and_grad(objective, argnums=(0, 1))(params, x)
+
+    text = jax.jit(step).lower(params, x, ids, ids).compile().as_text()
+    calls = [line.split("=")[0] for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    names = ("index_scores", "sparse_select", "index_loss", "flash_fwd", "flash_bwd")
+    assert {name: sum(name in call for call in calls) for name in names} == dict(
+        index_scores=2, sparse_select=1, index_loss=1, flash_fwd=1, flash_bwd=1
+    ) and len(calls) == 6
+
+
 def test_index_loss_kernel_compiles_at_the_keye_cells_shape(one_chip):
     """``index_loss`` at the cell's row (1 x 32,768, 32 heads over 4 of 128, 16
     index heads of 64), under a selection's int8 mask and, as a row of at most
