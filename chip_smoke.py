@@ -68,6 +68,7 @@ class Sizes:
     index_heads: tuple  # (index heads, their width) of the selected-key case
     loss_case: tuple  # (seq, kv heads) of the indexer's loss: a row twice the keys a query keeps
     select_cases: tuple  # (seq, keys a query) at which one block of scores is held against two passes
+    window_cases: tuple  # (seq, window, head_dim, (q heads, ...) over kv heads) of the windowed flash kernels
     train_seq: int
     train_steps: int
     prompt_lens: tuple
@@ -99,6 +100,7 @@ def chip_sizes(n_chips: int, bytes_limit: int) -> Sizes:
         index_heads=(16, 64),
         loss_case=(4096, 4),
         select_cases=((4096, 2048), (32768, 2048)),  # the second is the keye-vl-2.0-30b-a3b cell's row
+        window_cases=((8192, 512, 128, (72, 48), 8),),  # the laguna-s-2.1 cell's row and both its head counts
         train_seq=2048,
         train_steps=6,
         prompt_lens=(6, 7, 24, 30, 100, 120, 400, 500),
@@ -124,6 +126,7 @@ def toy_sizes() -> Sizes:
         index_heads=(4, 16),
         loss_case=(256, 2),
         select_cases=((256, 64),),
+        window_cases=((256, 40, 128, (3, 2), 1),),
         train_seq=128,
         train_steps=4,
         prompt_lens=(3, 5, 9, 12, 20, 26, 40, 50),
@@ -180,7 +183,8 @@ def check(cond: bool, what: str) -> None:
 def phase_kernels(run: Run) -> dict:
     """ops/flash.py forward and backward, compiled by Mosaic, plain and with
     packed-document segment ids, against ``blockwise_attention`` in float32
-    under ``highest`` matmul precision."""
+    under ``highest`` matmul precision; then under a selection, and under a
+    sliding window against an explicit mask."""
     import jax
     import jax.numpy as jnp
 
@@ -419,6 +423,67 @@ def phase_kernels(run: Run) -> dict:
         check(int(off_thresholds) == int(off_mask) == int(off_count) == int(counts[2]) == 0,
               f"select {label}: one block of scores and two passes disagree")
         cases.append({"case": "select " + label, "selected": int(counts[0]), "visible": int(counts[1])})
+    # the flash kernels under a sliding window (``flash_attention(window=)``:
+    # a second bound of the visit table, a mask inside the tiles it leaves)
+    # against float32 attention under an explicit [S, S] mask a block of
+    # queries: forward, log-sum-exp, dq, dk, dv, on a packed row (a document
+    # shorter than the window among them) and on one document
+    def windowed_by_hand(q, k, v, w, seg, window):
+        b, s, hq, dw = q.shape
+        kv = k.shape[2]
+        rows = min(s, 512)
+        at, qf = jnp.arange(s), q.astype(f32).reshape(b, s, kv, hq // kv, dw)
+
+        def whole(qf, kf, vf):
+            @jax.checkpoint
+            def one(first):
+                mine = first + jnp.arange(rows)
+                ahead = mine[:, None] - at[None, :]
+                vis = (ahead >= 0) & (ahead < window)
+                vis = vis[None] & (jax.lax.dynamic_slice_in_dim(seg, first, rows, 1)[:, :, None] == seg[:, None, :])
+                sc = jnp.einsum("bqkgd,bskd->bkgqs", jax.lax.dynamic_slice_in_dim(qf, first, rows, 1), kf) / dw**0.5
+                sc = jnp.where(vis[:, None, None], sc, -jnp.inf)
+                lse = jax.nn.logsumexp(sc, axis=-1)
+                return jnp.einsum("bkgqs,bskd->bqkgd", jnp.exp(sc - lse[..., None]), vf), lse
+
+            o, lse = jax.lax.map(one, jnp.arange(s // rows) * rows)
+            o = jnp.moveaxis(o, 0, 1).reshape(b, s, hq, dw)
+            return (o * w).sum(), (o, jnp.moveaxis(lse, 0, 3).reshape(b, hq, s))
+
+        (_, (o, lse)), grads = jax.value_and_grad(whole, argnums=(0, 1, 2), has_aux=True)(qf, k.astype(f32), v.astype(f32))
+        return o, lse, grads[0].reshape(q.shape), grads[1], grads[2]
+
+    def windowed_flash(q, k, v, w, seg, window):
+        def loss(q, k, v):
+            o, lse = flash(q, k, v, causal=True, segment_ids=seg, window=window, return_lse=True)
+            return (o.astype(f32) * w).sum(), (o, lse)
+
+        (_, (o, lse)), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (o, lse, *grads)
+
+    for s, window, dw, q_heads, kv in run.sizes.window_cases:
+        for hq in q_heads:
+            keys = jax.random.split(jax.random.key(s + hq), 4)
+            q, k, v = (jax.random.normal(kk, (1, s, n, dw), jnp.bfloat16) for kk, n in zip(keys, (hq, kv, kv)))
+            w = jax.random.normal(keys[3], (1, s, hq, dw), f32)
+            pos = jnp.arange(s)
+            # five documents, boundaries off the tile grid, the third shorter than the window; then one document
+            cuts = (s // 5 + 3, s // 2 - 9, s // 2 - 9 + window // 2, (3 * s) // 4 + 11)
+            packed_row = 1 + sum((pos >= c).astype(jnp.int32) for c in cuts)[None]
+            for name, seg in (("packed", packed_row), ("one document", jnp.ones((1, s), jnp.int32))):
+                label = f"B=1 S={s} window {window}, {hq}/{kv} heads of {dw}, {name}"
+                t0 = time.perf_counter()
+                got = jax.jit(windowed_flash, static_argnums=5)(q, k, v, w, seg, window)
+                jax.block_until_ready(got)
+                setup_s += time.perf_counter() - t0
+                with jax.default_matmul_precision("highest"):
+                    want = jax.jit(windowed_by_hand, static_argnums=5)(q, k, v, w, seg, window)
+                rel, finite = compare(got, want)
+                check(bool(finite), f"windowed flash {label}: a value is not finite")
+                errs = dict(zip(("out", "lse", "dq", "dk", "dv"), (float(e) for e in rel)))
+                run.say(f"  windowed flash {label}: rel err " + " ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+                check(max(errs.values()) <= tol, f"windowed flash {label} off the float32 reference: {errs} > {tol}")
+                cases.append({"case": "windowed " + label, **errs})
     return {
         "setup_s": setup_s, "heads": [h, kh, d], "tolerance": tol, "cases": cases,
         "compiled": not interpret,

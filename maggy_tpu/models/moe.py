@@ -71,7 +71,8 @@ class MoEConfig(DecoderConfig):
     # how the share form's router scores the experts: "sigmoid"
     # (``sigmoid_route``: DeepSeek-V3's, with the selection bias) or "softmax"
     # (``softmax_route``: ``qwen3_moe``'s, the chosen probabilities
-    # renormalised over themselves; no bias, no scaling)
+    # renormalised over themselves and times ``routed_scaling``; no bias).
+    # Either takes a shared expert beside it (``n_shared_experts``)
     router: str = "sigmoid"
     # a chunk of the share layer's buffer as a fraction of the expected load
     # ``T * top_k * experts_held / n_experts`` (``chunk_rows``); 0: an eighth
@@ -105,8 +106,8 @@ class MoEConfig(DecoderConfig):
                 raise ValueError("the share form has no LOCO gates")
             if self.router not in ("sigmoid", "softmax"):
                 raise ValueError("router is 'sigmoid' or 'softmax'")
-            if self.router == "softmax" and (self.select_bias_std or self.routed_scaling != 1.0):
-                raise ValueError("the softmax router takes no selection bias and no scaling")
+            if self.router == "softmax" and self.select_bias_std:
+                raise ValueError("the softmax router takes no selection bias")
         if self.chunk_of_load < 0 or (self.chunk_of_load and not self.experts_held):
             raise ValueError("chunk_of_load is a fraction of the share form's expected load")
         if not 0 <= self.n_dense_layers < self.n_layers:
@@ -474,20 +475,25 @@ def sigmoid_route(logits, select_bias, top_k: int, scaling: float, norm_eps: flo
     return sel, scaling * chosen / (chosen.sum(-1, keepdims=True) + norm_eps)
 
 
-def softmax_route(logits, top_k: int):
+def softmax_route(logits, top_k: int, scaling: float = 1.0):
     """``qwen3_moe``'s router (``norm_topk_prob``) from float32 logits
     [..., n_experts]: ``(sel [..., k] expert numbers, weights [..., k])`` with
     ``p = softmax(logits)`` over all the experts, ``sel = top_k(p)`` and the
-    chosen probabilities divided by their sum."""
+    chosen probabilities divided by their sum, times ``scaling`` where the
+    model scales its routed sum (``moe_routed_scaling_factor``)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     _, sel = jax.lax.top_k(jax.lax.stop_gradient(probs), top_k)
     chosen = jnp.take_along_axis(probs, sel, axis=-1)
-    return sel, chosen / chosen.sum(-1, keepdims=True)
+    weights = chosen / chosen.sum(-1, keepdims=True)
+    # no multiply by 1.0: an unscaled route's program stays the parent's (``transformer.rope``'s note)
+    return sel, weights if scaling == 1.0 else scaling * weights
 
 
 class ExpertShareBlock(nn.Module):
     """One chip's share of a sigmoid-routed expert layer, dropless
-    (``router="softmax"``: of a softmax-routed one, ``softmax_route``).
+    (``router="softmax"``: of a softmax-routed one, ``softmax_route``, with
+    ``routed_scaling`` on the renormalised probabilities and the shared
+    expert beside them where the model has one).
 
     The router scores all ``n_experts`` in float32: ``s = sigmoid(x W_r)``,
     ``sel = top_k(s + b)`` (``b`` the selection bias: no gradient),
@@ -524,7 +530,7 @@ class ExpertShareBlock(nn.Module):
                 name="router",
             )(tokens.astype(jnp.float32))
             if cfg.router == "softmax":
-                sel, weights = softmax_route(logits, k)
+                sel, weights = softmax_route(logits, k, cfg.routed_scaling)
             else:
                 sel, weights = sigmoid_route(
                     logits, select_bias, k, cfg.routed_scaling, cfg.route_norm_eps
@@ -633,7 +639,9 @@ class _ScannedMoELayer(nn.Module):
 class _ScannedPeriod(nn.Module):
     """Scan body of a stack whose layers' operators differ: one period of the
     pattern, ``layer_<j>`` of ``kinds[j]``, each recomputed on its own (the
-    flash kernel's kept results matter in the attention layers only).
+    flash kernel's kept results matter in the attention layers only). The
+    layers of a period are modules of their own, so they may differ in their
+    leaves' shapes too (a sliding layer's query heads against a full one's).
     ``per_layer`` arrives with a leading axis over the period's layers."""
 
     cfg: MoEConfig

@@ -70,7 +70,7 @@ REMAT_POLICIES = {
 }
 
 
-LAYER_KINDS = ("full_attention", "conv")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
 
 
 def _parse_ablated(ablated, n_layers: int):
@@ -201,6 +201,26 @@ class DecoderConfig:
     sparse_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    # a "sliding_attention" layer (``layer_types``; :class:`Attention` only):
+    # a query sees the ``sliding_window`` keys up to its own (``t - s <
+    # sliding_window``, inside its document), through ``sliding_heads`` query
+    # heads over the same ``n_kv_heads`` (0: ``n_heads``), under the default
+    # rotary embedding on the whole head at ``sliding_rope_theta`` (0:
+    # ``rope_theta``). Training and scoring only: the page allocator does not
+    # release what a window leaves behind yet (ROADMAP M2)
+    sliding_window: int = 0
+    sliding_heads: int = 0
+    sliding_rope_theta: float = 0.0
+    # the rotary form of a "full_attention" layer: ``rope_share`` of the head's
+    # width is rotated (its first dimensions; the rest pass as they are), and
+    # ``rope_yarn`` = (factor, original positions, beta_fast, beta_slow,
+    # attention factor) scales the frequencies as YaRN does and cos and sin by
+    # the attention factor (``yarn_inv_freq``); (): the default form
+    rope_share: float = 1.0
+    rope_yarn: tuple = ()
+    # a sigmoid gate a head and token on the heads' outputs before ``wo``, from
+    # a bias-free projection of the layer's normed input (:class:`Attention`)
+    attn_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -211,6 +231,28 @@ class DecoderConfig:
     def layer_kinds(self) -> tuple:
         """The operator of every layer, ``n_layers`` names."""
         return self.layer_types or ("full_attention",) * self.n_layers
+
+    def attention_form(self, kind: str) -> tuple:
+        """``(query heads, window, rotary)`` of an attention layer of ``kind``;
+        ``rotary`` = (base, rotated width, YaRN's constants or (), scale of cos
+        and sin) as :func:`rope` takes them. Without the fields above every
+        layer reads ``(n_heads, 0, (rope_theta, head_dim, (), 1.0))``."""
+        if kind == "sliding_attention":
+            return (
+                self.sliding_heads or self.n_heads, self.sliding_window,
+                (self.sliding_rope_theta or self.rope_theta, self.head_dim, (), 1.0),
+            )
+        yarn = tuple(self.rope_yarn)
+        return (
+            self.n_heads, 0,
+            (self.rope_theta, int(self.head_dim * self.rope_share), yarn[:4], yarn[4] if yarn else 1.0),
+        )
+
+    def attention_windows(self) -> tuple:
+        """The window of every attention layer (0: none), in order."""
+        return tuple(
+            self.attention_form(kind)[1] for kind in self.layer_kinds() if kind != "conv"
+        )
 
     def __post_init__(self):
         if self.kv_lora_rank:
@@ -226,11 +268,12 @@ class DecoderConfig:
                 )
             if self.decode:
                 raise ValueError("latent attention has no decode cache yet")
-        elif self.d_model % self.n_heads:
-            raise ValueError("d_model must be divisible by n_heads")
-        if self.n_heads % self.n_kv_heads:
-            raise ValueError("n_heads must be divisible by n_kv_heads")
+        elif not self.head_width and self.d_model % self.n_heads:
+            raise ValueError("d_model must be divisible by n_heads (or give head_width)")
+        if self.n_heads % self.n_kv_heads or self.sliding_heads % self.n_kv_heads:
+            raise ValueError("n_heads (and sliding_heads) must be divisible by n_kv_heads")
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "rope_yarn", tuple(self.rope_yarn))
         if self.layer_types:
             if len(self.layer_types) != self.n_layers or set(self.layer_types) - set(LAYER_KINDS):
                 raise ValueError(
@@ -241,6 +284,26 @@ class DecoderConfig:
                     "a conv layer has no decode state yet (the convolution's tail "
                     "beside the KV cache): this model trains and scores, it does not serve"
                 )
+            if "sliding_attention" in self.layer_types:
+                if self.sliding_window < 1:
+                    raise ValueError("a sliding_attention layer needs sliding_window")
+                if self.kv_lora_rank or self.sparse_topk or self.attention_fn is not None:
+                    raise ValueError("a window is Attention's, through the automatic dispatch")
+                if self.decode:
+                    raise ValueError(
+                        "a sliding_attention layer has a training form only: the page "
+                        "allocator keeps every page of a row, so decode=True with a window "
+                        "would hold what the window has left behind (ROADMAP M2)"
+                    )
+        if self.rope_yarn and len(self.rope_yarn) != 5:
+            raise ValueError(
+                "rope_yarn is (factor, original positions, beta_fast, beta_slow, attention factor)"
+            )
+        rotated = self.head_dim * self.rope_share
+        if self.rope_share != 1.0 and (not 0 < self.rope_share < 1 or rotated != int(rotated) or int(rotated) % 2):
+            raise ValueError("rope_share of the head's width is an even number of dimensions")
+        if (self.rope_share != 1.0 or self.rope_yarn) and self.kv_lora_rank:
+            raise ValueError("latent attention rotates its own narrow part: no rope_share, no rope_yarn")
         if self.remat_policy not in REMAT_POLICIES:
             raise ValueError(
                 f"remat_policy must be one of {sorted(REMAT_POLICIES)}"
@@ -387,20 +450,68 @@ class LayerNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + cfg.norm_eps) * scale + bias).astype(cfg.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_inv_freq(theta: float, width: int, factor: float, original: int, beta_fast: float, beta_slow: float):
+    """YaRN's frequency table for a rotated width of ``width`` (float32
+    [width / 2]): ``f_i = theta ** (-2i / width)``; the dimension whose
+    wavelength turns ``r`` times in ``original`` positions is ``c(r) = width
+    ln(original / (2 pi r)) / (2 ln theta)``; ``lo = floor(c(beta_fast))``,
+    ``hi = ceil(c(beta_slow))`` (truncated, clipped to the table);
+    ``m_i = 1 - clip((i - lo) / (hi - lo), 0, 1)`` and
+    ``inv_freq_i = (1 - m_i) f_i / factor + m_i f_i``: fast dimensions keep
+    their frequency, slow ones are interpolated by ``factor``."""
+    import math
+
+    def turns(r):
+        return width * math.log(original / (r * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(turns(beta_fast)), 0)
+    hi = min(math.ceil(turns(beta_slow)), width - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    half = width // 2
+    f = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    m = 1.0 - jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo) / (hi - lo), 0.0, 1.0)
+    return (1.0 - m) * f / factor + m * f
+
+
+def rope(
+    x: jax.Array, positions: jax.Array, theta: float, *, width: int = 0, yarn: tuple = (), scale: float = 1.0,
+) -> jax.Array:
     """Rotary position embedding over the last dim of [B, S, H, D] arrays.
 
     fp32 internally: sin/cos of large position*inv_freq products lose too much
-    precision in bf16.
+    precision in bf16. ``width`` (0: all of D) is the rotary width: the first
+    ``width`` dimensions are rotated, halves of them paired, and the others
+    pass as they are. ``yarn`` = (factor, original positions, beta_fast,
+    beta_slow) takes the frequencies from :func:`yarn_inv_freq` and not from
+    ``theta ** (-2i / width)``; ``scale`` multiplies cos and sin (YaRN's
+    attention factor: the rotated part of a query-key product grows by its
+    square, the part that passes does not). Table and scale are constants of
+    the trace, float32.
     """
-    half = x.shape[-1] // 2
-    freq = jnp.arange(half, dtype=jnp.float32) / half
-    inv_freq = theta ** (-freq)
+    d = x.shape[-1]
+    width = width or d
+    half = width // 2
+    if yarn:
+        inv_freq = yarn_inv_freq(theta, width, *yarn)
+    else:
+        freq = jnp.arange(half, dtype=jnp.float32) / half
+        inv_freq = theta ** (-freq)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, S, half]
     angles = angles[:, :, None, :]  # broadcast over heads
     sin, cos = jnp.sin(angles), jnp.cos(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
+    x32 = x.astype(jnp.float32)
+    # the forks here (``scale != 1.0``, ``width == d``), ``Attention``'s empty ``rotary`` and
+    # ``softmax_route``'s ``scaling == 1.0`` keep the whole-head unscaled form's operations as they
+    # were: the four older cells' steps then compile to the parent's programs (equal hashes from
+    # ``benchmark/tools/compile_step.py``: PR 37's acceptance test), which one general form would not
+    if width == d:
+        x1, x2 = jnp.split(x32, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    else:
+        x1, x2, rest = x32[..., :half], x32[..., half:width], x32[..., width:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -414,7 +525,9 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
     run on one v5e, where 1,024 x 1,024 tiles do not fit VMEM and
     ``_auto_blocks`` chooses others) have run; wider heads have not. Width 64
     (PR 30: 32 query heads over 8 key-value heads, B 4, S 8,192) runs the same
-    kernels with half-filled lanes (``ops/flash.py`` ``lane_fill``)."""
+    kernels with half-filled lanes (``ops/flash.py`` ``lane_fill``). A window
+    changes nothing here: it is a mask inside a tile and a bound of the visit
+    table, at every width the kernels take and at any tile size."""
     if jax.default_backend() != "tpu":
         return f"backend is {jax.default_backend()}"
     if lane_fill(d) is None:
@@ -424,7 +537,7 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
     return None
 
 
-def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", selected: int = 0):
+def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", selected: int = 0, window: int = 0):
     """Journal which kernel the automatic dispatch chose for this shape as
     one ``attention.kernel`` event; for the flash kernels also the tiles they
     run at (forward q, k, backward q, k), and always the head width with, for
@@ -439,12 +552,14 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
     layer and training step as this call builds them: the forward's, from
     which thresholds and mask both come, and one more where the kernels'
     backward makes the mask again (``reselect``: 2); the XLA attention keeps
-    the mask (1, and what a recomputed layer's replay runs again).
+    the mask (1, and what a recomputed layer's replay runs again), and
+    ``window``, the keys up to its own that a query of a sliding layer sees
+    (0: every causal key).
     The dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
 
-    attrs = {"head_dim": int(q.shape[3])}
+    attrs = {"head_dim": int(q.shape[3]), "window": int(window)}
     if selected:
         attrs["selected"] = int(selected)
         attrs["index_loss"] = "kernel" if kernel == "flash" else "blockwise"
@@ -467,7 +582,7 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
 
 def auto_attention(
     q, k, v, *, causal: bool = True, segment_ids=None, selected=None, reselect=None, topk: int = 0,
-    return_lse: bool = False,
+    return_lse: bool = False, window: int = 0,
 ):
     """Pick the fastest correct kernel for the backend/shape: the Pallas flash
     kernel (fwd+bwd) on TPU when the geometry tiles onto the MXU
@@ -497,34 +612,40 @@ def auto_attention(
     a tile of it as an operand, and their backward makes it again by
     ``reselect`` where the XLA path keeps the array. ``return_lse``: ``(out, lse)`` with the rows'
     log-sum-exp [B, H, Sq] where the one-chip kernels ran, which keep it
-    anyway, and None on every other path (the caller normalises by itself)."""
+    anyway, and None on every other path (the caller normalises by itself).
+    ``window`` (a sliding layer's; 0: none) masks the same pairs on every
+    path: ``t - s < window`` beside the causal and the segment masks, and in
+    the kernels the visit table's second bound (``ops/flash.py``)."""
     from maggy_tpu.parallel.mesh import ambient_mesh
 
-    sel = {} if selected is None else {"selected": selected}
+    masks = {} if selected is None else {"selected": selected}  # beside the causal and the segment masks
+    if window:
+        masks["window"] = window
     why = flash_tileable(q.shape[1], k.shape[1], q.shape[3])
     if why is None:
         mesh = ambient_mesh()
         if mesh is None or mesh.size == 1:
-            record_attention_kernel("flash", q, k, segment_ids, selected=topk)
+            record_attention_kernel("flash", q, k, segment_ids, selected=topk, window=window)
             return flash_attention(
-                q, k, v, causal=causal, segment_ids=segment_ids, return_lse=return_lse, reselect=reselect, **sel
+                q, k, v, causal=causal, segment_ids=segment_ids, return_lse=return_lse, reselect=reselect, **masks
             )
         out = sharded_flash_attention(
-            q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids, reselect=reselect, **sel
+            q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids, reselect=reselect, **masks
         )
         if out is not None:
-            record_attention_kernel("flash_sharded", q, k, segment_ids, selected=topk)
+            record_attention_kernel("flash_sharded", q, k, segment_ids, selected=topk, window=window)
             return (out, None) if return_lse else out
         why = f"mesh {dict(mesh.shape)} does not divide batch/heads or uses seq/stage axes"
-    record_attention_kernel("xla_dense", q, k, segment_ids, why, selected=topk)
-    out = default_attention(q, k, v, causal=causal, segment_ids=segment_ids, **sel)
+    record_attention_kernel("xla_dense", q, k, segment_ids, why, selected=topk, window=window)
+    out = default_attention(q, k, v, causal=causal, segment_ids=segment_ids, **masks)
     return (out, None) if return_lse else out
 
 
-def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selected=None):
+def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selected=None, window: int = 0):
     """Reference soft-max attention: q [B,S,H,D], k/v [B,S,Kh,D] with GQA
     head-group broadcast. fp32 logits/softmax for stability. ``selected``
-    [B, Sq, Sk]: the pairs a selection keeps (nonzero)."""
+    [B, Sq, Sk]: the pairs a selection keeps (nonzero). ``window`` (with
+    ``causal``): a query sees the ``window`` keys up to its own."""
     b, sq, h, d = q.shape
     kh = k.shape[2]
     group = h // kh
@@ -534,6 +655,8 @@ def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selecte
     if causal:
         sk = k.shape[1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool))
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((sq, sk), dtype=bool), -window)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
     if segment_ids is not None:
         seg_mask = segment_ids[:, None, None, :, None] == segment_ids[:, None, None, None, :]
@@ -546,27 +669,48 @@ def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selecte
 
 
 class Attention(nn.Module):
+    """Grouped-query attention of a layer of ``kind`` (``LAYER_KINDS``): the
+    kind gives the query heads, the window and the rotary form
+    (``DecoderConfig.attention_form``); a "sliding_attention" layer sows
+    ``window_pairs`` ([2]: the pairs inside window, document and causal order,
+    the causal pairs inside documents) for the trainer's step metrics."""
+
     cfg: DecoderConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
         cfg = self.cfg
         hd = cfg.head_dim
-        q = _dense((cfg.n_heads, hd), ("embed", "heads", None), cfg, "wq")(x)
+        n_heads, window, (theta, width, yarn, scale) = cfg.attention_form(self.kind)
+        rotary = {} if (width, yarn, scale) == (hd, (), 1.0) else dict(width=width, yarn=yarn, scale=scale)
+        q = _dense((n_heads, hd), ("embed", "heads", None), cfg, "wq")(x)
         k = _dense((cfg.n_kv_heads, hd), ("embed", "kv", None), cfg, "wk")(x)
         v = _dense((cfg.n_kv_heads, hd), ("embed", "kv", None), cfg, "wv")(x)
         if cfg.qk_norm:  # over the head's width, one scale for all heads
             q = RMSNorm(cfg, name="q_norm")(q)
             k = RMSNorm(cfg, name="k_norm")(k)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, theta, **rotary)
+        k = rope(k, positions, theta, **rotary)
         if cfg.decode:
             out = self._cached_attention(q, k, v, positions, segment_ids)
         elif cfg.sparse_topk:
             out = self._selected_attention(x, q, k, v, positions, segment_ids)
+        elif window:
+            out = auto_attention(q, k, v, causal=True, segment_ids=segment_ids, window=window)
+            # a query at position p of its document sees min(p + 1, window) of its p + 1 causal keys
+            seen = (positions.astype(jnp.float32) + 1.0) * (1.0 if segment_ids is None else segment_ids > 0)
+            self.sow(
+                "intermediates", "window_pairs",
+                jnp.stack([jnp.minimum(seen, float(window)).sum(), seen.sum()]),
+            )
         else:
             attn = cfg.attention_fn or auto_attention
             out = attn(q, k, v, causal=True, segment_ids=segment_ids)
+        if cfg.attn_gate:
+            with jax.named_scope("attn.gate"):  # one scalar a head and token, on the head's output before wo
+                gate = _dense(n_heads, ("embed", "heads"), cfg, "w_head_gate")(x)
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]).astype(cfg.dtype)
         out = nn.DenseGeneral(
             features=cfg.d_model,
             axis=(-2, -1),
@@ -1000,7 +1144,8 @@ def layer_operator(cfg: DecoderConfig, kind: str, x, positions, segment_ids):
     Called inside the layer's ``nn.compact`` method."""
     if kind == "conv":
         return ShortConv(cfg, name="conv")(RMSNorm(cfg, name="conv_norm")(x), positions, segment_ids)
-    return attention_module(cfg)(cfg, name="attn")(
+    of_kind = {} if kind == "full_attention" else {"kind": kind}
+    return attention_module(cfg)(cfg, name="attn", **of_kind)(
         RMSNorm(cfg, name="attn_norm")(x), positions, segment_ids
     )
 

@@ -33,7 +33,9 @@ visiting every tile), and the index maps of the reduction-side operands name
 the nearer end outside it, which is the block already in VMEM, so a step that
 computes nothing copies nothing either. One compiled step serves every
 packing. Calls with no segment ids get the same table from the diagonal
-alone (one row of ``n_outer`` bounds).
+alone (one row of ``n_outer`` bounds). A sliding window (``window``, static)
+is a second bound of the same table: the tiles it masks wholly are dropped
+from first..last at either end, whichever axis is outermost.
 
 Off-TPU the kernels run under the Pallas interpreter so tests run on CPU
 meshes, and shapes that do not tile evenly fall back to
@@ -98,16 +100,21 @@ _MASKED_COMPILER_PARAMS = pltpu.CompilerParams(
 )
 
 
-def _tile_mask(q_start, k_start, block_q, block_k):
+def _tile_mask(q_start, k_start, block_q, block_k, window=0):
+    """The causal pairs of a tile; with a ``window`` those of them whose key
+    lies fewer than ``window`` positions before the query (its own counts)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return (q_start + rows) >= (k_start + cols)
+    ahead = (q_start + rows) - (k_start + cols)
+    if window:
+        return (ahead >= 0) & (ahead < window)
+    return ahead >= 0
 
 
 # ------------------------------------------------------------- tile-visit table
 
 
-def needed_tiles(segs, *, causal, sq, sk, block_q, block_k, selected=None):
+def needed_tiles(segs, *, causal, sq, sk, block_q, block_k, selected=None, window=0):
     """bool [rows, sq // block_q, sk // block_k]: the tiles that can hold an
     unmasked pair. A tile is needed when it is not wholly above the causal
     diagonal and the two blocks' ranges of segment ids (minimum and maximum
@@ -117,12 +124,23 @@ def needed_tiles(segs, *, causal, sq, sk, block_q, block_k, selected=None):
     is ordered last. ``segs`` is [B, 1, S] (numpy on the host, or traced) or
     None, which gives one row that every batch row shares. ``selected``
     ([B, sq, sk], traced: the pairs a selection keeps, nonzero) drops the
-    tiles that hold no selected pair, a row of the table a batch row."""
+    tiles that hold no selected pair, a row of the table a batch row. A
+    ``window`` (static, causal calls only; 0: none) is a second bound on the
+    same table: a tile whose last key lies ``window`` positions or more before
+    its first query is wholly outside it and not needed, so a query block's
+    first needed key block is the later of its documents' first and the block
+    of ``q_start - window + 1``, and in the key-major visit a key block's last
+    needed query block the earlier of its documents' last and the block of
+    ``k_last + window - 1``."""
     nq, nk = sq // block_q, sk // block_k
     need = np.ones((1, nq, nk), bool)
     if causal:
         q_last = np.arange(nq)[:, None] * block_q + block_q - 1
         need = (np.arange(nk)[None, :] * block_k <= q_last)[None]
+    if window:
+        q_first = np.arange(nq)[:, None] * block_q
+        k_last = np.arange(nk)[None, :] * block_k + block_k - 1
+        need = need & (q_first - k_last < window)[None]
     if selected is not None:
         need = need & (selected.reshape(-1, nq, block_q, nk, block_k) != 0).any(axis=(2, 4))
     if segs is None:
@@ -142,8 +160,10 @@ def visit_bounds(segs, outer, **tiles):
     """int32 [rows * outer blocks * 2], flat for SMEM: the first and the last
     needed reduction block of every (row, outer block), q blocks outermost
     (``outer="q"``: forward, dq) or k blocks (``"k"``: the fused backward,
-    dkv); ``tiles`` as ``needed_tiles`` takes them. The kernels visit
-    first..last and nothing else; a row with no needed block reads (0, -1)."""
+    dkv); ``tiles`` as ``needed_tiles`` takes them, a ``window`` among them:
+    the tiles it leaves needed are a run of blocks in either order, so first
+    and last still say all. The kernels visit first..last and nothing else; a
+    row with no needed block reads (0, -1)."""
     need = needed_tiles(segs, **tiles)
     if outer == "k":
         need = need.swapaxes(1, 2)
@@ -179,11 +199,12 @@ def _resident(bounds_ref, row, outer, n_outer, red):
     return jnp.clip(red, first, jnp.maximum(last, first))
 
 
-def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None, head_dim=128):
+def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None, head_dim=128, window=0):
     """Of the tiles in the forward kernel's grid for a packed host batch
     (``segment_ids`` [B, S], numpy), the share the kernel visits, at the
     tile sizes chosen automatically for heads of ``head_dim`` unless others
-    are given. None where the tiles do not divide S."""
+    are given, under a ``window`` where the layer has one. None where the
+    tiles do not divide S."""
     seg = np.asarray(segment_ids)
     s = seg.shape[-1]
     auto = _auto_blocks(s, s, True, head_dim)
@@ -192,7 +213,7 @@ def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None,
         return None
     first, last = visit_bounds(
         seg.reshape(-1, 1, s), "q", causal=causal, sq=s, sk=s,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, window=window,
     ).reshape(-1, 2).T
     return float((last - first + 1).sum()) / (len(first) * (s // block_k))
 
@@ -217,7 +238,7 @@ def _selected(sel_ref):
 
 def _fwd_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
 ):
     (q_ref, k_ref, v_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(refs, 3, segmented, masked)
     o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
@@ -243,7 +264,7 @@ def _fwd_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        mask = _tile_mask(q_start, k_start, block_q, block_k) if causal else None
+        mask = _tile_mask(q_start, k_start, block_q, block_k, window) if causal else None
         if segmented:
             smask = qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
             mask = smask if mask is None else (mask & smask)
@@ -324,7 +345,7 @@ def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer, masked=
 
 def _fwd_call(
     q, k, v, segs, bounds, sel=None,
-    *, causal, block_q, block_k, group, heads, interpret,
+    *, causal, block_q, block_k, group, heads, interpret, window=0,
 ):
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -348,6 +369,7 @@ def _fwd_call(
             segmented=segmented,
             heads=heads,
             masked=masked,
+            window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -374,18 +396,18 @@ def _fwd_call(
 
 
 def _recompute_p_ds(
-    q, k, v, o, do, lse, *, scale, causal, q_start, k_start, qseg=None, kseg=None, sel=None
+    q, k, v, o, do, lse, *, scale, causal, q_start, k_start, qseg=None, kseg=None, sel=None, window=0
 ):
     """Shared tile math: probabilities from the saved LSE, then
     dS = P * (dP - delta) * scale with delta recomputed from the O/dO tiles.
-    The full forward mask (causal AND segments) must be re-applied — exp(s -
-    lse) is not zero for positions the forward masked out."""
+    The full forward mask (causal, window AND segments) must be re-applied —
+    exp(s - lse) is not zero for positions the forward masked out."""
     block_q, block_k = q.shape[0], k.shape[0]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     p = jnp.exp(s - lse)  # lse [block_q, 1]
-    mask = _tile_mask(q_start, k_start, block_q, block_k) if causal else None
+    mask = _tile_mask(q_start, k_start, block_q, block_k, window) if causal else None
     if qseg is not None:
         smask = qseg[:, None] == kseg[None, :]
         mask = smask if mask is None else (mask & smask)
@@ -405,7 +427,7 @@ def _recompute_p_ds(
 
 def _dq_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
 ):
     (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
         refs, 6, segmented, masked
@@ -428,7 +450,7 @@ def _dq_kernel(
             q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
-            sel=_selected(sel_ref) if masked else None,
+            sel=_selected(sel_ref) if masked else None, window=window,
         )
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
@@ -442,7 +464,7 @@ def _dq_kernel(
 
 def _dkv_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
 ):
     (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
         refs, 6, segmented, masked
@@ -469,7 +491,7 @@ def _dkv_kernel(
             q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
-            sel=_selected(sel_ref) if masked else None,
+            sel=_selected(sel_ref) if masked else None, window=window,
         )
         # dV += P^T dO ; dK += dS^T Q — contract the q dim of both operands
         dv_acc_ref[:] += jax.lax.dot_general(
@@ -489,7 +511,7 @@ def _dkv_kernel(
 
 def _bwd_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
 ):
     """dq, dk and dv from one visit of a tile: the grid is ``_dkv_kernel``'s
     (k blocks outer, q blocks inner, dk and dv accumulated across the inner
@@ -527,7 +549,7 @@ def _bwd_kernel(
             q_start=qi * block_q, k_start=ki * block_k,
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
-            sel=_selected(sel_ref) if masked else None,
+            sel=_selected(sel_ref) if masked else None, window=window,
         )
         ds = ds.astype(q.dtype)
         dv_acc_ref[:] += jax.lax.dot_general(
@@ -604,7 +626,7 @@ def _fused_vmem_bytes(sq, d, block_q, block_k, itemsize, masked=False):
 def _bwd_pallas(
     kernel, name, outer, grid, outs, scratch_shapes, compiler_params,
     q, k, v, o, do, lse, segs, bounds, sel=None,
-    *, causal, block_q, block_k, group, heads, interpret,
+    *, causal, block_q, block_k, group, heads, interpret, window=0,
 ):
     """One backward kernel over ``grid`` with q rows (``outer="q"``) or k rows
     (``"k"``) outermost; ``outs`` pairs each result's BlockSpec (a name of
@@ -623,7 +645,7 @@ def _bwd_pallas(
     return pl.pallas_call(
         functools.partial(
             kernel, scale=1.0 / d**0.5, causal=causal, block_q=block_q,
-            block_k=block_k, segmented=segmented, heads=heads, masked=masked,
+            block_k=block_k, segmented=segmented, heads=heads, masked=masked, window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -717,6 +739,7 @@ def _flash_core(
     causal: bool, block_q: int, block_k: int, bwd_block_q: int,
     bwd_block_k: int, group: int, heads: int, interpret: bool,
     segmented: bool, masked: bool = False, with_lse: bool = False, reselects: bool = False,
+    window: int = 0,
 ):
     """Differentiable flash attention on q [B*H, S, D], k/v [B*Kh, S, D]
     (GQA group = H // Kh handled by kernel index maps — the repeated K/V
@@ -727,7 +750,9 @@ def _flash_core(
     selection is no function of the scores it masks), and with ``reselects``
     a sixth, what gives that selection again when called
     (``flash_attention``'s ``reselect``): the backward calls it, and the
-    mask is no residual. ``with_lse``: the
+    mask is no residual. ``window`` (static): the causal mask keeps a key
+    fewer than ``window`` positions before its query only, in every kernel
+    and in the visit tables. ``with_lse``: the
     result is ``(o, lse)``, the rows' log-sum-exp as the backward keeps it
     (``[BH, S / 128, 128]`` float32, a constant to whoever reads it: its
     cotangent is dropped). Each kernel gets its visit bounds, computed
@@ -740,11 +765,11 @@ def _flash_core(
     sweeps both on the chip)."""
 
     kw = dict(causal=causal, block_q=block_q, block_k=block_k, group=group,
-              heads=heads, interpret=interpret)
+              heads=heads, interpret=interpret, window=window)
     bwd_kw = dict(kw, block_q=bwd_block_q, block_k=bwd_block_k)
 
     def bounds(q, k, segs, sel, block_q, block_k, outer):
-        tiles = dict(causal=causal, sq=q.shape[1], sk=k.shape[1], block_q=block_q, block_k=block_k)
+        tiles = dict(causal=causal, sq=q.shape[1], sk=k.shape[1], block_q=block_q, block_k=block_k, window=window)
         if sel:
             tiles["selected"] = sel[0]
         return jnp.asarray(visit_bounds(segs if segmented else None, outer, **tiles))
@@ -890,7 +915,7 @@ def _untileable(sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "block_q", "block_k", "bwd_block_q", "bwd_block_k", "interpret", "return_lse",
+        "causal", "block_q", "block_k", "bwd_block_q", "bwd_block_k", "interpret", "return_lse", "window",
     ),
 )
 def flash_attention(
@@ -908,6 +933,7 @@ def flash_attention(
     selected=None,
     reselect=None,
     return_lse: bool = False,
+    window: int = 0,
 ) -> jax.Array:
     """q [B,S,H,D], k/v [B,S,Kh,D] → [B,S,H,D]. Differentiable (custom VJP).
     The four tile sizes default to the measured-fastest tiling for the
@@ -928,7 +954,14 @@ def flash_attention(
     recomputed layer's replay would have to make once more. ``return_lse``:
     ``(out, lse)`` with the rows' log-sum-exp over the pairs kept, [B, H, Sq]
     float32 (+inf on a row that keeps none), as a constant; from the kernels
-    only (a shape that falls back raises).
+    only (a shape that falls back raises). ``window`` (static, with
+    ``causal``; 0: none): a query at row position ``t`` sees the keys at
+    ``s <= t`` with ``t - s < window``, its own among them, inside its
+    document where there are segment ids: a pair counts where all the masks
+    keep it, exactly, in the output, the log-sum-exp and the three gradients,
+    and a tile wholly outside the window is not visited, in the forward's
+    query-major and the backward's key-major order alike (``needed_tiles``).
+    ``window=0`` is the call without one, bit for bit.
 
     ``interpret`` defaults to the Pallas interpreter off-TPU and the compiled
     kernel on a TPU. Interpreted, a shape that does not tile falls back to
@@ -953,8 +986,10 @@ def flash_attention(
         sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
         segmented, compiled=not interpret,
     )
+    if window and not causal:
+        raise ValueError("a window bounds a causal call: keys before the query, its own among them")
     if why is not None:
-        if not interpret or selected is not None or return_lse:
+        if not interpret or selected is not None or return_lse or window:
             raise ValueError(
                 f"flash_attention cannot compile for q{q.shape} k{k.shape}: "
                 f"{why}. Pad the sequence, pass tiles that fit, or call "
@@ -978,7 +1013,7 @@ def flash_attention(
         sel += (reselect,)
     out = _flash_core(
         causal, block_q, block_k, bwd_block_q, bwd_block_k, h // kh, h,
-        interpret, segmented, selected is not None, return_lse, len(sel) == 2,
+        interpret, segmented, selected is not None, return_lse, len(sel) == 2, int(window),
     )(qr, kr, vr, segs, *sel)
     if return_lse:
         out, lse = out
@@ -997,6 +1032,7 @@ def sharded_flash_attention(
     segment_ids: Optional[jax.Array] = None,
     selected: Optional[jax.Array] = None,
     reselect=None,
+    window: int = 0,
 ):
     """Run the Pallas kernel per-shard under ``shard_map`` over ``mesh``.
 
@@ -1032,7 +1068,7 @@ def sharded_flash_attention(
         return None
     batch = (AXIS_DATA, AXIS_FSDP)
     spec = P(batch, None, AXIS_TENSOR, None)
-    fn = functools.partial(flash_attention, causal=causal, interpret=interpret)
+    fn = functools.partial(flash_attention, causal=causal, interpret=interpret, window=window)
     # what follows its batch row, where the call has it (``reselect``: every array it holds leads with the batch)
     by_row = lambda a: P(batch, *(None,) * (a.ndim - 1))
     rows = {"segment_ids": segment_ids, "selected": selected, "reselect": reselect}

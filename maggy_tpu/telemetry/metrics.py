@@ -37,6 +37,10 @@ GAUGES = frozenset(
         # the kernel visits (ops/flash.py tiles_visited_share; recorded per
         # batch in the prefetcher's thread by Trainer.fit)
         "attention.tiles_visited_share",
+        # a model with sliding-window attention layers (models/transformer.py
+        # Attention of kind sliding_attention): pairs inside window, document and
+        # causal order over the causal pairs inside documents, the sliding layers'
+        "attention.window_pairs_share",
         # an expert share model's step counters (models/moe.py
         # ExpertShareBlock, read by Trainer.fit with the loss of its last step)
         "moe.slots",  # (token, choice) slots on the experts this chip holds, all layers
@@ -288,6 +292,7 @@ SCOPES = (
     "conv.in_proj",  # short convolution: the product that makes the two gates and the input
     "conv.mix",  # short convolution: the gates and the taps between the two products (no product)
     "conv.out_proj",  # short convolution: the product back to the model's width
+    "attn.gate",  # the per-head output gate: its projection, the sigmoid and the product (Attention, attn_gate)
     "sparse.index",  # selected-key attention: the indexer's projections, the index scores, the mask from the thresholds
     "sparse.select",  # selected-key attention: each query's top-k threshold
     "sparse.index_loss",  # selected-key attention: the indexer's loss and its gradient, one pass
@@ -398,6 +403,7 @@ GAUGE_UNITS = {
     "input_wait_ms": "ms",
     "prefetch_depth": "count",
     "attention.tiles_visited_share": "ratio",
+    "attention.window_pairs_share": "ratio",
     "moe.slots": "count",
     "moe.slots_dropped": "count",
     "moe.load_max_over_mean": "ratio",

@@ -185,6 +185,20 @@ def sparse_counters(mods) -> Dict[str, jax.Array]:
     return out
 
 
+def window_counters(mods) -> Dict[str, jax.Array]:
+    """The step's counter of a model with sliding-window attention layers
+    (``Attention`` of kind ``sliding_attention`` sows ``window_pairs``: the
+    pairs inside window, document and causal order, and the causal pairs
+    inside documents, a layer): the first over the second, which says how much
+    of a full layer's attention the window keeps on this batch. Empty for
+    every other model."""
+    pairs = _sown(mods, "window_pairs")
+    if not pairs:
+        return {}
+    inside, causal = jnp.concatenate([a.reshape(-1, 2) for a in pairs]).astype(jnp.float32).sum(0)
+    return {"window_pairs_share": inside / jnp.maximum(causal, 1.0)}
+
+
 def _prefetch_depth(prefetch: Optional[int]) -> int:
     """Resolve an input-prefetch depth: an explicit argument wins, else the
     ``MAGGY_TPU_PREFETCH`` env knob, else 2 (double-buffered). 0 disables."""
@@ -828,21 +842,26 @@ class Trainer:
     def _place_packed(self, tel):
         """``shard_batch`` for ``fit``'s prefetcher thread, which has the host
         batch in hand: a packed one also records the share of the flash grid's
-        tiles its segment ids leave to visit (``attention.tiles_visited_share``),
-        off the loop thread and with nothing read back from the device."""
+        tiles its segment ids leave to visit (``attention.tiles_visited_share``;
+        where some layers take a window, a mean over the attention layers, each
+        with the window's tiles counted out), off the loop thread and with
+        nothing read back from the device."""
         import numpy as np
 
         from maggy_tpu.ops.flash import tiles_visited_share
 
-        head_dim = getattr(getattr(self.model, "cfg", None), "head_dim", None)
+        cfg = getattr(self.model, "cfg", None)
+        head_dim = getattr(cfg, "head_dim", None)
         head_dim = head_dim if isinstance(head_dim, int) else 128  # tiles depend on the width
+        windows = cfg.attention_windows() if hasattr(cfg, "attention_windows") else ()
+        windows = windows if any(windows) else (0,)
 
         def put(batch):
             seg = batch.get("segment_ids") if isinstance(batch, dict) else None
             if isinstance(seg, np.ndarray) and seg.ndim == 2:
-                share = tiles_visited_share(seg, head_dim=head_dim)
-                if share is not None:
-                    tel.gauge("attention.tiles_visited_share", share)
+                shares = {w: tiles_visited_share(seg, head_dim=head_dim, window=w) for w in set(windows)}
+                if None not in shares.values():
+                    tel.gauge("attention.tiles_visited_share", sum(shares[w] for w in windows) / len(windows))
             return self.shard_batch(batch)
 
         return put
@@ -1060,7 +1079,7 @@ class Trainer:
                     loss = self.loss_fn(logits, batch)
                     mtp = mtp_loss(mods, batch)
                 aux = collect_aux_losses(mods)
-                extra = {**expert_counters(mods), **conv_counters(mods), **sparse_counters(mods)}
+                extra = {**expert_counters(mods), **conv_counters(mods), **sparse_counters(mods), **window_counters(mods)}
                 total = loss + aux
                 if mtp is not None:
                     total = total + self.model.cfg.mtp_weight * mtp
@@ -1707,6 +1726,8 @@ class Trainer:
         if "sparse_rows_off_k" in out:
             tel.gauge("sparse.selected_share", out["sparse_selected_share"])
             tel.gauge("sparse.rows_off_k", out["sparse_rows_off_k"])
+        if "window_pairs_share" in out:  # a model with sliding-window attention layers
+            tel.gauge("attention.window_pairs_share", out["window_pairs_share"])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

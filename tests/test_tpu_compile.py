@@ -113,6 +113,28 @@ def test_fused_flash_backward_compiles_at_the_cells_shapes(one_chip, b, s, h, kh
     assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
 
 
+@pytest.mark.parametrize("h,window", [(72, 512), (48, 512), (72, 200)], ids=["72-heads", "48-heads", "window-off-the-tiles"])
+def test_windowed_flash_kernels_compile_at_the_laguna_cells_shape(one_chip, h, window):
+    """``flash_fwd`` and ``flash_bwd`` under a window at the laguna-s-2.1
+    cell's row: one packed row of 8,192, 72 or 48 query heads over 8 of 128,
+    bfloat16, the tiles ``_auto_blocks`` gives; the window is static, so it is
+    part of the kernel Mosaic compiles."""
+    s, kh, d = 8192, 8, 128
+    q = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, s, kh, d), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+
+    def step(q, k, v, segment_ids):
+        return jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, segment_ids=segment_ids, window=window, interpret=False)
+            .astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    text = jax.jit(step).lower(q, kv, kv, ids).compile().as_text()
+    assert flash_calls(text) == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
+
+
 def test_selected_key_kernels_compile_at_the_keye_cells_shape(one_chip):
     """The three kernels that keye-vl-2.0-30b-a3b's cell adds, at its row (1 x
     32,768, one document, 32 query heads over 4 of width 128, 16 index heads of

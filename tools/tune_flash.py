@@ -7,6 +7,8 @@
 
     python tools/tune_flash.py --packed --mix packed8k-r4 --heads 32 --kv-heads 8 --head-dim 64
 
+    python tools/tune_flash.py --packed --mix packed8k-r1 --heads 72 --kv-heads 8 --window 512
+
 Both modes time one attention call in bfloat16, by default at B 2 and 32 query
 and 8 key-value heads of 128 (Mistral-7B's; ``--heads``, ``--kv-heads``,
 ``--head-dim`` give another model's). ``--packed`` takes its segment ids from
@@ -56,6 +58,7 @@ def main():
     parser.add_argument("--heads", type=int, default=32)
     parser.add_argument("--kv-heads", type=int, default=8)
     parser.add_argument("--head-dim", type=int, default=128)
+    parser.add_argument("--window", type=int, default=0, help="a sliding layer's window (0: none)")
     args = parser.parse_args()
 
     import jax
@@ -87,7 +90,7 @@ def main():
         bq, bk, bbq, bbk = tiles
         return flash_attention(
             q, k, v, causal=True, block_q=bq, block_k=bk,
-            bwd_block_q=bbq, bwd_block_k=bbk, segment_ids=seg,
+            bwd_block_q=bbq, bwd_block_k=bbk, segment_ids=seg, window=args.window,
         )
 
     def time_ms(fn, *arrays):
@@ -115,7 +118,7 @@ def main():
     def visited(bq, bk):
         if calls[0] is None:
             return None
-        shares = [tiles_visited_share(s, block_q=bq, block_k=bk) for s in segs]
+        shares = [tiles_visited_share(s, block_q=bq, block_k=bk, window=args.window) for s in segs]
         return round(float(np.mean(shares)), 4)
 
     def sweep(what, run, tiles_of):
@@ -137,7 +140,7 @@ def main():
         r["bwd_ms"] = round(r["ms"] - fwd[0]["ms"], 4)
     auto = _auto_blocks(S, S, args.packed, D)
     print(json.dumps({
-        "geometry": f"B={B} S={S} H={H} KH={KH} D={D} packed={args.packed} calls={len(calls)}",
+        "geometry": f"B={B} S={S} H={H} KH={KH} D={D} packed={args.packed} window={args.window} calls={len(calls)}",
         "forward": fwd,
         "forward_tiles_under_backward": best,
         "forward_plus_backward": both,
