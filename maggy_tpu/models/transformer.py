@@ -405,15 +405,73 @@ def _partitioned(init, logical_axes, cfg):
     return init
 
 
-def _dense(features, logical_axes, cfg: DecoderConfig, name: str):
+def _dense(features, logical_axes, cfg: DecoderConfig, name: str, dot_general=None):
     return nn.DenseGeneral(
         features=features,
         use_bias=False,
         dtype=cfg.dtype,
         param_dtype=cfg.param_dtype,
         kernel_init=_partitioned(nn.initializers.normal(stddev=0.02), logical_axes, cfg),
+        dot_general=dot_general,
         name=name,
     )
+
+
+@jax.custom_vjp
+def _head_projection(x, w):
+    return jax.lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())))
+
+
+def _head_projection_fwd(x, w):
+    return _head_projection(x, w), (x, w)
+
+
+def _head_projection_bwd(res, g):
+    x, w = res
+    lead = tuple(range(x.ndim - 1))  # batch and sequence stay apart: each may be sharded
+    g2 = g.reshape(*x.shape[:-1], w.shape[1] * w.shape[2])
+    # The barrier holds the weight's gradient as the matrix [d, heads x width].
+    # Without it XLA folds the reshape into the product and, since the
+    # cotangent comes back head-major (the flash kernels' [heads, S, width],
+    # through rotary embedding and head norm), writes a convolution whose
+    # window is the heads, with a head-major result that AdamW's update then
+    # reads parameter, mu and nu into through transposing copies. A matrix has
+    # no head-major layout: the product is a plain one, in the state's layout.
+    dw = jax.lax.optimization_barrier(jax.lax.dot_general(x, g2, ((lead, lead), ((), ()))))
+    dx = jax.lax.dot_general(g2, w.reshape(w.shape[0], -1), (((x.ndim - 1,), (1,)), ((), ())))
+    return dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype)
+
+
+_head_projection.defvjp(_head_projection_fwd, _head_projection_bwd)
+
+
+def head_dot_general(lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
+    """``jax.lax.dot_general`` for a head-shaped projection (``lhs [..., d]``
+    by a kernel ``[d, heads, width]``), as ``nn.DenseGeneral`` calls it, with
+    a backward rule of its own: the forward is ``dot_general``'s, bit for bit;
+    the backward flattens the cotangent to ``[tokens, heads x width]`` and
+    makes the two gradients as products of matrices, the weight's held as the
+    matrix ``[d, heads x width]`` (``_head_projection_bwd``)."""
+    form = (((lhs.ndim - 1,), (0,)), ((), ()))
+    if rhs.ndim != 3 or dimension_numbers != form or precision is not None or preferred_element_type is not None:
+        raise ValueError(
+            f"head_dot_general contracts the last dimension of lhs with the first of a [d, heads, width] kernel at "
+            f"the default precision; got {lhs.shape} by {rhs.shape}, {dimension_numbers}, {precision}, "
+            f"{preferred_element_type}"
+        )
+    return _head_projection(lhs, rhs)
+
+
+def _head_dense(heads, logical_axes, cfg: DecoderConfig, name: str):
+    """A head-shaped projection of ``Attention``. Where it is wider than the
+    model (``heads x width > d_model``: more query heads than the model's
+    width holds) its backward is ``head_dot_general``'s: there the rule's
+    plain products gain more than its transposing pass of the cotangent costs
+    (72, 48 and 32 heads of 128 from 3,072 and 2,048, PERF.md section 6);
+    a square projection and the few key and value heads keep ``dot_general``'s
+    own transpose, which on a v5e they run no slower."""
+    wide = heads * cfg.head_dim > cfg.d_model
+    return _dense((heads, cfg.head_dim), logical_axes, cfg, name, head_dot_general if wide else None)
 
 
 class RMSNorm(nn.Module):
@@ -684,9 +742,9 @@ class Attention(nn.Module):
         hd = cfg.head_dim
         n_heads, window, (theta, width, yarn, scale) = cfg.attention_form(self.kind)
         rotary = {} if (width, yarn, scale) == (hd, (), 1.0) else dict(width=width, yarn=yarn, scale=scale)
-        q = _dense((n_heads, hd), ("embed", "heads", None), cfg, "wq")(x)
-        k = _dense((cfg.n_kv_heads, hd), ("embed", "kv", None), cfg, "wk")(x)
-        v = _dense((cfg.n_kv_heads, hd), ("embed", "kv", None), cfg, "wv")(x)
+        q = _head_dense(n_heads, ("embed", "heads", None), cfg, "wq")(x)
+        k = _head_dense(cfg.n_kv_heads, ("embed", "kv", None), cfg, "wk")(x)
+        v = _head_dense(cfg.n_kv_heads, ("embed", "kv", None), cfg, "wv")(x)
         if cfg.qk_norm:  # over the head's width, one scale for all heads
             q = RMSNorm(cfg, name="q_norm")(q)
             k = RMSNorm(cfg, name="k_norm")(k)

@@ -1,0 +1,233 @@
+"""``head_dot_general``, the ``dot_general`` that a head-shaped projection of
+``Attention`` takes where it is wider than the model: its forward is
+``jax.lax.dot_general``'s bit for bit, its backward rule gives autodiff's two
+gradients to the order of the float32 sums, alone, under ``jax.checkpoint``
+inside ``nn.scan`` and on a sharded mesh; the module's parameters and the
+decode path do not change."""
+
+import functools
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from maggy_tpu.models import Decoder, DecoderConfig, transformer
+from maggy_tpu.models.transformer import Attention, head_dot_general
+from maggy_tpu.parallel.mesh import make_mesh
+from maggy_tpu.parallel.spec import ShardingSpec
+
+D = 96  # the model's width here; heads and head widths are the cells'
+
+
+def dims(x):
+    return (((x.ndim - 1,), (0,)), ((), ()))
+
+
+def plain(x, w, dimension_numbers, precision=None, preferred_element_type=None):
+    return jax.lax.dot_general(x, w, dimension_numbers)
+
+
+def operands(heads, width, dtype, batch=2, tokens=24):
+    kx, kw, kt = jax.random.split(jax.random.key(heads * 1000 + width), 3)
+    x = jax.random.normal(kx, (batch, tokens, D), dtype)
+    w = (jax.random.normal(kw, (D, heads, width), jnp.float32) * 0.05).astype(dtype)
+    t = jax.random.normal(kt, (batch, tokens, heads, width), dtype)
+    return x, w, t
+
+
+def close(a, b, dtype):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    # float32: the order of the sums alone; bfloat16: one rounding of a result summed in another order
+    rtol = 2e-5 if dtype == jnp.float32 else 2**-7
+    np.testing.assert_allclose(a, b, rtol=rtol, atol=rtol * float(np.abs(b).max()))
+
+
+# the four cells that run Attention: query heads, key heads, head width
+CELLS = {"laguna-sliding": (72, 8, 128), "laguna-full": (48, 8, 128), "mistral": (32, 8, 128),
+         "lfm2": (32, 8, 64), "keye": (32, 4, 128)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_rule_against_autodiff_at_the_cells_head_forms(cell, dtype):
+    q_heads, kv_heads, width = CELLS[cell]
+    xq, wq, tq = operands(q_heads, width, dtype)
+    xk, wk, tk = operands(kv_heads, width, dtype)
+
+    @jax.jit
+    def both(xq, wq, xk, wk):
+        def objective(dot):
+            return lambda *a: sum(
+                (dot(x, w, dims(x)).astype(jnp.float32) * t.astype(jnp.float32)).sum()
+                for x, w, t in ((a[0], a[1], tq), (a[2], a[3], tk))
+            )
+
+        outs = [dot(x, w, dims(x)) for dot in (head_dot_general, plain) for x, w in ((xq, wq), (xk, wk))]
+        return outs, [jax.grad(objective(dot), argnums=(0, 1, 2, 3))(xq, wq, xk, wk) for dot in (head_dot_general, plain)]
+
+    (oq, ok, rq, rk), (grads, ref) = both(xq, wq, xk, wk)
+    for out, r, heads in ((oq, rq, q_heads), (ok, rk, kv_heads)):
+        assert out.dtype == dtype and out.shape == (2, 24, heads, width)
+        assert np.array_equal(np.asarray(out, np.float32), np.asarray(r, np.float32))  # the forward's bits
+    for g, r, operand in zip(grads, ref, (xq, wq, xk, wk)):
+        assert g.dtype == operand.dtype and g.shape == operand.shape
+        close(g, r, dtype)
+
+
+def test_rule_refuses_what_it_was_not_written_for():
+    x, w, _ = operands(4, 16, jnp.float32)
+    with pytest.raises(ValueError, match="head_dot_general"):
+        head_dot_general(x, w.reshape(D, 64), dims(x))  # a matrix
+    with pytest.raises(ValueError, match="head_dot_general"):
+        head_dot_general(x, w, (((1,), (0,)), ((), ())))  # another contraction
+    with pytest.raises(ValueError, match="head_dot_general"):
+        head_dot_general(x, w, dims(x), precision=jax.lax.Precision.HIGHEST)
+
+
+def tiny(**kw):
+    """A tiny decoder whose ``wq`` is wider than the model (4 heads of 32 from 64): the rule engages."""
+    return DecoderConfig.tiny(**{"head_width": 32, **kw})
+
+
+def rule_calls(monkeypatch):
+    """Counts the projections that reach the rule from here on."""
+    calls = []
+    monkeypatch.setattr(
+        transformer, "head_dot_general", lambda x, w, *a, **k: calls.append(w.shape) or head_dot_general(x, w, *a, **k)
+    )
+    return calls
+
+
+@pytest.mark.parametrize(
+    "cell,d_model,kinds",
+    [("laguna", 3072, {"full_attention": 48, "sliding_attention": 72}), ("keye", 2048, {"full_attention": 32}),
+     ("mistral", 4096, {"full_attention": 32}), ("lfm2", 2048, {"full_attention": 32})],
+)
+def test_which_projections_take_the_rule(monkeypatch, cell, d_model, kinds):
+    """At the four cells' shapes: ``wq`` where ``heads x width`` exceeds the
+    model's width (both kinds of Laguna layer, Keye), never ``wk`` or ``wv``,
+    nothing in a square projection (Mistral, LFM2)."""
+    kv_heads, width = {"laguna": (8, 128), "keye": (4, 128), "mistral": (8, 128), "lfm2": (8, 64)}[cell]
+    cfg = DecoderConfig(
+        d_model=d_model, n_heads=kinds["full_attention"], n_kv_heads=kv_heads, head_width=width, max_seq_len=16,
+        **({"sliding_heads": kinds["sliding_attention"], "sliding_window": 8} if "sliding_attention" in kinds else {}),
+    )
+    x, ids = jax.ShapeDtypeStruct((1, 16, d_model), cfg.dtype), jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    for kind, heads in kinds.items():
+        calls = rule_calls(monkeypatch)
+        jax.eval_shape(Attention(cfg, kind).init, jax.random.key(0), x, ids)
+        assert calls == ([(d_model, heads, width)] if cell in ("laguna", "keye") else []), (kind, calls)
+
+
+def decoder_grads(cfg, variables, tokens):
+    def objective(params):
+        return jnp.square(Decoder(cfg).apply({"params": params}, tokens).astype(jnp.float32)).mean()
+
+    return jax.jit(jax.value_and_grad(objective))(nn.meta.unbox(variables["params"]))
+
+
+def test_rule_under_checkpoint_inside_scan(monkeypatch):
+    """A scanned decoder, each layer under ``jax.checkpoint`` with the policy
+    ``nothing``: loss and every leaf's gradient as with plain ``dot_general``s."""
+    cfg = tiny(dtype=jnp.float32, scan_layers=True, remat=True, remat_policy="nothing")
+    tokens = jnp.asarray(np.arange(2 * 16).reshape(2, 16) % cfg.vocab_size, jnp.int32)
+    variables = jax.jit(Decoder(cfg).init)(jax.random.key(3), tokens)
+    calls = rule_calls(monkeypatch)
+    loss, grads = decoder_grads(cfg, variables, tokens)
+    assert calls and set(calls) == {(cfg.d_model, cfg.n_heads, 32)}
+    monkeypatch.setattr(transformer, "head_dot_general", None)  # DenseGeneral's own
+    ref_loss, ref_grads = decoder_grads(cfg, variables, tokens)
+    assert float(loss) == float(ref_loss)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref_grads)):
+        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-6 * float(np.abs(r).max()), err_msg=str(path))
+
+
+def test_rule_on_a_sharded_mesh_with_the_heads_on_tensor():
+    """Tokens over ``data`` x ``fsdp``, the kernel ``[embed, heads, width]``
+    over ``fsdp`` and ``tensor``: the gradients come back in the operands'
+    shardings and equal the unsharded rule's."""
+    mesh = make_mesh(ShardingSpec(dp=2, fsdp=2, tp=2))
+    x, w, t = operands(8, 16, jnp.float32, batch=4)
+    xs, ws = NamedSharding(mesh, P(("data", "fsdp"))), NamedSharding(mesh, P("fsdp", "tensor"))
+    ts = NamedSharding(mesh, P(("data", "fsdp"), None, "tensor"))
+
+    def grads(x, w, t):
+        return jax.grad(lambda x, w: (head_dot_general(x, w, dims(x)) * t).sum(), argnums=(0, 1))(x, w)
+
+    sharded = jax.jit(grads, in_shardings=(xs, ws, ts), out_shardings=(xs, ws))
+    dx, dw = sharded(jax.device_put(x, xs), jax.device_put(w, ws), jax.device_put(t, ts))
+    assert dx.sharding.is_equivalent_to(xs, 3) and dw.sharding.is_equivalent_to(ws, 3)
+    rdx, rdw = jax.jit(grads)(x, w, t)
+    np.testing.assert_allclose(dx, rdx, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(dw, rdw, rtol=2e-5, atol=2e-5)
+
+
+def test_attention_parameters_are_what_a_parent_checkpoint_holds():
+    """Names, shapes, dtypes and logical axes of an ``Attention``'s parameters:
+    the tree a checkpoint of the parent commit holds."""
+    cfg = DecoderConfig(d_model=48, n_heads=6, n_kv_heads=2, head_width=16, qk_norm=True, max_seq_len=32)  # wq is wide
+    x, ids = jnp.zeros((1, 8, 48), cfg.dtype), jnp.zeros((1, 8), jnp.int32)
+    boxed = jax.eval_shape(Attention(cfg).init, jax.random.key(0), x, ids)["params"]
+    tree = {
+        "/".join(k.key for k in path): (leaf.value.shape, leaf.value.dtype, leaf.names)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(boxed, is_leaf=lambda a: isinstance(a, nn.Partitioned))
+    }
+    f32 = jnp.dtype("float32")
+    assert tree == {
+        "wq/kernel": ((48, 6, 16), f32, ("embed", "heads", None)),
+        "wk/kernel": ((48, 2, 16), f32, ("embed", "kv", None)),
+        "wv/kernel": ((48, 2, 16), f32, ("embed", "kv", None)),
+        "wo/kernel": ((6, 16, 48), f32, ("heads", None, "embed")),
+        "q_norm/scale": ((16,), f32, ("norm",)),
+        "k_norm/scale": ((16,), f32, ("norm",)),
+    }
+
+
+def test_decode_values_unchanged(monkeypatch):
+    """The decode path shares the forward: a prompt's prefill and a cached
+    step give the bits ``DenseGeneral``'s own ``dot_general`` gives."""
+    cfg = tiny(decode=True, n_layers=1)
+    tokens = jnp.asarray(np.arange(2 * 10).reshape(2, 10) % cfg.vocab_size, jnp.int32)
+
+    def run():
+        model = Decoder(cfg)
+        variables = jax.jit(model.init)(jax.random.key(5), tokens[:, :1])
+        cache, outs = variables["cache"], []
+        for lo, hi in ((0, 9), (9, 10)):
+            positions = jnp.broadcast_to(jnp.arange(lo, hi), (2, hi - lo))
+            logits, mods = jax.jit(functools.partial(model.apply, mutable=["cache"]))(
+                {"params": variables["params"], "cache": cache}, tokens[:, lo:hi], positions
+            )
+            cache = mods["cache"]
+            outs.append(np.asarray(logits, np.float32))
+        return outs
+
+    ours = run()
+    monkeypatch.setattr(transformer, "head_dot_general", None)
+    for a, b in zip(ours, run()):
+        assert np.array_equal(a, b)
+
+
+def test_the_projections_share_reads_a_recorded_trace():
+    """``train.attn_qkv_proj_share`` on the benchmark's recorded train trace
+    (a small decoder's steps on a chip): the operations under ``attn/wq``,
+    ``wk``, ``wv`` are a part of those under ``attn``; without a trace the
+    reader returns nothing."""
+    import os
+    import types
+
+    from benchmark import run, spans
+
+    recorded = os.path.join(os.path.dirname(run.__file__), "checks", "recorded", "train.xplane.pb")
+    cell = types.SimpleNamespace(trace_dir="recorded-train-trace", chips=1)
+    spans._LOADED[cell.trace_dir] = spans.Timeline(recorded, chips=1, span_names=spans.TRAIN_SPANS)
+    try:
+        obs = {"cell": cell, "trace": {}, "needed_flops": 1.0}
+        share = run.reader("train.attn_qkv_proj_share").read(obs)
+        assert 0.0 < share < run.reader("train.scope_attn_share").read(obs) < 100.0
+        assert run.reader("train.attn_qkv_proj_share").read({"cell": cell, "trace": None}) is None
+    finally:
+        del spans._LOADED[cell.trace_dir]

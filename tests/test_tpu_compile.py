@@ -7,6 +7,7 @@ inside a fixture: only the worker that runs this file loads the TPU's library.
 
 import functools
 import os
+import re
 
 import flax.linen as nn
 import jax
@@ -242,3 +243,56 @@ def test_index_loss_kernel_compiles_at_the_keye_cells_shape(one_chip):
         calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
         assert len(calls) == 1 and "index_loss" in calls[0].split("=")[0]
 
+
+def test_laguna_width_projections_backward_is_plain_products(one_chip, monkeypatch):
+    """A recomputed ``Attention`` at laguna-s-2.1's sliding layer's widths (72
+    heads of 128 over 8 from 3,072, head norms, the gate) on one packed row of
+    8,192, scanned once as the cell's period is, its gradient and AdamW's
+    update of float32 parameters: no convolution under ``attn/wq`` writes a
+    kernel-shaped ``[3072, 72, 128]`` result (XLA's form of ``dot_general``'s
+    own transpose: a window over the heads and a head-major result); ``wq``'s
+    gradient is a product of matrices, and no ``copy`` transposes a float32
+    array of the kernel's shape (parameter, ``mu``, ``nu``) into a product's
+    layout or back. ``wk`` and ``wv`` (8 heads: narrower than the model) keep
+    ``dot_general``'s own transpose."""
+    import optax
+
+    from maggy_tpu.models.transformer import Attention, RMSNorm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s, d, heads = 8192, 3072, 72
+    cfg = DecoderConfig(
+        d_model=d, n_heads=heads, n_kv_heads=8, head_width=128, qk_norm=True, attn_gate=True, max_seq_len=s,
+        partition_params=False,
+    )
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x, positions, segment_ids):
+            return x + Attention(cfg, name="attn")(RMSNorm(cfg, name="attn_norm")(x), positions, segment_ids), None
+
+    period = nn.scan(
+        nn.remat(Layer, policy=REMAT_POLICIES["nothing"], prevent_cse=False),
+        variable_axes={"params": 0}, split_rngs={"params": True}, in_axes=nn.broadcast, length=1,
+    )()
+    tx = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+    described = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip))
+    params = described(jax.eval_shape(period.init, jax.random.key(0), x, ids, ids))
+    opt_state = described(jax.eval_shape(tx.init, params))
+
+    def step(params, opt_state, x, positions, segment_ids):
+        grads = jax.grad(lambda p: jnp.square(period.apply(p, x, positions, segment_ids)[0].astype(jnp.float32)).mean())(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, x, ids, ids).compile().as_text()
+    products = [
+        re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])", line).group(1)
+        for line in text.splitlines()
+        if " convolution(" in line and re.search(r'op_name="[^"]*transpose\(jvp[^"]*attn/w[qkv]/dot_general', line)
+    ]
+    assert f"bf16[{d},{heads},128]" not in products and f"bf16[{d},{heads * 128}]" in products, products
+    assert products.count(f"bf16[{d},8,128]") == 2, products  # wk and wv
+    assert not re.findall(rf"= f32\[(?:1,)?{d},{heads},128\]\S* copy\(", text)
