@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from maggy_tpu.ops import attention as ops_attn
-from maggy_tpu.ops import sparse_select
+from maggy_tpu.ops import eva, sparse_select
 from maggy_tpu.ops.flash import (
     FLASH_RESIDUALS,
     flash_attention,
@@ -70,7 +70,7 @@ REMAT_POLICIES = {
 }
 
 
-LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "eva_attention")
 
 
 def _parse_ablated(ablated, n_layers: int):
@@ -221,6 +221,30 @@ class DecoderConfig:
     # a sigmoid gate a head and token on the heads' outputs before ``wo``, from
     # a bias-free projection of the layer's normed input (:class:`Attention`)
     attn_gate: bool = False
+    # an "eva_attention" layer (``layer_types``; :class:`Attention` only,
+    # ``ops/eva.py``): a query sees the exact keys of its own window of
+    # ``eva_window`` positions on the row's grid, up to itself and inside its
+    # document, and one learned summary for every ``eva_chunk`` positions of
+    # the earlier windows that end in its document, under one softmax. A row
+    # of at most ``eva_window`` positions is plain causal attention. Training
+    # and scoring only: the summaries have no decode state
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # the heads of the final product: head ``i`` predicts the token ``i + 1``
+    # ahead from one untied ``[d_model, pred_heads x vocab_size]`` kernel. The
+    # model's output is head 0's logits; the others' are sown (``mtp_logits``)
+    # and the trainer adds the mean of their losses ``mtp_weight`` times, which
+    # is their sum. 1: the one next-token head
+    pred_heads: int = 1
+    # every RMSNorm multiplies by ``1 + scale`` (a scale that starts at zero)
+    norm_unit_offset: bool = False
+    # the residual stream and a layer's two sums into it in float32
+    residual_f32: bool = False
+
+    @property
+    def mtp_weight(self) -> float:
+        """What the trainer weighs the further heads' mean loss by: their number."""
+        return float(self.pred_heads - 1)
 
     @property
     def head_dim(self) -> int:
@@ -295,6 +319,20 @@ class DecoderConfig:
                         "allocator keeps every page of a row, so decode=True with a window "
                         "would hold what the window has left behind (ROADMAP M2)"
                     )
+            if "eva_attention" in self.layer_types:
+                if self.eva_chunk < 1 or self.eva_window < 1 or self.eva_window % self.eva_chunk:
+                    raise ValueError("an eva_attention layer needs eva_window and an eva_chunk that divides it")
+                if self.kv_lora_rank or self.sparse_topk or self.attention_fn is not None or self.attn_gate:
+                    raise ValueError("chunk summaries are Attention's, through the automatic dispatch, ungated")
+                if self.n_kv_heads != self.n_heads:
+                    raise ValueError("an eva_attention layer takes n_kv_heads == n_heads: a summary a head")
+                if self.decode:
+                    raise ValueError(
+                        "an eva_attention layer has a training form only: the chunk "
+                        "summaries have no decode state beside the KV cache"
+                    )
+        if self.pred_heads < 1 or (self.pred_heads > 1 and self.tie_embeddings):
+            raise ValueError("pred_heads >= 1, and more than one head takes an untied kernel")
         if self.rope_yarn and len(self.rope_yarn) != 5:
             raise ValueError(
                 "rope_yarn is (factor, original positions, beta_fast, beta_slow, attention factor)"
@@ -479,16 +517,18 @@ class RMSNorm(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        # getattr: the norm is shared by config classes without the field
+        offset = getattr(self.cfg, "norm_unit_offset", False)
         scale = self.param(
             "scale",
-            _partitioned(nn.initializers.ones_init(), ("norm",), self.cfg),
+            _partitioned(nn.initializers.zeros_init() if offset else nn.initializers.ones_init(), ("norm",), self.cfg),
             (x.shape[-1],),
             self.cfg.param_dtype,
         )
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = x32 * jax.lax.rsqrt(var + self.cfg.norm_eps)
-        return (y * scale).astype(self.cfg.dtype)
+        return (y * (1.0 + scale) if offset else y * scale).astype(self.cfg.dtype)
 
 
 class LayerNorm(nn.Module):
@@ -595,7 +635,9 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
     return None
 
 
-def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", selected: int = 0, window: int = 0):
+def record_attention_kernel(
+    kernel: str, q, k, segment_ids, reason: str = "", selected: int = 0, window: int = 0, chunk: int = 0,
+):
     """Journal which kernel the automatic dispatch chose for this shape as
     one ``attention.kernel`` event; for the flash kernels also the tiles they
     run at (forward q, k, backward q, k), and always the head width with, for
@@ -612,7 +654,11 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
     backward makes the mask again (``reselect``: 2); the XLA attention keeps
     the mask (1, and what a recomputed layer's replay runs again), and
     ``window``, the keys up to its own that a query of a sliding layer sees
-    (0: every causal key).
+    (0: every causal key). A layer of chunk summaries (``chunk`` > 0:
+    ``ops/eva.py``) says so as ``form`` ``eva`` beside its ``window`` (there
+    the window of the row's grid) and ``chunk``; the four tile sizes are then
+    its local calls', on rows of one window, and ``remote_blocks`` the four of
+    the calls on the summaries.
     The dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
@@ -622,15 +668,18 @@ def record_attention_kernel(kernel: str, q, k, segment_ids, reason: str = "", se
         attrs["selected"] = int(selected)
         attrs["index_loss"] = "kernel" if kernel == "flash" else "blockwise"
         attrs["index_passes"] = 2 if kernel.startswith("flash") else 1
+    if chunk:
+        attrs.update(form="eva", chunk=int(chunk))
     if kernel.startswith("flash"):
         from maggy_tpu.ops.flash import _auto_blocks, backward_form
 
         attrs["lanes"] = lane_fill(q.shape[3])
         attrs["backward"] = backward_form(q.shape[1], q.shape[3])
-        attrs.update(zip(
-            ("block_q", "block_k", "bwd_block_q", "bwd_block_k"),
-            _auto_blocks(q.shape[1], k.shape[1], segment_ids is not None, q.shape[3]),
-        ))
+        blocks = _auto_blocks(q.shape[1], k.shape[1], segment_ids is not None, q.shape[3])
+        if chunk:
+            tiles = eva.tiles(q.shape[1], window, chunk, q.shape[3])
+            blocks, attrs["remote_blocks"] = tiles["local"], list(tiles["remote"])
+        attrs.update(zip(("block_q", "block_k", "bwd_block_q", "bwd_block_k"), blocks))
     telemetry.get().event(
         "attention.kernel", kernel=kernel, reason=reason,
         q=list(q.shape), kv=list(k.shape), segmented=segment_ids is not None,
@@ -699,6 +748,29 @@ def auto_attention(
     return (out, None) if return_lse else out
 
 
+def auto_eva_attention(q, k, v, ks, vs, *, segment_ids=None, window: int, chunk: int):
+    """The dispatch of a layer of chunk summaries (``ops/eva.py``): on one TPU
+    chip, where the two parts' shapes tile, the flash kernels on the windows'
+    rows and on the summaries under their selection, joined by the rows'
+    log-sum-exp (recorded as ``flash``, ``form`` ``eva``); anywhere else the
+    same mathematics in XLA (``xla_dense``, with the reason)."""
+    from maggy_tpu.parallel.mesh import ambient_mesh
+
+    s, d = q.shape[1], q.shape[3]
+    mesh = ambient_mesh()
+    if jax.default_backend() != "tpu":
+        why = f"backend is {jax.default_backend()}"
+    elif mesh is not None and mesh.size > 1:
+        why = f"mesh {dict(mesh.shape)}: the windows' rows and the summaries run on one chip"
+    else:
+        why = eva.untileable(s, window, chunk, d, compiled=True)
+    if why is None:
+        record_attention_kernel("flash", q, k, segment_ids, window=window, chunk=chunk)
+        return eva.eva_attention(q, k, v, ks, vs, segment_ids, window=window, chunk=chunk)
+    record_attention_kernel("xla_dense", q, k, segment_ids, why, window=window, chunk=chunk)
+    return eva.eva_attention_xla(q, k, v, ks, vs, segment_ids, window=window, chunk=chunk)
+
+
 def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selected=None, window: int = 0):
     """Reference soft-max attention: q [B,S,H,D], k/v [B,S,Kh,D] with GQA
     head-group broadcast. fp32 logits/softmax for stability. ``selected``
@@ -750,7 +822,9 @@ class Attention(nn.Module):
             k = RMSNorm(cfg, name="k_norm")(k)
         q = rope(q, positions, theta, **rotary)
         k = rope(k, positions, theta, **rotary)
-        if cfg.decode:
+        if self.kind == "eva_attention":
+            out = self._summary_attention(q, k, v, positions, segment_ids)
+        elif cfg.decode:
             out = self._cached_attention(q, k, v, positions, segment_ids)
         elif cfg.sparse_topk:
             out = self._selected_attention(x, q, k, v, positions, segment_ids)
@@ -780,6 +854,47 @@ class Attention(nn.Module):
             ),
             name="wo",
         )(out)
+        return out
+
+    def _summary_attention(self, q, k, v, positions, segment_ids):
+        """A layer of chunk summaries (``ops/eva.py``): two learned vectors a
+        head, ``eva_phi`` (what a chunk's keys are weighed by) and ``eva_mu``
+        (added to the summary key), make one key and value of every
+        ``eva_chunk`` positions under the scope ``eva.prep``; a query then
+        sees its own window's keys and the earlier windows' summaries under
+        one softmax. A row of at most ``eva_window`` positions has no earlier
+        window: plain causal attention, the two vectors unused. Sows
+        ``eva_counts`` ([4]: summaries the step's real queries see, all the
+        entries they see, chunks in which two documents meet, chunks) for the
+        trainer's step metrics, counted from positions and segment ids."""
+        cfg = self.cfg
+        b, s, h, hd = q.shape
+        window, chunk = cfg.eva_window, cfg.eva_chunk
+        vector = lambda name: self.param(
+            name, _partitioned(nn.initializers.normal(stddev=0.02), ("heads", None), cfg), (h, hd), cfg.param_dtype
+        )
+        phi, mu = vector("eva_phi"), vector("eva_mu")
+        if s <= window:
+            return auto_attention(q, k, v, causal=True, segment_ids=segment_ids)
+        eva.check_grid(s, window, chunk)
+        with jax.named_scope("eva.prep"):
+            ks, vs = eva.summaries(k, v, phi, mu, segment_ids, chunk)
+        out = auto_eva_attention(q, k, v, ks, vs, segment_ids=segment_ids, window=window, chunk=chunk)
+        # a query at row index t, position p of its document: min(p, t mod window) + 1 exact keys, and the
+        # chunks from the one that ends first inside its document to the last before its window
+        at = jnp.arange(s, dtype=jnp.int32)[None, :]
+        seg = jnp.ones((b, s), jnp.int32) if segment_ids is None else segment_ids
+        real = (seg > 0).astype(jnp.float32)
+        local = jnp.minimum(positions, at % window) + 1
+        remote = jnp.maximum(at // window * (window // chunk) - (at - positions) // chunk, 0)
+        cut = seg[:, ::chunk] != seg[:, chunk - 1::chunk]
+        self.sow(
+            "intermediates", "eva_counts",
+            jnp.stack([
+                (remote * real).sum(), ((remote + local) * real).sum(),
+                cut.sum().astype(jnp.float32), jnp.float32(b * (s // chunk)),
+            ]),
+        )
         return out
 
     def _selected_attention(self, x, q, k, v, positions, segment_ids):
@@ -1247,10 +1362,12 @@ class DecoderLayer(nn.Module):
         zero gate removes that sublayer's contribution (residual becomes
         identity) and cuts its gradients, with an unchanged param tree.
         ``segment_ids`` — optional [B, S] packed-sequence ids."""
+        # the stream's type: float32 under ``residual_f32`` (the sums then run in it), else the layers' own
+        into = (lambda a: a.astype(jnp.float32)) if self.cfg.residual_f32 else (lambda a: a)
         a = layer_operator(self.cfg, self.kind, x, positions, segment_ids)
-        x = x + (a if gates is None else a * gates[0].astype(a.dtype))
+        x = into(x) + into(a if gates is None else a * gates[0].astype(a.dtype))
         m = MLPBlock(self.cfg, name="mlp")(RMSNorm(self.cfg, name="mlp_norm")(x))
-        x = x + (m if gates is None else m * gates[1].astype(m.dtype))
+        x = x + into(m if gates is None else m * gates[1].astype(m.dtype))
         return _constrain_residual(x)
 
 
@@ -1303,6 +1420,8 @@ class Decoder(nn.Module):
             cfg.param_dtype,
         )
         x = _constrain_residual(jnp.asarray(embed, cfg.dtype)[tokens])
+        if cfg.residual_f32:
+            x = x.astype(jnp.float32)
 
         gates = _parse_ablated(cfg.ablated, cfg.n_layers)
         kinds = cfg.layer_kinds()
@@ -1353,7 +1472,12 @@ class Decoder(nn.Module):
             with jax.named_scope("lm_head"):  # the scope the untied head's module gives
                 logits = jnp.einsum("bsd,vd->bsv", x, jnp.asarray(embed, cfg.dtype))
         else:
-            logits = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, "lm_head")(x)
+            logits = _dense(cfg.vocab_size * cfg.pred_heads, ("embed", "vocab"), cfg, "lm_head")(x)
         if cfg.logits_softcap:
             logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
-        return logits.astype(jnp.float32)
+        logits = logits.astype(jnp.float32)
+        if cfg.pred_heads > 1:  # head i's columns are i * vocab_size onward; head 0 is the model's output
+            heads = logits.reshape(*logits.shape[:-1], cfg.pred_heads, cfg.vocab_size)
+            self.sow("intermediates", "mtp_logits", heads[..., 1:, :])
+            return heads[..., 0, :]
+        return logits

@@ -41,6 +41,11 @@ GAUGES = frozenset(
         # Attention of kind sliding_attention): pairs inside window, document and
         # causal order over the causal pairs inside documents, the sliding layers'
         "attention.window_pairs_share",
+        # a model with layers of chunk summaries (Attention of kind
+        # eva_attention): the summaries over all the entries the step's real
+        # queries see, and the chunks a document's start cuts over all chunks
+        "attention.eva_remote_share",
+        "attention.eva_chunks_cut_share",
         # an expert share model's step counters (models/moe.py
         # ExpertShareBlock, read by Trainer.fit with the loss of its last step)
         "moe.slots",  # (token, choice) slots on the experts this chip holds, all layers
@@ -296,6 +301,10 @@ SCOPES = (
     "sparse.index",  # selected-key attention: the indexer's projections, the index scores, the mask from the thresholds
     "sparse.select",  # selected-key attention: each query's top-k threshold
     "sparse.index_loss",  # selected-key attention: the indexer's loss and its gradient, one pass
+    "eva.prep",  # chunk summaries: a chunk's keys against phi, the softmax, the two weighted sums, mu (Attention, eva_attention)
+    "eva.local",  # chunk summaries: the flash kernels on the windows folded into rows, and their visit table (ops/eva.py)
+    "eva.remote",  # chunk summaries: the flash kernels on the summaries under their selection, and their visit table
+    "eva.merge",  # chunk summaries: the two calls' outputs joined by their log-sum-exp; the two dq added
     # (flax module names are scopes too and need no entry: attn, mlp, moe,
     # and mtp, the multi-token-prediction module)
     "decode_attn",  # page/chunk gather + online softmax over the KV cache
@@ -404,6 +413,8 @@ GAUGE_UNITS = {
     "prefetch_depth": "count",
     "attention.tiles_visited_share": "ratio",
     "attention.window_pairs_share": "ratio",
+    "attention.eva_remote_share": "ratio",
+    "attention.eva_chunks_cut_share": "ratio",
     "moe.slots": "count",
     "moe.slots_dropped": "count",
     "moe.load_max_over_mean": "ratio",

@@ -125,9 +125,18 @@ def _sown(mods, name: str) -> list:
 def mtp_loss(mods, batch: Dict[str, jax.Array]) -> Optional[jax.Array]:
     """The multi-token-prediction loss of a model that sowed ``mtp_logits``
     (models/moe.py): cross entropy of the token two ahead, counted where it
-    lies in the predictor's document. ``None`` for every other model."""
+    lies in the predictor's document. Further heads of one product
+    (``DecoderConfig.pred_heads``: ``[B, S, heads - 1, vocab]``, head ``i``
+    predicting the token ``i + 2`` ahead) give the mean of their losses, each
+    over the positions whose target lies in the predictor's document.
+    ``None`` for every other model."""
     logits = _sown(mods, "mtp_logits")
-    return lm_loss_fn(logits[0], batch, ahead=2) if logits else None
+    if not logits:
+        return None
+    if logits[0].ndim == 4:
+        heads = logits[0].shape[2]
+        return sum(lm_loss_fn(logits[0][:, :, i], batch, ahead=i + 2) for i in range(heads)) / heads
+    return lm_loss_fn(logits[0], batch, ahead=2)
 
 
 def expert_counters(mods) -> Dict[str, jax.Array]:
@@ -197,6 +206,20 @@ def window_counters(mods) -> Dict[str, jax.Array]:
         return {}
     inside, causal = jnp.concatenate([a.reshape(-1, 2) for a in pairs]).astype(jnp.float32).sum(0)
     return {"window_pairs_share": inside / jnp.maximum(causal, 1.0)}
+
+
+def eva_counters(mods) -> Dict[str, jax.Array]:
+    """The step's counters of a model with layers of chunk summaries
+    (``Attention`` of kind ``eva_attention`` sows ``eva_counts``: summaries
+    seen, all entries seen, chunks in which two documents meet, chunks, a
+    layer): the summaries over all the entries the real queries' softmax runs
+    over, and the chunks a document's start cuts over all chunks, which says
+    that the packing reached the summaries. Empty for every other model."""
+    counts = _sown(mods, "eva_counts")
+    if not counts:
+        return {}
+    remote, seen, cut, chunks = jnp.concatenate([a.reshape(-1, 4) for a in counts]).astype(jnp.float32).sum(0)
+    return {"eva_remote_share": remote / jnp.maximum(seen, 1.0), "eva_chunks_cut_share": cut / jnp.maximum(chunks, 1.0)}
 
 
 def _prefetch_depth(prefetch: Optional[int]) -> int:
@@ -844,10 +867,12 @@ class Trainer:
         batch in hand: a packed one also records the share of the flash grid's
         tiles its segment ids leave to visit (``attention.tiles_visited_share``;
         where some layers take a window, a mean over the attention layers, each
-        with the window's tiles counted out), off the loop thread and with
-        nothing read back from the device."""
+        with the window's tiles counted out; a layer of chunk summaries counts
+        the tiles of its two grids, ``ops.eva.tiles_visited_share``), off the
+        loop thread and with nothing read back from the device."""
         import numpy as np
 
+        from maggy_tpu.ops import eva
         from maggy_tpu.ops.flash import tiles_visited_share
 
         cfg = getattr(self.model, "cfg", None)
@@ -855,13 +880,26 @@ class Trainer:
         head_dim = head_dim if isinstance(head_dim, int) else 128  # tiles depend on the width
         windows = cfg.attention_windows() if hasattr(cfg, "attention_windows") else ()
         windows = windows if any(windows) else (0,)
+        kinds = cfg.layer_kinds() if hasattr(cfg, "layer_kinds") else ()
+        # the forms the attention layers take, one a layer: a window (0: none), or the summaries' grid
+        forms = list(windows)
+        if "eva_attention" in kinds:
+            forms = [
+                ("eva", cfg.eva_window, cfg.eva_chunk) if kind == "eva_attention" else w
+                for kind, w in zip([k for k in kinds if k != "conv"], cfg.attention_windows())
+            ]
+
+        def visited(seg, form):
+            if isinstance(form, tuple) and seg.shape[1] > form[1]:
+                return eva.tiles_visited_share(seg, window=form[1], chunk=form[2], head_dim=head_dim)
+            return tiles_visited_share(seg, head_dim=head_dim, window=0 if isinstance(form, tuple) else form)
 
         def put(batch):
             seg = batch.get("segment_ids") if isinstance(batch, dict) else None
             if isinstance(seg, np.ndarray) and seg.ndim == 2:
-                shares = {w: tiles_visited_share(seg, head_dim=head_dim, window=w) for w in set(windows)}
+                shares = {f: visited(seg, f) for f in set(forms)}
                 if None not in shares.values():
-                    tel.gauge("attention.tiles_visited_share", sum(shares[w] for w in windows) / len(windows))
+                    tel.gauge("attention.tiles_visited_share", sum(shares[f] for f in forms) / len(forms))
             return self.shard_batch(batch)
 
         return put
@@ -1079,7 +1117,10 @@ class Trainer:
                     loss = self.loss_fn(logits, batch)
                     mtp = mtp_loss(mods, batch)
                 aux = collect_aux_losses(mods)
-                extra = {**expert_counters(mods), **conv_counters(mods), **sparse_counters(mods), **window_counters(mods)}
+                extra = {
+                    **expert_counters(mods), **conv_counters(mods), **sparse_counters(mods), **window_counters(mods),
+                    **eva_counters(mods),
+                }
                 total = loss + aux
                 if mtp is not None:
                     total = total + self.model.cfg.mtp_weight * mtp
@@ -1728,6 +1769,9 @@ class Trainer:
             tel.gauge("sparse.rows_off_k", out["sparse_rows_off_k"])
         if "window_pairs_share" in out:  # a model with sliding-window attention layers
             tel.gauge("attention.window_pairs_share", out["window_pairs_share"])
+        if "eva_remote_share" in out:  # a model with layers of chunk summaries
+            tel.gauge("attention.eva_remote_share", out["eva_remote_share"])
+            tel.gauge("attention.eva_chunks_cut_share", out["eva_chunks_cut_share"])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

@@ -296,3 +296,33 @@ def test_laguna_width_projections_backward_is_plain_products(one_chip, monkeypat
     assert f"bf16[{d},{heads},128]" not in products and f"bf16[{d},{heads * 128}]" in products, products
     assert products.count(f"bf16[{d},8,128]") == 2, products  # wk and wv
     assert not re.findall(rf"= f32\[(?:1,)?{d},{heads},128\]\S* copy\(", text)
+
+
+def test_chunk_summary_attention_compiles_at_the_evabyte_cells_shape(one_chip):
+    """A layer of chunk summaries (``ops/eva.py``) at the evabyte cell's row:
+    one packed row of 16,384, 32 heads of 128, windows of 2,048 and chunks of
+    16, bfloat16, the summaries in XLA and the two parts on the flash kernels
+    with its loss and gradient, ``phi`` and ``mu`` among them: a local and a
+    remote ``flash_fwd``, a local and a remote ``flash_bwd`` (the remote one
+    fused, a head's whole dq of 16,384 rows resident beside the selection's
+    int8 tile), and under the recompute policy no third forward."""
+    from maggy_tpu.ops import eva
+
+    s, h, d, window, chunk = 16384, 32, 128, 2048, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, phi, mu, segment_ids):
+        ks, vs = eva.summaries(k, v, phi, mu, segment_ids, chunk)
+        out = eva.eva_attention(q, k, v, ks, vs, segment_ids, window=window, chunk=chunk, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    def step(q, k, v, phi, mu, segment_ids):
+        kept = jax.checkpoint(functools.partial(loss, segment_ids=segment_ids), policy=REMAT_POLICIES["nothing"])
+        return jax.grad(kept, argnums=(0, 1, 2, 3, 4))(q, k, v, phi, mu)
+
+    head = sds((1, s, h, d), jnp.bfloat16)
+    text = jax.jit(step).lower(head, head, head, sds((h, d), jnp.float32), sds((h, d), jnp.float32), sds((1, s), jnp.int32)).compile().as_text()
+    assert eva.untileable(s, window, chunk, d, compiled=True) is None and backward_form(s, d) == "fused"
+    assert flash_calls(text) == {"flash_fwd": 2, "flash_bwd": 2, "flash_dq": 0, "flash_dkv": 0}
