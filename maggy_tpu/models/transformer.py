@@ -19,6 +19,7 @@ BASELINE Llama-3-8B config trains):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -456,48 +457,63 @@ def _dense(features, logical_axes, cfg: DecoderConfig, name: str, dot_general=No
 
 
 @jax.custom_vjp
-def _head_projection(x, w):
+def _projection(x, w):
     return jax.lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())))
 
 
-def _head_projection_fwd(x, w):
-    return _head_projection(x, w), (x, w)
+def _projection_fwd(x, w):
+    return _projection(x, w), (x, w)
 
 
-def _head_projection_bwd(res, g):
-    x, w = res
+def _projection_bwd(res, g):
+    x, w = res  # w is [d, heads, width] or the matrix [d, features]: the reshapes below are that one's identity
     lead = tuple(range(x.ndim - 1))  # batch and sequence stay apart: each may be sharded
-    g2 = g.reshape(*x.shape[:-1], w.shape[1] * w.shape[2])
-    # The barrier holds the weight's gradient as the matrix [d, heads x width].
-    # Without it XLA folds the reshape into the product and, since the
-    # cotangent comes back head-major (the flash kernels' [heads, S, width],
-    # through rotary embedding and head norm), writes a convolution whose
-    # window is the heads, with a head-major result that AdamW's update then
-    # reads parameter, mu and nu into through transposing copies. A matrix has
-    # no head-major layout: the product is a plain one, in the state's layout.
+    g2 = g.reshape(*x.shape[:-1], -1)
+    # The barrier holds the weight's gradient as the bfloat16 matrix [d,
+    # features], a product of its own. Without it XLA fuses what consumes the
+    # gradient into the product: AdamW's update of an unrolled layer's leaf
+    # (parameter, mu, nu read and written through the product's output tile,
+    # which halves the product's rate) or the cast and write into a scanned
+    # layer's stacked float32 gradient; and for a head-shaped kernel it folds the reshape in
+    # and, since the cotangent comes back head-major (the flash kernels'
+    # [heads, S, width], through rotary embedding and head norm), writes a
+    # convolution whose window is the heads, with a head-major result that
+    # AdamW's update then reads parameter, mu and nu into through transposing
+    # copies. A matrix has no head-major layout: the product is a plain one, in
+    # the state's layout, and its consumer a memory-bound fusion of its own.
     dw = jax.lax.optimization_barrier(jax.lax.dot_general(x, g2, ((lead, lead), ((), ()))))
     dx = jax.lax.dot_general(g2, w.reshape(w.shape[0], -1), (((x.ndim - 1,), (1,)), ((), ())))
     return dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype)
 
 
-_head_projection.defvjp(_head_projection_fwd, _head_projection_bwd)
+_projection.defvjp(_projection_fwd, _projection_bwd)
 
 
-def head_dot_general(lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
-    """``jax.lax.dot_general`` for a head-shaped projection (``lhs [..., d]``
-    by a kernel ``[d, heads, width]``), as ``nn.DenseGeneral`` calls it, with
-    a backward rule of its own: the forward is ``dot_general``'s, bit for bit;
-    the backward flattens the cotangent to ``[tokens, heads x width]`` and
-    makes the two gradients as products of matrices, the weight's held as the
-    matrix ``[d, heads x width]`` (``_head_projection_bwd``)."""
+def _checked_projection(name, kernel_dims, lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
+    """``_projection`` as a ``dot_general`` for ``nn.DenseGeneral``: ``name``
+    takes a kernel of the dimensions ``kernel_dims``, contracted with the last
+    dimension of ``lhs`` at the default precision, and refuses anything else.
+    The forward is ``dot_general``'s, bit for bit; the backward makes the two
+    gradients as products of matrices, the weight's held apart from what
+    consumes it (``_projection_bwd``)."""
     form = (((lhs.ndim - 1,), (0,)), ((), ()))
-    if rhs.ndim != 3 or dimension_numbers != form or precision is not None or preferred_element_type is not None:
+    if (
+        rhs.ndim != len(kernel_dims) or dimension_numbers != form or precision is not None
+        or preferred_element_type is not None
+    ):
         raise ValueError(
-            f"head_dot_general contracts the last dimension of lhs with the first of a [d, heads, width] kernel at "
+            f"{name} contracts the last dimension of lhs with the first of a [{', '.join(kernel_dims)}] kernel at "
             f"the default precision; got {lhs.shape} by {rhs.shape}, {dimension_numbers}, {precision}, "
             f"{preferred_element_type}"
         )
-    return _head_projection(lhs, rhs)
+    return _projection(lhs, rhs)
+
+
+# The rule's two forms: a head-shaped projection (``wq``/``wk``/``wv``,
+# ``_head_dense``), whose cotangent the backward flattens to ``[tokens, heads x
+# width]``, and ``MLPBlock``'s three matrices.
+head_dot_general = functools.partial(_checked_projection, "head_dot_general", ("d", "heads", "width"))
+matrix_dot_general = functools.partial(_checked_projection, "matrix_dot_general", ("d_in", "d_out"))
 
 
 def _head_dense(heads, logical_axes, cfg: DecoderConfig, name: str):
@@ -1324,14 +1340,19 @@ def layer_operator(cfg: DecoderConfig, kind: str, x, positions, segment_ids):
 
 
 class MLPBlock(nn.Module):
+    """SwiGLU. Its three matrices take ``matrix_dot_general``: each weight
+    gradient is a plain product, and AdamW's update of the leaf (or the write
+    into a scanned layer's stacked gradient) a fusion of its own (PERF.md
+    section 5 has both at their measured shares of the peak)."""
+
     cfg: DecoderConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate = _dense(cfg.d_ff, ("embed", "mlp"), cfg, "w_gate")(x)
-        up = _dense(cfg.d_ff, ("embed", "mlp"), cfg, "w_up")(x)
-        return _dense(cfg.d_model, ("mlp", "embed"), cfg, "w_down")(
+        gate = _dense(cfg.d_ff, ("embed", "mlp"), cfg, "w_gate", matrix_dot_general)(x)
+        up = _dense(cfg.d_ff, ("embed", "mlp"), cfg, "w_up", matrix_dot_general)(x)
+        return _dense(cfg.d_model, ("mlp", "embed"), cfg, "w_down", matrix_dot_general)(
             nn.silu(gate) * up
         )
 

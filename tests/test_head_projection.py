@@ -1,9 +1,10 @@
 """``head_dot_general``, the ``dot_general`` that a head-shaped projection of
-``Attention`` takes where it is wider than the model: its forward is
-``jax.lax.dot_general``'s bit for bit, its backward rule gives autodiff's two
-gradients to the order of the float32 sums, alone, under ``jax.checkpoint``
-inside ``nn.scan`` and on a sharded mesh; the module's parameters and the
-decode path do not change."""
+``Attention`` takes where it is wider than the model, and
+``matrix_dot_general``, the same rule for the three matrices of ``MLPBlock``:
+the forward is ``jax.lax.dot_general``'s bit for bit, the backward rule gives
+autodiff's two gradients to the order of the float32 sums, alone, under
+``jax.checkpoint`` inside ``nn.scan`` and unrolled, and on a sharded mesh; the
+modules' parameters and the decode path do not change."""
 
 import functools
 
@@ -14,12 +15,14 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from maggy_tpu.models import Decoder, DecoderConfig, transformer
-from maggy_tpu.models.transformer import Attention, head_dot_general
+from maggy_tpu.models import Decoder, DecoderConfig, MoEConfig, transformer
+from maggy_tpu.models.moe import ExpertShareBlock
+from maggy_tpu.models.transformer import Attention, MLPBlock, head_dot_general, matrix_dot_general
 from maggy_tpu.parallel.mesh import make_mesh
 from maggy_tpu.parallel.spec import ShardingSpec
 
-D = 96  # the model's width here; heads and head widths are the cells'
+D = 96  # the model's width here; heads, head widths and feed-forward widths are the cells'
+RULES = {"head": head_dot_general, "matrix": matrix_dot_general}
 
 
 def dims(x):
@@ -77,14 +80,55 @@ def test_rule_against_autodiff_at_the_cells_head_forms(cell, dtype):
         close(g, r, dtype)
 
 
-def test_rule_refuses_what_it_was_not_written_for():
+# the five cells that run MLPBlock: the feed-forward's width (the shared expert's where the cell has one)
+FEED_FORWARDS = {"mistral": 14336, "glm-dense": 10240, "glm-shared": 1536, "lfm2": 11776, "laguna-dense": 12288,
+                 "laguna-shared": 1024, "evabyte": 11008}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("cell", FEED_FORWARDS)
+def test_matrix_rule_against_autodiff_at_the_cells_feed_forward_widths(cell, dtype):
+    """``w_gate`` and ``w_up``'s form ``[D, d_ff]`` and ``w_down``'s ``[d_ff, D]``."""
+    d_ff = FEED_FORWARDS[cell]
+    kx, kw, kt = jax.random.split(jax.random.key(d_ff), 3)
+    xs = [jax.random.normal(k, (2, 24, d), dtype) for k, d in zip(jax.random.split(kx), (D, d_ff))]
+    ws = [(jax.random.normal(k, shape, jnp.float32) * 0.05).astype(dtype)
+          for k, shape in zip(jax.random.split(kw), ((D, d_ff), (d_ff, D)))]
+    ts = [jax.random.normal(k, (2, 24, d), dtype) for k, d in zip(jax.random.split(kt), (d_ff, D))]
+
+    @jax.jit
+    def both(xs, ws):
+        def objective(dot):
+            return lambda xs, ws: sum(
+                (dot(x, w, dims(x)).astype(jnp.float32) * t.astype(jnp.float32)).sum() for x, w, t in zip(xs, ws, ts)
+            )
+
+        outs = [[dot(x, w, dims(x)) for x, w in zip(xs, ws)] for dot in (matrix_dot_general, plain)]
+        return outs, [jax.grad(objective(dot), argnums=(0, 1))(xs, ws) for dot in (matrix_dot_general, plain)]
+
+    (outs, ref_outs), (grads, ref) = both(xs, ws)
+    for out, r, t in zip(outs, ref_outs, ts):
+        assert out.dtype == dtype and out.shape == t.shape
+        assert np.array_equal(np.asarray(out, np.float32), np.asarray(r, np.float32))  # the forward's bits
+    for g, r, operand in zip(jax.tree.leaves(grads), jax.tree.leaves(ref), (*xs, *ws)):
+        assert g.dtype == operand.dtype and g.shape == operand.shape
+        close(g, r, dtype)
+
+
+@pytest.mark.parametrize("form", RULES)
+def test_rule_refuses_what_it_was_not_written_for(form):
     x, w, _ = operands(4, 16, jnp.float32)
-    with pytest.raises(ValueError, match="head_dot_general"):
-        head_dot_general(x, w.reshape(D, 64), dims(x))  # a matrix
-    with pytest.raises(ValueError, match="head_dot_general"):
-        head_dot_general(x, w, (((1,), (0,)), ((), ())))  # another contraction
-    with pytest.raises(ValueError, match="head_dot_general"):
-        head_dot_general(x, w, dims(x), precision=jax.lax.Precision.HIGHEST)
+    kernels = {"head": w, "matrix": w.reshape(D, 64)}
+    rule, name, w = RULES[form], f"{form}_dot_general", kernels.pop(form)
+    (other,) = kernels.values()  # the other form's kernel
+    with pytest.raises(ValueError, match=name):
+        rule(x, other, dims(x))
+    with pytest.raises(ValueError, match=name):
+        rule(x, w, (((1,), (0,)), ((), ())))  # another contraction
+    with pytest.raises(ValueError, match=name):
+        rule(x, w, dims(x), precision=jax.lax.Precision.HIGHEST)
+    with pytest.raises(ValueError, match=name):
+        rule(x, w, dims(x), preferred_element_type=jnp.float32)
 
 
 def tiny(**kw):
@@ -92,13 +136,19 @@ def tiny(**kw):
     return DecoderConfig.tiny(**{"head_width": 32, **kw})
 
 
-def rule_calls(monkeypatch):
-    """Counts the projections that reach the rule from here on."""
-    calls = []
+def rule_calls(monkeypatch, form="head"):
+    """Counts the projections that reach the rule of ``form`` from here on."""
+    calls, rule = [], RULES[form]
     monkeypatch.setattr(
-        transformer, "head_dot_general", lambda x, w, *a, **k: calls.append(w.shape) or head_dot_general(x, w, *a, **k)
+        transformer, f"{form}_dot_general", lambda x, w, *a, **k: calls.append(w.shape) or rule(x, w, *a, **k)
     )
     return calls
+
+
+def without_rules(monkeypatch):
+    """Every projection takes ``DenseGeneral``'s own ``dot_general`` from here on."""
+    for form in RULES:
+        monkeypatch.setattr(transformer, f"{form}_dot_general", None)
 
 
 @pytest.mark.parametrize(
@@ -129,40 +179,90 @@ def decoder_grads(cfg, variables, tokens):
     return jax.jit(jax.value_and_grad(objective))(nn.meta.unbox(variables["params"]))
 
 
-def test_rule_under_checkpoint_inside_scan(monkeypatch):
-    """A scanned decoder, each layer under ``jax.checkpoint`` with the policy
-    ``nothing``: loss and every leaf's gradient as with plain ``dot_general``s."""
-    cfg = tiny(dtype=jnp.float32, scan_layers=True, remat=True, remat_policy="nothing")
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scanned", "unrolled"])
+def test_rule_under_checkpoint_inside_scan(monkeypatch, scan_layers):
+    """A decoder, scanned or unrolled, each layer under ``jax.checkpoint`` with
+    the policy ``nothing``: loss and every leaf's gradient as with plain
+    ``dot_general``s; ``wq`` takes the head form, the feed-forward's three
+    matrices the matrix form."""
+    cfg = tiny(dtype=jnp.float32, scan_layers=scan_layers, remat=True, remat_policy="nothing")
     tokens = jnp.asarray(np.arange(2 * 16).reshape(2, 16) % cfg.vocab_size, jnp.int32)
     variables = jax.jit(Decoder(cfg).init)(jax.random.key(3), tokens)
-    calls = rule_calls(monkeypatch)
+    calls, matrix_calls = rule_calls(monkeypatch), rule_calls(monkeypatch, "matrix")
     loss, grads = decoder_grads(cfg, variables, tokens)
     assert calls and set(calls) == {(cfg.d_model, cfg.n_heads, 32)}
-    monkeypatch.setattr(transformer, "head_dot_general", None)  # DenseGeneral's own
+    assert set(matrix_calls) == {(cfg.d_model, cfg.d_ff), (cfg.d_ff, cfg.d_model)}
+    without_rules(monkeypatch)
     ref_loss, ref_grads = decoder_grads(cfg, variables, tokens)
     assert float(loss) == float(ref_loss)
     for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref_grads)):
         np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-6 * float(np.abs(r).max()), err_msg=str(path))
 
 
-def test_rule_on_a_sharded_mesh_with_the_heads_on_tensor():
-    """Tokens over ``data`` x ``fsdp``, the kernel ``[embed, heads, width]``
-    over ``fsdp`` and ``tensor``: the gradients come back in the operands'
-    shardings and equal the unsharded rule's."""
-    mesh = make_mesh(ShardingSpec(dp=2, fsdp=2, tp=2))
+@pytest.mark.parametrize(
+    "form,degrees,tokens,kernel,out",
+    [
+        ("head", dict(dp=2, fsdp=2, tp=2), P(("data", "fsdp")), P("fsdp", "tensor"), P(("data", "fsdp"), None, "tensor")),
+        ("matrix", dict(dp=2, fsdp=2, tp=2), P(("data", "fsdp")), P("fsdp", "tensor"), P(("data", "fsdp"), None, "tensor")),
+        ("matrix", dict(dp=2, fsdp=2, tp=2), P(("data", "fsdp"), None, "tensor"), P("tensor", "fsdp"), P(("data", "fsdp"))),
+        ("matrix", dict(fsdp=2, sp=4), P("fsdp", "seq"), P("fsdp"), P("fsdp", "seq")),
+    ],
+    ids=["heads-on-tensor", "gate-mlp-on-tensor", "down-mlp-on-tensor", "seq-sharded"],
+)
+def test_rule_on_a_sharded_mesh(form, degrees, tokens, kernel, out):
+    """Tokens over ``data`` x ``fsdp`` and the kernel over ``fsdp`` and
+    ``tensor`` (the heads of ``[embed, heads, width]``; ``mlp`` in ``w_gate``'s
+    and in ``w_down``'s orientation), and tokens over ``fsdp`` and ``seq``: the
+    gradients come back in the operands' shardings and equal the unsharded
+    rule's. (That the partitioner rematerialises nothing in a whole step on a
+    ``seq``-sharded mesh is ``test_packed_sequences.py``'s to see: its tiny
+    decoder's feed-forward takes the matrix form.)"""
+    mesh, rule = make_mesh(ShardingSpec(**degrees)), RULES[form]
     x, w, t = operands(8, 16, jnp.float32, batch=4)
-    xs, ws = NamedSharding(mesh, P(("data", "fsdp"))), NamedSharding(mesh, P("fsdp", "tensor"))
-    ts = NamedSharding(mesh, P(("data", "fsdp"), None, "tensor"))
+    if form == "matrix":
+        w, t = w.reshape(D, 128), t.reshape(4, 24, 128)
+    xs, ws, ts = (NamedSharding(mesh, spec) for spec in (tokens, kernel, out))
 
     def grads(x, w, t):
-        return jax.grad(lambda x, w: (head_dot_general(x, w, dims(x)) * t).sum(), argnums=(0, 1))(x, w)
+        return jax.grad(lambda x, w: (rule(x, w, dims(x)) * t).sum(), argnums=(0, 1))(x, w)
 
     sharded = jax.jit(grads, in_shardings=(xs, ws, ts), out_shardings=(xs, ws))
     dx, dw = sharded(jax.device_put(x, xs), jax.device_put(w, ws), jax.device_put(t, ts))
-    assert dx.sharding.is_equivalent_to(xs, 3) and dw.sharding.is_equivalent_to(ws, 3)
+    assert dx.sharding.is_equivalent_to(xs, 3) and dw.sharding.is_equivalent_to(ws, w.ndim)
     rdx, rdw = jax.jit(grads)(x, w, t)
     np.testing.assert_allclose(dx, rdx, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(dw, rdw, rtol=2e-5, atol=2e-5)
+
+
+def partitioned_tree(boxed):
+    return {
+        "/".join(k.key for k in path): (leaf.value.shape, leaf.value.dtype, leaf.names)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(boxed, is_leaf=lambda a: isinstance(a, nn.Partitioned))
+    }
+
+
+@pytest.mark.parametrize("module", ["mlp", "shared-expert"])
+def test_feed_forward_parameters_are_what_a_parent_checkpoint_holds(module):
+    """Names, shapes, dtypes and logical axes of an ``MLPBlock``'s parameters,
+    alone and as the shared expert of an ``ExpertShareBlock``."""
+    f32 = jnp.dtype("float32")
+    x = jnp.zeros((1, 8, 48), jnp.bfloat16)
+    if module == "mlp":
+        cfg, d_ff, under = DecoderConfig(d_model=48, n_heads=6, n_kv_heads=2, d_ff=80, max_seq_len=32), 80, ""
+        boxed = jax.eval_shape(MLPBlock(cfg).init, jax.random.key(0), x)["params"]
+    else:
+        cfg = MoEConfig(
+            d_model=48, n_heads=6, n_kv_heads=2, d_ff=80, moe_d_ff=24, n_experts=4, experts_held=2, top_k=2,
+            n_shared_experts=2, max_seq_len=32,
+        )
+        d_ff, under = 48, "shared/"
+        boxed = jax.eval_shape(ExpertShareBlock(cfg).init, jax.random.key(0), x)["params"]
+    tree = {k: v for k, v in partitioned_tree(boxed).items() if k.startswith(under + "w_")}
+    assert tree == {
+        under + "w_gate/kernel": ((48, d_ff), f32, ("embed", "mlp")),
+        under + "w_up/kernel": ((48, d_ff), f32, ("embed", "mlp")),
+        under + "w_down/kernel": ((d_ff, 48), f32, ("mlp", "embed")),
+    }
 
 
 def test_attention_parameters_are_what_a_parent_checkpoint_holds():
@@ -171,12 +271,8 @@ def test_attention_parameters_are_what_a_parent_checkpoint_holds():
     cfg = DecoderConfig(d_model=48, n_heads=6, n_kv_heads=2, head_width=16, qk_norm=True, max_seq_len=32)  # wq is wide
     x, ids = jnp.zeros((1, 8, 48), cfg.dtype), jnp.zeros((1, 8), jnp.int32)
     boxed = jax.eval_shape(Attention(cfg).init, jax.random.key(0), x, ids)["params"]
-    tree = {
-        "/".join(k.key for k in path): (leaf.value.shape, leaf.value.dtype, leaf.names)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(boxed, is_leaf=lambda a: isinstance(a, nn.Partitioned))
-    }
     f32 = jnp.dtype("float32")
-    assert tree == {
+    assert partitioned_tree(boxed) == {
         "wq/kernel": ((48, 6, 16), f32, ("embed", "heads", None)),
         "wk/kernel": ((48, 2, 16), f32, ("embed", "kv", None)),
         "wv/kernel": ((48, 2, 16), f32, ("embed", "kv", None)),
@@ -205,8 +301,10 @@ def test_decode_values_unchanged(monkeypatch):
             outs.append(np.asarray(logits, np.float32))
         return outs
 
+    calls, matrix_calls = rule_calls(monkeypatch), rule_calls(monkeypatch, "matrix")
     ours = run()
-    monkeypatch.setattr(transformer, "head_dot_general", None)
+    assert calls and matrix_calls  # both forms stand in the decode path's forward
+    without_rules(monkeypatch)
     for a, b in zip(ours, run()):
         assert np.array_equal(a, b)
 

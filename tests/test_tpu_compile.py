@@ -298,6 +298,55 @@ def test_laguna_width_projections_backward_is_plain_products(one_chip, monkeypat
     assert not re.findall(rf"= f32\[(?:1,)?{d},{heads},128\]\S* copy\(", text)
 
 
+def test_evabyte_width_feed_forward_weight_gradients_are_plain_products(one_chip, monkeypatch):
+    """An unrolled, recomputed feed-forward at evabyte's widths (4,096 by
+    11,008) on one packed row of 16,384, its gradient and AdamW's update of
+    float32 parameters: no fusion holds both a weight gradient's product (a
+    convolution under ``mlp/w_*/dot_general`` in the backward) and a float32
+    result of the kernel's shape, which is AdamW's update of the leaf written
+    through the product's output tile. With ``DenseGeneral``'s own
+    ``dot_general`` (the positive control) every one of the three does."""
+    import optax
+
+    from maggy_tpu.models import transformer
+
+    s, d, d_ff = 16384, 4096, 11008
+    cfg = DecoderConfig(d_model=d, n_heads=32, n_kv_heads=32, d_ff=d_ff, max_seq_len=s, partition_params=False)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return x + transformer.MLPBlock(cfg, name="mlp")(transformer.RMSNorm(cfg, name="mlp_norm")(x))
+
+    layer = nn.remat(Layer, policy=REMAT_POLICIES["nothing"])()
+    tx = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip)
+    described = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip))
+    params = described(jax.eval_shape(layer.init, jax.random.key(0), x))
+    opt_state = described(jax.eval_shape(tx.init, params))
+
+    def updates_inside_products():
+        def step(params, opt_state, x):  # a function of its own each time: jit's cache is keyed by it
+            grads = jax.grad(lambda p: jnp.square(layer.apply(p, x).astype(jnp.float32)).mean())(params)
+            updates, new_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), new_state
+
+        text = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, x).compile().as_text()
+        held = []
+        for computation in re.split(r"\n(?=\S[^\n]*\{\n)", text):
+            product = re.search(
+                r' convolution\([^\n]*op_name="[^"]*transpose\(jvp[^"]*mlp/(w_\w+)/dot_general', computation
+            )
+            root = re.search(r"\n\s*ROOT [^\n]*", computation)
+            if product and root and re.search(rf"f32\[(?:{d},{d_ff}|{d_ff},{d})\]", root.group(0).split(" = ")[1]):
+                held.append(product.group(1))
+        return sorted(held)
+
+    assert updates_inside_products() == []
+    monkeypatch.setattr(transformer, "matrix_dot_general", None)
+    assert updates_inside_products() == ["w_down", "w_gate", "w_up"]
+
+
 def test_chunk_summary_attention_compiles_at_the_evabyte_cells_shape(one_chip):
     """A layer of chunk summaries (``ops/eva.py``) at the evabyte cell's row:
     one packed row of 16,384, 32 heads of 128, windows of 2,048 and chunks of
