@@ -483,6 +483,22 @@ def _projection_bwd(res, g):
     # the state's layout, and its consumer a memory-bound fusion of its own.
     dw = jax.lax.optimization_barrier(jax.lax.dot_general(x, g2, ((lead, lead), ((), ()))))
     dx = jax.lax.dot_general(g2, w.reshape(w.shape[0], -1), (((x.ndim - 1,), (1,)), ((), ())))
+    # The input's gradient is held the same way where the product narrows
+    # ([tokens, features] by [features, d] with features > d: w_gate's and
+    # w_up's in a feed-forward wider than the model, a wide wq's). What
+    # consumes such a dx is a sum with its siblings' and the row and column
+    # reductions of the norm's backward before it; fused into the product they
+    # tile it for their small float32 results and it runs at half its rate.
+    # Held, the product is a plain one and the sum and the reductions one
+    # memory-bound pass over [tokens, d]. Where the product widens (w_down's
+    # [tokens, d] by [d, d_ff] in such a feed-forward) dx keeps autodiff's
+    # form: its consumer is elementwise on [tokens, d_ff] (the SwiGLU's
+    # backward) and runs as the product's epilogue, which a barrier would turn
+    # into a pass over the wider array and one more live copy of it. (A shared
+    # expert narrower than the model has the shapes the other way round; its
+    # products are small and either form runs them alike.)
+    if g2.shape[-1] > x.shape[-1]:
+        dx = jax.lax.optimization_barrier(dx)
     return dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype)
 
 
@@ -495,7 +511,8 @@ def _checked_projection(name, kernel_dims, lhs, rhs, dimension_numbers, precisio
     dimension of ``lhs`` at the default precision, and refuses anything else.
     The forward is ``dot_general``'s, bit for bit; the backward makes the two
     gradients as products of matrices, the weight's held apart from what
-    consumes it (``_projection_bwd``)."""
+    consumes it and the input's too where it is narrower than the cotangent
+    (``_projection_bwd``)."""
     form = (((lhs.ndim - 1,), (0,)), ((), ()))
     if (
         rhs.ndim != len(kernel_dims) or dimension_numbers != form or precision is not None
@@ -520,8 +537,11 @@ def _head_dense(heads, logical_axes, cfg: DecoderConfig, name: str):
     """A head-shaped projection of ``Attention``. Where it is wider than the
     model (``heads x width > d_model``: more query heads than the model's
     width holds) its backward is ``head_dot_general``'s: there the rule's
-    plain products gain more than its transposing pass of the cotangent costs
-    (72, 48 and 32 heads of 128 from 3,072 and 2,048, PERF.md section 6);
+    plain products (the weight's gradient and, the product narrowing from
+    ``heads x width`` to ``d_model``, the input's, whose sum with ``wk``'s and
+    ``wv``'s is then a pass of its own) gain more than its transposing pass of
+    the cotangent costs (72, 48 and 32 heads of 128 from 3,072 and 2,048,
+    PERF.md section 6);
     a square projection and the few key and value heads keep ``dot_general``'s
     own transpose, which on a v5e they run no slower."""
     wide = heads * cfg.head_dim > cfg.d_model
@@ -1342,8 +1362,13 @@ def layer_operator(cfg: DecoderConfig, kind: str, x, positions, segment_ids):
 class MLPBlock(nn.Module):
     """SwiGLU. Its three matrices take ``matrix_dot_general``: each weight
     gradient is a plain product, and AdamW's update of the leaf (or the write
-    into a scanned layer's stacked gradient) a fusion of its own (PERF.md
-    section 5 has both at their measured shares of the peak)."""
+    into a scanned layer's stacked gradient) a fusion of its own; an input
+    gradient narrower than its cotangent (``w_gate``'s and ``w_up``'s,
+    ``[tokens, d_ff]`` by ``[d_ff, d_model]``, where ``d_ff > d_model``) is a
+    plain product too, and the sum of the two with the norm's backward before
+    this block one pass over ``[tokens, d_model]``; a wider one (``w_down``'s
+    there) keeps the SwiGLU's elementwise backward as its epilogue (PERF.md
+    section 5 has them at their measured shares of the peak)."""
 
     cfg: DecoderConfig
 

@@ -3,8 +3,10 @@
 ``matrix_dot_general``, the same rule for the three matrices of ``MLPBlock``:
 the forward is ``jax.lax.dot_general``'s bit for bit, the backward rule gives
 autodiff's two gradients to the order of the float32 sums, alone, under
-``jax.checkpoint`` inside ``nn.scan`` and unrolled, and on a sharded mesh; the
-modules' parameters and the decode path do not change."""
+``jax.checkpoint`` inside ``nn.scan`` and unrolled, and on a sharded mesh, in
+bfloat16 and float32; the weight's gradient is held by a barrier always, the
+input's where the product narrows; the modules' parameters and the decode path
+do not change."""
 
 import functools
 
@@ -179,13 +181,16 @@ def decoder_grads(cfg, variables, tokens):
     return jax.jit(jax.value_and_grad(objective))(nn.meta.unbox(variables["params"]))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("scan_layers", [True, False], ids=["scanned", "unrolled"])
-def test_rule_under_checkpoint_inside_scan(monkeypatch, scan_layers):
+def test_rule_under_checkpoint_inside_scan(monkeypatch, scan_layers, dtype):
     """A decoder, scanned or unrolled, each layer under ``jax.checkpoint`` with
     the policy ``nothing``: loss and every leaf's gradient as with plain
     ``dot_general``s; ``wq`` takes the head form, the feed-forward's three
-    matrices the matrix form."""
-    cfg = tiny(dtype=jnp.float32, scan_layers=scan_layers, remat=True, remat_policy="nothing")
+    matrices the matrix form. Both forms' ``dx`` (``wq``'s, ``w_gate``'s and
+    ``w_up``'s held by the barrier, ``w_down``'s not) reach every leaf below
+    them: the norms' scales, the layers under the last, the embedding."""
+    cfg = tiny(dtype=dtype, scan_layers=scan_layers, remat=True, remat_policy="nothing")
     tokens = jnp.asarray(np.arange(2 * 16).reshape(2, 16) % cfg.vocab_size, jnp.int32)
     variables = jax.jit(Decoder(cfg).init)(jax.random.key(3), tokens)
     calls, matrix_calls = rule_calls(monkeypatch), rule_calls(monkeypatch, "matrix")
@@ -195,8 +200,10 @@ def test_rule_under_checkpoint_inside_scan(monkeypatch, scan_layers):
     without_rules(monkeypatch)
     ref_loss, ref_grads = decoder_grads(cfg, variables, tokens)
     assert float(loss) == float(ref_loss)
+    # float32: the order of the sums; bfloat16: a rounding of each product on the way down, against the leaf's largest
+    rtol, atol = (2e-4, 2e-6) if dtype == jnp.float32 else (2**-5, 2**-7)
     for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref_grads)):
-        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-6 * float(np.abs(r).max()), err_msg=str(path))
+        np.testing.assert_allclose(g, r, rtol=rtol, atol=atol * float(np.abs(r).max()), err_msg=str(path))
 
 
 @pytest.mark.parametrize(
@@ -206,21 +213,28 @@ def test_rule_under_checkpoint_inside_scan(monkeypatch, scan_layers):
         ("matrix", dict(dp=2, fsdp=2, tp=2), P(("data", "fsdp")), P("fsdp", "tensor"), P(("data", "fsdp"), None, "tensor")),
         ("matrix", dict(dp=2, fsdp=2, tp=2), P(("data", "fsdp"), None, "tensor"), P("tensor", "fsdp"), P(("data", "fsdp"))),
         ("matrix", dict(fsdp=2, sp=4), P("fsdp", "seq"), P("fsdp"), P("fsdp", "seq")),
+        ("down", dict(dp=2, fsdp=2, tp=2), P(("data", "fsdp"), None, "tensor"), P("tensor", "fsdp"), P(("data", "fsdp"))),
     ],
-    ids=["heads-on-tensor", "gate-mlp-on-tensor", "down-mlp-on-tensor", "seq-sharded"],
+    ids=["heads-on-tensor", "gate-mlp-on-tensor", "down-mlp-on-tensor", "seq-sharded", "down-widens-on-tensor"],
 )
-def test_rule_on_a_sharded_mesh(form, degrees, tokens, kernel, out):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_rule_on_a_sharded_mesh(form, degrees, tokens, kernel, out, dtype):
     """Tokens over ``data`` x ``fsdp`` and the kernel over ``fsdp`` and
     ``tensor`` (the heads of ``[embed, heads, width]``; ``mlp`` in ``w_gate``'s
     and in ``w_down``'s orientation), and tokens over ``fsdp`` and ``seq``: the
-    gradients come back in the operands' shardings and equal the unsharded
-    rule's. (That the partitioner rematerialises nothing in a whole step on a
-    ``seq``-sharded mesh is ``test_packed_sequences.py``'s to see: its tiny
-    decoder's feed-forward takes the matrix form.)"""
-    mesh, rule = make_mesh(ShardingSpec(**degrees)), RULES[form]
-    x, w, t = operands(8, 16, jnp.float32, batch=4)
-    if form == "matrix":
+    gradients come back in the operands' shardings and types and equal the
+    unsharded rule's. ``dx`` is held by the barrier in the first four cases
+    (96 inputs, 128 features) and left to autodiff in the last, the matrix
+    form at ``w_down``'s own shape (128 inputs, 96 features). (That the
+    partitioner rematerialises nothing in a whole step on a ``seq``-sharded
+    mesh is ``test_packed_sequences.py``'s to see: its tiny decoder's
+    feed-forward takes the matrix form.)"""
+    mesh, rule = make_mesh(ShardingSpec(**degrees)), RULES.get(form, matrix_dot_general)  # "down" is a matrix too
+    x, w, t = operands(8, 16, dtype, batch=4)
+    if form != "head":
         w, t = w.reshape(D, 128), t.reshape(4, 24, 128)
+    if form == "down":
+        x, w, t = t, w.T, x
     xs, ws, ts = (NamedSharding(mesh, spec) for spec in (tokens, kernel, out))
 
     def grads(x, w, t):
@@ -229,9 +243,41 @@ def test_rule_on_a_sharded_mesh(form, degrees, tokens, kernel, out):
     sharded = jax.jit(grads, in_shardings=(xs, ws, ts), out_shardings=(xs, ws))
     dx, dw = sharded(jax.device_put(x, xs), jax.device_put(w, ws), jax.device_put(t, ts))
     assert dx.sharding.is_equivalent_to(xs, 3) and dw.sharding.is_equivalent_to(ws, w.ndim)
+    assert dx.dtype == dw.dtype == dtype
     rdx, rdw = jax.jit(grads)(x, w, t)
-    np.testing.assert_allclose(dx, rdx, rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(dw, rdw, rtol=2e-5, atol=2e-5)
+    close(dx, rdx, dtype)
+    close(dw, rdw, dtype)
+
+
+def barriers(jaxpr):
+    """The shapes that ``optimization_barrier``s hold in a jaxpr and in the jaxprs inside it."""
+    held = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "optimization_barrier":
+            held += [v.aval.shape for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            held += barriers(sub)
+    return held
+
+
+@pytest.mark.parametrize("form", RULES)
+def test_which_gradients_the_barrier_holds(form):
+    """In the backward's jaxpr: the weight's gradient is held always, as the
+    matrix ``[d, features]``; the input's where the product narrows (``wq``
+    wider than the model, ``w_gate`` and ``w_up``: ``[tokens, features]`` by
+    ``[features, d]`` with ``features > d``), not where it widens
+    (``w_down``, whose ``dx`` is ``[tokens, d_ff]``) or keeps the width."""
+    x, w, t = operands(8, 16, jnp.bfloat16)  # 8 x 16 = 128 features from D = 96
+    narrow, same = operands(2, 16, jnp.bfloat16), operands(6, 16, jnp.bfloat16)  # 32 and 96 features from 96
+    cases = [((x, w, t), [(D, 128), x.shape]), (narrow, [(D, 32)]), (same, [(D, 96)])]
+    if form == "matrix":
+        cases = [((x, w.reshape(D, -1), t.reshape(2, 24, -1)), held) for (x, w, t), held in cases]
+        cases.append(((t.reshape(2, 24, 128), w.reshape(D, 128).T, x), [(128, D)]))  # w_down's orientation
+    for (x, w, t), held in cases:
+        jaxpr = jax.make_jaxpr(
+            jax.grad(lambda x, w: (RULES[form](x, w, dims(x)).astype(jnp.float32) * t).sum(), argnums=(0, 1))
+        )(x, w)
+        assert sorted(barriers(jaxpr.jaxpr)) == sorted(held), (w.shape, barriers(jaxpr.jaxpr))
 
 
 def partitioned_tree(boxed):

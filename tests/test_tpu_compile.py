@@ -298,6 +298,31 @@ def test_laguna_width_projections_backward_is_plain_products(one_chip, monkeypat
     assert not re.findall(rf"= f32\[(?:1,)?{d},{heads},128\]\S* copy\(", text)
 
 
+def evabyte_feed_forward(one_chip):
+    """An unrolled, recomputed feed-forward behind its ``RMSNorm`` at evabyte's
+    widths (4,096 by 11,008) on one packed row of 16,384, described for the
+    chip: the layer, its input, its parameters and the three sizes."""
+    from maggy_tpu.models import transformer
+
+    s, d, d_ff = 16384, 4096, 11008
+    cfg = DecoderConfig(d_model=d, n_heads=32, n_kv_heads=32, d_ff=d_ff, max_seq_len=s, partition_params=False)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return x + transformer.MLPBlock(cfg, name="mlp")(transformer.RMSNorm(cfg, name="mlp_norm")(x))
+
+    layer = nn.remat(Layer, policy=REMAT_POLICIES["nothing"])()
+    x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip)
+    described = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip))
+    return layer, x, described(jax.eval_shape(layer.init, jax.random.key(0), x)), described, (s, d, d_ff)
+
+
+def fused_computations(text):
+    """The computations of a compiled program's text, one string each."""
+    return re.split(r"\n(?=\S[^\n]*\{\n)", text)
+
+
 def test_evabyte_width_feed_forward_weight_gradients_are_plain_products(one_chip, monkeypatch):
     """An unrolled, recomputed feed-forward at evabyte's widths (4,096 by
     11,008) on one packed row of 16,384, its gradient and AdamW's update of
@@ -310,19 +335,8 @@ def test_evabyte_width_feed_forward_weight_gradients_are_plain_products(one_chip
 
     from maggy_tpu.models import transformer
 
-    s, d, d_ff = 16384, 4096, 11008
-    cfg = DecoderConfig(d_model=d, n_heads=32, n_kv_heads=32, d_ff=d_ff, max_seq_len=s, partition_params=False)
-
-    class Layer(nn.Module):
-        @nn.compact
-        def __call__(self, x):
-            return x + transformer.MLPBlock(cfg, name="mlp")(transformer.RMSNorm(cfg, name="mlp_norm")(x))
-
-    layer = nn.remat(Layer, policy=REMAT_POLICIES["nothing"])()
+    layer, x, params, described, (_, d, d_ff) = evabyte_feed_forward(one_chip)
     tx = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
-    x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip)
-    described = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip))
-    params = described(jax.eval_shape(layer.init, jax.random.key(0), x))
     opt_state = described(jax.eval_shape(tx.init, params))
 
     def updates_inside_products():
@@ -333,7 +347,7 @@ def test_evabyte_width_feed_forward_weight_gradients_are_plain_products(one_chip
 
         text = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, x).compile().as_text()
         held = []
-        for computation in re.split(r"\n(?=\S[^\n]*\{\n)", text):
+        for computation in fused_computations(text):
             product = re.search(
                 r' convolution\([^\n]*op_name="[^"]*transpose\(jvp[^"]*mlp/(w_\w+)/dot_general', computation
             )
@@ -345,6 +359,38 @@ def test_evabyte_width_feed_forward_weight_gradients_are_plain_products(one_chip
     assert updates_inside_products() == []
     monkeypatch.setattr(transformer, "matrix_dot_general", None)
     assert updates_inside_products() == ["w_down", "w_gate", "w_up"]
+
+
+def test_evabyte_width_feed_forward_input_gradient_is_a_plain_product(one_chip, monkeypatch):
+    """The same feed-forward, the gradient of its parameters and of its input:
+    no fusion holds both an input gradient's narrowing product (``[16384,
+    11008]`` by ``[11008, 4096]``, a convolution under ``mlp/w_gate`` or
+    ``mlp/w_up`` in the backward) and a reduction to ``f32[4096]``, which is
+    the norm's scale gradient computed through the product's output tile.
+    With ``DenseGeneral``'s own ``dot_general`` (the positive control) one
+    does: ``w_gate``'s, with the sum of the two input gradients inside."""
+    from maggy_tpu.models import transformer
+
+    layer, x, params, _, (s, d, _) = evabyte_feed_forward(one_chip)
+
+    def reductions_inside_products():
+        def step(params, x):  # a function of its own each time: jit's cache is keyed by it
+            return jax.grad(lambda p, x: jnp.square(layer.apply(p, x).astype(jnp.float32)).mean(), argnums=(0, 1))(params, x)
+
+        text = jax.jit(step).lower(params, x).compile().as_text()
+        held = []
+        for computation in fused_computations(text):
+            product = re.search(
+                rf' bf16\[{s},{d}\]\S* convolution\([^\n]*op_name="[^"]*transpose\(jvp[^"]*mlp/(w_\w+)/dot_general',
+                computation,
+            )
+            if product and re.search(rf" f32\[{d}\]\S* reduce\(", computation):
+                held.append(product.group(1))
+        return sorted(held)
+
+    assert reductions_inside_products() == []
+    monkeypatch.setattr(transformer, "matrix_dot_general", None)
+    assert reductions_inside_products() == ["w_gate"]
 
 
 def test_chunk_summary_attention_compiles_at_the_evabyte_cells_shape(one_chip):
