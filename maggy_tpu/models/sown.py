@@ -1,0 +1,154 @@
+"""What a model hands back from a step beside its logits: the names its layers
+sow into ``intermediates`` and what each becomes. A train step applies the
+model with ``mutable=["intermediates"]`` and reads the result through here and
+nowhere else: the dense step, the bucketed/ZeRO step and the pipeline's stage
+adapter (``train/trainer.py``, ``train/pipeline_adapter.py``) and the tests.
+
+A step counter is written in three places: the layer's ``sow``, its row in
+:data:`COUNTERS`, and the gauge's name and unit in ``telemetry/metrics.py``
+(``docs/observability.md`` "Adding a step counter"). ``Trainer.step`` returns
+every key of :func:`step_counters` and ``Trainer.fit`` records each under its
+row's gauge name; neither names a layer.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+def _leaves(mods, match: str) -> list:
+    """The intermediates whose path holds ``match``, in the tree's order."""
+    flat = jax.tree_util.tree_flatten_with_path(mods.get("intermediates", {}))[0]
+    return [leaf for path, leaf in flat if match in jax.tree_util.keystr(path)]
+
+
+def sown(mods, name: str) -> list:
+    """Every intermediate a model sowed under ``name``, wherever in it."""
+    return _leaves(mods, f"'{name}'")
+
+
+def collect_aux_losses(mods) -> jax.Array:
+    """Sum every ``*aux_loss`` intermediate a model sowed (MoE router
+    balancing, the indexer's loss). THE one matching rule — every train step
+    and the tests collect through here, so models that sow and trainers that
+    collect cannot silently desync."""
+    aux = jnp.zeros((), jnp.float32)
+    for leaf in _leaves(mods, "aux_loss"):
+        aux = aux + jnp.sum(leaf).astype(jnp.float32)
+    return aux
+
+
+def mtp_logits(mods) -> Optional[jax.Array]:
+    """The further heads' logits of a model that sowed ``mtp_logits``
+    (``MoEDecoder``: ``[B, S, vocab]``, the token two ahead; ``Decoder`` with
+    ``pred_heads``: ``[B, S, heads - 1, vocab]``, head ``i`` the token
+    ``i + 2`` ahead). ``None`` for every other model."""
+    logits = sown(mods, "mtp_logits")
+    return logits[0] if logits else None
+
+
+def _stacked(leaves: list, width: int) -> jax.Array:
+    """A row of ``width`` numbers a layer (scanned layers sow them stacked), all layers' rows."""
+    return jnp.concatenate([a.reshape(-1, width) for a in leaves])
+
+
+def _expert_load(load, dropped):
+    load = jnp.concatenate([a.reshape(-1, a.shape[-1]) for a in load]).astype(jnp.float32)
+    return {
+        "moe_slots": load.sum(),
+        "moe_slots_dropped": sum(jnp.sum(a) for a in dropped).astype(jnp.float32),
+        "moe_load_max_over_mean": jnp.mean(load.max(-1) / jnp.maximum(load.mean(-1), 1.0)),
+    }
+
+
+def _rows_visited(rows):
+    visited, of = _stacked(rows, 2).sum(0)
+    return {"moe_rows_visited_share": visited / of}
+
+
+def _taps_masked(taps):
+    masked, of = _stacked(taps, 2).sum(0)
+    return {"conv_taps_masked_share": masked / of}
+
+
+def _index_loss(loss):
+    return {"index_loss": sum(jnp.sum(a) for a in loss).astype(jnp.float32)}
+
+
+def _sparse_counts(counts):
+    selected, visible, off = _stacked(counts, 3).astype(jnp.float32).sum(0)
+    return {"sparse_selected_share": selected / jnp.maximum(visible, 1.0), "sparse_rows_off_k": off}
+
+
+def _window_pairs(pairs):
+    inside, causal = _stacked(pairs, 2).astype(jnp.float32).sum(0)
+    return {"window_pairs_share": inside / jnp.maximum(causal, 1.0)}
+
+
+def _eva_counts(counts):
+    remote, seen, cut, chunks = _stacked(counts, 4).astype(jnp.float32).sum(0)
+    return {
+        "eva_remote_share": remote / jnp.maximum(seen, 1.0), "eva_chunks_cut_share": cut / jnp.maximum(chunks, 1.0),
+    }
+
+
+class Counter(NamedTuple):
+    """One row: the names a layer sows, what the step makes of every layer's
+    leaves under them (one list a name, in order), and for each key of that in
+    the step's output the gauge ``Trainer.fit`` records it under. A row reads
+    nothing for a model that did not sow its first name."""
+
+    names: Tuple[str, ...]
+    reduce: Callable[..., Dict[str, jax.Array]]
+    gauges: Dict[str, str]
+
+
+COUNTERS: Tuple[Counter, ...] = (
+    # ``ExpertShareBlock`` (models/moe.py): the (token, choice) slots on held experts, those a
+    # buffer cut (dropless: 0), the busiest held expert's load over the mean one's, a mean over
+    # the layers
+    Counter(("expert_load", "slots_dropped"), _expert_load, {
+        "moe_slots": "moe.slots", "moe_slots_dropped": "moe.slots_dropped",
+        "moe_load_max_over_mean": "moe.load_max_over_mean",
+    }),
+    # ``ExpertShareBlock``, [visited, of] a layer: the rows of the layers' buffers that the chunks
+    # that ran visited over the rows they hold (1.0: every layer worked through its whole buffer)
+    Counter(("rows_visited",), _rows_visited, {"moe_rows_visited_share": "moe.rows_visited_share"}),
+    # ``ShortConv``, [masked, of] a layer: the taps zeroed at row and document starts over all
+    # taps, which says that the batch's packing reached the operator
+    Counter(("taps_masked",), _taps_masked, {"conv_taps_masked_share": "conv.taps_masked_share"}),
+    # ``Attention`` with ``sparse_topk``: the indexer's loss summed over the layers
+    Counter(("index_aux_loss",), _index_loss, {"index_loss": "sparse.index_loss"}),
+    # ``Attention`` where it selects, [pairs selected, pairs visible, queries off their count] a
+    # layer: the first over the second, and the queries whose set is not
+    # ``min(sparse_topk, visible)`` keys (an exact selection: 0)
+    Counter(("sparse_counts",), _sparse_counts, {
+        "sparse_selected_share": "sparse.selected_share", "sparse_rows_off_k": "sparse.rows_off_k",
+    }),
+    # ``Attention`` of kind ``sliding_attention``, [pairs inside window, document and causal
+    # order, causal pairs inside documents] a layer: how much of a full layer's attention the
+    # window keeps on this batch
+    Counter(("window_pairs",), _window_pairs, {"window_pairs_share": "attention.window_pairs_share"}),
+    # ``Attention`` of kind ``eva_attention``, [summaries seen, all entries seen, chunks in which
+    # two documents meet, chunks] a layer: the summaries over all the entries the real queries'
+    # softmax runs over, and the chunks a document's start cuts over all chunks, which says that
+    # the packing reached the summaries
+    Counter(("eva_counts",), _eva_counts, {
+        "eva_remote_share": "attention.eva_remote_share", "eva_chunks_cut_share": "attention.eva_chunks_cut_share",
+    }),
+)
+
+
+def step_counters(mods) -> Dict[str, jax.Array]:
+    """The step's counters of whatever layers the model has: every row of
+    :data:`COUNTERS` whose names it sowed, reduced over its layers. Empty for
+    a model that sows none."""
+    out: Dict[str, jax.Array] = {}
+    for row in COUNTERS:
+        leaves = [sown(mods, name) for name in row.names]
+        if leaves[0]:
+            out.update(row.reduce(*leaves))
+    return out

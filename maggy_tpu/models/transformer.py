@@ -33,6 +33,7 @@ from maggy_tpu.ops.flash import (
     flash_attention,
     lane_fill,
     sharded_flash_attention,
+    tiles_visited_share,
 )
 
 Dtype = Any
@@ -278,6 +279,33 @@ class DecoderConfig:
         return tuple(
             self.attention_form(kind)[1] for kind in self.layer_kinds() if kind != "conv"
         )
+
+    def tiles_visited_share(self, segment_ids) -> Optional[float]:
+        """Of the tiles in the attention layers' forward grids for a packed
+        host batch (``segment_ids`` [B, S], numpy), the share the kernels
+        visit: a mean over the attention layers, each in its form. A plain
+        layer's grid is ``ops.flash.tiles_visited_share``, a windowed one's
+        the same with the window's tiles counted out, and a layer of chunk
+        summaries on a row longer than its window counts the tiles of its two
+        grids (``ops.eva.tiles_visited_share``). None where any form's tiles
+        do not divide the row."""
+        kinds = [kind for kind in self.layer_kinds() if kind != "conv"]
+        forms = [  # (window, chunk) a layer; chunk 0: no summaries
+            (self.eva_window, self.eva_chunk) if kind == "eva_attention" else (window, 0)
+            for kind, window in zip(kinds, self.attention_windows())
+        ]
+        if not any(map(any, forms)):  # every layer plain: one form
+            forms = [(0, 0)]
+
+        def visited(window, chunk):
+            if chunk and segment_ids.shape[1] > window:
+                return eva.tiles_visited_share(segment_ids, window=window, chunk=chunk, head_dim=self.head_dim)
+            return tiles_visited_share(segment_ids, head_dim=self.head_dim, window=0 if chunk else window)
+
+        shares = {form: visited(*form) for form in set(forms)}
+        if None in shares.values():
+            return None
+        return sum(shares[form] for form in forms) / len(forms)
 
     def __post_init__(self):
         if self.kv_lora_rank:

@@ -135,16 +135,10 @@ def _telemetry_lines(status: dict, width: int) -> list:
             parts.append(f"lag {g['metrics_lag']:.0f}")
         if "mfu_est" in g:
             parts.append(f"mfu {100 * g['mfu_est']:.1f}%")
-        # gradient-overlap health (docs/distributed.md "Gradient overlap &
-        # ZeRO"): reduction buckets in the compiled step, and how much comm
-        # is still exposed on the critical path vs hidden under backward
+        # gradient overlap (docs/distributed.md "Gradient overlap & ZeRO"):
+        # reduction buckets in the compiled step
         if "train.bucket_count" in g:
             parts.append(f"buckets {g['train.bucket_count']:.0f}")
-        if "train.comm_exposed_ms" in g:
-            parts.append(
-                f"comm {g['train.comm_exposed_ms']:.1f}ms exposed"
-                f"/{g.get('train.comm_overlapped_ms', 0):.1f}ms hidden"
-            )
         if "compile_time_ms" in g:
             parts.append(f"compile {g['compile_time_ms'] / 1e3:.1f}s")
         if "heartbeat_rtt_ms" in g:

@@ -55,7 +55,6 @@ __all__ = [
     "reflatten_opt_state",
     "opt_state_bytes_per_device",
     "latency_hiding_flags",
-    "record_overlap_gauges",
 ]
 
 
@@ -305,37 +304,3 @@ def latency_hiding_flags() -> Tuple[str, ...]:
         "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
         "--xla_tpu_overlap_compute_collective_tc=true",
     )
-
-
-def record_overlap_gauges(
-    times: Dict[str, float], manual_axes, telemetry_recorder=None
-) -> Dict[str, float]:
-    """Fold measured step times into the ``train.comm_*`` gauges.
-
-    ``times`` needs ``dense`` (unbucketed GSPMD step), ``bucketed`` (full
-    overlap step) and ``nocomm`` (overlap step with every reduction
-    stripped — pure compute); optional ``only_<axis>`` entries (reduction
-    over one mesh axis only) yield the per-axis ICI-vs-DCN exposure
-    gauges. total comm = dense - nocomm; exposed = bucketed - nocomm;
-    overlapped = total - exposed."""
-    from maggy_tpu import telemetry
-
-    tel = telemetry_recorder if telemetry_recorder is not None else telemetry.get()
-    nocomm = times["nocomm"]
-    total = max(times["dense"] - nocomm, 0.0)
-    exposed = max(times["bucketed"] - nocomm, 0.0)
-    overlapped = max(total - exposed, 0.0)
-    tel.gauge("train.comm_exposed_ms", exposed)
-    tel.gauge("train.comm_overlapped_ms", overlapped)
-    out = {
-        "comm_total_ms": total,
-        "comm_exposed_ms": exposed,
-        "comm_overlapped_ms": overlapped,
-    }
-    for ax in manual_axes:
-        key = f"only_{ax}"
-        if key in times:
-            ax_exposed = max(times[key] - nocomm, 0.0)
-            tel.gauge(f"train.comm_exposed_ms.{ax}", ax_exposed)
-            out[f"comm_exposed_ms_{ax}"] = ax_exposed
-    return out

@@ -114,8 +114,6 @@ GAUGES = frozenset(
         # gradient overlap + ZeRO (parallel/overlap.py, train/trainer.py;
         # docs/distributed.md "Gradient overlap & ZeRO")
         "train.bucket_count",  # gradient-reduction buckets in the compiled step
-        "train.comm_exposed_ms",  # comm time still on the critical path
-        "train.comm_overlapped_ms",  # comm time hidden under backward
         # autopilot online controller (autopilot/controller.py)
         "autopilot.tick_ms",  # per-sample controller cost (≤2% budget)
         # elastic membership (resilience/membership.py, core/driver/distributed.py)
@@ -375,7 +373,6 @@ DYNAMIC_PREFIXES = (
     "serve.requests_",  # scheduler terminal-state counters
     "rpc_errors.",  # per-verb client failures (recorder.rpc)
     "rpc_frame_errors.",  # server frame hygiene (core/rpc.py)
-    "train.comm_exposed_ms.",  # per-mesh-axis comm exposure (".data" ICI / ".slice" DCN)
     "serve.qos.",  # per-class tails resolved from the closed qos set
     "mem.account.",  # per-account ledger gauges (telemetry/memtrack.py)
 )
@@ -459,8 +456,6 @@ GAUGE_UNITS = {
     "tune.pruned_oom": "count",
     "tune.best_step_time": "ms",
     "train.bucket_count": "count",
-    "train.comm_exposed_ms": "ms",
-    "train.comm_overlapped_ms": "ms",
     "autopilot.tick_ms": "ms",
     "resilience.membership_epoch": "count",
     "resilience.active_slices": "count",

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 
+from maggy_tpu.models import sown
 from maggy_tpu.models.transformer import (
     REMAT_POLICIES,
     Decoder,
@@ -276,15 +277,13 @@ def decoder_pipeline_parts(
 
     if is_moe:
         def stage_fn(params, x, raw):
-            from maggy_tpu.train.trainer import collect_aux_losses
-
             positions, segment_ids = _side_inputs(x, raw)
             (y, _), mods = chunk.apply(
                 {"params": params["layers"]}, x, positions, {}, segment_ids,
                 mutable=["intermediates"],
             )  # {}: nothing rides the scan per layer (no gates, no bias)
             # this stage's router balancing losses (shared collection rule)
-            return y, collect_aux_losses(mods)
+            return y, sown.collect_aux_losses(mods)
     else:
         def stage_fn(params, x, raw):
             positions, segment_ids = _side_inputs(x, raw)

@@ -34,6 +34,7 @@ import numpy as np
 import optax
 from flax.training import train_state
 
+from maggy_tpu.models import sown
 from maggy_tpu.parallel import sharding as shd
 from maggy_tpu.parallel.spec import (
     AXIS_DATA,
@@ -97,129 +98,19 @@ def classification_loss_fn(logits: jax.Array, batch: Dict[str, jax.Array]) -> ja
     return -jnp.take_along_axis(logp, labels[..., None], axis=-1).mean()
 
 
-def collect_aux_losses(mods) -> jax.Array:
-    """Sum every ``*aux_loss`` intermediate a model sowed (MoE router
-    balancing). THE one matching rule — the dense train step, the pipeline
-    stage adapter, and tests all collect through here, so models that sow
-    and trainers that collect cannot silently desync."""
-    aux = jnp.zeros((), jnp.float32)
-    for path, leaf in jax.tree_util.tree_flatten_with_path(
-        mods.get("intermediates", {})
-    )[0]:
-        if "aux_loss" in jax.tree_util.keystr(path):
-            aux = aux + jnp.sum(leaf).astype(jnp.float32)
-    return aux
-
-
-def _sown(mods, name: str) -> list:
-    """Every intermediate a model sowed under ``name``, wherever in it."""
-    return [
-        leaf
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-            mods.get("intermediates", {})
-        )[0]
-        if f"'{name}'" in jax.tree_util.keystr(path)
-    ]
-
-
 def mtp_loss(mods, batch: Dict[str, jax.Array]) -> Optional[jax.Array]:
-    """The multi-token-prediction loss of a model that sowed ``mtp_logits``
-    (models/moe.py): cross entropy of the token two ahead, counted where it
-    lies in the predictor's document. Further heads of one product
-    (``DecoderConfig.pred_heads``: ``[B, S, heads - 1, vocab]``, head ``i``
-    predicting the token ``i + 2`` ahead) give the mean of their losses, each
-    over the positions whose target lies in the predictor's document.
-    ``None`` for every other model."""
-    logits = _sown(mods, "mtp_logits")
-    if not logits:
+    """The loss of a model's further heads (``sown.mtp_logits``): cross entropy
+    of the token two ahead, counted where it lies in the predictor's document.
+    Several heads (head ``i`` predicts the token ``i + 2`` ahead) give the mean
+    of their losses, each over the positions whose target lies in the
+    predictor's document. ``None`` for a model with one head."""
+    logits = sown.mtp_logits(mods)
+    if logits is None:
         return None
-    if logits[0].ndim == 4:
-        heads = logits[0].shape[2]
-        return sum(lm_loss_fn(logits[0][:, :, i], batch, ahead=i + 2) for i in range(heads)) / heads
-    return lm_loss_fn(logits[0], batch, ahead=2)
-
-
-def expert_counters(mods) -> Dict[str, jax.Array]:
-    """The step's counters of a model with expert share layers
-    (``ExpertShareBlock`` sows ``expert_load``, ``slots_dropped`` and
-    ``rows_visited``): the (token, choice) slots on held experts, those a
-    buffer cut (dropless: 0), the busiest held expert's load over the mean
-    one, a mean over the layers, and the rows of the layers' buffers that the
-    chunks that ran visited over the rows they hold (1.0: every layer worked
-    through its whole buffer). Empty for every other model."""
-    load = _sown(mods, "expert_load")
-    if not load:
-        return {}
-    load = jnp.concatenate([a.reshape(-1, a.shape[-1]) for a in load]).astype(jnp.float32)
-    out = {
-        "moe_slots": load.sum(),
-        "moe_slots_dropped": sum(jnp.sum(a) for a in _sown(mods, "slots_dropped")).astype(jnp.float32),
-        "moe_load_max_over_mean": jnp.mean(load.max(-1) / jnp.maximum(load.mean(-1), 1.0)),
-    }
-    rows = _sown(mods, "rows_visited")  # [visited, of] a layer
-    if rows:
-        visited, of = jnp.concatenate([a.reshape(-1, 2) for a in rows]).sum(0)
-        out["moe_rows_visited_share"] = visited / of
-    return out
-
-
-def conv_counters(mods) -> Dict[str, jax.Array]:
-    """The step's counter of a model with short-convolution layers
-    (``ShortConv`` sows ``taps_masked``): the taps zeroed at row and document
-    starts over all taps of the step's conv layers, which says that the
-    batch's packing reached the operator. Empty for every other model."""
-    taps = _sown(mods, "taps_masked")  # [masked, of] a layer
-    if not taps:
-        return {}
-    masked, of = jnp.concatenate([a.reshape(-1, 2) for a in taps]).sum(0)
-    return {"conv_taps_masked_share": masked / of}
-
-
-def sparse_counters(mods) -> Dict[str, jax.Array]:
-    """The step's counters of a model with selected-key attention layers
-    (``Attention`` sows ``index_aux_loss`` and, where it selects,
-    ``sparse_counts``: pairs selected, pairs visible, queries off their
-    count, a layer): the selected pairs over the visible ones, the queries
-    whose set is not ``min(sparse_topk, visible)`` keys (an exact selection:
-    0), and the indexer's loss summed over the layers. Empty for every other
-    model."""
-    loss = _sown(mods, "index_aux_loss")
-    if not loss:
-        return {}
-    out = {"index_loss": sum(jnp.sum(a) for a in loss).astype(jnp.float32)}
-    counts = _sown(mods, "sparse_counts")
-    if counts:
-        selected, visible, off = jnp.concatenate([a.reshape(-1, 3) for a in counts]).astype(jnp.float32).sum(0)
-        out.update(sparse_selected_share=selected / jnp.maximum(visible, 1.0), sparse_rows_off_k=off)
-    return out
-
-
-def window_counters(mods) -> Dict[str, jax.Array]:
-    """The step's counter of a model with sliding-window attention layers
-    (``Attention`` of kind ``sliding_attention`` sows ``window_pairs``: the
-    pairs inside window, document and causal order, and the causal pairs
-    inside documents, a layer): the first over the second, which says how much
-    of a full layer's attention the window keeps on this batch. Empty for
-    every other model."""
-    pairs = _sown(mods, "window_pairs")
-    if not pairs:
-        return {}
-    inside, causal = jnp.concatenate([a.reshape(-1, 2) for a in pairs]).astype(jnp.float32).sum(0)
-    return {"window_pairs_share": inside / jnp.maximum(causal, 1.0)}
-
-
-def eva_counters(mods) -> Dict[str, jax.Array]:
-    """The step's counters of a model with layers of chunk summaries
-    (``Attention`` of kind ``eva_attention`` sows ``eva_counts``: summaries
-    seen, all entries seen, chunks in which two documents meet, chunks, a
-    layer): the summaries over all the entries the real queries' softmax runs
-    over, and the chunks a document's start cuts over all chunks, which says
-    that the packing reached the summaries. Empty for every other model."""
-    counts = _sown(mods, "eva_counts")
-    if not counts:
-        return {}
-    remote, seen, cut, chunks = jnp.concatenate([a.reshape(-1, 4) for a in counts]).astype(jnp.float32).sum(0)
-    return {"eva_remote_share": remote / jnp.maximum(seen, 1.0), "eva_chunks_cut_share": cut / jnp.maximum(chunks, 1.0)}
+    if logits.ndim == 4:
+        heads = logits.shape[2]
+        return sum(lm_loss_fn(logits[:, :, i], batch, ahead=i + 2) for i in range(heads)) / heads
+    return lm_loss_fn(logits, batch, ahead=2)
 
 
 def _prefetch_depth(prefetch: Optional[int]) -> int:
@@ -485,12 +376,12 @@ class Trainer:
                 {"params": params}, *_model_inputs(batch),
                 mutable=["intermediates"],
             )
-            if _sown(mods, "mtp_logits"):
+            if sown.mtp_logits(mods) is not None:
                 raise NotImplementedError(
                     "the bucketed/ZeRO overlap step has no multi-token-"
                     "prediction loss: train this model with overlap off"
                 )
-            aux_dev = collect_aux_losses(mods) / n_manual
+            aux_dev = sown.collect_aux_losses(mods) / n_manual
             with jax.named_scope("loss"):
                 if is_lm:
                     ll_sum, weight = _lm_loss_parts(logits, batch)
@@ -864,42 +755,19 @@ class Trainer:
 
     def _place_packed(self, tel):
         """``shard_batch`` for ``fit``'s prefetcher thread, which has the host
-        batch in hand: a packed one also records the share of the flash grid's
-        tiles its segment ids leave to visit (``attention.tiles_visited_share``;
-        where some layers take a window, a mean over the attention layers, each
-        with the window's tiles counted out; a layer of chunk summaries counts
-        the tiles of its two grids, ``ops.eva.tiles_visited_share``), off the
+        batch in hand: a packed one also records the share of the attention
+        layers' flash tiles its segment ids leave to visit
+        (``attention.tiles_visited_share``), as the model's config counts them
+        for its own layers (``DecoderConfig.tiles_visited_share``), off the
         loop thread and with nothing read back from the device."""
-        import numpy as np
-
-        from maggy_tpu.ops import eva
-        from maggy_tpu.ops.flash import tiles_visited_share
-
-        cfg = getattr(self.model, "cfg", None)
-        head_dim = getattr(cfg, "head_dim", None)
-        head_dim = head_dim if isinstance(head_dim, int) else 128  # tiles depend on the width
-        windows = cfg.attention_windows() if hasattr(cfg, "attention_windows") else ()
-        windows = windows if any(windows) else (0,)
-        kinds = cfg.layer_kinds() if hasattr(cfg, "layer_kinds") else ()
-        # the forms the attention layers take, one a layer: a window (0: none), or the summaries' grid
-        forms = list(windows)
-        if "eva_attention" in kinds:
-            forms = [
-                ("eva", cfg.eva_window, cfg.eva_chunk) if kind == "eva_attention" else w
-                for kind, w in zip([k for k in kinds if k != "conv"], cfg.attention_windows())
-            ]
-
-        def visited(seg, form):
-            if isinstance(form, tuple) and seg.shape[1] > form[1]:
-                return eva.tiles_visited_share(seg, window=form[1], chunk=form[2], head_dim=head_dim)
-            return tiles_visited_share(seg, head_dim=head_dim, window=0 if isinstance(form, tuple) else form)
+        share_of = getattr(getattr(self.model, "cfg", None), "tiles_visited_share", None)
 
         def put(batch):
             seg = batch.get("segment_ids") if isinstance(batch, dict) else None
-            if isinstance(seg, np.ndarray) and seg.ndim == 2:
-                shares = {f: visited(seg, f) for f in set(forms)}
-                if None not in shares.values():
-                    tel.gauge("attention.tiles_visited_share", sum(shares[f] for f in forms) / len(forms))
+            if share_of is not None and isinstance(seg, np.ndarray) and seg.ndim == 2:
+                share = share_of(seg)
+                if share is not None:
+                    tel.gauge("attention.tiles_visited_share", share)
             return self.shard_batch(batch)
 
         return put
@@ -1107,20 +975,16 @@ class Trainer:
             self._step_traces += 1  # trace-time: counts compiles, not calls
 
             def loss_of(params):
-                # mutable intermediates so modules can sow auxiliary losses
-                # (MoE router balancing); "*aux_loss" leaves are added to the
-                # objective — without this, flax `sow` is a silent no-op
+                # mutable intermediates, or flax `sow` is a silent no-op: what the
+                # model's layers sow is read through models/sown.py
                 logits, mods = state.apply_fn(
                     {"params": params}, *_model_inputs(batch), mutable=["intermediates"]
                 )
                 with jax.named_scope("loss"):
                     loss = self.loss_fn(logits, batch)
                     mtp = mtp_loss(mods, batch)
-                aux = collect_aux_losses(mods)
-                extra = {
-                    **expert_counters(mods), **conv_counters(mods), **sparse_counters(mods), **window_counters(mods),
-                    **eva_counters(mods),
-                }
+                aux = sown.collect_aux_losses(mods)
+                extra = sown.step_counters(mods)
                 total = loss + aux
                 if mtp is not None:
                     total = total + self.model.cfg.mtp_weight * mtp
@@ -1754,24 +1618,10 @@ class Trainer:
         )
         with tel.span("train.drain", why="return"):
             out = {k: float(v) for k, v in metrics.items()}
-        if "moe_slots" in out:  # an expert share model's counters, read with the loss
-            tel.gauge("moe.slots", out["moe_slots"])
-            tel.gauge("moe.slots_dropped", out["moe_slots_dropped"])
-            tel.gauge("moe.load_max_over_mean", out["moe_load_max_over_mean"])
-        if "moe_rows_visited_share" in out:
-            tel.gauge("moe.rows_visited_share", out["moe_rows_visited_share"])
-        if "conv_taps_masked_share" in out:
-            tel.gauge("conv.taps_masked_share", out["conv_taps_masked_share"])
-        if "index_loss" in out:  # a selected-key attention model's counters
-            tel.gauge("sparse.index_loss", out["index_loss"])
-        if "sparse_rows_off_k" in out:
-            tel.gauge("sparse.selected_share", out["sparse_selected_share"])
-            tel.gauge("sparse.rows_off_k", out["sparse_rows_off_k"])
-        if "window_pairs_share" in out:  # a model with sliding-window attention layers
-            tel.gauge("attention.window_pairs_share", out["window_pairs_share"])
-        if "eva_remote_share" in out:  # a model with layers of chunk summaries
-            tel.gauge("attention.eva_remote_share", out["eva_remote_share"])
-            tel.gauge("attention.eva_chunks_cut_share", out["eva_chunks_cut_share"])
+        for row in sown.COUNTERS:  # the model's step counters, read with the loss of its last step
+            for key, gauge in row.gauges.items():
+                if key in out:
+                    tel.gauge(gauge, out[key])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

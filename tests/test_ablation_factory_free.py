@@ -164,7 +164,7 @@ def test_moe_without_gates_forward():
     )
     assert not np.allclose(np.asarray(ablated), np.asarray(all_off), atol=1e-5)
     # the gate also silences the router aux loss of the ablated block
-    from maggy_tpu.train.trainer import collect_aux_losses
+    from maggy_tpu.models.sown import collect_aux_losses
 
     _, mods_abl = MoEDecoder(cfg.without("mlp")).apply(
         {"params": params}, tokens, mutable=["intermediates"]

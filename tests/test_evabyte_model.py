@@ -19,7 +19,7 @@ from test_evabyte_attention import DOCS, KIND, S, batch, load, packed, program_o
 from benchmark import counts_evabyte
 from benchmark.references import eva_dense as reference
 from benchmark.references.decoder import adamw_apply
-from maggy_tpu.models import transformer
+from maggy_tpu.models import sown, transformer
 from maggy_tpu.ops import eva
 from maggy_tpu.train import trainer as trainer_mod
 
@@ -103,7 +103,7 @@ def test_eight_heads_logits_both_losses_and_the_counters(tiny, batch, seeded):
     np.testing.assert_allclose(main, parts["main"], rtol=1e-5)
     np.testing.assert_allclose(mtp, parts["mtp"], rtol=1e-5)
     np.testing.assert_allclose(main + pcfg.mtp_weight * mtp, total, rtol=1e-5)  # the sum of the eight
-    counters = trainer_mod.eva_counters(mods)
+    counters = sown.step_counters(mods)
     (remote, local), (cut, chunks) = reference.seen_entries(batch, sizes)
     assert remote > 0 and cut == 4 and chunks == 2 * S // 4  # at 45 and 115 (the padding's start), at 19 and 109: all inside chunks
     np.testing.assert_allclose(counters["eva_remote_share"], remote / (remote + local), rtol=1e-6)
@@ -111,7 +111,7 @@ def test_eight_heads_logits_both_losses_and_the_counters(tiny, batch, seeded):
     docs = [n for row in DOCS for n in row]
     assert counts_evabyte.entries(docs, sizes) == (remote, local)
     assert counts_evabyte.rows_of(docs, S) == DOCS
-    assert trainer_mod.eva_counters({}) == {}
+    assert sown.step_counters({}) == {}
 
 
 def test_a_later_byte_or_another_document_changes_no_logit_bit(tiny, batch, seeded):

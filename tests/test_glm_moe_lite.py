@@ -27,7 +27,7 @@ sys.path.insert(0, REPO)
 
 from benchmark import configs, run as bench_run, weights  # noqa: E402
 from benchmark.references import mla_moe  # noqa: E402
-from maggy_tpu.models import moe, transformer  # noqa: E402
+from maggy_tpu.models import moe, sown, transformer  # noqa: E402
 from maggy_tpu.ops.flash import flash_attention  # noqa: E402
 from maggy_tpu.train import trainer as trainer_mod  # noqa: E402
 
@@ -402,7 +402,7 @@ def program_loss(model, mtp_weight, params, batch):
     logits, mods = program_outputs(model, params, batch)
     main = trainer_mod.lm_loss_fn(logits, batch)
     mtp = trainer_mod.mtp_loss(mods, batch)
-    return main + mtp_weight * mtp, (main, mtp, trainer_mod.expert_counters(mods))
+    return main + mtp_weight * mtp, (main, mtp, sown.step_counters(mods))
 
 
 def test_loss_parts_and_slots(tiny, batch, seeded):

@@ -30,7 +30,7 @@ sys.path.insert(0, REPO)
 from benchmark import configs, run as bench_run, weights  # noqa: E402
 from benchmark.references import sparse_gqa_moe as reference  # noqa: E402
 from benchmark.references.decoder import adamw_apply  # noqa: E402
-from maggy_tpu.models import moe, transformer  # noqa: E402
+from maggy_tpu.models import moe, sown, transformer  # noqa: E402
 from maggy_tpu.ops import sparse_select  # noqa: E402
 from maggy_tpu.ops.flash import backward_form, flash_attention  # noqa: E402
 from maggy_tpu.train import trainer as trainer_mod  # noqa: E402
@@ -102,7 +102,7 @@ def program_outputs(model, params, batch):
 
 def program_objective(model, params, batch):
     logits, mods = program_outputs(model, params, batch)
-    return trainer_mod.lm_loss_fn(logits, batch) + trainer_mod.collect_aux_losses(mods), mods
+    return trainer_mod.lm_loss_fn(logits, batch) + sown.collect_aux_losses(mods), mods
 
 
 def layer_leaves(leaves, layer):
@@ -391,8 +391,8 @@ def test_logits_loss_index_loss_and_slots(tiny, batch, seeded):
     np.testing.assert_allclose(logits, reference.logits_of(leaves, batch, sizes), rtol=1e-4, atol=2e-5)
     _, parts = reference.losses(leaves, batch, sizes)
     np.testing.assert_allclose(trainer_mod.lm_loss_fn(logits, batch), parts["main"], rtol=1e-5)
-    np.testing.assert_allclose(trainer_mod.collect_aux_losses(mods), parts["index"], rtol=1e-5)
-    counters = {**trainer_mod.expert_counters(mods), **trainer_mod.sparse_counters(mods)}
+    np.testing.assert_allclose(sown.collect_aux_losses(mods), parts["index"], rtol=1e-5)
+    counters = sown.step_counters(mods)
     assert float(counters["moe_slots"]) == float(parts["slots"]) > 0 and float(counters["moe_slots_dropped"]) == 0
     np.testing.assert_allclose(counters["index_loss"], parts["index"], rtol=1e-5)
     np.testing.assert_allclose(counters["sparse_selected_share"], parts["pairs"][0] / parts["pairs"][1], rtol=1e-6)
@@ -446,7 +446,7 @@ def selecting_decoder(**fields):
 
     def fn(p):
         logits, mods = transformer.Decoder(cfg).apply({"params": p}, tokens, mutable=["intermediates"])
-        return jnp.square(logits).mean() + trainer_mod.collect_aux_losses(mods)
+        return jnp.square(logits).mean() + sown.collect_aux_losses(mods)
 
     return jax.value_and_grad(fn), params
 
@@ -590,7 +590,7 @@ def test_dense_decoder_scans_selected_key_layers_too():
     params = nn.meta.unbox(model.init(jax.random.key(0), tokens)["params"])
     assert params["layers"]["layer"]["attn"]["wq"]["kernel"].shape == (3, 64, 4, 32)
     _logits, mods = model.apply({"params": params}, tokens, mutable=["intermediates"])
-    counters = trainer_mod.sparse_counters(mods)
+    counters = sown.step_counters(mods)
     assert mods["intermediates"]["layers"]["layer"]["attn"]["index_aux_loss"][0].shape == (3,)
     assert float(counters["sparse_rows_off_k"]) == 0 and float(counters["index_loss"]) > 0
     want = sum(min(t + 1, 16) for t in range(S)) / (S * (S + 1) / 2)

@@ -18,7 +18,7 @@ from test_laguna_window import KIND, S, batch, program_outputs, seeded, tiny  # 
 from benchmark import counts_laguna
 from benchmark.references import window_gqa_moe as reference
 from benchmark.references.decoder import adamw_apply
-from maggy_tpu.models import moe, transformer
+from maggy_tpu.models import moe, sown, transformer
 from maggy_tpu.ops.flash import tiles_visited_share
 from maggy_tpu.train import trainer as trainer_mod
 
@@ -113,7 +113,7 @@ def test_logits_loss_slots_and_the_windows_share_of_the_pairs(tiny, batch, seede
     np.testing.assert_allclose(logits, want, rtol=2e-4, atol=5e-5)
     _, parts = jax.jit(lambda p: reference.losses(p, batch, sizes))(leaves)
     np.testing.assert_allclose(trainer_mod.lm_loss_fn(logits, batch), parts["main"], rtol=1e-5)
-    counters = {**trainer_mod.expert_counters(mods), **trainer_mod.window_counters(mods)}
+    counters = sown.step_counters(mods)
     assert float(counters["moe_slots"]) == float(parts["slots"]) > 0 and float(counters["moe_slots_dropped"]) == 0
     inside, causal = reference.window_pairs(batch, sizes["window"])
     np.testing.assert_allclose(counters["window_pairs_share"], inside / causal, rtol=1e-6)

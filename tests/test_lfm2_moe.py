@@ -28,7 +28,7 @@ sys.path.insert(0, REPO)
 from benchmark import configs, run as bench_run, weights  # noqa: E402
 from benchmark.references import conv_moe  # noqa: E402
 from benchmark.references.decoder import adamw_apply  # noqa: E402
-from maggy_tpu.models import moe, transformer  # noqa: E402
+from maggy_tpu.models import moe, sown, transformer  # noqa: E402
 from maggy_tpu.train import trainer as trainer_mod  # noqa: E402
 
 KIND = "train_packed_ref"
@@ -149,7 +149,7 @@ def test_two_periods_scanned_agree_with_the_same_layers_unrolled(batch):
     flat = moe.MoEDecoder(dataclasses.replace(pcfg, scan_layers=False))
     got, mods_flat = program_outputs(flat, unrolled, batch)
     np.testing.assert_allclose(got, scanned, rtol=1e-5, atol=1e-6)
-    slots = [float(trainer_mod.expert_counters(m)["moe_slots"]) for m in (mods, mods_flat)]
+    slots = [float(sown.step_counters(m)["moe_slots"]) for m in (mods, mods_flat)]
     assert slots[0] == slots[1] > 0
 
 
@@ -298,9 +298,9 @@ def test_logits_loss_and_slots(tiny, batch, seeded):
     np.testing.assert_allclose(logits, conv_moe.logits_of(leaves, batch, sizes), rtol=1e-4, atol=2e-5)
     want, parts = conv_moe.losses(leaves, batch, sizes)
     np.testing.assert_allclose(trainer_mod.lm_loss_fn(logits, batch), want, rtol=1e-5)
-    counters = trainer_mod.expert_counters(mods)
+    counters = sown.step_counters(mods)
     assert float(counters["moe_slots"]) == float(parts["slots"]) > 0 and float(counters["moe_slots_dropped"]) == 0
-    assert 0 < float(trainer_mod.conv_counters(mods)["conv_taps_masked_share"]) < 0.1
+    assert 0 < float(counters["conv_taps_masked_share"]) < 0.1
 
 
 def test_gradient_of_every_leaf_and_the_change_after_two_steps(tiny, batch, seeded):

@@ -130,33 +130,7 @@ def test_opt_state_flatten_and_reflatten_roundtrip():
     )
 
 
-# --------------------------------------------------------- gauges / config
-
-
-class _FakeTel:
-    def __init__(self):
-        self.gauges = {}
-
-    def gauge(self, name, value):
-        self.gauges[name] = value
-
-
-def test_record_overlap_gauges():
-    tel = _FakeTel()
-    times = {
-        "dense": 10.0, "bucketed": 7.0, "nocomm": 5.0,
-        "only_data": 6.0, "only_slice": 8.5,
-    }
-    out = ovl.record_overlap_gauges(
-        times, ("slice", "data"), telemetry_recorder=tel
-    )
-    assert out["comm_total_ms"] == pytest.approx(5.0)
-    assert out["comm_exposed_ms"] == pytest.approx(2.0)
-    assert out["comm_overlapped_ms"] == pytest.approx(3.0)
-    assert tel.gauges["train.comm_exposed_ms"] == pytest.approx(2.0)
-    assert tel.gauges["train.comm_overlapped_ms"] == pytest.approx(3.0)
-    assert tel.gauges["train.comm_exposed_ms.data"] == pytest.approx(1.0)
-    assert tel.gauges["train.comm_exposed_ms.slice"] == pytest.approx(3.5)
+# ------------------------------------------------------------------ config
 
 
 def test_sharding_spec_zero_fields():

@@ -198,7 +198,7 @@ def test_pp_moe_decoder_trains_with_router_aux():
     dense_params = jax.device_get(jax.jit(parts.unstack)(state.params))
 
     # dense reference: loss + summed router aux (the Trainer's dense path)
-    from maggy_tpu.train.trainer import collect_aux_losses
+    from maggy_tpu.models.sown import collect_aux_losses
 
     model = MoEDecoder(cfg)
     logits, mods = model.apply(
@@ -514,7 +514,7 @@ def test_pp_ep_moe_matches_dense():
     stage (GSPMD-auto in the pipeline's partial-manual region), and the
     step matches the dense trainer's loss + router aux on the same params."""
     from maggy_tpu.models import MoEConfig, MoEDecoder
-    from maggy_tpu.train.trainer import collect_aux_losses
+    from maggy_tpu.models.sown import collect_aux_losses
 
     cfg = MoEConfig.tiny_moe()
     batch = _batch(cfg, bsz=8, seq=16)
@@ -575,7 +575,7 @@ def test_pp_tp_ep_three_way_composition():
 
     # dense-reference parity, same bar as the 2-way composition tests: a
     # subtly wrong 3-way layout that still "trains" must not pass
-    from maggy_tpu.train.trainer import collect_aux_losses
+    from maggy_tpu.models.sown import collect_aux_losses
 
     parts = trainer._pipeline_parts()
     dense_params = jax.device_get(jax.jit(parts.unstack)(state.params))
