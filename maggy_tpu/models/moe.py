@@ -89,9 +89,61 @@ class MoEConfig(DecoderConfig):
     # and head; Trainer adds mtp_weight times its loss
     mtp_depth: int = 0
     mtp_weight: float = 0.3
+    # the objective "generation by diffusion over blocks" (SDAR's training
+    # step; ``block_noise``, ``ops/blockdiff.py``): every row goes through the
+    # layers twice in one program, clean and noised. A token at position ``p``
+    # of its document lies in block ``p // block``; each block draws a noise
+    # level ``t = noise_eps + (1 - noise_eps) u`` and each of its tokens
+    # becomes ``mask_token_id`` with probability ``t``, from a key that is a
+    # pure function of ``noise_seed`` and the optimizer step (``noise_key``);
+    # attention masks block-wise between the streams; the head reads the
+    # noised stream and a masked position predicts its own token, weighted
+    # ``1 / t`` (the model sows the weights, ``models/sown.py``). Training and
+    # scoring only: a decode step that yields a block is not written.
+    # ``[MASK]`` is a learned vector of its own (``mask_embedding`` [d_model],
+    # drawn at 0.02), not a row of the table: where the vocabulary is sliced
+    # over chips the published id lies in one chip's slice, and it is a token
+    # the checkpoint being adapted never trained, so its vector starts at the
+    # initializer's scale; ``mask_token_id`` is the id the noised row shows
+    # at a masked position
+    block_diffusion: bool = False
+    block: int = 4
+    mask_token_id: int = 0
+    noise_seed: int = 0
+    noise_eps: float = 1e-3
+
+    @property
+    def stream_block(self) -> int:
+        return self.block if self.block_diffusion else 0
+
+    def noise_key(self, step):
+        """The noise key of optimizer step ``step`` (a traced count or an int):
+        the noise is drawn in the jitted step, and a resumed job continues it."""
+        return jax.random.fold_in(jax.random.key(self.noise_seed), step)
+
+    def step_inputs(self, step) -> dict:
+        """What ``apply`` takes in a train step beside the batch
+        (``models.sown.step_inputs``): the step's noise key under this
+        objective, nothing else."""
+        return {"noise_key": self.noise_key(step)} if self.block_diffusion else {}
 
     def __post_init__(self):
         super().__post_init__()
+        if self.block_diffusion:
+            if self.block < 1 or not 0 <= self.mask_token_id < self.vocab_size or not 0 <= self.noise_eps < 1:
+                raise ValueError("block_diffusion needs block >= 1, a mask_token_id of the vocabulary and 0 <= noise_eps < 1")
+            if self.decode:
+                raise ValueError(
+                    "block diffusion has a training form only: a decode step that yields a block "
+                    "after several denoising passes is not written (ROADMAP M7)"
+                )
+            if self.sparse_topk or self.kv_lora_rank or self.attention_fn is not None or self.mtp_depth:
+                raise ValueError(
+                    "the two streams' block-wise mask is Attention's, through the automatic dispatch: "
+                    "no selected keys, no latent attention, no attention_fn, no multi-token-prediction module"
+                )
+            if set(self.layer_types) - {"full_attention"}:
+                raise ValueError("the two streams' block-wise mask takes full_attention layers: no window, no summaries, no conv")
         if self.experts_held:
             if self.n_experts % self.experts_held or not (
                 0 <= self.expert_offset < self.n_experts // self.experts_held
@@ -687,6 +739,28 @@ class MTPModule(nn.Module):
         return RMSNorm(cfg, name="final_norm")(x)
 
 
+def block_noise(cfg: MoEConfig, tokens, positions, segment_ids, key):
+    """One step's noise (``MoEConfig.block_diffusion``): ``(noised tokens
+    [B, L], target weights [B, L] float32, [masked, real] float32)``. Each
+    block of ``cfg.block`` positions of a document draws one level ``t =
+    noise_eps + (1 - noise_eps) u``, ``u ~ U[0, 1)`` (the draw at the row index
+    of its first token, of ``uniform(fold_in(key, 0), [B, L])``); a real token
+    is masked where ``uniform(fold_in(key, 1), [B, L]) < t`` and then reads
+    ``mask_token_id`` (``MoEDecoder`` embeds it as the vector
+    ``mask_embedding``); a masked token's weight in the objective is ``1 / t``
+    (the masked-diffusion bound under a linear schedule), every other's 0.
+    Float32 throughout."""
+    b, l = tokens.shape
+    real = jnp.ones((b, l), bool) if segment_ids is None else segment_ids > 0
+    first = jnp.arange(l, dtype=jnp.int32)[None, :] - positions.astype(jnp.int32) % cfg.block
+    u = jax.random.uniform(jax.random.fold_in(key, 0), (b, l), jnp.float32)
+    t = cfg.noise_eps + (1.0 - cfg.noise_eps) * jnp.take_along_axis(u, first, axis=1)
+    masked = (jax.random.uniform(jax.random.fold_in(key, 1), (b, l), jnp.float32) < t) & real
+    noised = jnp.where(masked, jnp.asarray(cfg.mask_token_id, tokens.dtype), tokens)
+    counts = jnp.stack([masked.sum(dtype=jnp.float32), real.sum(dtype=jnp.float32)])
+    return noised, jnp.where(masked, 1.0 / t, 0.0), counts
+
+
 class MoEDecoder(nn.Module):
     """Sparse-MoE causal LM; same interface as
     :class:`maggy_tpu.models.transformer.Decoder`. With ``mtp_depth`` it also
@@ -694,13 +768,23 @@ class MoEDecoder(nn.Module):
     trainer's loss. ``layer_types`` gives every layer its operator: the
     leading dense layers and the layers over after the last whole period are
     unrolled (``dense_<i>``, ``tail_<i>``), the whole periods scanned
-    (``layers``: one kind of layer as ``layer``, several as ``layer_<j>``)."""
+    (``layers``: one kind of layer as ``layer``, several as ``layer_<j>``).
+    Under ``block_diffusion`` the objective is the model's own: it draws the
+    step's noise (``block_noise``), runs the clean and the noised row through
+    the layers as one row of ``2L`` positions, returns the noised stream's
+    logits and sows ``target_weights`` (what the train step's loss weighs each
+    position's own token by) and ``diffusion_masked``."""
 
     cfg: MoEConfig
 
     @nn.compact
-    def __call__(self, tokens, positions=None, segment_ids=None):
+    def __call__(self, tokens, positions=None, segment_ids=None, noise_key=None):
+        """``noise_key``: the step's noise key under ``block_diffusion``
+        (``MoEConfig.step_inputs``; None: step 0's). The layers then run on
+        rows of ``2L`` positions, the clean stream and the noised one, and
+        the logits ``[B, L, vocab]`` are the noised stream's."""
         cfg = self.cfg
+        length = tokens.shape[1]
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
@@ -712,7 +796,25 @@ class MoEDecoder(nn.Module):
             cfg.param_dtype,
         )
         embed = jnp.asarray(embed, cfg.dtype)
+        if cfg.block_diffusion:
+            with jax.named_scope("diffusion.noise"):
+                noised, weights, counts = block_noise(
+                    cfg, tokens, positions, segment_ids, cfg.noise_key(0) if noise_key is None else noise_key
+                )
+                tokens, positions = (jnp.concatenate([a, b], axis=1) for a, b in ((tokens, noised), (positions, positions)))
+                if segment_ids is not None:
+                    segment_ids = jnp.concatenate([segment_ids, segment_ids], axis=1)
+            self.sow("intermediates", "target_weights", weights)
+            self.sow("intermediates", "diffusion_masked", counts)
         x = embed[tokens]
+        if cfg.block_diffusion:
+            mask = self.param(
+                "mask_embedding", _partitioned(nn.initializers.normal(0.02), ("norm",), cfg),
+                (cfg.d_model,), cfg.param_dtype,
+            )
+            with jax.named_scope("diffusion.noise"):
+                masked = jnp.concatenate([jnp.zeros_like(weights, bool), weights > 0], axis=1)
+                x = jnp.where(masked[..., None], jnp.asarray(mask, cfg.dtype), x)
 
         n_dense, n_moe = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
         kinds = cfg.layer_kinds()
@@ -766,6 +868,8 @@ class MoEDecoder(nn.Module):
                     x, positions, per_layer(i), segment_ids
                 )
 
+        if cfg.block_diffusion:  # the head reads the noised stream; the clean one fed the keys and values below
+            x = x[:, length:]
         x_norm = RMSNorm(cfg, name="final_norm")(x)
         if cfg.tie_embeddings:
             if cfg.mtp_depth:

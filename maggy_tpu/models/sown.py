@@ -4,6 +4,10 @@ model with ``mutable=["intermediates"]`` and reads the result through here and
 nowhere else: the dense step, the bucketed/ZeRO step and the pipeline's stage
 adapter (``train/trainer.py``, ``train/pipeline_adapter.py``) and the tests.
 
+The same seam carries an objective that is the model's own: the weights of the
+positions' targets (:func:`target_weights`) and what ``apply`` takes from the
+step's count (:func:`step_inputs`).
+
 A step counter is written in three places: the layer's ``sow``, its row in
 :data:`COUNTERS`, and the gauge's name and unit in ``telemetry/metrics.py``
 (``docs/observability.md`` "Adding a step counter"). ``Trainer.step`` returns
@@ -48,6 +52,26 @@ def mtp_logits(mods) -> Optional[jax.Array]:
     ``i + 2`` ahead). ``None`` for every other model."""
     logits = sown(mods, "mtp_logits")
     return logits[0] if logits else None
+
+
+def target_weights(mods) -> Optional[jax.Array]:
+    """The objective's weight of each position's own token, float32 ``[B, L]``,
+    of a model that sowed ``target_weights`` (``MoEDecoder`` under
+    ``block_diffusion``: ``1 / t`` on the tokens the step masked, 0 elsewhere;
+    its logits ``[B, L, vocab]`` predict position ``i``'s own token, with no
+    shift). The train step then takes the weighted loss in the place of its
+    ``loss_fn``. ``None`` for every other model."""
+    weights = sown(mods, "target_weights")
+    return weights[0] if weights else None
+
+
+def step_inputs(model, step) -> Dict[str, jax.Array]:
+    """What a model's ``apply`` takes in a train step beside the batch, from
+    the optimizer step's count: whatever its configuration's ``step_inputs``
+    says (``MoEConfig``: the step's noise key under ``block_diffusion``). Empty
+    for every other model, whose program is then as it was."""
+    make = getattr(getattr(model, "cfg", None), "step_inputs", None)
+    return make(step) if make is not None else {}
 
 
 def _stacked(leaves: list, width: int) -> jax.Array:
@@ -95,6 +119,15 @@ def _eva_counts(counts):
     }
 
 
+def _diffusion_counts(masked, pairs):
+    masked, real = _stacked(masked, 2).sum(0)
+    kept, causal = _stacked(pairs, 2).astype(jnp.float32).sum(0)
+    return {
+        "diffusion_masked_share": masked / jnp.maximum(real, 1.0),
+        "blockdiff_pairs_share": kept / jnp.maximum(causal, 1.0),
+    }
+
+
 class Counter(NamedTuple):
     """One row: the names a layer sows, what the step makes of every layer's
     leaves under them (one list a name, in order), and for each key of that in
@@ -138,6 +171,14 @@ COUNTERS: Tuple[Counter, ...] = (
     # the packing reached the summaries
     Counter(("eva_counts",), _eva_counts, {
         "eva_remote_share": "attention.eva_remote_share", "eva_chunks_cut_share": "attention.eva_chunks_cut_share",
+    }),
+    # ``MoEDecoder`` under ``block_diffusion``, [tokens masked, real tokens] of the step, and its
+    # ``Attention`` layers, [pairs the block-wise mask keeps (clean on clean, noised on clean,
+    # noised on its own block), one causal stream's pairs inside documents] a layer: the share of
+    # the real tokens the step's noise masked (about a half: the level is uniform), and what the
+    # two streams' attention computes over what a causal step would (about 2)
+    Counter(("diffusion_masked", "blockdiff_pairs"), _diffusion_counts, {
+        "diffusion_masked_share": "diffusion.masked_share", "blockdiff_pairs_share": "attention.blockdiff_pairs_share",
     }),
 )
 

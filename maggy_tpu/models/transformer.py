@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from maggy_tpu.ops import attention as ops_attn
-from maggy_tpu.ops import eva, sparse_select
+from maggy_tpu.ops import blockdiff, eva, sparse_select
 from maggy_tpu.ops.flash import (
     FLASH_RESIDUALS,
     flash_attention,
@@ -249,6 +249,15 @@ class DecoderConfig:
         return float(self.pred_heads - 1)
 
     @property
+    def stream_block(self) -> int:
+        """The block length of a two-stream layout, where the model's
+        objective runs a clean and a noised copy of every row through its
+        layers (``MoEConfig.block_diffusion``: rows of ``2L`` positions, the
+        clean stream first; :class:`Attention` then masks block-wise between
+        them, ``ops/blockdiff.py``); 0: one stream, causal."""
+        return 0
+
+    @property
     def head_dim(self) -> int:
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -287,8 +296,11 @@ class DecoderConfig:
         layer's grid is ``ops.flash.tiles_visited_share``, a windowed one's
         the same with the window's tiles counted out, and a layer of chunk
         summaries on a row longer than its window counts the tiles of its two
-        grids (``ops.eva.tiles_visited_share``). None where any form's tiles
-        do not divide the row."""
+        grids (``ops.eva.tiles_visited_share``), a two-stream layer those of
+        its two bounded grids (``ops.blockdiff.tiles_visited_share``). None
+        where any form's tiles do not divide the row."""
+        if self.stream_block:  # every layer the two grids of the block-wise mask
+            return blockdiff.tiles_visited_share(segment_ids, block=self.stream_block, head_dim=self.head_dim)
         kinds = [kind for kind in self.layer_kinds() if kind != "conv"]
         forms = [  # (window, chunk) a layer; chunk 0: no summaries
             (self.eva_window, self.eva_chunk) if kind == "eva_attention" else (window, 0)
@@ -701,6 +713,7 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
 
 def record_attention_kernel(
     kernel: str, q, k, segment_ids, reason: str = "", selected: int = 0, window: int = 0, chunk: int = 0,
+    block: int = 0,
 ):
     """Journal which kernel the automatic dispatch chose for this shape as
     one ``attention.kernel`` event; for the flash kernels also the tiles they
@@ -722,7 +735,10 @@ def record_attention_kernel(
     ``ops/eva.py``) says so as ``form`` ``eva`` beside its ``window`` (there
     the window of the row's grid) and ``chunk``; the four tile sizes are then
     its local calls', on rows of one window, and ``remote_blocks`` the four of
-    the calls on the summaries.
+    the calls on the summaries. A two-stream layer (``block`` > 0:
+    ``ops/blockdiff.py``) says so as ``form`` ``blockdiff`` beside its ``block``
+    and ``calls``, the flash calls a layer (2: one a stream, each under its
+    causal bound a query); ``q`` and ``kv`` are then one stream's.
     The dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
@@ -734,6 +750,8 @@ def record_attention_kernel(
         attrs["index_passes"] = 2 if kernel.startswith("flash") else 1
     if chunk:
         attrs.update(form="eva", chunk=int(chunk))
+    if block:
+        attrs.update(form="blockdiff", block=int(block), calls=2)
     if kernel.startswith("flash"):
         from maggy_tpu.ops.flash import _auto_blocks, backward_form
 
@@ -835,18 +853,58 @@ def auto_eva_attention(q, k, v, ks, vs, *, segment_ids=None, window: int, chunk:
     return eva.eva_attention_xla(q, k, v, ks, vs, segment_ids, window=window, chunk=chunk)
 
 
-def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selected=None, window: int = 0):
+def auto_blockdiff_attention(q, k, v, positions, segment_ids, lay, *, block: int):
+    """The dispatch of a two-stream layer (``ops/blockdiff.py``): q [B, 2L, H,
+    D], k, v [B, 2L, Kh, D], the clean stream first; ``positions``,
+    ``segment_ids`` [B, L] and ``lay`` (``blockdiff.layout``) one stream's. On
+    one TPU chip, where a stream's shape tiles, two calls of the flash kernels
+    under their bounds (the clean queries block-causal on the clean keys; the
+    noised queries on the clean keys before their block, joined with their own
+    block's noised keys by the rows' log-sum-exp), recorded as ``flash``,
+    ``form`` ``blockdiff``; anywhere else both streams on explicit masks in
+    XLA (``xla_dense``, with the reason): one choice for the two calls."""
+    from maggy_tpu.parallel.mesh import ambient_mesh
+
+    l, d = q.shape[1] // 2, q.shape[3]
+    (q_c, q_n), (k_c, k_n), (v_c, v_n) = ((a[:, :l], a[:, l:]) for a in (q, k, v))
+    mesh = ambient_mesh()
+    if jax.default_backend() != "tpu":
+        why = f"backend is {jax.default_backend()}"
+    elif mesh is not None and mesh.size > 1:
+        why = f"mesh {dict(mesh.shape)}: the two streams' calls run on one chip"
+    else:
+        why = blockdiff.untileable(l, d, compiled=True)
+    if why is None:
+        record_attention_kernel("flash", q_c, k_c, segment_ids, block=block)
+        out_c = flash_attention(q_c, k_c, v_c, causal=True, segment_ids=segment_ids, bound=lay.hi_clean)
+        out_n = blockdiff.noised_attention(q_n, k_c, v_c, k_n, v_n, segment_ids, lay, block=block)
+    else:
+        record_attention_kernel("xla_dense", q_c, k_c, segment_ids, why, block=block)
+        out_c = default_attention(q_c, k_c, v_c, causal=True, segment_ids=segment_ids, bound=lay.hi_clean)
+        out_n = default_attention(  # one softmax over the clean keys before the block and the block's noised ones
+            q_n, jnp.concatenate([k_c, k_n], axis=1), jnp.concatenate([v_c, v_n], axis=1), causal=False,
+            selected=blockdiff.noised_mask(positions, segment_ids, lay, block),
+        )
+    return jnp.concatenate([out_c, out_n], axis=1)
+
+
+def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selected=None, window: int = 0, bound=None):
     """Reference soft-max attention: q [B,S,H,D], k/v [B,S,Kh,D] with GQA
     head-group broadcast. fp32 logits/softmax for stability. ``selected``
     [B, Sq, Sk]: the pairs a selection keeps (nonzero). ``window`` (with
-    ``causal``): a query sees the ``window`` keys up to its own."""
+    ``causal``): a query sees the ``window`` keys up to its own. ``bound``
+    ([B, Sq] int32, with ``causal``): the query at row index ``t`` sees the
+    keys at ``s <= bound[t]`` in place of ``s <= t``."""
     b, sq, h, d = q.shape
     kh = k.shape[2]
     group = h // kh
     q = q.reshape(b, sq, kh, group, d)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", q, k).astype(jnp.float32)
     logits = logits / jnp.sqrt(d).astype(jnp.float32)
-    if causal:
+    if causal and bound is not None:
+        mask = jnp.arange(k.shape[1])[None, None, :] <= bound[:, :, None]
+        logits = jnp.where(mask[:, None, None], logits, -1e30)
+    elif causal:
         sk = k.shape[1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool))
         if window:
@@ -867,7 +925,10 @@ class Attention(nn.Module):
     kind gives the query heads, the window and the rotary form
     (``DecoderConfig.attention_form``); a "sliding_attention" layer sows
     ``window_pairs`` ([2]: the pairs inside window, document and causal order,
-    the causal pairs inside documents) for the trainer's step metrics."""
+    the causal pairs inside documents) for the trainer's step metrics. Under a
+    two-stream layout (``cfg.stream_block``) the row holds a clean and a
+    noised stream, which the four projections, the head norms and the rotary
+    embedding read as one row of ``2L`` positions (``_stream_attention``)."""
 
     cfg: DecoderConfig
     kind: str = "full_attention"
@@ -890,6 +951,8 @@ class Attention(nn.Module):
             out = self._summary_attention(q, k, v, positions, segment_ids)
         elif cfg.decode:
             out = self._cached_attention(q, k, v, positions, segment_ids)
+        elif cfg.stream_block:
+            out = self._stream_attention(q, k, v, positions, segment_ids)
         elif cfg.sparse_topk:
             out = self._selected_attention(x, q, k, v, positions, segment_ids)
         elif window:
@@ -919,6 +982,25 @@ class Attention(nn.Module):
             name="wo",
         )(out)
         return out
+
+    def _stream_attention(self, q, k, v, positions, segment_ids):
+        """Two streams of one row (``ops/blockdiff.py``): the first ``L`` of the
+        ``2L`` positions are the clean stream, the others the noised one, with
+        the same positions and segment ids in both halves. A clean query sees
+        the clean keys of its document up to the end of its block of
+        ``cfg.stream_block`` positions; a noised one the noised keys of its
+        own block and the clean keys before it, under one softmax. The bounds
+        come from positions and segment ids under the scope
+        ``diffusion.noise``. Sows ``blockdiff_pairs`` ([2]: the pairs the mask
+        keeps for the real queries, all three forms; one causal stream's pairs
+        inside documents) for the trainer's step metrics."""
+        block, l = self.cfg.stream_block, q.shape[1] // 2
+        pos = positions[:, :l]
+        seg = jnp.ones(pos.shape, jnp.int32) if segment_ids is None else segment_ids[:, :l]
+        with jax.named_scope("diffusion.noise"):
+            lay = blockdiff.layout(pos, seg, block)
+            self.sow("intermediates", "blockdiff_pairs", blockdiff.pairs(pos, seg, lay, block))
+        return auto_blockdiff_attention(q, k, v, pos, seg, lay, block=block)
 
     def _summary_attention(self, q, k, v, positions, segment_ids):
         """A layer of chunk summaries (``ops/eva.py``): two learned vectors a

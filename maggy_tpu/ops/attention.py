@@ -101,12 +101,15 @@ def blockwise_attention(
     segment_ids: Optional[jax.Array] = None,
     block_k: int = 512,
     remat_blocks: bool = True,
+    bound: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Memory-efficient attention, drop-in for
     :func:`maggy_tpu.models.transformer.default_attention`.
 
     q [B,S,H,D]; k/v [B,S,Kh,D] (GQA broadcast internally); never materializes
-    more than [B,H,S,block_k] scores.
+    more than [B,H,S,block_k] scores. ``bound`` ([B, Sq] int32, with
+    ``causal``): the query at row index ``t`` sees the keys at ``s <= bound[t]``
+    in place of ``s <= t`` (``ops.flash.flash_attention``'s causal bound a query).
     """
     b, sq, h, d = q.shape
     k = _repeat_kv(k, h)
@@ -122,7 +125,7 @@ def blockwise_attention(
             segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)), constant_values=-1)
 
     scale = 1.0 / (d**0.5)
-    q_pos = jnp.arange(sq)
+    q_hi = jnp.arange(sq)[None] if bound is None else bound  # the last key index a query sees
     kv_pos = jnp.arange(n_blocks * block_k)
 
     k_blocks = k.reshape(b, n_blocks, block_k, h, d).transpose(1, 0, 2, 3, 4)
@@ -137,7 +140,7 @@ def blockwise_attention(
         k_blk, v_blk, kpos, seg = blk
         mask = jnp.ones((1, 1, sq, block_k), bool)
         if causal:
-            mask = mask & (q_pos[None, None, :, None] >= kpos[None, None, None, :])
+            mask = mask & (q_hi[:, None, :, None] >= kpos[None, None, None, :])
         mask = mask & (kpos < sk)[None, None, None, :]  # padding
         if segment_ids is not None:
             qseg = segment_ids[:, :sq]

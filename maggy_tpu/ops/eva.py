@@ -172,6 +172,21 @@ def _rows_of_lanes(lse, rows: int, s: int):
     return lse.reshape(rows, s // lanes, lanes)
 
 
+def join_by_lse(o_a, lse_a, o_b, lse_b):
+    """Two parts of one softmax joined by their rows' log-sum-exp: outputs
+    ``[rows, S, D]`` each normalised over its own set of keys, log-sum-exp as
+    rows of lanes ``[rows, S / 128, 128]`` float32 -> the output over both sets
+    (in ``o_b``'s type) and the joint log-sum-exp, in float32. Part ``b`` may
+    see nothing (the kernels write +inf there, read as -inf here); part ``a``
+    always sees a key. ``ops/blockdiff.py`` joins its two parts the same way."""
+    rows, s, _ = o_b.shape
+    lse_b = jnp.where(lse_b == jnp.inf, -jnp.inf, lse_b)
+    lse = jnp.logaddexp(lse_a, lse_b)
+    w_a = jnp.exp(lse_a - lse).reshape(rows, s, 1)
+    w_b = jnp.exp(lse_b - lse).reshape(rows, s, 1)
+    return (o_a.astype(jnp.float32) * w_a + o_b.astype(jnp.float32) * w_b).astype(o_b.dtype), lse
+
+
 @functools.lru_cache(maxsize=None)
 def _core(window: int, chunk: int, heads: int, local: tuple, remote: tuple, interpret: bool):
     """Differentiable EVA attention on q, k, v [B*H, S, D], ks, vs
@@ -205,12 +220,8 @@ def _core(window: int, chunk: int, heads: int, local: tuple, remote: tuple, inte
                 block_q=remote[0], block_k=remote[1], **kw_r,
             )
         with jax.named_scope("eva.merge"):
-            lse_l, lse_r = _rows_of_lanes(lse_l, bh, s), _rows_of_lanes(lse_r, bh, s)
-            lse_r = jnp.where(lse_r == jnp.inf, -jnp.inf, lse_r)  # a query with no summary in sight
-            lse = jnp.logaddexp(lse_l, lse_r)
-            w_l = jnp.exp(lse_l - lse).reshape(bh, s, 1)
-            w_r = jnp.exp(lse_r - lse).reshape(bh, s, 1)
-            o = (o_l.reshape(bh, s, d).astype(jnp.float32) * w_l + o_r.astype(jnp.float32) * w_r).astype(q.dtype)
+            # a query with no summary in sight reads +inf from the remote kernel
+            o, lse = join_by_lse(o_l.reshape(bh, s, d), _rows_of_lanes(lse_l, bh, s), o_r, _rows_of_lanes(lse_r, bh, s))
         return o, lse
 
     @jax.custom_vjp

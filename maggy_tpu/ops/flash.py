@@ -35,7 +35,9 @@ computes nothing copies nothing either. One compiled step serves every
 packing. Calls with no segment ids get the same table from the diagonal
 alone (one row of ``n_outer`` bounds). A sliding window (``window``, static)
 is a second bound of the same table: the tiles it masks wholly are dropped
-from first..last at either end, whichever axis is outermost.
+from first..last at either end, whichever axis is outermost. A causal bound a
+query (``bound``, data: a block-causal or a strict cross-stream mask) stands in
+the diagonal's place, in the tiles' masks and in the table alike.
 
 Off-TPU the kernels run under the Pallas interpreter so tests run on CPU
 meshes, and shapes that do not tile evenly fall back to
@@ -100,11 +102,16 @@ _MASKED_COMPILER_PARAMS = pltpu.CompilerParams(
 )
 
 
-def _tile_mask(q_start, k_start, block_q, block_k, window=0):
+def _tile_mask(q_start, k_start, block_q, block_k, window=0, hi=None):
     """The causal pairs of a tile; with a ``window`` those of them whose key
-    lies fewer than ``window`` positions before the query (its own counts)."""
+    lies fewer than ``window`` positions before the query (its own counts).
+    ``hi`` (int32 ``[block_q]``, a bound a query: ``flash_attention``'s
+    ``bound``) stands in the place of the query's own row index: the pairs
+    whose key's index is at most its query's bound."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    if hi is not None:
+        return hi[:, None] - (k_start + cols) >= 0
     ahead = (q_start + rows) - (k_start + cols)
     if window:
         return (ahead >= 0) & (ahead < window)
@@ -114,7 +121,7 @@ def _tile_mask(q_start, k_start, block_q, block_k, window=0):
 # ------------------------------------------------------------- tile-visit table
 
 
-def needed_tiles(segs, *, causal, sq, sk, block_q, block_k, selected=None, window=0):
+def needed_tiles(segs, *, causal, sq, sk, block_q, block_k, selected=None, window=0, bound=None):
     """bool [rows, sq // block_q, sk // block_k]: the tiles that can hold an
     unmasked pair. A tile is needed when it is not wholly above the causal
     diagonal and the two blocks' ranges of segment ids (minimum and maximum
@@ -131,10 +138,18 @@ def needed_tiles(segs, *, causal, sq, sk, block_q, block_k, selected=None, windo
     first needed key block is the later of its documents' first and the block
     of ``q_start - window + 1``, and in the key-major visit a key block's last
     needed query block the earlier of its documents' last and the block of
-    ``k_last + window - 1``."""
+    ``k_last + window - 1``. ``bound`` ([B, 1, sq] int32, numpy or traced,
+    causal calls only: the last key index each query may see) takes the
+    diagonal's place: a tile is needed when its first key lies at or before
+    the largest bound of its query block, a row of the table a batch row. With
+    bounds that never decrease along a row the needed tiles of a query block
+    and of a key block are each one run, as the diagonal's are; with any
+    others first..last is a superset, and the kernels mask inside a tile."""
     nq, nk = sq // block_q, sk // block_k
     need = np.ones((1, nq, nk), bool)
-    if causal:
+    if causal and bound is not None:
+        need = np.arange(nk)[None, None, :] * block_k <= bound.reshape(-1, nq, block_q).max(-1)[:, :, None]
+    elif causal:
         q_last = np.arange(nq)[:, None] * block_q + block_q - 1
         need = (np.arange(nk)[None, :] * block_k <= q_last)[None]
     if window:
@@ -221,14 +236,15 @@ def tiles_visited_share(segment_ids, *, causal=True, block_q=None, block_k=None,
 # --------------------------------------------------------------------- forward
 
 
-def _optional_refs(refs, n, segmented, masked):
+def _optional_refs(refs, n, segmented, masked, bounded=False):
     """A kernel's references apart: its ``n`` fixed operands, then the two
-    segment-id blocks and the selection's tile, each where the call has one,
-    then results and scratch."""
+    segment-id blocks, the queries' bounds and the selection's tile, each
+    where the call has one, then results and scratch."""
     ins, rest = refs[:n], list(refs[n:])
     qseg_ref, kseg_ref = (rest.pop(0), rest.pop(0)) if segmented else (None, None)
+    hi_ref = rest.pop(0) if bounded else None
     sel_ref = rest.pop(0) if masked else None
-    return ins, qseg_ref, kseg_ref, sel_ref, rest
+    return ins, qseg_ref, kseg_ref, sel_ref, hi_ref, rest
 
 
 def _selected(sel_ref):
@@ -238,9 +254,9 @@ def _selected(sel_ref):
 
 def _fwd_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0, bounded=False,
 ):
-    (q_ref, k_ref, v_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(refs, 3, segmented, masked)
+    (q_ref, k_ref, v_ref), qseg_ref, kseg_ref, sel_ref, hi_ref, rest = _optional_refs(refs, 3, segmented, masked, bounded)
     o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -256,7 +272,7 @@ def _fwd_kernel(
     k_start = ki * block_k
 
     # a skipped tile leaves m, l and the accumulator as they were (corr = 1, p = 0)
-    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, ki))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked or bounded else 0, ki))
     def _compute():
         q = q_ref[0]
         k = k_ref[0]
@@ -264,7 +280,7 @@ def _fwd_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        mask = _tile_mask(q_start, k_start, block_q, block_k, window) if causal else None
+        mask = _tile_mask(q_start, k_start, block_q, block_k, window, hi_ref[0, 0] if bounded else None) if causal else None
         if segmented:
             smask = qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
             mask = smask if mask is None else (mask & smask)
@@ -300,7 +316,7 @@ def _fwd_kernel(
         )
 
 
-def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer, masked=False):
+def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer, masked=False, bounded=False):
     """BlockSpecs of one kernel's grid (batch*heads, outer blocks, reduction
     blocks) with q rows (``outer="q"``: forward, dq) or k rows (``"k"``: the
     fused backward, dkv) outermost. Every index map also gets the visit
@@ -312,9 +328,10 @@ def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer, masked=
     segment ids are per (batch, seq) — row i // heads — shared by all heads.
     They arrive as [B, 1, S]: Mosaic wants a block's last two dims to be
     (8, 128)-aligned or the array's full extent, which a (1, block) tile of
-    [B, S] is only at B == 1. A selection's tile (``sel``, int8
+    [B, S] is only at B == 1. The queries' bounds ([B, 1, S] too) lie as the
+    query side's segment ids do (``qseg``). A selection's tile (``sel``, int8
     ``[B, sq, sk]``, shared by a batch row's heads) lies at both blocks."""
-    rows = segmented or masked  # the visit table has a row a batch row
+    rows = segmented or masked or bounded  # the visit table has a row a batch row
 
     def at(which, place):
         def index_map(i, o, r, bounds_ref):
@@ -344,18 +361,21 @@ def _specs(block_q, block_k, d, group, heads, segmented, outer, n_outer, masked=
 
 
 def _fwd_call(
-    q, k, v, segs, bounds, sel=None,
+    q, k, v, segs, bounds, sel=None, hi=None,
     *, causal, block_q, block_k, group, heads, interpret, window=0,
 ):
     bh, sq, d = q.shape
     sk = k.shape[1]
-    segmented, masked = segs is not None, sel is not None
-    sp = _specs(block_q, block_k, d, group, heads, segmented, "q", sq // block_q, masked)
+    segmented, masked, bounded = segs is not None, sel is not None, hi is not None
+    sp = _specs(block_q, block_k, d, group, heads, segmented, "q", sq // block_q, masked, bounded)
     in_specs = [sp["q"], sp["kv"], sp["kv"]]
     operands = [q, k, v]
     if segmented:
         in_specs += [sp["qseg"], sp["kseg"]]
         operands += [segs, segs]
+    if bounded:
+        in_specs.append(sp["qseg"])
+        operands.append(hi)
     if masked:
         in_specs.append(sp["sel"])
         operands.append(sel)
@@ -370,6 +390,7 @@ def _fwd_call(
             heads=heads,
             masked=masked,
             window=window,
+            bounded=bounded,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -396,7 +417,7 @@ def _fwd_call(
 
 
 def _recompute_p_ds(
-    q, k, v, o, do, lse, *, scale, causal, q_start, k_start, qseg=None, kseg=None, sel=None, window=0
+    q, k, v, o, do, lse, *, scale, causal, q_start, k_start, qseg=None, kseg=None, sel=None, window=0, hi=None
 ):
     """Shared tile math: probabilities from the saved LSE, then
     dS = P * (dP - delta) * scale with delta recomputed from the O/dO tiles.
@@ -407,7 +428,7 @@ def _recompute_p_ds(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     p = jnp.exp(s - lse)  # lse [block_q, 1]
-    mask = _tile_mask(q_start, k_start, block_q, block_k, window) if causal else None
+    mask = _tile_mask(q_start, k_start, block_q, block_k, window, hi) if causal else None
     if qseg is not None:
         smask = qseg[:, None] == kseg[None, :]
         mask = smask if mask is None else (mask & smask)
@@ -427,10 +448,10 @@ def _recompute_p_ds(
 
 def _dq_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0, bounded=False,
 ):
-    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
-        refs, 6, segmented, masked
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, hi_ref, rest = _optional_refs(
+        refs, 6, segmented, masked, bounded
     )
     dq_ref, acc_ref = rest
     qi = pl.program_id(1)
@@ -441,7 +462,7 @@ def _dq_kernel(
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, ki))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked or bounded else 0, ki))
     def _compute():
         k = k_ref[0]
         _, ds = _recompute_p_ds(
@@ -451,6 +472,7 @@ def _dq_kernel(
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
             sel=_selected(sel_ref) if masked else None, window=window,
+            hi=hi_ref[0, 0] if bounded else None,
         )
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
@@ -464,10 +486,10 @@ def _dq_kernel(
 
 def _dkv_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0, bounded=False,
 ):
-    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
-        refs, 6, segmented, masked
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, hi_ref, rest = _optional_refs(
+        refs, 6, segmented, masked, bounded
     )
     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = rest
     ki = pl.program_id(1)
@@ -481,7 +503,7 @@ def _dkv_kernel(
 
     # a KV block receives gradient only from the Q blocks at or after the
     # diagonal that share a document with it: its first-to-last needed block
-    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, qi))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked or bounded else 0, qi))
     def _compute():
         q = q_ref[0]
         do = do_ref[0]
@@ -492,6 +514,7 @@ def _dkv_kernel(
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
             sel=_selected(sel_ref) if masked else None, window=window,
+            hi=hi_ref[0, 0] if bounded else None,
         )
         # dV += P^T dO ; dK += dS^T Q — contract the q dim of both operands
         dv_acc_ref[:] += jax.lax.dot_general(
@@ -511,7 +534,7 @@ def _dkv_kernel(
 
 def _bwd_kernel(
     bounds_ref, *refs,
-    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0,
+    scale, causal, block_q, block_k, segmented, heads, masked=False, window=0, bounded=False,
 ):
     """dq, dk and dv from one visit of a tile: the grid is ``_dkv_kernel``'s
     (k blocks outer, q blocks inner, dk and dv accumulated across the inner
@@ -520,8 +543,8 @@ def _bwd_kernel(
     first k block, gets ``ds k`` from every visited tile (k blocks in
     ascending order, as ``_dq_kernel`` adds them) and is cast into the
     head's output block at the last."""
-    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
-        refs, 6, segmented, masked
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref), qseg_ref, kseg_ref, sel_ref, hi_ref, rest = _optional_refs(
+        refs, 6, segmented, masked, bounded
     )
     dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref = rest
     ki = pl.program_id(1)
@@ -538,7 +561,7 @@ def _bwd_kernel(
     def _init_q():
         dq_acc_ref[qi] = jnp.zeros(dq_acc_ref.shape[1:], dq_acc_ref.dtype)
 
-    @pl.when(_visits(bounds_ref, heads if segmented or masked else 0, qi))
+    @pl.when(_visits(bounds_ref, heads if segmented or masked or bounded else 0, qi))
     def _compute():
         q = q_ref[0]
         k = k_ref[0]
@@ -550,6 +573,7 @@ def _bwd_kernel(
             qseg=qseg_ref[0, 0] if segmented else None,
             kseg=kseg_ref[0, 0] if segmented else None,
             sel=_selected(sel_ref) if masked else None, window=window,
+            hi=hi_ref[0, 0] if bounded else None,
         )
         ds = ds.astype(q.dtype)
         dv_acc_ref[:] += jax.lax.dot_general(
@@ -625,20 +649,23 @@ def _fused_vmem_bytes(sq, d, block_q, block_k, itemsize, masked=False):
 
 def _bwd_pallas(
     kernel, name, outer, grid, outs, scratch_shapes, compiler_params,
-    q, k, v, o, do, lse, segs, bounds, sel=None,
+    q, k, v, o, do, lse, segs, bounds, sel=None, hi=None,
     *, causal, block_q, block_k, group, heads, interpret, window=0,
 ):
     """One backward kernel over ``grid`` with q rows (``outer="q"``) or k rows
     (``"k"``) outermost; ``outs`` pairs each result's BlockSpec (a name of
     ``_specs`` or a spec) with its shape."""
     d = q.shape[2]
-    segmented, masked = segs is not None, sel is not None
-    sp = _specs(block_q, block_k, d, group, heads, segmented, outer, grid[1], masked)
+    segmented, masked, bounded = segs is not None, sel is not None, hi is not None
+    sp = _specs(block_q, block_k, d, group, heads, segmented, outer, grid[1], masked, bounded)
     in_specs = [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"], sp["lse"]]
     operands = [q, k, v, o, do, lse]
     if segmented:
         in_specs += [sp["qseg"], sp["kseg"]]
         operands += [segs, segs]
+    if bounded:
+        in_specs.append(sp["qseg"])
+        operands.append(hi)
     if masked:
         in_specs.append(sp["sel"])
         operands.append(sel)
@@ -646,6 +673,7 @@ def _bwd_pallas(
         functools.partial(
             kernel, scale=1.0 / d**0.5, causal=causal, block_q=block_q,
             block_k=block_k, segmented=segmented, heads=heads, masked=masked, window=window,
+            bounded=bounded,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -661,7 +689,7 @@ def _bwd_pallas(
     )(bounds, *operands)
 
 
-def _bwd_split(q, k, v, o, do, lse, segs, bounds, sel=None, *, block_q, block_k, **kw):
+def _bwd_split(q, k, v, o, do, lse, segs, bounds, sel=None, hi=None, *, block_q, block_k, **kw):
     """The two-kernel backward (FlashAttention-2's): ``flash_dq`` sums over k
     blocks, ``flash_dkv`` over q blocks, each recomputing p and ds on every
     tile it visits. Its VMEM does not grow with the row's length."""
@@ -673,7 +701,7 @@ def _bwd_split(q, k, v, o, do, lse, segs, bounds, sel=None, *, block_q, block_k,
         _dq_kernel, "flash_dq", "q", (bh, sq // block_q, sk // block_k),
         [("q", jax.ShapeDtypeStruct((bh, sq, d), q.dtype))],
         [pltpu.VMEM((block_q, d), jnp.float32)], params,
-        q, k, v, o, do, lse, segs, bounds("q"), sel, **kw,
+        q, k, v, o, do, lse, segs, bounds("q"), sel, hi, **kw,
     )
     dk, dv = _bwd_pallas(
         _dkv_kernel, "flash_dkv", "k", (bh, sk // block_k, sq // block_q),
@@ -682,12 +710,12 @@ def _bwd_split(q, k, v, o, do, lse, segs, bounds, sel=None, *, block_q, block_k,
             ("dkv", jax.ShapeDtypeStruct((bh, sk, d), v.dtype)),
         ],
         [pltpu.VMEM((block_k, d), jnp.float32)] * 2, params,
-        q, k, v, o, do, lse, segs, bounds("k"), sel, **kw,
+        q, k, v, o, do, lse, segs, bounds("k"), sel, hi, **kw,
     )
     return dq, dk, dv
 
 
-def _bwd_fused(q, k, v, o, do, lse, segs, bounds, sel=None, *, block_q, block_k, **kw):
+def _bwd_fused(q, k, v, o, do, lse, segs, bounds, sel=None, hi=None, *, block_q, block_k, **kw):
     """``flash_bwd``: the grid and visit table of ``flash_dkv``, dq besides
     (``_bwd_kernel``). dq leaves as ``[BH, n_q, block_q, D]`` in ``q.dtype``,
     one block a head, which is ``[BH, S, D]`` read another way: no buffer the
@@ -718,20 +746,34 @@ def _bwd_fused(q, k, v, o, do, lse, segs, bounds, sel=None, *, block_q, block_k,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_fused_vmem_bytes(sq, d, block_q, block_k, q.dtype.itemsize, sel is not None),
         ),
-        q, k, v, o, do, lse, segs, bounds("k"), sel,
+        q, k, v, o, do, lse, segs, bounds("k"), sel, hi,
         block_q=block_q, block_k=block_k, **kw,
     )
     return dq.reshape(bh, sq, d), dk, dv
 
 
-def _bwd_call(q, k, v, o, do, lse, segs, bounds, sel=None, **kw):
+def _bwd_call(q, k, v, o, do, lse, segs, bounds, sel=None, hi=None, **kw):
     """dq and, per q head, dk and dv (the caller sums each GQA group: a KV
     block cannot accumulate across grid rows). ``bounds(outer)`` gives the
     visit table with q or k blocks outermost; the form chosen from the row's
     length and the head's width (``backward_form``) asks for the one or two
     it reads."""
     fused = backward_form(q.shape[1], q.shape[2]) == "fused"
-    return (_bwd_fused if fused else _bwd_split)(q, k, v, o, do, lse, segs, bounds, sel, **kw)
+    return (_bwd_fused if fused else _bwd_split)(q, k, v, o, do, lse, segs, bounds, sel, hi, **kw)
+
+
+def sum_groups(dk_h, dv_h, group: int, k_dtype, v_dtype):
+    """The kernels emit dk and dv per q head ``[B*H, S, D]``: each GQA group
+    summed in float32, ``[B*Kh, S, D]`` in the keys' and values' types."""
+    if group == 1:
+        return dk_h, dv_h
+    bh, sk, d = dk_h.shape
+
+    def gsum(x, dtype):
+        x = x.reshape(bh // group, group, sk, d).astype(jnp.float32)
+        return x.sum(axis=1).astype(dtype)
+
+    return gsum(dk_h, k_dtype), gsum(dv_h, v_dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -739,7 +781,7 @@ def _flash_core(
     causal: bool, block_q: int, block_k: int, bwd_block_q: int,
     bwd_block_k: int, group: int, heads: int, interpret: bool,
     segmented: bool, masked: bool = False, with_lse: bool = False, reselects: bool = False,
-    window: int = 0,
+    window: int = 0, bounded: bool = False,
 ):
     """Differentiable flash attention on q [B*H, S, D], k/v [B*Kh, S, D]
     (GQA group = H // Kh handled by kernel index maps — the repeated K/V
@@ -752,7 +794,9 @@ def _flash_core(
     (``flash_attention``'s ``reselect``): the backward calls it, and the
     mask is no residual. ``window`` (static): the causal mask keeps a key
     fewer than ``window`` positions before its query only, in every kernel
-    and in the visit tables. ``with_lse``: the
+    and in the visit tables. With ``bounded`` an operand follows the segment
+    ids, int32 [B, 1, S]: the last key index each query may see, in the
+    diagonal's place in every kernel and visit table (no cotangent). ``with_lse``: the
     result is ``(o, lse)``, the rows' log-sum-exp as the backward keeps it
     (``[BH, S / 128, 128]`` float32, a constant to whoever reads it: its
     cotangent is dropped). Each kernel gets its visit bounds, computed
@@ -768,17 +812,24 @@ def _flash_core(
               heads=heads, interpret=interpret, window=window)
     bwd_kw = dict(kw, block_q=bwd_block_q, block_k=bwd_block_k)
 
-    def bounds(q, k, segs, sel, block_q, block_k, outer):
+    def bounds(q, k, segs, hi, sel, block_q, block_k, outer):
         tiles = dict(causal=causal, sq=q.shape[1], sk=k.shape[1], block_q=block_q, block_k=block_k, window=window)
         if sel:
             tiles["selected"] = sel[0]
+        if hi is not None:
+            tiles["bound"] = hi
         return jnp.asarray(visit_bounds(segs if segmented else None, outer, **tiles))
 
-    def forward(q, k, v, segs, *sel):
+    def apart(more):
+        """The queries' bounds (None without) and what follows them: the selection's operands."""
+        return (more[0], more[1:]) if bounded else (None, more)
+
+    def forward(q, k, v, segs, *more):
+        hi, sel = apart(more)
         sel = sel[:1]  # the selection itself; what may follow it is the backward's (``reselect``)
         return _fwd_call(
             q, k, v, segs if segmented else None,
-            bounds(q, k, segs, sel, block_q, block_k, "q"), *sel, **kw,
+            bounds(q, k, segs, hi, sel, block_q, block_k, "q"), *sel, hi=hi, **kw,
         )
 
     def rows_of_lanes(lse, sq):
@@ -803,10 +854,11 @@ def _flash_core(
         # layer's replay would have to select again to have
         o = checkpoint_name(o, FLASH_RESIDUALS[0])
         lse = checkpoint_name(rows_of_lanes(lse, q.shape[1]), FLASH_RESIDUALS[1])
-        return ((o, lse) if with_lse else o), (q, k, v, segs, o, lse, *(sel[1:] if reselects else sel))
+        hi, sel = apart(sel)
+        return ((o, lse) if with_lse else o), (q, k, v, segs, o, lse, hi, *(sel[1:] if reselects else sel))
 
     def core_bwd(res, g):
-        q, k, v, segs, o, lse, *sel = res
+        q, k, v, segs, o, lse, hi, *sel = res
         if reselects:
             sel = [sel[0]()]
         g = g[0] if with_lse else g
@@ -815,20 +867,12 @@ def _flash_core(
         dq, dk_h, dv_h = _bwd_call(
             q, k, v, o, g.astype(o.dtype), lse,
             segs if segmented else None,
-            functools.partial(bounds, q, k, segs, sel, bwd_block_q, bwd_block_k),
-            *sel, **bwd_kw,
+            functools.partial(bounds, q, k, segs, hi, sel, bwd_block_q, bwd_block_k),
+            *sel, hi=hi, **bwd_kw,
         )
-        if group > 1:
-            # the kernel emits dk, dv per q head; sum each GQA group in fp32
-            bh, sk, d = dk_h.shape
-
-            def gsum(x, dtype):
-                x = x.reshape(bh // group, group, sk, d).astype(jnp.float32)
-                return x.sum(axis=1).astype(dtype)
-
-            dk_h, dv_h = gsum(dk_h, k.dtype), gsum(dv_h, v.dtype)
-        # int segment ids, an int8 selection and what makes it again: no cotangent
-        return (dq, dk_h, dv_h, None, *(None,) * (masked + reselects))
+        dk_h, dv_h = sum_groups(dk_h, dv_h, group, k.dtype, v.dtype)
+        # int segment ids, the queries' bounds, an int8 selection and what makes it again: no cotangent
+        return (dq, dk_h, dv_h, None, *(None,) * (bounded + masked + reselects))
 
     core.defvjp(core_fwd, core_bwd)
     return core
@@ -934,6 +978,7 @@ def flash_attention(
     reselect=None,
     return_lse: bool = False,
     window: int = 0,
+    bound=None,
 ) -> jax.Array:
     """q [B,S,H,D], k/v [B,S,Kh,D] → [B,S,H,D]. Differentiable (custom VJP).
     The four tile sizes default to the measured-fastest tiling for the
@@ -961,7 +1006,16 @@ def flash_attention(
     keep it, exactly, in the output, the log-sum-exp and the three gradients,
     and a tile wholly outside the window is not visited, in the forward's
     query-major and the backward's key-major order alike (``needed_tiles``).
-    ``window=0`` is the call without one, bit for bit.
+    ``window=0`` is the call without one, bit for bit. ``bound`` ([B, Sq]
+    int32, with ``causal`` and no window): a causal bound a query. The query
+    at row index ``t`` sees the keys at ``s <= bound[t]`` (inside its document
+    where there are segment ids) in place of ``s <= t``: a block-causal mask
+    (``bound[t]`` the last index of ``t``'s block), a strict one over another
+    stream's keys (the last index before ``t``'s block), any staircase. Exact
+    in the output, the log-sum-exp and the three gradients; the bound of a
+    query block (its largest) takes the diagonal's place in the visit table in
+    both orders (``needed_tiles``), and a query that sees no key reads 0 with
+    a log-sum-exp of +inf. ``bound[t] = t`` is the causal call, bit for bit.
 
     ``interpret`` defaults to the Pallas interpreter off-TPU and the compiled
     kernel on a TPU. Interpreted, a shape that does not tile falls back to
@@ -984,10 +1038,12 @@ def flash_attention(
         interpret = jax.default_backend() != "tpu"
     why = _untileable(
         sq, sk, d, block_q, block_k, bwd_block_q, bwd_block_k,
-        segmented, compiled=not interpret,
+        segmented or bound is not None, compiled=not interpret,
     )
     if window and not causal:
         raise ValueError("a window bounds a causal call: keys before the query, its own among them")
+    if bound is not None and (window or not causal):
+        raise ValueError("a bound a query stands in the diagonal's place: a causal call with no window")
     if why is not None:
         if not interpret or selected is not None or return_lse or window:
             raise ValueError(
@@ -996,7 +1052,7 @@ def flash_attention(
                 "auto_attention, which routes such shapes to the XLA path."
             )
         return blockwise_attention(
-            q, k, v, causal=causal, segment_ids=segment_ids
+            q, k, v, causal=causal, segment_ids=segment_ids, bound=bound
         )  # repeats GQA itself
 
     qr = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
@@ -1011,10 +1067,11 @@ def flash_attention(
     sel = () if selected is None else (selected.astype(jnp.int8),)
     if sel and reselect is not None:
         sel += (reselect,)
+    hi = () if bound is None else (bound.astype(jnp.int32).reshape(b, 1, sq),)
     out = _flash_core(
         causal, block_q, block_k, bwd_block_q, bwd_block_k, h // kh, h,
-        interpret, segmented, selected is not None, return_lse, len(sel) == 2, int(window),
-    )(qr, kr, vr, segs, *sel)
+        interpret, segmented, selected is not None, return_lse, len(sel) == 2, int(window), bound is not None,
+    )(qr, kr, vr, segs, *hi, *sel)
     if return_lse:
         out, lse = out
         return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), jax.lax.stop_gradient(lse.reshape(b, h, sq))

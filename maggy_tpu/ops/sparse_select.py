@@ -489,7 +489,7 @@ def _loss_kernel(*refs, scale, block_q, block_k, heads, group, index_heads, segm
     output's block, float32 and transposed (``[key tiles, Dj, block_k]``: the
     products that make it then fill the lanes and transpose ``qi``'s tile,
     not ``d z``), and leaves once a batch row."""
-    (q_ref, k_ref, qi_ref, ki_ref, rows_ref), qseg_ref, kseg_ref, sel_ref, rest = _optional_refs(
+    (q_ref, k_ref, qi_ref, ki_ref, rows_ref), qseg_ref, kseg_ref, sel_ref, _hi_ref, rest = _optional_refs(
         refs, 5, segmented, masked
     )
     dqi_ref, dki_ref, dw_ref, kl_ref, dqi_acc, kl_acc = rest

@@ -46,6 +46,12 @@ GAUGES = frozenset(
         # queries see, and the chunks a document's start cuts over all chunks
         "attention.eva_remote_share",
         "attention.eva_chunks_cut_share",
+        # a model that trains by diffusion over blocks (models/moe.py MoEDecoder
+        # under block_diffusion): of the step's real tokens those its noise masked
+        # (about a half), and the pairs the two streams' block-wise mask keeps over
+        # one causal stream's pairs inside documents (about 2)
+        "diffusion.masked_share",
+        "attention.blockdiff_pairs_share",
         # an expert share model's step counters (models/moe.py
         # ExpertShareBlock, read by Trainer.fit with the loss of its last step)
         "moe.slots",  # (token, choice) slots on the experts this chip holds, all layers
@@ -303,6 +309,8 @@ SCOPES = (
     "eva.local",  # chunk summaries: the flash kernels on the windows folded into rows, and their visit table (ops/eva.py)
     "eva.remote",  # chunk summaries: the flash kernels on the summaries under their selection, and their visit table
     "eva.merge",  # chunk summaries: the two calls' outputs joined by their log-sum-exp; the two dq added
+    "diffusion.noise",  # block diffusion: the step's noise and [MASK], the two streams' assembly (MoEDecoder), a layer's bounds and pair counts (Attention)
+    "diffusion.merge",  # block diffusion: the noised queries' own block in XLA, joined with the flash call on the clean keys by their log-sum-exp; their backward (ops/blockdiff.py)
     # (flax module names are scopes too and need no entry: attn, mlp, moe,
     # and mtp, the multi-token-prediction module)
     "decode_attn",  # page/chunk gather + online softmax over the KV cache
@@ -412,6 +420,8 @@ GAUGE_UNITS = {
     "attention.window_pairs_share": "ratio",
     "attention.eva_remote_share": "ratio",
     "attention.eva_chunks_cut_share": "ratio",
+    "diffusion.masked_share": "ratio",
+    "attention.blockdiff_pairs_share": "ratio",
     "moe.slots": "count",
     "moe.slots_dropped": "count",
     "moe.load_max_over_mean": "ratio",

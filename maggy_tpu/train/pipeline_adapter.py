@@ -185,6 +185,12 @@ def decoder_pipeline_parts(
             "dense layers, the multi-token-prediction module or the expert "
             "share form has no stage chunks yet"
         )
+    if getattr(cfg, "stream_block", 0):
+        raise ValueError(
+            "pp>1 has no two-stream step: the block-diffusion objective (a clean "
+            "and a noised stream, a noise key a step, targets the model weighs) "
+            "runs on the dense step only"
+        )
     if cfg.tie_embeddings:
         raise ValueError(
             "tie_embeddings=True is not supported with pp>1: the input "

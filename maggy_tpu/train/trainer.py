@@ -92,6 +92,31 @@ def lm_loss_fn(
     return -ll_sum / jnp.maximum(weight, 1.0)
 
 
+def target_weighted_loss(logits: jax.Array, batch: Dict[str, jax.Array], weights: jax.Array) -> jax.Array:
+    """The objective of a model that weighs its own targets
+    (``sown.target_weights``): position ``i``'s logits predict token ``i``
+    itself, ``-(sum_i w_i log softmax(logits_i)[x_i]) / N`` with ``N`` the
+    batch's real tokens (``loss_mask``, else the segment ids above 0, else
+    every position), float32. Weights on masked-out positions do not count."""
+    tokens = batch["tokens"]
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    ll = jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
+    real = batch.get("loss_mask")
+    if real is None and batch.get("segment_ids") is not None:
+        real = batch["segment_ids"] > 0
+    if real is None:
+        return -(ll * weights).sum() / jnp.float32(ll.size)
+    real = real.astype(jnp.float32)
+    return -(ll * weights * real).sum() / jnp.maximum(real.sum(), 1.0)
+
+
+def model_loss(loss_fn: Callable, logits: jax.Array, mods, batch: Dict[str, jax.Array]) -> jax.Array:
+    """The data loss of a dense step: the trainer's ``loss_fn``, or, where the
+    model sowed its targets' weights, its own objective."""
+    weights = sown.target_weights(mods)
+    return loss_fn(logits, batch) if weights is None else target_weighted_loss(logits, batch, weights)
+
+
 def classification_loss_fn(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
     labels = batch["labels"]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -190,7 +215,6 @@ class Trainer:
     mesh: Any
     loss_fn: Callable = lm_loss_fn
     rules: Tuple = shd.DEFAULT_RULES
-    rngs_in_apply: bool = False
     # pipeline parallelism: microbatches per step when the mesh has a stage
     # axis > 1 (defaults to 2*pp — enough to amortize the 1F1B bubble while
     # staying valid for small test batches); must divide the batch size
@@ -380,6 +404,12 @@ class Trainer:
                 raise NotImplementedError(
                     "the bucketed/ZeRO overlap step has no multi-token-"
                     "prediction loss: train this model with overlap off"
+                )
+            if sown.target_weights(mods) is not None:
+                raise NotImplementedError(
+                    "the bucketed/ZeRO overlap step has no objective that weighs "
+                    "its own targets (block diffusion: a noise key a step, no next-"
+                    "token shift): train this model with overlap off"
                 )
             aux_dev = sown.collect_aux_losses(mods) / n_manual
             with jax.named_scope("loss"):
@@ -978,10 +1008,11 @@ class Trainer:
                 # mutable intermediates, or flax `sow` is a silent no-op: what the
                 # model's layers sow is read through models/sown.py
                 logits, mods = state.apply_fn(
-                    {"params": params}, *_model_inputs(batch), mutable=["intermediates"]
+                    {"params": params}, *_model_inputs(batch), mutable=["intermediates"],
+                    **sown.step_inputs(self.model, state.step),
                 )
                 with jax.named_scope("loss"):
-                    loss = self.loss_fn(logits, batch)
+                    loss = model_loss(self.loss_fn, logits, mods, batch)
                     mtp = mtp_loss(mods, batch)
                 aux = sown.collect_aux_losses(mods)
                 extra = sown.step_counters(mods)
@@ -1127,7 +1158,9 @@ class Trainer:
                     return self.model.apply({"params": params}, *_model_inputs(batch))
             else:
                 def eval_step(state, batch):
-                    return state.apply_fn({"params": state.params}, *_model_inputs(batch))
+                    return state.apply_fn(
+                        {"params": state.params}, *_model_inputs(batch), **sown.step_inputs(self.model, state.step)
+                    )
 
             self._eval_step = jax.jit(eval_step)
         with self.mesh:
@@ -1182,8 +1215,11 @@ class Trainer:
                     return loss
             else:
                 def eval_loss(state, batch):
-                    logits = state.apply_fn({"params": state.params}, *_model_inputs(batch))
-                    return self.loss_fn(logits, batch)
+                    logits, mods = state.apply_fn(
+                        {"params": state.params}, *_model_inputs(batch), mutable=["intermediates"],
+                        **sown.step_inputs(self.model, state.step),
+                    )
+                    return model_loss(self.loss_fn, logits, mods, batch)
 
             self._eval_loss_step = jax.jit(eval_loss)
         from maggy_tpu import telemetry

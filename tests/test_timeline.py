@@ -364,7 +364,7 @@ def test_every_span_call_in_the_package_is_registered():
 
 def test_scopes_registry_is_closed_and_used():
     text = ""
-    for path in ("models/transformer.py", "models/moe.py", "ops/sparse_select.py", "ops/eva.py", "train/trainer.py", "serve/engine.py"):
+    for path in ("models/transformer.py", "models/moe.py", "ops/sparse_select.py", "ops/eva.py", "ops/blockdiff.py", "train/trainer.py", "serve/engine.py"):
         with open(os.path.join(REPO, "maggy_tpu", path)) as f:
             text += f.read()
     used = set(re.findall(r"named_scope\(\"([\w.]+)\"\)", text))

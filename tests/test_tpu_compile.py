@@ -421,3 +421,39 @@ def test_chunk_summary_attention_compiles_at_the_evabyte_cells_shape(one_chip):
     text = jax.jit(step).lower(head, head, head, sds((h, d), jnp.float32), sds((h, d), jnp.float32), sds((1, s), jnp.int32)).compile().as_text()
     assert eva.untileable(s, window, chunk, d, compiled=True) is None and backward_form(s, d) == "fused"
     assert flash_calls(text) == {"flash_fwd": 2, "flash_bwd": 2, "flash_dq": 0, "flash_dkv": 0}
+
+
+def test_two_stream_attention_compiles_at_the_sdar_cells_shape(one_chip):
+    """A two-stream layer's attention (``ops/blockdiff.py``) at the
+    sdar-30b-a3b-chat cell's rows: two packed rows of 8,192 a stream, 32 query
+    heads over 4 of 128, bfloat16, block 4: the clean stream's ``flash_fwd`` /
+    ``flash_bwd`` under its block-causal bound and the noised stream's under
+    the bound before its block (the bounds are data: an int32 block beside the
+    segment ids in every kernel), the own block in XLA, and under the recompute
+    policy no third forward."""
+    from maggy_tpu.ops import blockdiff
+
+    b, l, h, kh, d, block = 2, 8192, 32, 4, 128, 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, positions, segment_ids):
+        lay = blockdiff.layout(positions, segment_ids, block)
+        (q_c, q_n), (k_c, k_n), (v_c, v_n) = ((a[:, :l], a[:, l:]) for a in (q, k, v))
+        clean = flash_attention(q_c, k_c, v_c, segment_ids=segment_ids, bound=lay.hi_clean, interpret=False)
+        noised = blockdiff.noised_attention(q_n, k_c, v_c, k_n, v_n, segment_ids, lay, block=block, interpret=False)
+        return clean.astype(jnp.float32).sum() + noised.astype(jnp.float32).sum()
+
+    def step(q, k, v, positions, segment_ids):
+        kept = jax.checkpoint(
+            functools.partial(loss, positions=positions, segment_ids=segment_ids), policy=REMAT_POLICIES["nothing"]
+        )
+        return jax.grad(kept, argnums=(0, 1, 2))(q, k, v)
+
+    ids = sds((b, l), jnp.int32)
+    text = jax.jit(step).lower(
+        sds((b, 2 * l, h, d), jnp.bfloat16), sds((b, 2 * l, kh, d), jnp.bfloat16), sds((b, 2 * l, kh, d), jnp.bfloat16), ids, ids
+    ).compile().as_text()
+    assert blockdiff.untileable(l, d, compiled=True) is None and backward_form(l, d) == "fused"
+    assert flash_calls(text) == {"flash_fwd": 2, "flash_bwd": 2, "flash_dq": 0, "flash_dkv": 0}
