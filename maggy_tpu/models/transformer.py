@@ -738,7 +738,9 @@ def record_attention_kernel(
     the calls on the summaries. A two-stream layer (``block`` > 0:
     ``ops/blockdiff.py``) says so as ``form`` ``blockdiff`` beside its ``block``
     and ``calls``, the flash calls a layer (2: one a stream, each under its
-    causal bound a query); ``q`` and ``kv`` are then one stream's.
+    causal bound a query), and ``own_block``, how the noised queries' own
+    block is computed (``kernel``: the band kernels beside the flash calls;
+    ``xla``: inside the explicit mask); ``q`` and ``kv`` are then one stream's.
     The dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
@@ -751,7 +753,7 @@ def record_attention_kernel(
     if chunk:
         attrs.update(form="eva", chunk=int(chunk))
     if block:
-        attrs.update(form="blockdiff", block=int(block), calls=2)
+        attrs.update(form="blockdiff", block=int(block), calls=2, own_block="kernel" if kernel == "flash" else "xla")
     if kernel.startswith("flash"):
         from maggy_tpu.ops.flash import _auto_blocks, backward_form
 
@@ -859,10 +861,11 @@ def auto_blockdiff_attention(q, k, v, positions, segment_ids, lay, *, block: int
     ``segment_ids`` [B, L] and ``lay`` (``blockdiff.layout``) one stream's. On
     one TPU chip, where a stream's shape tiles, two calls of the flash kernels
     under their bounds (the clean queries block-causal on the clean keys; the
-    noised queries on the clean keys before their block, joined with their own
-    block's noised keys by the rows' log-sum-exp), recorded as ``flash``,
-    ``form`` ``blockdiff``; anywhere else both streams on explicit masks in
-    XLA (``xla_dense``, with the reason): one choice for the two calls."""
+    noised queries on the clean keys before their block, whose softmax the
+    band kernels continue over their own block's noised keys), recorded as
+    ``flash``, ``form`` ``blockdiff``, ``own_block`` ``kernel``; anywhere else
+    both streams on explicit masks in XLA (``xla_dense``, with the reason,
+    ``own_block`` ``xla``): one choice for the two calls."""
     from maggy_tpu.parallel.mesh import ambient_mesh
 
     l, d = q.shape[1] // 2, q.shape[3]
@@ -873,7 +876,7 @@ def auto_blockdiff_attention(q, k, v, positions, segment_ids, lay, *, block: int
     elif mesh is not None and mesh.size > 1:
         why = f"mesh {dict(mesh.shape)}: the two streams' calls run on one chip"
     else:
-        why = blockdiff.untileable(l, d, compiled=True)
+        why = blockdiff.untileable(l, d, block, compiled=True)
     if why is None:
         record_attention_kernel("flash", q_c, k_c, segment_ids, block=block)
         out_c = flash_attention(q_c, k_c, v_c, causal=True, segment_ids=segment_ids, bound=lay.hi_clean)

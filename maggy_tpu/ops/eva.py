@@ -178,7 +178,7 @@ def join_by_lse(o_a, lse_a, o_b, lse_b):
     rows of lanes ``[rows, S / 128, 128]`` float32 -> the output over both sets
     (in ``o_b``'s type) and the joint log-sum-exp, in float32. Part ``b`` may
     see nothing (the kernels write +inf there, read as -inf here); part ``a``
-    always sees a key. ``ops/blockdiff.py`` joins its two parts the same way."""
+    always sees a key."""
     rows, s, _ = o_b.shape
     lse_b = jnp.where(lse_b == jnp.inf, -jnp.inf, lse_b)
     lse = jnp.logaddexp(lse_a, lse_b)

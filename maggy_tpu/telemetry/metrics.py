@@ -310,7 +310,7 @@ SCOPES = (
     "eva.remote",  # chunk summaries: the flash kernels on the summaries under their selection, and their visit table
     "eva.merge",  # chunk summaries: the two calls' outputs joined by their log-sum-exp; the two dq added
     "diffusion.noise",  # block diffusion: the step's noise and [MASK], the two streams' assembly (MoEDecoder), a layer's bounds and pair counts (Attention)
-    "diffusion.merge",  # block diffusion: the noised queries' own block in XLA, joined with the flash call on the clean keys by their log-sum-exp; their backward (ops/blockdiff.py)
+    "diffusion.merge",  # block diffusion: the noised queries' own block, two band kernels that continue the flash call on the clean keys (own_block_fwd, own_block_bwd), and the tiles with halos XLA builds for them (ops/blockdiff.py)
     # (flax module names are scopes too and need no entry: attn, mlp, moe,
     # and mtp, the multi-token-prediction module)
     "decode_attn",  # page/chunk gather + online softmax over the KV cache
@@ -345,8 +345,9 @@ EVENTS = frozenset(
         # which attention kernel the automatic dispatch took for a traced
         # shape, and why not flash (models/transformer.py auto_attention);
         # for the flash kernels their tiles, ``lanes`` and ``backward``
-        # (``fused`` or ``split``: ops/flash.py backward_form), and ``selected``,
-        # the keys a query keeps, where a selection masks the call
+        # (``fused`` or ``split``: ops/flash.py backward_form), ``selected``,
+        # the keys a query keeps, where a selection masks the call, and for a
+        # two-stream layer ``own_block`` (``kernel`` or ``xla``: ops/blockdiff.py)
         "attention.kernel",
         # autopilot decisions (autopilot/controller.py, serve/scheduler.py):
         # the auditable telemetry→config loop — diagnosis verdicts, applied

@@ -5,8 +5,10 @@ scores, output, log-sum-exp and the three gradients, on packed documents that
 are not multiples of the block long, with blocks that straddle a tile; the
 bound ``t`` bit for bit the causal call; the visit table against a brute-force
 count in both orders; and the noised stream's attention (``ops/blockdiff.py``:
-the kernels on the clean keys, the own block in XLA, the two joined by their
-log-sum-exp) against the explicit ``[L, 2L]`` mask, with every gradient.
+the flash kernels on the clean keys, the band kernels on the own block, which
+continue the flash call's softmax) against the explicit ``[L, 2L]`` mask, with
+the joint log-sum-exp and every gradient, on rows whose blocks and documents
+meet the band kernels' tiles every way.
 
 Both sides compute in float32 here, so what differs is the order of the sums.
 """
@@ -201,6 +203,77 @@ def test_noised_attention_is_one_softmax_over_both_sets(path, monkeypatch):
     for a, b_, what in zip(got, ref, ("q", "k_clean", "v_clean", "k_noised", "v_noised")):
         np.testing.assert_allclose(a, b_, atol=1e-4, err_msg=f"d{what}")
     blockdiff._core.cache_clear()
+
+
+# what a row of the band kernels' grid can hold, two rows a case (tiles of 64 or 128 rows, products of 64 queries)
+BAND_ROWS = {
+    # positions 64..67 of the second document lie at rows 125..128: the queries at 125..127 have a mate in the
+    # next tile's first row, the query at 128 its three mates in the tile before
+    "a_block_cut_by_a_tile_edge": DOCS,
+    # the second document ends at row 63 in a block of two, the third at row 192 in a block of one that opens a tile
+    "a_document_ends_at_a_tile_edge": [[50, 14, 129, 63], [64, 64, 128]],
+    "documents_shorter_than_a_block": [[3, 1, 2, 3, 2, 1, 244], [1] * 9 + [2] * 5 + [3] * 3 + [228]],
+    "a_padded_tail": [[19, 90, 33, 50], [61, 3]],
+}
+
+
+@pytest.mark.parametrize("rows,heads,kv_heads,band", [
+    ("a_block_cut_by_a_tile_edge", 4, 2, (64, 64, 8)),
+    ("a_block_cut_by_a_tile_edge", 8, 1, (128, 64, 8)),
+    ("a_document_ends_at_a_tile_edge", 2, 2, (64, 64, 8)),
+    ("a_document_ends_at_a_tile_edge", 4, 2, (128, 64, 8)),
+    ("documents_shorter_than_a_block", 2, 2, (128, 64, 8)),
+    ("documents_shorter_than_a_block", 8, 1, (64, 64, 8)),
+    ("a_padded_tail", 4, 2, (64, 64, 8)),
+    ("a_padded_tail", 2, 2, (128, 64, 8)),
+])
+def test_the_band_kernels_against_the_explicit_mask(rows, heads, kv_heads, band, monkeypatch):
+    """The noised stream on the kernels in the interpreter (the flash call on the clean keys, ``own_block_fwd``
+    and ``own_block_bwd`` on the own block) against the explicit ``[L, 2L]`` mask: output, joint log-sum-exp and
+    the five gradients, at groups of 1, 2 and 8 heads a key head and two tile sizes."""
+    monkeypatch.setattr(flash, "_auto_blocks", lambda *a, **k: (TILE, TILE, TILE, TILE))
+    monkeypatch.setattr(blockdiff, "band_tiles", lambda l, block: band)
+    blockdiff._core.cache_clear()
+    pos, seg = (jnp.asarray(a) for a in rows_of(BAND_ROWS[rows]))
+    lay = blockdiff.layout(pos, seg, BLOCK)
+    rng = np.random.default_rng(11)
+    draw = lambda n: jnp.asarray(rng.standard_normal((B, L, n, D)), jnp.float32)
+    args = (draw(heads), draw(kv_heads), draw(kv_heads), draw(kv_heads), draw(kv_heads))
+    mask = blockdiff.noised_mask(pos, seg, lay, BLOCK)
+    both = lambda c, n: jnp.concatenate([c, n], 1)
+    fn = lambda *a: blockdiff.noised_attention(*a, seg, lay, block=BLOCK, interpret=True)
+    ref = lambda q, k_c, v_c, k_n, v_n: default_attention(q, both(k_c, k_n), both(v_c, v_n), causal=False, selected=mask)
+    # the real queries: a padding position sees padding, by the row's neighbours here and by block 0 there
+    real = np.asarray(seg) > 0
+    np.testing.assert_allclose(fn(*args)[real], ref(*args)[real], atol=2e-5)
+    _, lse = blockdiff._forward(
+        (TILE,) * 4, band, True, *args, seg.reshape(B, 1, L), lay.hi_noised.reshape(B, 1, L), blockdiff.block_ids(lay, seg),
+    )
+    want = explicit(args[0], both(args[1], args[3]), both(args[2], args[4]), mask)[1]
+    np.testing.assert_allclose(lse.reshape(B, heads, L).transpose(0, 2, 1)[real], want.transpose(0, 2, 1)[real], atol=2e-5)
+    w = jnp.asarray(rng.standard_normal(args[0].shape), jnp.float32) * real[:, :, None, None]
+    got = jax.grad(lambda *a: (fn(*a) * w).sum(), argnums=range(5))(*args)
+    exp = jax.grad(lambda *a: (ref(*a) * w).sum(), argnums=range(5))(*args)
+    for a, b_, what in zip(got, exp, ("q", "k_clean", "v_clean", "k_noised", "v_noised")):
+        np.testing.assert_allclose(a, b_, atol=1e-4, err_msg=f"d{what}")
+    blockdiff._core.cache_clear()
+
+
+def test_block_ids_number_the_runs_of_block_mates():
+    """Two real positions are block-mates (``Layout.own``) exactly where their numbers are equal; a padding position
+    (all at position 0 here) is its own block."""
+    for docs in BAND_ROWS.values():
+        pos, seg = rows_of(docs)
+        lay = blockdiff.layout(pos, seg, BLOCK)
+        ids = np.asarray(blockdiff.block_ids(blockdiff.layout(jnp.asarray(pos), jnp.asarray(seg), BLOCK), jnp.asarray(seg)))
+        assert ids.min() == 1
+        for j, off in enumerate(range(1 - BLOCK, BLOCK)):
+            same = np.roll(ids, -off, axis=1) == ids
+            at = np.arange(L)[None, :] + off
+            np.testing.assert_array_equal((same & (at >= 0) & (at < L))[seg > 0], lay.own[:, :, j][seg > 0])
+        assert (np.diff(ids, axis=1)[(seg == 0)[:, 1:]] == 1).all()
+        far = np.abs(np.subtract.outer(np.arange(L), np.arange(L))) >= BLOCK  # no number comes back past the band
+        assert not (far[None] & (ids[:, :, None] == ids[:, None, :])).any()
 
 
 def test_the_pairs_the_mask_keeps():

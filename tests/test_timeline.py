@@ -371,6 +371,31 @@ def test_scopes_registry_is_closed_and_used():
     assert used - {"lm_head"} == set(registry.SCOPES)
 
 
+def test_a_two_stream_layers_event_says_how_its_own_block_ran():
+    """``attention.kernel`` of a block-diffusion layer names the form of the
+    noised queries' own block beside the flash calls: the band kernels where
+    the flash kernels run, the explicit mask where the dispatch fell to XLA (as
+    it does here, off the chip); a layer of another kind does not carry it."""
+    from maggy_tpu import telemetry
+    from maggy_tpu.models import transformer
+    from maggy_tpu.ops import blockdiff
+
+    tel = Telemetry(worker="t")
+    big, kv = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16), jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16)
+    pos = jnp.tile(jnp.arange(32, dtype=jnp.int32), (1, 2))
+    seg = jnp.repeat(jnp.asarray([[1, 2]], jnp.int32), 32, axis=1)
+    q, k = jnp.ones((1, 128, 2, 64), jnp.float32), jnp.ones((1, 128, 1, 64), jnp.float32)
+    with telemetry.current(tel):
+        transformer.record_attention_kernel("flash", big, kv, seg, block=4)
+        transformer.record_attention_kernel("flash", big, kv, seg)
+        transformer.auto_blockdiff_attention(q, k, k, pos, seg, blockdiff.layout(pos, seg, 4), block=4)
+    on_chip, plain, here = [e["attrs"] for e in tel.drain_events() if e["name"] == "attention.kernel"]
+    assert (on_chip["kernel"], on_chip["form"], on_chip["calls"], on_chip["own_block"]) == ("flash", "blockdiff", 2, "kernel")
+    assert (here["kernel"], here["form"], here["own_block"]) == ("xla_dense", "blockdiff", "xla") and "cpu" in here["reason"]
+    assert "own_block" not in plain and "form" not in plain
+    assert blockdiff.untileable(8192, 128, 4, compiled=True) is None
+
+
 # --------------------------------------------------------- the two alert repairs
 
 

@@ -32,10 +32,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def kernel_calls(text, *names):
+    """How often a compiled program calls each Pallas kernel of these names."""
+    calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    return {name: sum(name in line for line in calls) for name in names}
+
+
 def flash_calls(text):
     """The flash kernels a compiled program calls, by name."""
-    calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
-    return {name: sum(name in line for line in calls) for name in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv")}
+    return kernel_calls(text, "flash_fwd", "flash_bwd", "flash_dq", "flash_dkv")
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)], ids=["gate_and_up", "down"])
@@ -429,8 +434,8 @@ def test_two_stream_attention_compiles_at_the_sdar_cells_shape(one_chip):
     heads over 4 of 128, bfloat16, block 4: the clean stream's ``flash_fwd`` /
     ``flash_bwd`` under its block-causal bound and the noised stream's under
     the bound before its block (the bounds are data: an int32 block beside the
-    segment ids in every kernel), the own block in XLA, and under the recompute
-    policy no third forward."""
+    segment ids in every kernel), the own block in its band kernels, once
+    each, and under the recompute policy no third forward."""
     from maggy_tpu.ops import blockdiff
 
     b, l, h, kh, d, block = 2, 8192, 32, 4, 128, 4
@@ -455,5 +460,44 @@ def test_two_stream_attention_compiles_at_the_sdar_cells_shape(one_chip):
     text = jax.jit(step).lower(
         sds((b, 2 * l, h, d), jnp.bfloat16), sds((b, 2 * l, kh, d), jnp.bfloat16), sds((b, 2 * l, kh, d), jnp.bfloat16), ids, ids
     ).compile().as_text()
-    assert blockdiff.untileable(l, d, compiled=True) is None and backward_form(l, d) == "fused"
+    assert blockdiff.untileable(l, d, block, compiled=True) is None and backward_form(l, d) == "fused"
     assert flash_calls(text) == {"flash_fwd": 2, "flash_bwd": 2, "flash_dq": 0, "flash_dkv": 0}
+    assert kernel_calls(text, "own_block_fwd", "own_block_bwd") == {"own_block_fwd": 1, "own_block_bwd": 1}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_the_band_kernels_compile_at_the_sdar_cells_shape(one_chip, dtype):
+    """``own_block_fwd`` and ``own_block_bwd`` (``ops/blockdiff.py``) alone at
+    the sdar-30b-a3b-chat cell's rows, 2 x 8,192 noised queries of 32 heads
+    over 4 of 128, block 4, at the tiles ``band_tiles`` gives that shape: the
+    windows of a tile are slices Mosaic has to take (128 queries, 144 keys from
+    a multiple of 128), the group's heads an axis whose key tile stays, dq
+    written over the flash call's; all inside Mosaic's default VMEM, which
+    the calls do not raise. In float32 a tile is twice the bytes."""
+    from maggy_tpu.ops import blockdiff
+
+    b, l, h, kh, d, block = 2, 8192, 32, 4, 128, 4
+    tq, sub, halo = band = blockdiff.band_tiles(l, block)
+    assert (tq, sub, halo) == (1024, 128, 8)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows, keys = sds((b * h, l, d), dtype), sds((b * kh, l // tq, tq + 2 * halo, d), dtype)
+    row, kid, qid = sds((b * h, 1, l), jnp.float32), sds((b, l // tq, tq + 2 * halo, 1), jnp.int32), sds((b, 1, l), jnp.int32)
+    kw = dict(band=band, group=h // kh, kv_heads=kh, interpret=False)
+
+    def forward(q, k, v, kid, qid, o_c, lse_c):
+        ops = [("q", q), ("kv", k), ("kv", v), ("kid", kid), ("qid", qid), ("q", o_c), ("row", lse_c)]
+        return blockdiff._own_call(blockdiff._own_fwd_kernel, "own_block_fwd", ops, [("q", rows), ("row", row)], **kw)
+
+    def backward(q, g, k, v, kid, qid, lse, delta, dq_c):
+        ops = [("q", q), ("q", g), ("kv", k), ("kv", v), ("kid", kid), ("qid", qid), ("row", lse), ("row", delta), ("q", dq_c)]
+        edges = jax.ShapeDtypeStruct(keys.shape, jnp.float32)
+        return blockdiff._own_call(
+            blockdiff._own_bwd_kernel, "own_block_bwd", ops, [("q", rows), ("kv", edges), ("kv", edges)], aliases={8: 0}, **kw
+        )
+
+    text = jax.jit(forward).lower(rows, keys, keys, kid, qid, rows, row).compile().as_text()
+    text += jax.jit(backward).lower(rows, rows, keys, keys, kid, qid, row, row, rows).compile().as_text()
+    assert kernel_calls(text, "own_block_fwd", "own_block_bwd") == {"own_block_fwd": 1, "own_block_bwd": 1}
