@@ -632,6 +632,7 @@ class ExpertShareBlock(nn.Module):
 class MoELayer(nn.Module):
     cfg: MoEConfig
     kind: str = "full_attention"
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, gates=None, select_bias=None):
@@ -641,7 +642,7 @@ class MoELayer(nn.Module):
         router aux loss — an ablated expert block must not keep pushing
         balancing gradients into its router. ``select_bias`` — the share
         form's [n_experts] selection bias of this layer."""
-        a = layer_operator(self.cfg, self.kind, x, positions, segment_ids)
+        a = layer_operator(self.cfg, self.kind, x, positions, segment_ids, self.stacked)
         x = x + (a if gates is None else a * gates[0].astype(a.dtype))
         xn = RMSNorm(self.cfg, name="mlp_norm")(x)
         if self.cfg.experts_held:
@@ -680,10 +681,11 @@ class _ScannedMoELayer(nn.Module):
 
     cfg: MoEConfig
     kind: str = "full_attention"
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, x, positions, per_layer, segment_ids=None):
-        return MoELayer(self.cfg, self.kind, name="layer")(
+        return MoELayer(self.cfg, self.kind, self.stacked, name="layer")(
             x, positions, segment_ids, **per_layer
         ), None
 
@@ -698,11 +700,12 @@ class _ScannedPeriod(nn.Module):
 
     cfg: MoEConfig
     kinds: tuple
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, x, positions, per_layer, segment_ids=None):
         for j, kind in enumerate(self.kinds):
-            x, _ = _remat(_ScannedMoELayer, self.cfg, True)(self.cfg, kind, name=f"layer_{j}")(
+            x, _ = _remat(_ScannedMoELayer, self.cfg, True)(self.cfg, kind, self.stacked, name=f"layer_{j}")(
                 x, positions, {k: v[j] for k, v in per_layer.items()}, segment_ids
             )
         return x, None
@@ -857,7 +860,7 @@ class MoEDecoder(nn.Module):
                 in_axes=(nn.broadcast, 0, nn.broadcast),
                 length=n_periods,
                 metadata_params={nn.PARTITION_NAME: None},
-            )(cfg, kind, name="layers")(x, positions, scanned, segment_ids)
+            )(cfg, kind, n_periods > 1, name="layers")(x, positions, scanned, segment_ids)
             for i, kind in enumerate(tail):
                 x, _ = _remat(_ScannedMoELayer, cfg, apart)(cfg, kind, name=f"tail_{i}")(
                     x, positions, per_layer(n_scanned + i), segment_ids

@@ -496,17 +496,29 @@ def _dense(features, logical_axes, cfg: DecoderConfig, name: str, dot_general=No
     )
 
 
-@jax.custom_vjp
-def _projection(x, w):
-    return jax.lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())))
+def _last_with_first(ndim, contracted):
+    """``dot_general``'s dimension numbers: the last ``contracted`` dimensions
+    of an lhs of ``ndim`` with the first of rhs."""
+    return ((tuple(range(ndim - contracted, ndim)), tuple(range(contracted))), ((), ()))
 
 
-def _projection_fwd(x, w):
-    return _projection(x, w), (x, w)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _projection(contracted, x, w):
+    return jax.lax.dot_general(x, w, _last_with_first(x.ndim, contracted))
 
 
-def _projection_bwd(res, g):
-    x, w = res  # w is [d, heads, width] or the matrix [d, features]: the reshapes below are that one's identity
+def _projection_fwd(contracted, x, w):
+    return _projection(contracted, x, w), (x, w)
+
+
+def _projection_bwd(contracted, res, g):
+    # The operands as matrices: x [.., d_in] and w [d_in, features], where w is
+    # [d, heads, width] (d_in = d), the matrix [d, features], or wo's [heads,
+    # width, d] (d_in = heads x width, contracted as one: x is [.., heads,
+    # width]). For a matrix the reshapes are the identity.
+    x_shape, w_shape = res[0].shape, res[1].shape
+    x = res[0].reshape(*x_shape[: len(x_shape) - contracted], -1)
+    w = res[1].reshape(x.shape[-1], -1)
     lead = tuple(range(x.ndim - 1))  # batch and sequence stay apart: each may be sharded
     g2 = g.reshape(*x.shape[:-1], -1)
     # The barrier holds the weight's gradient as the bfloat16 matrix [d,
@@ -519,10 +531,15 @@ def _projection_bwd(res, g):
     # [heads, S, width], through rotary embedding and head norm), writes a
     # convolution whose window is the heads, with a head-major result that
     # AdamW's update then reads parameter, mu and nu into through transposing
-    # copies. A matrix has no head-major layout: the product is a plain one, in
-    # the state's layout, and its consumer a memory-bound fusion of its own.
+    # copies (wq, wk and wv; for wo the heads are a spatial dimension of the
+    # operand, and the result is head-major the same way). A matrix has no
+    # head-major layout: the product is a plain one, in the state's layout, and
+    # its consumer a memory-bound fusion of its own. The price is one
+    # transposing pass of the head-major array a projection (the cotangent, for
+    # wo the attention's output) where XLA does not fuse it into what made it:
+    # 0.4-0.9 ms for bf16[16384,32,128] and bf16[8192,72,128] on a v5e.
     dw = jax.lax.optimization_barrier(jax.lax.dot_general(x, g2, ((lead, lead), ((), ()))))
-    dx = jax.lax.dot_general(g2, w.reshape(w.shape[0], -1), (((x.ndim - 1,), (1,)), ((), ())))
+    dx = jax.lax.dot_general(g2, w, (((x.ndim - 1,), (1,)), ((), ())))
     # The input's gradient is held the same way where the product narrows
     # ([tokens, features] by [features, d] with features > d: w_gate's and
     # w_up's in a feed-forward wider than the model, a wide wq's). What
@@ -536,56 +553,76 @@ def _projection_bwd(res, g):
     # backward) and runs as the product's epilogue, which a barrier would turn
     # into a pass over the wider array and one more live copy of it. (A shared
     # expert narrower than the model has the shapes the other way round; its
-    # products are small and either form runs them alike.)
+    # products are small and either form runs them alike. wo's product widens
+    # or keeps the width: its dx goes to the attention's backward as it is.)
+    # A product that keeps its width (evabyte's square wq, wk, wv, wo) is not
+    # held either: with the weight gradients out of their way those dx run at
+    # 80-83% of the peak with their consumers inside, and held they gained
+    # nothing (3.34 for 3.36 ms) while what they fed became passes of their own,
+    # 6.9 ms a step more (PERF.md section 6, PR 45).
     if g2.shape[-1] > x.shape[-1]:
         dx = jax.lax.optimization_barrier(dx)
-    return dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype)
+    return dx.reshape(x_shape).astype(x.dtype), dw.reshape(w_shape).astype(w.dtype)
 
 
 _projection.defvjp(_projection_fwd, _projection_bwd)
 
 
-def _checked_projection(name, kernel_dims, lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
+def _checked_projection(
+    name, kernel_dims, contracted, lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None
+):
     """``_projection`` as a ``dot_general`` for ``nn.DenseGeneral``: ``name``
-    takes a kernel of the dimensions ``kernel_dims``, contracted with the last
-    dimension of ``lhs`` at the default precision, and refuses anything else.
-    The forward is ``dot_general``'s, bit for bit; the backward makes the two
-    gradients as products of matrices, the weight's held apart from what
-    consumes it and the input's too where it is narrower than the cotangent
-    (``_projection_bwd``)."""
-    form = (((lhs.ndim - 1,), (0,)), ((), ()))
+    takes a kernel of the dimensions ``kernel_dims``, whose first
+    ``contracted`` are contracted with the last of ``lhs`` at the default
+    precision, and refuses anything else. The forward is ``dot_general``'s
+    on the operands as they are, bit for bit; the backward reads them as
+    matrices (two contracted dimensions as one: ``[.., heads, width]`` as
+    ``[.., heads x width]``, the kernel as ``[heads x width, d]``) and makes
+    the two gradients as products of matrices, the weight's held apart from
+    what consumes it and the input's too where it is narrower than the
+    cotangent (``_projection_bwd``), each handed back in its operand's
+    shape."""
     if (
-        rhs.ndim != len(kernel_dims) or dimension_numbers != form or precision is not None
-        or preferred_element_type is not None
+        rhs.ndim != len(kernel_dims) or dimension_numbers != _last_with_first(lhs.ndim, contracted)
+        or precision is not None or preferred_element_type is not None
     ):
         raise ValueError(
-            f"{name} contracts the last dimension of lhs with the first of a [{', '.join(kernel_dims)}] kernel at "
+            f"{name} contracts the last {contracted} of lhs with the first of a [{', '.join(kernel_dims)}] kernel at "
             f"the default precision; got {lhs.shape} by {rhs.shape}, {dimension_numbers}, {precision}, "
             f"{preferred_element_type}"
         )
-    return _projection(lhs, rhs)
+    return _projection(contracted, lhs, rhs)
 
 
-# The rule's two forms: a head-shaped projection (``wq``/``wk``/``wv``,
+# The rule's three forms: a projection to heads (``wq``/``wk``/``wv``,
 # ``_head_dense``), whose cotangent the backward flattens to ``[tokens, heads x
-# width]``, and ``MLPBlock``'s three matrices.
-head_dot_general = functools.partial(_checked_projection, "head_dot_general", ("d", "heads", "width"))
-matrix_dot_general = functools.partial(_checked_projection, "matrix_dot_general", ("d_in", "d_out"))
+# width]``; one from heads (``wo``), whose operand and kernel it flattens; and
+# ``MLPBlock``'s three matrices.
+head_dot_general = functools.partial(_checked_projection, "head_dot_general", ("d", "heads", "width"), 1)
+merge_dot_general = functools.partial(_checked_projection, "merge_dot_general", ("heads", "width", "d"), 2)
+matrix_dot_general = functools.partial(_checked_projection, "matrix_dot_general", ("d_in", "d_out"), 1)
 
 
-def _head_dense(heads, logical_axes, cfg: DecoderConfig, name: str):
-    """A head-shaped projection of ``Attention``. Where it is wider than the
-    model (``heads x width > d_model``: more query heads than the model's
-    width holds) its backward is ``head_dot_general``'s: there the rule's
-    plain products (the weight's gradient and, the product narrowing from
-    ``heads x width`` to ``d_model``, the input's, whose sum with ``wk``'s and
-    ``wv``'s is then a pass of its own) gain more than its transposing pass of
-    the cotangent costs (72, 48 and 32 heads of 128 from 3,072 and 2,048,
-    PERF.md section 6);
-    a square projection and the few key and value heads keep ``dot_general``'s
-    own transpose, which on a v5e they run no slower."""
-    wide = heads * cfg.head_dim > cfg.d_model
-    return _dense((heads, cfg.head_dim), logical_axes, cfg, name, head_dot_general if wide else None)
+def _head_dense(heads, logical_axes, cfg: DecoderConfig, name: str, stacked: bool = False):
+    """A projection of ``Attention`` to ``heads`` heads. Its backward is
+    ``head_dot_general``'s whatever its shape: the weight's gradient is a plain
+    product in the state's layout (95-97% of a v5e's peak at evabyte's square
+    widths, where the head-major fusion with AdamW inside ran at 33-58%) and
+    AdamW's update of the leaf a pass of its own. One exception, by structure
+    and not by a model's name: in a layer that is one of a ``stacked`` scan
+    (a scan over several layers, whose gradients land in the scan's stacked
+    buffer: there is no update to take out of the product) only a projection
+    wider than the model takes the rule, as since PR 38 (Keye +0.58% with
+    ``wq``, -0.30% with ``wk`` and ``wv`` too). Tried without the exception
+    (PERF.md section 6, PR 45): the Keye step, compiled at the memory limit,
+    then plans 14.71 GiB for 14.45 and computes the head's input gradient, a
+    product of 2.55 T, twice to fit, with ``wk``/``wv`` or with ``wo`` alone
+    under the rule: 2,255.5 -> 2,292.4 ms busy a step on the chip (+1.6%);
+    the Mistral step read 258.13 -> 257.98 (nothing to win); the compiler
+    puts the SDAR step 1.4% slower. The same holds for ``wo``
+    (``Attention``)."""
+    rule = not stacked or heads * cfg.head_dim > cfg.d_model
+    return _dense((heads, cfg.head_dim), logical_axes, cfg, name, head_dot_general if rule else None)
 
 
 class RMSNorm(nn.Module):
@@ -931,10 +968,20 @@ class Attention(nn.Module):
     the causal pairs inside documents) for the trainer's step metrics. Under a
     two-stream layout (``cfg.stream_block``) the row holds a clean and a
     noised stream, which the four projections, the head norms and the rotary
-    embedding read as one row of ``2L`` positions (``_stream_attention``)."""
+    embedding read as one row of ``2L`` positions (``_stream_attention``).
+    The four projections take ``_projection``'s backward rule, ``wq``, ``wk``
+    and ``wv`` to heads (``_head_dense``) and ``wo`` from them
+    (``merge_dot_general``): the forward, the parameters' names, shapes and
+    axes are ``nn.DenseGeneral``'s own. ``stacked`` says that the layer is
+    one of a scan over several, whose gradients land in the scan's stacked
+    buffer and not in a leaf AdamW reads: the code that builds the stack sets
+    it (``Decoder``, ``MoEDecoder``, ``_ScannedPeriod``; no configuration
+    does), and such a layer keeps ``dot_general``'s own transpose but for a
+    ``wq`` wider than the model (``_head_dense`` has the measured reasons)."""
 
     cfg: DecoderConfig
     kind: str = "full_attention"
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
@@ -942,9 +989,9 @@ class Attention(nn.Module):
         hd = cfg.head_dim
         n_heads, window, (theta, width, yarn, scale) = cfg.attention_form(self.kind)
         rotary = {} if (width, yarn, scale) == (hd, (), 1.0) else dict(width=width, yarn=yarn, scale=scale)
-        q = _head_dense(n_heads, ("embed", "heads", None), cfg, "wq")(x)
-        k = _head_dense(cfg.n_kv_heads, ("embed", "kv", None), cfg, "wk")(x)
-        v = _head_dense(cfg.n_kv_heads, ("embed", "kv", None), cfg, "wv")(x)
+        q = _head_dense(n_heads, ("embed", "heads", None), cfg, "wq", self.stacked)(x)
+        k = _head_dense(cfg.n_kv_heads, ("embed", "kv", None), cfg, "wk", self.stacked)(x)
+        v = _head_dense(cfg.n_kv_heads, ("embed", "kv", None), cfg, "wv", self.stacked)(x)
         if cfg.qk_norm:  # over the head's width, one scale for all heads
             q = RMSNorm(cfg, name="q_norm")(q)
             k = RMSNorm(cfg, name="k_norm")(k)
@@ -982,6 +1029,7 @@ class Attention(nn.Module):
             kernel_init=_partitioned(
                 nn.initializers.normal(stddev=0.02), ("heads", None, "embed"), cfg
             ),
+            dot_general=None if self.stacked else merge_dot_general,
             name="wo",
         )(out)
         return out
@@ -1460,13 +1508,15 @@ def attention_module(cfg: DecoderConfig):
     return LatentAttention if cfg.kv_lora_rank else Attention
 
 
-def layer_operator(cfg: DecoderConfig, kind: str, x, positions, segment_ids):
+def layer_operator(cfg: DecoderConfig, kind: str, x, positions, segment_ids, stacked: bool = False):
     """The operator a layer of ``kind`` takes (``LAYER_KINDS``) on the normed
     residual: ``attn`` under ``attn_norm`` or ``conv`` under ``conv_norm``.
     Called inside the layer's ``nn.compact`` method."""
     if kind == "conv":
         return ShortConv(cfg, name="conv")(RMSNorm(cfg, name="conv_norm")(x), positions, segment_ids)
     of_kind = {} if kind == "full_attention" else {"kind": kind}
+    if stacked and not cfg.kv_lora_rank:
+        of_kind["stacked"] = True
     return attention_module(cfg)(cfg, name="attn", **of_kind)(
         RMSNorm(cfg, name="attn_norm")(x), positions, segment_ids
     )
@@ -1514,6 +1564,7 @@ def _constrain_residual(x):
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     kind: str = "full_attention"
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, x, positions, gates=None, segment_ids=None):
@@ -1523,7 +1574,7 @@ class DecoderLayer(nn.Module):
         ``segment_ids`` — optional [B, S] packed-sequence ids."""
         # the stream's type: float32 under ``residual_f32`` (the sums then run in it), else the layers' own
         into = (lambda a: a.astype(jnp.float32)) if self.cfg.residual_f32 else (lambda a: a)
-        a = layer_operator(self.cfg, self.kind, x, positions, segment_ids)
+        a = layer_operator(self.cfg, self.kind, x, positions, segment_ids, self.stacked)
         x = into(x) + into(a if gates is None else a * gates[0].astype(a.dtype))
         m = MLPBlock(self.cfg, name="mlp")(RMSNorm(self.cfg, name="mlp_norm")(x))
         x = x + into(m if gates is None else m * gates[1].astype(m.dtype))
@@ -1533,10 +1584,11 @@ class DecoderLayer(nn.Module):
 class _ScannedLayer(nn.Module):
     cfg: DecoderConfig
     kind: str = "full_attention"
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
-        return DecoderLayer(self.cfg, self.kind, name="layer")(
+        return DecoderLayer(self.cfg, self.kind, self.stacked, name="layer")(
             x, positions, None, segment_ids
         ), None
 
@@ -1547,10 +1599,11 @@ class _ScannedGatedLayer(nn.Module):
 
     cfg: DecoderConfig
     kind: str = "full_attention"
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, x, positions, gates, segment_ids=None):
-        return DecoderLayer(self.cfg, self.kind, name="layer")(
+        return DecoderLayer(self.cfg, self.kind, self.stacked, name="layer")(
             x, positions, gates, segment_ids
         ), None
 
@@ -1610,7 +1663,7 @@ class Decoder(nn.Module):
                 ),
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: None},
-            )(cfg, kinds[0], name="layers")
+            )(cfg, kinds[0], cfg.n_layers > 1, name="layers")
             if gates is None:
                 x, _ = scanned(x, positions, segment_ids)
             else:

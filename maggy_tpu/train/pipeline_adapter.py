@@ -258,7 +258,7 @@ def decoder_pipeline_parts(
         in_axes=nn.broadcast,
         length=l_per,
         metadata_params={nn.PARTITION_NAME: None},
-    )(stage_cfg)
+    )(stage_cfg, stacked=l_per > 1)
 
     # raw microbatch layouts (decided per-trace by ndim/width): [mb, S]
     # plain tokens; [mb, S, 2] (tokens, positions); [mb, S, 3] (tokens,

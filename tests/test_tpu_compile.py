@@ -5,6 +5,7 @@ tiling) fails here and not on the chip. One file, and the topology described
 inside a fixture: only the worker that runs this file loads the TPU's library.
 """
 
+import collections
 import functools
 import os
 import re
@@ -249,25 +250,37 @@ def test_index_loss_kernel_compiles_at_the_keye_cells_shape(one_chip):
         assert len(calls) == 1 and "index_loss" in calls[0].split("=")[0]
 
 
-def test_laguna_width_projections_backward_is_plain_products(one_chip, monkeypatch):
-    """A recomputed ``Attention`` at laguna-s-2.1's sliding layer's widths (72
-    heads of 128 over 8 from 3,072, head norms, the gate) on one packed row of
-    8,192, scanned once as the cell's period is, its gradient and AdamW's
-    update of float32 parameters: no convolution under ``attn/wq`` writes a
-    kernel-shaped ``[3072, 72, 128]`` result (XLA's form of ``dot_general``'s
-    own transpose: a window over the heads and a head-major result); ``wq``'s
-    gradient is a product of matrices, and no ``copy`` transposes a float32
-    array of the kernel's shape (parameter, ``mu``, ``nu``) into a product's
-    layout or back. ``wk`` and ``wv`` (8 heads: narrower than the model) keep
-    ``dot_general``'s own transpose."""
+@pytest.mark.parametrize(
+    "s,d,heads,kv_heads,gate,scanned",
+    [(8192, 3072, 72, 8, True, True), (16384, 4096, 32, 32, False, False)],
+    ids=["laguna-sliding", "evabyte-square"],
+)
+def test_attention_projections_backward_is_plain_products(one_chip, monkeypatch, s, d, heads, kv_heads, gate, scanned):
+    """A recomputed ``Attention`` behind its ``RMSNorm``, its gradient and
+    AdamW's update of float32 parameters, at laguna-s-2.1's sliding layer's
+    widths (72 heads of 128 over 8 from 3,072, head norms, the gate; one
+    packed row of 8,192, scanned once as the cell's period is) and at
+    evabyte's (32 heads of 128 over 32 from 4,096: every projection square;
+    one row of 16,384, unrolled as the cell's layers are). No convolution
+    under ``attn/wq``, ``wk``, ``wv`` or ``wo`` in the backward writes a
+    kernel-shaped result (``[d, heads, 128]``, ``[heads, 128, d]``: XLA's form
+    of ``dot_general``'s own transpose, a window over the heads and a
+    head-major result): the four weight gradients are products of matrices,
+    ``[d, heads x 128]`` and ``wo``'s ``[heads x 128, d]``; no ``copy``
+    transposes a float32 array of a kernel's shape (parameter, ``mu``,
+    ``nu``) into a product's layout or back; and no fusion holds both such a
+    product and a float32 result of a kernel's shape, which is AdamW's update
+    written through the product's output tile. With ``DenseGeneral``'s own
+    ``dot_general`` (the positive control, at the square widths) all four
+    weight gradients of the unrolled layer do."""
     import optax
 
+    from maggy_tpu.models import transformer
     from maggy_tpu.models.transformer import Attention, RMSNorm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    s, d, heads = 8192, 3072, 72
     cfg = DecoderConfig(
-        d_model=d, n_heads=heads, n_kv_heads=8, head_width=128, qk_norm=True, attn_gate=True, max_seq_len=s,
+        d_model=d, n_heads=heads, n_kv_heads=kv_heads, head_width=128, qk_norm=gate, attn_gate=gate, max_seq_len=s,
         partition_params=False,
     )
 
@@ -276,31 +289,54 @@ def test_laguna_width_projections_backward_is_plain_products(one_chip, monkeypat
         def __call__(self, x, positions, segment_ids):
             return x + Attention(cfg, name="attn")(RMSNorm(cfg, name="attn_norm")(x), positions, segment_ids), None
 
-    period = nn.scan(
-        nn.remat(Layer, policy=REMAT_POLICIES["nothing"], prevent_cse=False),
-        variable_axes={"params": 0}, split_rngs={"params": True}, in_axes=nn.broadcast, length=1,
+    kept = nn.remat(Layer, policy=REMAT_POLICIES["nothing"], prevent_cse=False)
+    layer = kept() if not scanned else nn.scan(
+        kept, variable_axes={"params": 0}, split_rngs={"params": True}, in_axes=nn.broadcast, length=1
     )()
     tx = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
     x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip)
     ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
     described = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip))
-    params = described(jax.eval_shape(period.init, jax.random.key(0), x, ids, ids))
+    params = described(jax.eval_shape(layer.init, jax.random.key(0), x, ids, ids))
     opt_state = described(jax.eval_shape(tx.init, params))
+    kernels = "|".join(f"{a},{b},{c}" for a, b, c in ((d, heads, 128), (d, kv_heads, 128), (heads, 128, d)))
 
-    def step(params, opt_state, x, positions, segment_ids):
-        grads = jax.grad(lambda p: jnp.square(period.apply(p, x, positions, segment_ids)[0].astype(jnp.float32)).mean())(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state
+    def compiled():
+        def step(params, opt_state, x, positions, segment_ids):  # a function of its own each time: jit's cache is keyed by it
+            grads = jax.grad(lambda p: jnp.square(layer.apply(p, x, positions, segment_ids)[0].astype(jnp.float32)).mean())(params)
+            updates, new_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), new_state
 
-    text = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, x, ids, ids).compile().as_text()
+        return jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, x, ids, ids).compile().as_text()
+
+    backward = r'op_name="[^"]*transpose\(jvp[^"]*attn/(w[qkvo])/dot_general'
+
+    def updates_inside_products(text):
+        held = []
+        for computation in fused_computations(text):
+            product = re.search(r" convolution\([^\n]*" + backward, computation)
+            root = re.search(r"\n\s*ROOT [^\n]*", computation)
+            if product and root and re.search(rf"f32\[(?:1,)?(?:{kernels})\]", root.group(0).split(" = ")[1]):
+                held.append(product.group(1))
+        return sorted(held)
+
+    text = compiled()
     products = [
         re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])", line).group(1)
         for line in text.splitlines()
-        if " convolution(" in line and re.search(r'op_name="[^"]*transpose\(jvp[^"]*attn/w[qkv]/dot_general', line)
+        if " convolution(" in line and re.search(backward, line)
     ]
-    assert f"bf16[{d},{heads},128]" not in products and f"bf16[{d},{heads * 128}]" in products, products
-    assert products.count(f"bf16[{d},8,128]") == 2, products  # wk and wv
-    assert not re.findall(rf"= f32\[(?:1,)?{d},{heads},128\]\S* copy\(", text)
+    assert not re.findall(rf"bf16\[(?:{kernels})\]", " ".join(products)), products
+    matrices = collections.Counter(  # wq, wk and wv, wo; at the square widths the four are one shape
+        f"bf16[{a},{b}]" for a, b in ((d, heads * 128), (d, kv_heads * 128), (d, kv_heads * 128), (heads * 128, d))
+    )
+    assert {shape: products.count(shape) for shape in matrices} == dict(matrices), products
+    assert not re.findall(rf"= f32\[(?:1,)?(?:{kernels})\]\S* copy\(", text)
+    assert updates_inside_products(text) == []
+    if not scanned:
+        for form in ("head", "merge"):
+            monkeypatch.setattr(transformer, f"{form}_dot_general", None)
+        assert updates_inside_products(compiled()) == ["wk", "wo", "wq", "wv"]
 
 
 def evabyte_feed_forward(one_chip):
