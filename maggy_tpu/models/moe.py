@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -41,6 +42,10 @@ from maggy_tpu.models.transformer import (
     _ScannedLayer,
     layer_operator,
 )
+
+
+# the routed experts' gate activation by ``MoEConfig.expert_act``
+EXPERT_ACTS = {"silu": nn.silu, "relu": nn.relu}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +79,18 @@ class MoEConfig(DecoderConfig):
     # renormalised over themselves and times ``routed_scaling``; no bias).
     # Either takes a shared expert beside it (``n_shared_experts``)
     router: str = "sigmoid"
+    # what the share form's router reads: "mlp_norm", the layer's residual
+    # after attention under ``mlp_norm`` (what the experts read), or
+    # "layer_input", the residual as it enters the layer, before ``attn_norm``
+    # and un-normed: route, counting sort and slot order are then computed
+    # ahead of the attention operator (scope ``moe.preroute``) and the experts
+    # take them from there, so that the router's cotangent reaches the layer's
+    # input directly, a second path beside the residual's
+    route_from: str = "mlp_norm"
+    # the routed experts' gate activation (``EXPERT_ACTS``): "silu" (SwiGLU) or
+    # "relu" (ReGLU; the layer then sows ``hidden_zeros``, the hidden
+    # activations that are exactly zero: ``moe_hidden_zero_share``)
+    expert_act: str = "silu"
     # a chunk of the share layer's buffer as a fraction of the expected load
     # ``T * top_k * experts_held / n_experts`` (``chunk_rows``); 0: an eighth
     # of the buffer
@@ -160,6 +177,18 @@ class MoEConfig(DecoderConfig):
                 raise ValueError("router is 'sigmoid' or 'softmax'")
             if self.router == "softmax" and self.select_bias_std:
                 raise ValueError("the softmax router takes no selection bias")
+        if self.route_from not in ("mlp_norm", "layer_input") or self.expert_act not in EXPERT_ACTS:
+            raise ValueError(f"route_from is 'mlp_norm' or 'layer_input', expert_act one of {sorted(EXPERT_ACTS)}")
+        if self.route_from == "layer_input" and (not self.experts_held or self.decode):
+            raise ValueError(
+                "a router that reads the layer's input is the share form's (experts_held), training and "
+                "scoring only: a decode step that routes ahead of attention is not written"
+            )
+        if self.expert_act != "silu" and (not self.experts_held or self.n_shared_experts):
+            raise ValueError(
+                "expert_act is the share form's routed experts': the capacity form and the shared "
+                "expert (MLPBlock) are SwiGLU"
+            )
         if self.chunk_of_load < 0 or (self.chunk_of_load and not self.experts_held):
             raise ValueError("chunk_of_load is a fraction of the share form's expected load")
         if not 0 <= self.n_dense_layers < self.n_layers:
@@ -409,26 +438,35 @@ def _chunk_sizes(load, first_row, rows: int):
     return jnp.clip(jnp.minimum(ends, first_row + rows) - jnp.maximum(ends - load, first_row), 0, rows)
 
 
-@jax.jit
-def _chunk_experts(a, sizes, w_gate, w_up, w_down):
-    """The three grouped products over one chunk's rows ``a`` [rows, d].
+@functools.partial(jax.jit, static_argnames="act")
+def _chunk_experts(a, sizes, w_gate, w_up, w_down, act="silu"):
+    """The three grouped products over one chunk's rows ``a`` [rows, d] with
+    the gate's activation ``act`` (``EXPERT_ACTS``): ``(the chunk's result,
+    zeros)``, where ``zeros`` under "relu" counts the hidden activations
+    ``relu(a W_gate)`` that are exactly zero in the rows that hold a slot
+    (the first ``sizes.sum()``), in the pass that multiplies the two halves,
+    and is None otherwise ("silu" is the program it was).
     Under ``jax.jit`` for its cache alone: every expert layer of a model and
     every pass calls it at the same shapes, and tracing the kernels anew each
     time took longer than loading the compiled step."""
     with jax.named_scope("moe.experts"):
-        hidden = nn.silu(grouped_dot(a, w_gate, sizes)) * grouped_dot(a, w_up, sizes)
-        return grouped_dot(hidden, w_down, sizes)
+        gate = EXPERT_ACTS[act](grouped_dot(a, w_gate, sizes))
+        y = grouped_dot(gate * grouped_dot(a, w_up, sizes), w_down, sizes)
+        if act != "relu":
+            return y, None
+        live = jnp.arange(a.shape[0], dtype=jnp.int32) < sizes.sum()
+        return y, jnp.sum((gate == 0) & live[:, None], dtype=jnp.int32)
 
 
-@jax.jit
-def _chunk_experts_back(a, sizes, w_gate, w_up, w_down, g_rows, scale):
+@functools.partial(jax.jit, static_argnames="act")
+def _chunk_experts_back(a, sizes, w_gate, w_up, w_down, g_rows, scale, act="silu"):
     """One chunk again and backward, from the rows ``g_rows`` of the result's
     cotangent that belong to its slots and the slots' weights ``scale`` (zero
     where the slot's expert is not held): the cotangent of ``a``, each slot's
     dot product of its result with ``g_rows`` (the weights' gradient), and
     the three expert weights' gradients."""
     y, vjp = jax.vjp(
-        lambda a, w_gate, w_up, w_down: _chunk_experts(a, sizes, w_gate, w_up, w_down),
+        lambda a, w_gate, w_up, w_down: _chunk_experts(a, sizes, w_gate, w_up, w_down, act)[0],
         a, w_gate, w_up, w_down,
     )
     with jax.named_scope("moe.combine"):
@@ -437,8 +475,8 @@ def _chunk_experts_back(a, sizes, w_gate, w_up, w_down, g_rows, scale):
     return (*vjp(d_y), dot)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(rows: int, tokens, weights, order, inv, held, load, w_gate, w_up, w_down):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(rows: int, act: str, tokens, weights, order, inv, held, load, w_gate, w_up, w_down):
     """The routed part of the share layer: ``out[t] = sum over the held
     choices j of weights[t, j] * expert(tokens[t])``, the buffer (slot order:
     ``order`` [chunks * rows] names the slot of each row, ``inv`` [T, k] the
@@ -447,34 +485,42 @@ def _routed(rows: int, tokens, weights, order, inv, held, load, w_gate, w_up, w_
     read in the step, so one traced and compiled body serves every load. A
     chunk gathers its rows of ``tokens``, runs the three grouped products and
     writes its part of the buffer; then a token's choices are gathered back
-    and summed in float32. Its own VJP (a loop of unknown length has no
-    transpose): the forward pass keeps the arguments only; the backward pass
-    is a second loop whose chunk runs forward again and then backward,
-    the expert weights' gradients summed over the chunks in float32, and
-    both directions gather by token where a scatter-add would stand."""
+    and summed in float32. Returns ``(out, zeros)``: ``zeros`` is the chunks'
+    count of ``_chunk_experts`` summed (None unless ``act`` is "relu"), taken
+    in these forward chunks and not in the backward pass's. Its own VJP (a
+    loop of unknown length has no transpose): the forward pass keeps the
+    arguments only; the backward pass is a second loop whose chunk runs
+    forward again and then backward, the expert weights' gradients summed
+    over the chunks in float32, and both directions gather by token where a
+    scatter-add would stand."""
     k = inv.shape[1]
 
-    def chunk(i, y):
+    def chunk(i, carry):
+        y, zeros = carry
         first = i * rows
         with jax.named_scope("moe.dispatch"):
             a = tokens[jax.lax.dynamic_slice(order, (first,), (rows,)) // k]
-        y_c = _chunk_experts(a, _chunk_sizes(load, first, rows), w_gate, w_up, w_down)
-        return jax.lax.dynamic_update_slice(y, y_c, (first, 0))
+        y_c, zeros_c = _chunk_experts(a, _chunk_sizes(load, first, rows), w_gate, w_up, w_down, act)
+        return jax.lax.dynamic_update_slice(y, y_c, (first, 0)), None if zeros is None else zeros + zeros_c
 
-    y = jax.lax.fori_loop(
+    y, zeros = jax.lax.fori_loop(
         0, (load.sum() + rows - 1) // rows, chunk,
-        jnp.zeros((order.shape[0], tokens.shape[1]), tokens.dtype),
+        (
+            jnp.zeros((order.shape[0], tokens.shape[1]), tokens.dtype),
+            jnp.zeros((), jnp.int32) if act == "relu" else None,
+        ),
     )
     with jax.named_scope("moe.combine"):
-        return _by_token(y, inv, held, weights).astype(tokens.dtype)
+        return _by_token(y, inv, held, weights).astype(tokens.dtype), zeros
 
 
-def _routed_fwd(rows, *args):
-    return _routed(rows, *args), args
+def _routed_fwd(rows, act, *args):
+    return _routed(rows, act, *args), args
 
 
-def _routed_bwd(rows, res, g):
+def _routed_bwd(rows, act, res, g):
     tokens, weights, order, inv, held, load, w_gate, w_up, w_down = res
+    g, _ = g  # the count has no cotangent
     k = inv.shape[1]
     scale = jnp.where(held, weights, 0).reshape(-1)
 
@@ -487,7 +533,7 @@ def _routed_bwd(rows, res, g):
         with jax.named_scope("moe.combine"):
             g_rows, scale_rows = g[slots // k], scale[slots]
         d_a_c, *d_experts_c, dot_c = _chunk_experts_back(
-            a, _chunk_sizes(load, first, rows), w_gate, w_up, w_down, g_rows, scale_rows
+            a, _chunk_sizes(load, first, rows), w_gate, w_up, w_down, g_rows, scale_rows, act
         )
         return (
             jax.lax.dynamic_update_slice(d_a, d_a_c, (first, 0)),
@@ -541,6 +587,20 @@ def softmax_route(logits, top_k: int, scaling: float = 1.0):
     return sel, weights if scaling == 1.0 else scaling * weights
 
 
+class Route(NamedTuple):
+    """What ``ExpertShareBlock.route`` hands its experts: each token's
+    ``weights`` [T, k] in float32, and the slots' order by held expert
+    (``_routed``'s ``order``, ``inv``, ``held``, ``load``) with the rows of
+    the buffer that the chunks which run will visit."""
+
+    weights: jax.Array
+    order: jax.Array
+    inv: jax.Array
+    held: jax.Array
+    load: jax.Array
+    visited: jax.Array
+
+
 class ExpertShareBlock(nn.Module):
     """One chip's share of a sigmoid-routed expert layer, dropless
     (``router="softmax"``: of a softmax-routed one, ``softmax_route``, with
@@ -553,34 +613,56 @@ class ExpertShareBlock(nn.Module):
     is ``shared(x)`` (where there is a shared expert) ``+ sum over chosen experts held here of w_e * expert_e(x)``:
     what the absent experts would add is another chip's part. The (token,
     choice) slots are put in order of held expert by a counting sort (those
-    on absent experts last) and counted, and the routed part (``_routed``:
+    on absent experts last) and counted (:meth:`route`), and the routed part (``_routed``:
     gather into slot order, three grouped products, the weighted sum back by
     token) works through the buffer chunk by chunk (``chunk_rows``), as many
     chunks as the counted slots fill, in a loop whose length is read in the
     step: the rows of width ``d_model`` that move follow the load, not
     ``T * top_k``. The buffer has a row for every slot, so none on a held
     expert is ever cut: ``slots_dropped`` counts what the chunks that ran
-    left out, and reads 0. Sows ``expert_load`` ([experts_held] slots an
+    left out, and reads 0. The router reads what the experts read, or, given
+    ``route`` from outside, whatever :meth:`route` was called on
+    (``MoELayer`` under ``route_from="layer_input"``: the layer's input,
+    before attention). Sows ``expert_load`` ([experts_held] slots an
     expert), ``slots_dropped`` and ``rows_visited`` ([2]: the rows of the
-    chunks that ran, of ``T * top_k``) for the trainer's step metrics."""
+    chunks that ran, of ``T * top_k``) for the trainer's step metrics, and
+    under ``expert_act="relu"`` ``hidden_zeros`` ([2]: the hidden activations
+    ``relu(x W_gate)`` of the slots on held experts that are exactly zero, of
+    all of them: the sparsity a down product could skip)."""
 
     cfg: MoEConfig
 
-    @nn.compact
-    def __call__(self, x, select_bias=None):
+    def setup(self):
         cfg = self.cfg
-        b, s, d = x.shape
-        t, k, e, held, f = b * s, cfg.top_k, cfg.n_experts, cfg.experts_held, cfg.moe_d_ff
-        lo = cfg.expert_offset * held
-        tokens = x.reshape(t, d)
+        d, held, f = cfg.d_model, cfg.experts_held, cfg.moe_d_ff
+        self.router = nn.DenseGeneral(
+            features=cfg.n_experts, use_bias=False, dtype=jnp.float32,
+            param_dtype=cfg.param_dtype, precision=jax.lax.Precision.HIGHEST,
+            kernel_init=_partitioned(nn.initializers.normal(0.02), ("embed", None), cfg),
+        )
 
+        def experts(name, axes, shape):
+            return self.param(
+                name, _partitioned(nn.initializers.normal(0.02), axes, cfg), shape, cfg.param_dtype,
+            )
+
+        self.w_gate = experts("w_gate", ("expert", "embed", "mlp"), (held, d, f))
+        self.w_up = experts("w_up", ("expert", "embed", "mlp"), (held, d, f))
+        self.w_down = experts("w_down", ("expert", "mlp", "embed"), (held, f, d))
+        if cfg.n_shared_experts:
+            self.shared = MLPBlock(dataclasses.replace(cfg, d_ff=f * cfg.n_shared_experts))
+
+    def _rows(self, t: int) -> int:
+        cfg = self.cfg
+        return chunk_rows(t * cfg.top_k, cfg.experts_held, cfg.n_experts, cfg.chunk_of_load)
+
+    def route(self, tokens, select_bias=None) -> Route:
+        """Router, selection, weights and the slots' counting sort from
+        ``tokens`` [T, d_model] (scopes ``moe.route`` and ``moe.dispatch``)."""
+        cfg = self.cfg
+        t, k, held = tokens.shape[0], cfg.top_k, cfg.experts_held
         with jax.named_scope("moe.route"):
-            logits = nn.DenseGeneral(
-                features=e, use_bias=False, dtype=jnp.float32,
-                param_dtype=cfg.param_dtype, precision=jax.lax.Precision.HIGHEST,
-                kernel_init=_partitioned(nn.initializers.normal(0.02), ("embed", None), cfg),
-                name="router",
-            )(tokens.astype(jnp.float32))
+            logits = self.router(tokens.astype(jnp.float32))
             if cfg.router == "softmax":
                 sel, weights = softmax_route(logits, k, cfg.routed_scaling)
             else:
@@ -589,43 +671,43 @@ class ExpertShareBlock(nn.Module):
                 )  # [t, k]
 
         with jax.named_scope("moe.dispatch"):
-            local = sel - lo
+            local = sel - cfg.expert_offset * held
             is_held = (local >= 0) & (local < held)  # [t, k]
             key = jnp.where(is_held, local, held).reshape(t * k)
             inv, load = counting_sort(key, held + 1)
             load = load[:held]
             # a row of the buffer for every slot, in chunks: the last chunk may overhang
-            rows = chunk_rows(t * k, held, e, cfg.chunk_of_load)
+            rows = self._rows(t)
             chunks = -(-t * k // rows)
             order = jnp.zeros(chunks * rows, jnp.int32).at[inv].set(
                 jnp.arange(t * k, dtype=jnp.int32), unique_indices=True
             )
             visited = jnp.minimum((load.sum() + rows - 1) // rows * rows, t * k)
+        return Route(weights, order, inv.reshape(t, k), is_held, load, visited)
 
-        def experts(name, axes, shape):
-            w = self.param(
-                name, _partitioned(nn.initializers.normal(0.02), axes, cfg),
-                shape, cfg.param_dtype,
-            )
-            return jnp.asarray(w, cfg.dtype)
+    def __call__(self, x, select_bias=None, route: Optional[Route] = None):
+        cfg = self.cfg
+        b, s, d = x.shape
+        t, k = b * s, cfg.top_k
+        tokens = x.reshape(t, d)
+        if route is None:
+            route = self.route(tokens, select_bias)
+        load, visited = route.load, route.visited
 
-        w_gate = experts("w_gate", ("expert", "embed", "mlp"), (held, d, f))
-        w_up = experts("w_up", ("expert", "embed", "mlp"), (held, d, f))
-        w_down = experts("w_down", ("expert", "mlp", "embed"), (held, f, d))
-        y = _routed(
-            rows, tokens, weights.astype(tokens.dtype), order, inv.reshape(t, k),
-            is_held, load, w_gate, w_up, w_down,
+        w_gate, w_up, w_down = (jnp.asarray(w, cfg.dtype) for w in (self.w_gate, self.w_up, self.w_down))
+        y, zeros = _routed(
+            self._rows(t), cfg.expert_act, tokens, route.weights.astype(tokens.dtype), route.order, route.inv,
+            route.held, load, w_gate, w_up, w_down,
         )
 
         if cfg.n_shared_experts:
             with jax.named_scope("moe.shared"):
-                y = y + MLPBlock(
-                    dataclasses.replace(cfg, d_ff=f * cfg.n_shared_experts),
-                    name="shared",
-                )(tokens)
+                y = y + self.shared(tokens)
         self.sow("intermediates", "expert_load", load)
         self.sow("intermediates", "slots_dropped", jnp.maximum(load.sum() - visited, 0))
         self.sow("intermediates", "rows_visited", jnp.stack([visited, jnp.int32(t * k)]))
+        if zeros is not None:
+            self.sow("intermediates", "hidden_zeros", jnp.stack([zeros, load.sum() * cfg.moe_d_ff]))
         return y.reshape(b, s, d)
 
 
@@ -641,13 +723,22 @@ class MoELayer(nn.Module):
         zero grads, unchanged param tree). The gate also scales the sown
         router aux loss — an ablated expert block must not keep pushing
         balancing gradients into its router. ``select_bias`` — the share
-        form's [n_experts] selection bias of this layer."""
-        a = layer_operator(self.cfg, self.kind, x, positions, segment_ids, self.stacked)
+        form's [n_experts] selection bias of this layer. Under
+        ``route_from="layer_input"`` the share form's route is computed here,
+        from ``x`` as it arrives and ahead of the operator (scope
+        ``moe.preroute`` around the router's own scopes)."""
+        cfg = self.cfg
+        share = ExpertShareBlock(cfg, name="moe") if cfg.experts_held else None
+        route = None
+        if cfg.route_from == "layer_input":
+            with jax.named_scope("moe.preroute"):
+                route = share.route(x.reshape(-1, x.shape[-1]), select_bias)
+        a = layer_operator(cfg, self.kind, x, positions, segment_ids, self.stacked)
         x = x + (a if gates is None else a * gates[0].astype(a.dtype))
-        xn = RMSNorm(self.cfg, name="mlp_norm")(x)
-        if self.cfg.experts_held:
-            return x + ExpertShareBlock(self.cfg, name="moe")(xn, select_bias)
-        m = MoEBlock(self.cfg, name="moe")(
+        xn = RMSNorm(cfg, name="mlp_norm")(x)
+        if share is not None:
+            return x + share(xn, select_bias, route)
+        m = MoEBlock(cfg, name="moe")(
             xn, aux_gate=None if gates is None else gates[1]
         )
         x = x + (m if gates is None else m * gates[1].astype(m.dtype))

@@ -93,6 +93,11 @@ def _rows_visited(rows):
     return {"moe_rows_visited_share": visited / of}
 
 
+def _hidden_zeros(zeros):
+    per_layer = _stacked(zeros, 2).astype(jnp.float32)
+    return {"moe_hidden_zero_share": jnp.mean(per_layer[:, 0] / jnp.maximum(per_layer[:, 1], 1.0))}
+
+
 def _taps_masked(taps):
     masked, of = _stacked(taps, 2).sum(0)
     return {"conv_taps_masked_share": masked / of}
@@ -150,6 +155,10 @@ COUNTERS: Tuple[Counter, ...] = (
     # ``ExpertShareBlock``, [visited, of] a layer: the rows of the layers' buffers that the chunks
     # that ran visited over the rows they hold (1.0: every layer worked through its whole buffer)
     Counter(("rows_visited",), _rows_visited, {"moe_rows_visited_share": "moe.rows_visited_share"}),
+    # ``ExpertShareBlock`` under ``expert_act="relu"``, [zeros, of] a layer: of the hidden
+    # activations ``relu(x W_gate)`` of the slots on held experts those that are exactly zero (the
+    # forward chunks' count), a mean over the layers: what a down product that skips zeros would save
+    Counter(("hidden_zeros",), _hidden_zeros, {"moe_hidden_zero_share": "moe.hidden_zero_share"}),
     # ``ShortConv``, [masked, of] a layer: the taps zeroed at row and document starts over all
     # taps, which says that the batch's packing reached the operator
     Counter(("taps_masked",), _taps_masked, {"conv_taps_masked_share": "conv.taps_masked_share"}),

@@ -220,6 +220,12 @@ class DecoderConfig:
     # the attention factor (``yarn_inv_freq``); (): the default form
     rope_share: float = 1.0
     rope_yarn: tuple = ()
+    # False: a "full_attention" layer has no positional embedding at all (its
+    # queries and keys go to the kernel as the projections give them:
+    # ``Attention`` skips :func:`rope`, it does not multiply by a table of
+    # ones), while the "sliding_attention" layers keep theirs; a model of such
+    # global layers beside windowed ones. :class:`Attention` only
+    full_rope: bool = True
     # a sigmoid gate a head and token on the heads' outputs before ``wo``, from
     # a bias-free projection of the layer's normed input (:class:`Attention`)
     attn_gate: bool = False
@@ -270,13 +276,18 @@ class DecoderConfig:
     def attention_form(self, kind: str) -> tuple:
         """``(query heads, window, rotary)`` of an attention layer of ``kind``;
         ``rotary`` = (base, rotated width, YaRN's constants or (), scale of cos
-        and sin) as :func:`rope` takes them. Without the fields above every
-        layer reads ``(n_heads, 0, (rope_theta, head_dim, (), 1.0))``."""
+        and sin) as :func:`rope` takes them; a rotated width of 0 says that the
+        layer has no rotary embedding (``full_rope`` False: ``rope`` is not
+        called, where its own ``width=0`` would rotate the whole head).
+        Without the fields above every layer reads
+        ``(n_heads, 0, (rope_theta, head_dim, (), 1.0))``."""
         if kind == "sliding_attention":
             return (
                 self.sliding_heads or self.n_heads, self.sliding_window,
                 (self.sliding_rope_theta or self.rope_theta, self.head_dim, (), 1.0),
             )
+        if not self.full_rope and kind == "full_attention":
+            return self.n_heads, 0, (self.rope_theta, 0, (), 1.0)
         yarn = tuple(self.rope_yarn)
         return (
             self.n_heads, 0,
@@ -383,6 +394,12 @@ class DecoderConfig:
             raise ValueError("rope_share of the head's width is an even number of dimensions")
         if (self.rope_share != 1.0 or self.rope_yarn) and self.kv_lora_rank:
             raise ValueError("latent attention rotates its own narrow part: no rope_share, no rope_yarn")
+        if not self.full_rope and (self.rope_share != 1.0 or self.rope_yarn or self.kv_lora_rank or self.sparse_topk):
+            raise ValueError(
+                "full_rope=False is Attention's: a full layer with no rotary embedding has no rope_share "
+                "and no rope_yarn, and neither the latent form nor the indexer, which rotate parts of "
+                "their own, is written without positions"
+            )
         if self.remat_policy not in REMAT_POLICIES:
             raise ValueError(
                 f"remat_policy must be one of {sorted(REMAT_POLICIES)}"
@@ -995,8 +1012,9 @@ class Attention(nn.Module):
         if cfg.qk_norm:  # over the head's width, one scale for all heads
             q = RMSNorm(cfg, name="q_norm")(q)
             k = RMSNorm(cfg, name="k_norm")(k)
-        q = rope(q, positions, theta, **rotary)
-        k = rope(k, positions, theta, **rotary)
+        if width:  # 0: a layer with no positional embedding (``full_rope`` False)
+            q = rope(q, positions, theta, **rotary)
+            k = rope(k, positions, theta, **rotary)
         if self.kind == "eva_attention":
             out = self._summary_attention(q, k, v, positions, segment_ids)
         elif cfg.decode:

@@ -60,6 +60,9 @@ GAUGES = frozenset(
         # rows of the layers' buffers the chunks that ran visited over the rows
         # the buffers hold (T * top_k a layer); 1.0 = the mechanism does nothing
         "moe.rows_visited_share",
+        # ReLU-gated experts (MoEConfig.expert_act "relu"): of the hidden activations
+        # relu(x W_gate) of the slots on held experts, the share exactly zero, a mean over layers
+        "moe.hidden_zero_share",
         # a model with short-convolution layers (models/transformer.py
         # ShortConv): taps zeroed at row and document starts over all taps of
         # the step's conv layers; above 0 the batch's packing reached the operator
@@ -295,6 +298,7 @@ SCOPES = (
     "moe.experts",  # the experts' feed-forward matmuls
     "moe.combine",  # weighted gather back to tokens
     "moe.shared",  # the shared expert beside the routed ones (ExpertShareBlock)
+    "moe.preroute",  # around moe.route and moe.dispatch where the router reads the layer's input, ahead of attention (MoELayer, route_from "layer_input")
     "mla.q",  # latent attention: query down-projection, norm, up-projection
     "mla.kv",  # latent attention: key-value down-projection, norm, up-projection
     "mla.rope",  # latent attention: rope on the narrow part, heads put together
@@ -427,6 +431,7 @@ GAUGE_UNITS = {
     "moe.slots_dropped": "count",
     "moe.load_max_over_mean": "ratio",
     "moe.rows_visited_share": "ratio",
+    "moe.hidden_zero_share": "ratio",
     "conv.taps_masked_share": "ratio",
     "sparse.selected_share": "ratio",
     "sparse.rows_off_k": "count",

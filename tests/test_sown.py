@@ -36,6 +36,7 @@ REPORTS = {
     "lfm2-24b-a2b": EXPERT | {"conv_taps_masked_share"},
     "keye-vl-2.0-30b-a3b": EXPERT | {"index_loss", "sparse_selected_share", "sparse_rows_off_k"},
     "laguna-s-2.1": EXPERT | {"window_pairs_share"},
+    "smallthinker-21ba3b-instruct": EXPERT | {"window_pairs_share", "moe_hidden_zero_share"},
     "evabyte": {"eva_remote_share", "eva_chunks_cut_share"},
 }
 FURTHER_HEADS = {"glm-4.7-flash", "evabyte"}  # the architectures that sow ``mtp_logits``

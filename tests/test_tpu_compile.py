@@ -537,3 +537,52 @@ def test_the_band_kernels_compile_at_the_sdar_cells_shape(one_chip, dtype):
     text = jax.jit(forward).lower(rows, keys, keys, kid, qid, rows, row).compile().as_text()
     text += jax.jit(backward).lower(rows, rows, keys, keys, kid, qid, row, row, rows).compile().as_text()
     assert kernel_calls(text, "own_block_fwd", "own_block_bwd") == {"own_block_fwd": 1, "own_block_bwd": 1}
+
+
+@pytest.mark.parametrize("window", [4096, 0], ids=["windowed", "global"])
+def test_flash_kernels_compile_at_the_smallthinker_cells_shape(one_chip, window):
+    """``flash_fwd`` and the backward at the smallthinker-21ba3b-instruct
+    cell's row: one document of 16,384, 28 query heads over 4 of 128 (seven a
+    key-value head), bfloat16, under the window of 4,096 (a band eight
+    512-tiles wide) and with none (a global layer, whose queries and keys
+    come unrotated: the kernel does not know)."""
+    s, h, kh, d = 16384, 28, 4, 128
+    q = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, s, kh, d), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+
+    def step(q, k, v, segment_ids):
+        return jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, segment_ids=segment_ids, window=window, interpret=False)
+            .astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    calls = flash_calls(jax.jit(step).lower(q, kv, kv, ids).compile().as_text())
+    fused = backward_form(s, d) == "fused"
+    assert calls == {"flash_fwd": 1, "flash_bwd": int(fused), "flash_dq": int(not fused), "flash_dkv": int(not fused)}
+
+
+def test_relu_gated_chunk_compiles_with_its_count_at_the_smallthinker_widths(one_chip, monkeypatch):
+    """One chunk of the share layer with ReLU-gated experts
+    (``_chunk_experts(..., "relu")`` and its backward) at the
+    smallthinker-21ba3b-instruct cell's widths: 18,432 rows (three quarters of
+    the expected load), 16 held experts of 2,560 x 768, bfloat16: the grouped
+    products stay the Pallas kernels, three forward, and the count of zero
+    hidden activations comes out as one int32 beside them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, held, d, f = 18432, 16, 2560, 768
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(a, sizes, w_gate, w_up, w_down, g_rows, scale):
+        y, zeros = moe._chunk_experts(a, sizes, w_gate, w_up, w_down, "relu")
+        return y, zeros, moe._chunk_experts_back(a, sizes, w_gate, w_up, w_down, g_rows, scale, "relu")
+
+    args = (sds((rows, d)), sds((held,), jnp.int32), sds((held, d, f)), sds((held, d, f)), sds((held, f, d)),
+            sds((rows, d)), sds((rows,)))
+    lowered = jax.jit(step).lower(*args)
+    assert lowered.out_info[1].shape == () and lowered.out_info[1].dtype == jnp.int32
+    calls = kernel_calls(lowered.compile().as_text(), "gmm", "tgmm")  # "gmm" counts the "tgmm" lines too
+    assert calls["tgmm"] == 3 and calls["gmm"] - calls["tgmm"] >= 6
