@@ -23,16 +23,18 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from maggy_tpu.models import head
 from maggy_tpu.models.transformer import (
     REMAT_POLICIES,
     DecoderConfig,
+    HeadKernel,
     MLPBlock,
     RMSNorm,
     _dense,
@@ -143,6 +145,15 @@ class MoEConfig(DecoderConfig):
         (``models.sown.step_inputs``): the step's noise key under this
         objective, nothing else."""
         return {"noise_key": self.noise_key(step)} if self.block_diffusion else {}
+
+    def head_aheads(self) -> Tuple[int, ...]:
+        """How far ahead of its position each head predicts: the main head 1
+        and the ``mtp`` pass 2, or 0 where the objective is the model's own
+        (``block_diffusion``: a position predicts its own token and the model
+        weighs the targets)."""
+        if self.block_diffusion:
+            return (0,)
+        return (1, 2) if self.mtp_depth else (1,)
 
     def __post_init__(self):
         super().__post_init__()
@@ -872,11 +883,16 @@ class MoEDecoder(nn.Module):
     cfg: MoEConfig
 
     @nn.compact
-    def __call__(self, tokens, positions=None, segment_ids=None, noise_key=None):
+    def __call__(self, tokens, positions=None, segment_ids=None, noise_key=None, targets=None):
         """``noise_key``: the step's noise key under ``block_diffusion``
         (``MoEConfig.step_inputs``; None: step 0's). The layers then run on
         rows of ``2L`` positions, the clean stream and the noised one, and
-        the logits ``[B, L, vocab]`` are the noised stream's."""
+        the logits ``[B, L, vocab]`` are the noised stream's. With ``targets``
+        (``models/head.py`` ``Targets``, a row of it for each of
+        ``cfg.head_aheads()``) the head runs inside the loss, in blocks of the
+        sequence, and the model returns the losses ``[heads]`` float32 in the
+        place of logits: the main head's, then the ``mtp`` pass's; under
+        ``block_diffusion`` its own weights times the row's."""
         cfg = self.cfg
         length = tokens.shape[1]
         if positions is None:
@@ -965,19 +981,32 @@ class MoEDecoder(nn.Module):
         if cfg.block_diffusion:  # the head reads the noised stream; the clean one fed the keys and values below
             x = x[:, length:]
         x_norm = RMSNorm(cfg, name="final_norm")(x)
-        if cfg.tie_embeddings:
-            if cfg.mtp_depth:
-                raise ValueError("the multi-token-prediction module takes an untied head")
-            with jax.named_scope("lm_head"):  # the scope the untied head's module gives
-                return jnp.einsum("bsd,vd->bsv", x_norm, embed).astype(jnp.float32)
-        head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, "lm_head")
-        logits = head(x_norm)
-        if cfg.mtp_depth:
+        if cfg.tie_embeddings and cfg.mtp_depth:
+            raise ValueError("the multi-token-prediction module takes an untied head")
+
+        def mtp_hidden():
             # token i+1's embedding beside position i; the row's last position
             # wraps and is never a target's predictor (its target is masked)
-            mtp = MTPModule(cfg, name="mtp")(
+            return MTPModule(cfg, name="mtp")(
                 x, embed[jnp.roll(tokens, -1, axis=1)], positions, segment_ids,
                 per_layer(n_moe),
             )
-            self.sow("intermediates", "mtp_logits", head(mtp).astype(jnp.float32))
+
+        if targets is not None:
+            kernel = embed if cfg.tie_embeddings else HeadKernel(cfg, cfg.vocab_size, name="lm_head")()
+            own = weights if cfg.block_diffusion else 1.0
+            return jnp.stack([
+                head.loss(
+                    h, kernel, targets.ids[i], targets.weights[i] * own, rows=targets.rows, dtype=cfg.dtype,
+                    tied=cfg.tie_embeddings,
+                )
+                for i, h in enumerate((x_norm, mtp_hidden()) if cfg.mtp_depth else (x_norm,))
+            ])
+        if cfg.tie_embeddings:
+            with jax.named_scope("lm_head"):  # the scope the untied head's module gives
+                return jnp.einsum("bsd,vd->bsv", x_norm, embed).astype(jnp.float32)
+        lm_head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, "lm_head")
+        logits = lm_head(x_norm)
+        if cfg.mtp_depth:
+            self.sow("intermediates", "mtp_logits", lm_head(mtp_hidden()).astype(jnp.float32))
         return logits.astype(jnp.float32)

@@ -20,12 +20,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from maggy_tpu.models import head
 from maggy_tpu.ops import attention as ops_attn
 from maggy_tpu.ops import blockdiff, eva, sparse_select
 from maggy_tpu.ops.flash import (
@@ -253,6 +254,12 @@ class DecoderConfig:
     def mtp_weight(self) -> float:
         """What the trainer weighs the further heads' mean loss by: their number."""
         return float(self.pred_heads - 1)
+
+    def head_aheads(self) -> Tuple[int, ...]:
+        """How far ahead of its position each head predicts, head by head
+        (``models/head.py`` ``step_targets``: a model with this method returns
+        its heads' losses where it is handed ``targets``)."""
+        return tuple(range(1, self.pred_heads + 1))
 
     @property
     def stream_block(self) -> int:
@@ -511,6 +518,20 @@ def _dense(features, logical_axes, cfg: DecoderConfig, name: str, dot_general=No
         dot_general=dot_general,
         name=name,
     )
+
+
+class HeadKernel(nn.Module):
+    """An untied head's kernel without its product, for a head that runs
+    inside the loss (``models/head.py``): the leaf ``lm_head/kernel`` as
+    ``_dense`` makes it."""
+
+    cfg: DecoderConfig
+    features: int
+
+    @nn.compact
+    def __call__(self):
+        init = _partitioned(nn.initializers.normal(stddev=0.02), ("embed", "vocab"), self.cfg)
+        return self.param("kernel", init, (self.cfg.d_model, self.features), self.cfg.param_dtype)
 
 
 def _last_with_first(ndim, contracted):
@@ -1632,10 +1653,13 @@ class Decoder(nn.Module):
     cfg: DecoderConfig
 
     @nn.compact
-    def __call__(self, tokens, positions=None, segment_ids=None):
+    def __call__(self, tokens, positions=None, segment_ids=None, targets=None):
         """``positions`` default to per-row arange; packed batches pass both
         ``positions`` (restarting per segment) and ``segment_ids`` [B, S]
-        (attention masks across segment boundaries, SURVEY §5.7)."""
+        (attention masks across segment boundaries, SURVEY §5.7). With
+        ``targets`` (``models/head.py`` ``Targets``, a row a head) the head
+        runs inside the loss, in blocks of the sequence, and the model returns
+        its heads' losses ``[pred_heads]`` float32 in the place of logits."""
         cfg = self.cfg
         if positions is None:
             positions = jnp.broadcast_to(
@@ -1698,6 +1722,17 @@ class Decoder(nn.Module):
                     )
 
         x = RMSNorm(cfg, name="final_norm")(x)
+        if targets is not None:
+            width = cfg.vocab_size
+            kernel = embed if cfg.tie_embeddings else HeadKernel(cfg, width * cfg.pred_heads, name="lm_head")()
+            return jnp.stack([
+                head.loss(
+                    x, kernel if cfg.pred_heads == 1 else kernel[:, i * width:(i + 1) * width], targets.ids[i],
+                    targets.weights[i], rows=targets.rows, dtype=cfg.dtype, tied=cfg.tie_embeddings,
+                    softcap=cfg.logits_softcap,
+                )
+                for i in range(cfg.pred_heads)
+            ])
         if cfg.tie_embeddings:
             with jax.named_scope("lm_head"):  # the scope the untied head's module gives
                 logits = jnp.einsum("bsd,vd->bsv", x, jnp.asarray(embed, cfg.dtype))

@@ -353,6 +353,9 @@ EVENTS = frozenset(
         # the keys a query keeps, where a selection masks the call, and for a
         # two-stream layer ``own_block`` (``kernel`` or ``xla``: ops/blockdiff.py)
         "attention.kernel",
+        # whether a step's head and loss run over whole logits or in blocks of
+        # the sequence, and the block (models/head.py step_targets)
+        "loss.blocks",
         # autopilot decisions (autopilot/controller.py, serve/scheduler.py):
         # the auditable telemetry→config loop — diagnosis verdicts, applied
         # moves, guarded commits, automatic rollbacks

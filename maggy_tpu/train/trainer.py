@@ -34,7 +34,7 @@ import numpy as np
 import optax
 from flax.training import train_state
 
-from maggy_tpu.models import sown
+from maggy_tpu.models import head, sown
 from maggy_tpu.parallel import sharding as shd
 from maggy_tpu.parallel.spec import (
     AXIS_DATA,
@@ -70,12 +70,7 @@ def _lm_loss_parts(
     logits = logits[:, :-ahead]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    mask = batch.get("loss_mask")
-    mask = None if mask is None else mask[:, ahead:].astype(jnp.float32)
-    seg = batch.get("segment_ids")
-    if seg is not None:
-        same = (seg[:, ahead:] == seg[:, :-ahead]).astype(jnp.float32)
-        mask = same if mask is None else mask * same
+    mask = head.kept_targets(batch, ahead)
     if mask is None:
         return ll.sum(), jnp.float32(ll.size)
     return (ll * mask).sum(), mask.sum()
@@ -101,9 +96,7 @@ def target_weighted_loss(logits: jax.Array, batch: Dict[str, jax.Array], weights
     tokens = batch["tokens"]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
-    real = batch.get("loss_mask")
-    if real is None and batch.get("segment_ids") is not None:
-        real = batch["segment_ids"] > 0
+    real = head.real_tokens(batch)
     if real is None:
         return -(ll * weights).sum() / jnp.float32(ll.size)
     real = real.astype(jnp.float32)
@@ -136,6 +129,17 @@ def mtp_loss(mods, batch: Dict[str, jax.Array]) -> Optional[jax.Array]:
         heads = logits.shape[2]
         return sum(lm_loss_fn(logits[:, :, i], batch, ahead=i + 2) for i in range(heads)) / heads
     return lm_loss_fn(logits, batch, ahead=2)
+
+
+def data_losses(loss_fn: Callable, out: jax.Array, mods, batch, in_head: bool) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """``(data loss, further heads' loss or None)`` of a dense step from what
+    the model returned: its heads' losses where the head ran inside the loss
+    (``models/head.py``: the first is the main head's, the mean of the others
+    what :func:`mtp_loss` gives), else logits, from which the losses are taken
+    here."""
+    if in_head:
+        return out[0], (out[1:].mean() if out.shape[0] > 1 else None)
+    return model_loss(loss_fn, out, mods, batch), mtp_loss(mods, batch)
 
 
 def _prefetch_depth(prefetch: Optional[int]) -> int:
@@ -993,6 +997,13 @@ class Trainer:
 
         return jax.jit(train_step, donate_argnums=(0,))
 
+    def _head_targets(self, batch) -> Dict[str, Any]:
+        """What the model's ``apply`` takes for its head to run inside the
+        loss, in blocks of the sequence (``models/head.py`` ``step_targets``):
+        the built-in language-model loss has that form, a user's ``loss_fn``
+        takes logits."""
+        return head.step_targets(self.model, batch, self.mesh) if self.loss_fn is lm_loss_fn else {}
+
     def _build_train_step(self):
         if self.pp > 1:
             self._overlap_mode()  # zero/bucket on a pp mesh: one-time warning
@@ -1007,13 +1018,13 @@ class Trainer:
             def loss_of(params):
                 # mutable intermediates, or flax `sow` is a silent no-op: what the
                 # model's layers sow is read through models/sown.py
-                logits, mods = state.apply_fn(
+                targets = self._head_targets(batch)
+                out, mods = state.apply_fn(
                     {"params": params}, *_model_inputs(batch), mutable=["intermediates"],
-                    **sown.step_inputs(self.model, state.step),
+                    **sown.step_inputs(self.model, state.step), **targets,
                 )
                 with jax.named_scope("loss"):
-                    loss = model_loss(self.loss_fn, logits, mods, batch)
-                    mtp = mtp_loss(mods, batch)
+                    loss, mtp = data_losses(self.loss_fn, out, mods, batch, bool(targets))
                 aux = sown.collect_aux_losses(mods)
                 extra = sown.step_counters(mods)
                 total = loss + aux
@@ -1215,11 +1226,12 @@ class Trainer:
                     return loss
             else:
                 def eval_loss(state, batch):
-                    logits, mods = state.apply_fn(
+                    targets = self._head_targets(batch)
+                    out, mods = state.apply_fn(
                         {"params": state.params}, *_model_inputs(batch), mutable=["intermediates"],
-                        **sown.step_inputs(self.model, state.step),
+                        **sown.step_inputs(self.model, state.step), **targets,
                     )
-                    return model_loss(self.loss_fn, logits, mods, batch)
+                    return out[0] if targets else model_loss(self.loss_fn, out, mods, batch)
 
             self._eval_loss_step = jax.jit(eval_loss)
         from maggy_tpu import telemetry

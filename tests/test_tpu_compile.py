@@ -586,3 +586,36 @@ def test_relu_gated_chunk_compiles_with_its_count_at_the_smallthinker_widths(one
     assert lowered.out_info[1].shape == () and lowered.out_info[1].dtype == jnp.int32
     calls = kernel_calls(lowered.compile().as_text(), "gmm", "tgmm")  # "gmm" counts the "tgmm" lines too
     assert calls["tgmm"] == 3 and calls["gmm"] - calls["tgmm"] >= 6
+
+
+@pytest.mark.parametrize(
+    "s,d,vocab", [(16384, 2560, 37984), (32768, 2048, 18992)], ids=["smallthinker-16384x37984", "keye-32768x18992"]
+)
+def test_head_and_loss_in_blocks_keep_no_whole_float32_logits(one_chip, s, d, vocab):
+    """``head.loss`` at the two cells' shapes whose float32 logits are 2.32 GiB,
+    value and gradients, at the rows ``head.block_rows`` gives: one loop in the
+    compiled program, the three products a block in it, and no float32 array
+    of the whole logits' size anywhere (PR 46's step held three)."""
+    from maggy_tpu.models import head
+
+    rows = head.block_rows(1, s, vocab)
+    assert rows < s and rows * vocab * 4 <= head.BLOCK_LOGITS_BYTES
+
+    def step(hidden, kernel, ids, weights):
+        return jax.value_and_grad(
+            lambda h, w: head.loss(h, w, ids, weights, rows=rows, dtype=jnp.bfloat16), argnums=(0, 1)
+        )(hidden, kernel)
+
+    args = (
+        jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((d, vocab), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, s), jnp.float32, sharding=one_chip),
+    )
+    compiled = jax.jit(step).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" convolution\(", text)) == 3
+    whole = [m for m in re.findall(r"f32\[([\d,]+)\]", text) if {str(vocab)} <= set(m.split(",")) and {str(s), str(s - 1)} & set(m.split(","))]
+    assert not whole
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * vocab * 4 + 3 * d * vocab * 4
