@@ -228,18 +228,11 @@ def _connect_with_deadline(
     from maggy_tpu.core import rpc
     from maggy_tpu.exceptions import RpcError
 
-    from maggy_tpu import telemetry
-
-    start = time.perf_counter()
     deadline = time.time() + deadline_s
     delay = 0.2
     while True:
         try:
-            client = rpc.Client((host, port), pid, secret, hb_interval)
-            telemetry.get().gauge(
-                "driver_connect_ms", (time.perf_counter() - start) * 1e3
-            )
-            return client
+            return rpc.Client((host, port), pid, secret, hb_interval)
         except RpcError as e:
             if time.time() > deadline:
                 hint = ""

@@ -22,10 +22,14 @@ first_token→finished = ``decode``, ...; unknown pairs land in ``other``).
 Segments therefore sum to the measured e2e by construction — the report's
 job is to show *which* bucket ate the time.
 
-Per-step attribution reads the training gauges: ``step_time_ms`` (host wall
-per step), ``input_wait_ms`` (blocked on the input pipeline), and
-``metrics_drain_ms`` (lagged broadcast reads), with the remainder reported
-as compute/dispatch.
+Per-step attribution reads the training gauges: ``step_time_ms`` (a step's
+time on the device: the duration of its ``train.step_device`` span, which
+``Trainer.fit``'s ``fit-steps`` thread measures from the later of the previous
+step's end and the step's dispatch to the moment its output was ready: not the
+dispatch of the jitted call, which returns in a few ms whatever the step
+takes), ``input_wait_ms`` (blocked on the input pipeline), and
+``metrics_drain_ms`` (lagged broadcast reads), with the remainder reported as
+compute.
 
 :func:`analyze` returns the machine-readable result (the exact object
 ``tools/analyze_trace.py --json`` prints); its layout is versioned under

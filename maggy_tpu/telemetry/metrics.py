@@ -22,14 +22,16 @@ from __future__ import annotations
 GAUGES = frozenset(
     {
         # training loop (train/trainer.py)
-        "step_time_ms",  # host wall clock per step
+        # a step's time on the device: the duration of its ``train.step_device``
+        # span, from the later of the previous step's end and its own dispatch to
+        # the moment its output was ready (fit's ``fit-steps`` thread)
+        "step_time_ms",
         "compile_time_ms",  # a step whose call traced the program, synced to cover the XLA compile
         "steps_per_sec",
         "tokens_per_sec",
         "mfu_est",  # 6*params FLOPs estimate vs detected chip peak
         "metrics_lag",  # steps between a broadcast and its metric
         "metrics_drain_ms",  # host time in the lagged broadcast read
-        "resumed_step",  # resume="auto" restore point
         # input pipeline (train/prefetch.py)
         "input_wait_ms",
         "prefetch_depth",
@@ -53,7 +55,8 @@ GAUGES = frozenset(
         "diffusion.masked_share",
         "attention.blockdiff_pairs_share",
         # an expert share model's step counters (models/moe.py
-        # ExpertShareBlock, read by Trainer.fit with the loss of its last step)
+        # ExpertShareBlock; like every step counter below, gauged by Trainer.fit
+        # from each step's output and an attribute of its ``train.step_device`` span)
         "moe.slots",  # (token, choice) slots on the experts this chip holds, all layers
         "moe.slots_dropped",  # of them, cut by a buffer: the layer is dropless, so 0
         "moe.load_max_over_mean",  # busiest held expert's slots over the mean one's, a mean over layers
@@ -72,11 +75,8 @@ GAUGES = frozenset(
         "sparse.selected_share",  # pairs the selections keep over the pairs visible inside documents
         "sparse.rows_off_k",  # queries whose set is not min(sparse_topk, visible) keys: exact, so 0
         "sparse.index_loss",  # the indexer's KL loss, summed over the layers
-        # checkpointing (train/checkpoint.py)
-        "checkpoint_save_ms",
-        # control plane (core/rpc.py, core/pod.py)
+        # control plane (core/rpc.py)
         "heartbeat_rtt_ms",
-        "driver_connect_ms",
         # serving engine + scheduler (serve/)
         "serve.ttft_ms",
         "serve.tokens_per_sec",
@@ -251,6 +251,12 @@ SPANS = frozenset(
         "shard_batch",  # host gather + H2D of one batch (prefetcher thread, or inline)
         "train_step",  # dispatch of the jitted step
         "train.drain",  # the loop thread waits for the device (metric reads, syncs)
+        # fit's ``fit-steps`` thread, one pair a step: the live wait for the
+        # step's output (on the profiler's trace too), and the step's time on the
+        # device journaled after it, with ``step``, ``global_step``, ``compiled``,
+        # ``tokens``, ``loss``, ``mtp_loss`` and the step counters as attributes
+        "train.step_wait",
+        "train.step_device",
         "train.checkpoint",  # Checkpointer.save called from the loop
         # checkpointing (train/checkpoint.py)
         "checkpoint_save",
@@ -421,7 +427,6 @@ GAUGE_UNITS = {
     "mfu_est": "ratio",
     "metrics_lag": "count",
     "metrics_drain_ms": "ms",
-    "resumed_step": "count",
     "input_wait_ms": "ms",
     "prefetch_depth": "count",
     "attention.tiles_visited_share": "ratio",
@@ -439,9 +444,7 @@ GAUGE_UNITS = {
     "sparse.selected_share": "ratio",
     "sparse.rows_off_k": "count",
     "sparse.index_loss": "ratio",  # nats, like a loss: no unit of its own in the vocabulary
-    "checkpoint_save_ms": "ms",
     "heartbeat_rtt_ms": "ms",
-    "driver_connect_ms": "ms",
     "serve.ttft_ms": "ms",
     "serve.tokens_per_sec": "per_s",
     "serve.queue_depth": "count",

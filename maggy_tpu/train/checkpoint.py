@@ -51,15 +51,12 @@ class Checkpointer:
 
         from maggy_tpu import telemetry
 
-        tel = telemetry.get()
-        t0 = time.perf_counter()
-        with tel.span("checkpoint_save", step=int(step)):
+        # an async save's span is its blocking (dispatch) cost — the part
+        # that actually steals step time
+        with telemetry.get().span("checkpoint_save", step=int(step)):
             self._manager.save(int(step), args=ocp.args.StandardSave(state))
         if meta is not None:
             self._write_meta(int(step), meta)
-        # async saves measure the blocking (dispatch) cost — the part that
-        # actually steals step time
-        tel.gauge("checkpoint_save_ms", (time.perf_counter() - t0) * 1e3)
 
     # ------------------------------------------------------------------ meta
 

@@ -23,6 +23,8 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
+import queue
+import threading
 import time
 import warnings
 from collections import deque
@@ -208,6 +210,71 @@ class _FitAutopilotTarget:
             self.metrics_window = max(0, int(value))
             return True
         return False
+
+
+class _StepWatcher:
+    """What ``fit``'s loop cannot see without draining the pipeline: each
+    step's end. One daemon thread (``fit-steps``) a ``fit`` call takes the
+    steps in the order the loop dispatched them, waits for each one's output
+    inside the live span ``train.step_wait``, brings it to the host (a dozen
+    scalars) and journals ``train.step_device`` from the later of the previous
+    step's end and this one's dispatch to its end, with the step's loss and
+    every counter a row of ``sown.COUNTERS`` names as attributes, and the
+    gauges from the same values. All state the two threads share rides the
+    queue; the loop thread's part is one ``put`` a step."""
+
+    def __init__(self, tel, trace, step0: int):
+        self._tel, self._trace, self._step0 = tel, trace, step0
+        self._gauges = {key: name for row in sown.COUNTERS for key, name in row.gauges.items()}
+        self._queue: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._closed = False
+        self._thread = threading.Thread(target=self._run, name="fit-steps", daemon=True)
+        self._thread.start()
+
+    def put(self, i: int, start: float, compiled: bool, tokens: int, metrics) -> None:
+        """Step ``i`` of the call, dispatched at ``start`` on ``time.time()``."""
+        self._queue.put((i, start, compiled, tokens, metrics))
+
+    def close(self) -> None:
+        """Wait until every step handed over is journaled, then end the
+        thread. Idempotent."""
+        if not self._closed:
+            self._closed = True
+            self._queue.put(None)  # no step follows
+        self._thread.join()
+
+    def _run(self) -> None:
+        from maggy_tpu.telemetry import tracing as _tracing
+
+        tel = self._tel
+        _tracing.set_current(self._trace)
+        prev_end = 0.0
+        while True:
+            item = self._queue.get()
+            if item is None:
+                return
+            i, start, compiled, tokens, metrics = item
+            try:
+                with tel.span("train.step_wait", step=i):
+                    jax.block_until_ready(metrics)
+                    end = time.time()
+                values = {k: float(v) for k, v in jax.device_get(metrics).items()}
+            except Exception:  # noqa: BLE001 — a failed step is the loop thread's to raise, when it reads the same output
+                continue
+            start = max(prev_end, start)
+            prev_end = end
+            counters = {k: v for k, v in values.items() if k in self._gauges}
+            attrs = {k: values[k] for k in ("loss", "mtp_loss") if k in values}
+            tel.record_span(
+                "train.step_device", start, end, step=i, global_step=self._step0 + i,
+                compiled=compiled, tokens=tokens, **attrs, **counters,
+            )
+            if compiled:
+                tel.gauge("compile_time_ms", (end - start) * 1e3)
+            else:
+                tel.gauge("step_time_ms", (end - start) * 1e3)
+            for key, value in counters.items():
+                tel.gauge(self._gauges[key], value)
 
 
 @dataclasses.dataclass
@@ -1348,19 +1415,21 @@ class Trainer:
         ``train.input_wait`` (the blocked pull of the next batch),
         ``train_step`` (dispatch), ``train.drain`` (every wait for the
         device), ``train.checkpoint`` — which inside a profiler session lie
-        in its trace beside the device events, plus ``step_time_ms`` /
-        ``tokens_per_sec`` / ``mfu_est`` gauges. A step whose call traced the
-        program (the first of a cold trainer, a rebuild) is synced once to
-        cover the XLA compile and lands in ``compile_time_ms`` instead of
-        ``step_time_ms``; step 0 of a later ``fit`` on a warm trainer is an
-        ordinary step. The prefetcher adds ``input_wait_ms`` (host time
-        blocked waiting for an input batch) and ``prefetch_depth`` (queue
-        occupancy) gauges, plus the ``shard_batch`` spans the synchronous
-        path records inline. The returned
-        metrics dict always carries the measured ``steps_per_sec``
-        regardless of the telemetry flag. Host wall-clock per later step is
-        measured without extra device syncs (dispatch overlaps; the device
-        queue's backpressure makes the mean converge to true step time).
+        in its trace beside the device events, plus ``tokens_per_sec`` /
+        ``mfu_est`` gauges. Under a live recorder a thread of the call's own
+        (:class:`_StepWatcher`) waits for every step's output in turn and
+        journals one ``train.step_device`` span a step, with the step's loss
+        and counters, and its duration as ``step_time_ms``. A step whose call
+        traced the program (the first of a cold trainer, a rebuild) is synced
+        once on the loop thread to cover the XLA compile and lands in
+        ``compile_time_ms`` instead of ``step_time_ms``; step 0 of a later
+        ``fit`` on a warm trainer is an ordinary step. The prefetcher adds
+        ``input_wait_ms`` (host time blocked waiting for an input batch) and
+        ``prefetch_depth`` (queue occupancy) gauges, plus the ``shard_batch``
+        spans the synchronous path records inline. The returned metrics dict
+        always carries the measured ``steps_per_sec`` regardless of the
+        telemetry flag. The loop thread itself syncs on nothing it does not
+        read.
         """
         from maggy_tpu import telemetry
         from maggy_tpu.resilience import chaos as _chaos
@@ -1405,7 +1474,6 @@ class Trainer:
 
                     skip_batches(data_iter, skipped)
                     tel.count("resilience.auto_resumes")
-                    tel.gauge("resumed_step", resumed_from)
             # num_steps is the TOTAL budget for this fit call; a resumed fit only
             # executes the remainder
             num_steps = max(0, num_steps - skipped)
@@ -1492,6 +1560,11 @@ class Trainer:
 
             ts_store = _timeseries.SeriesStore()
             sentinel = _Sentinel(ts_store, tel, scope="worker", steady=("train_step",))
+            # the steps' ends, seen from a thread of their own (a live recorder
+            # only: with telemetry off no thread starts and the loop is as it
+            # was). Last in the span: the thread's start is set-up's time, and
+            # nothing stands between it and the try that ends it
+            watcher = _StepWatcher(tel, run_trace, step0) if tel.active else None
         try:
             for i in range(num_steps):  # hot-loop (tools/check_host_sync.py)
                 wd.beat("train.step", detail=step0 + i)
@@ -1556,10 +1629,8 @@ class Trainer:
                     with tel.span("train.drain", step=i, why="compile"):
                         jax.block_until_ready(metrics)  # sync: ok — compile timing
                 dt_ms = (time.perf_counter() - t0) * 1e3
-                if compiled:
-                    tel.gauge("compile_time_ms", dt_ms)
-                else:
-                    tel.gauge("step_time_ms", dt_ms)
+                if watcher is not None:  # dispatched dt_ms ago, on the records' clock
+                    watcher.put(i, time.time() - dt_ms / 1e3, compiled, tokens_per_batch, metrics)
                 if self._expect_recompile:
                     sentinel.expect("train_step")
                     self._expect_recompile = False
@@ -1653,6 +1724,10 @@ class Trainer:
                             }
                         )
                         window = max(0, ap_target.metrics_window)
+        except BaseException:
+            if watcher is not None:
+                watcher.close()  # a step that raises leaves no thread behind
+            raise
         finally:
             wd.end("train.step")
             _tracing.set_current(trace_prev)
@@ -1665,11 +1740,9 @@ class Trainer:
             preempted=preempted,
         )
         with tel.span("train.drain", why="return"):
+            if watcher is not None:
+                watcher.close()  # the loop thread's wait for the device is booked here, as before
             out = {k: float(v) for k, v in metrics.items()}
-        for row in sown.COUNTERS:  # the model's step counters, read with the loss of its last step
-            for key, gauge in row.gauges.items():
-                if key in out:
-                    tel.gauge(gauge, out[key])
         if resumed_from is not None:
             out["resumed_from"] = float(resumed_from)
         if preempted:

@@ -373,7 +373,8 @@ def test_trainer_step_reports_the_taps_and_fit_publishes_the_gauge(tiny, batch):
     seg = np.asarray(batch["segment_ids"])
     by_hand = sum(2 * j + int((seg[:, j:] != seg[:, :-j]).sum()) for j in (1, 2)) / (2 * 64 * 3)
     np.testing.assert_allclose(out["conv_taps_masked_share"], by_hand, rtol=1e-6)  # the same in all four conv layers
-    assert seen == [out["conv_taps_masked_share"]] and out["moe_slots_dropped"] == 0
+    # gauged from every step's output (the same batch twice: the same share twice)
+    assert seen == [out["conv_taps_masked_share"]] * 2 and out["moe_slots_dropped"] == 0
 
 
 @pytest.mark.parametrize("bad", [

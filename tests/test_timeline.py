@@ -9,6 +9,7 @@ import glob
 import os
 import re
 import signal
+import threading
 import time
 
 import jax
@@ -299,9 +300,14 @@ def test_phase_spans_never_nest_under_their_own_name(served):
 @limit(120)
 def test_only_a_step_that_compiled_is_synced_and_gauged(monkeypatch):
     trainer, state, data = tiny_trainer()
-    syncs = []
+    syncs, watched = [], []  # the loop thread's syncs; the ``fit-steps`` thread waits for every step
     real = jax.block_until_ready
-    monkeypatch.setattr(jax, "block_until_ready", lambda x: (syncs.append(1), real(x))[1])
+
+    def counted(x):
+        (watched if threading.current_thread().name == "fit-steps" else syncs).append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counted)
 
     def fit():
         tel = Telemetry(worker=0)
@@ -316,7 +322,7 @@ def test_only_a_step_that_compiled_is_synced_and_gauged(monkeypatch):
     state_box = [state]
     gauges, drains = fit()
     assert gauges.count("compile_time_ms") == 1 and gauges.count("step_time_ms") == 2
-    assert drains == ["compile", "return"] and len(syncs) == 1
+    assert drains == ["compile", "return"] and len(syncs) == 1 and len(watched) == 3
     # a warm trainer: step 0 of the next fit is an ordinary step
     del syncs[:]
     gauges, drains = fit()
