@@ -29,6 +29,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from maggy_tpu.models import head
 from maggy_tpu.models.transformer import (
@@ -356,19 +358,27 @@ class MoEBlock(nn.Module):
         return y
 
 
-def counting_sort(key, n_keys: int):
+def counting_sort(key, n_keys: int, every: int = 0):
     """A stable sort of ``key`` [n] (whole numbers below ``n_keys``, a small
     static count) without sorting: ``(inv, load)`` with ``load[v]`` the
     entries of value ``v`` and ``inv[i]`` the place of entry ``i`` in sorted
     order, its value's offset (the exclusive running sum of ``load``) plus
     its rank among the entries of that value. ``order`` with
-    ``order[inv[i]] = i`` is ``jnp.argsort(key, stable=True)``."""
+    ``order[inv[i]] = i`` is ``jnp.argsort(key, stable=True)``. With
+    ``every`` a third result, ``ends`` [ceil(n / every), n_keys]: the entries
+    of each value up to the end of each run of ``every`` entries (the last
+    run may be short), rows of the running sum that the places come from. The
+    sort is stable, so the entries of one value in one such run stand
+    together in sorted order, at ``start[v] + [ends[b - 1, v], ends[b, v])``."""
     onehot = (key[:, None] == jnp.arange(n_keys, dtype=key.dtype)).astype(jnp.int32)
     upto = jnp.cumsum(onehot, axis=0)  # [n, n_keys]: scalars a slot, never rows of width d
     load = upto[-1] if key.shape[0] else jnp.zeros(n_keys, jnp.int32)
     start = jnp.cumsum(load) - load
-    inv = ((upto - 1 + start) * onehot).sum(-1)
-    return inv.astype(jnp.int32), load
+    inv = ((upto - 1 + start) * onehot).sum(-1).astype(jnp.int32)
+    if not every:
+        return inv, load
+    last = np.minimum(np.arange(1, -(-key.shape[0] // every) + 1) * every, key.shape[0]) - 1
+    return inv, load, upto[last]
 
 
 def chunk_rows(slots: int, held: int, n_experts: int, of_load: float = 0.0) -> int:
@@ -405,6 +415,206 @@ def _by_token(rows, inv, held, weights=None):
             row = weights[:, j, None].astype(jnp.float32) * row
         total = total + jnp.where(held[:, j, None], row, 0)
     return total
+
+
+# the token-side sum's kernel (``slots_to_tokens``): tokens a block, rows a tile (one DMA), and the
+# rows of tiles a block's buffer holds before it is multiplied; the best of seven on one v5e at
+# the expert cells' shapes taken together (``slots_to_tokens``; PERF.md section 6, PR 49)
+TOKEN_TILES = (512, 16, 1024)
+
+
+# the columns of the placement the kernel builds and multiplies at a time: tiles of the buffer side by side
+PLACE_COLS = 256
+
+
+def token_tiles(ends, load, tile: int = TOKEN_TILES[1]):
+    """Which tiles of ``tile`` rows of the buffer hold the slots of each block
+    of tokens, from ``counting_sort``'s ``ends`` [blocks, held] and ``load``
+    [held]: int32 ``[2, blocks, held]``, for block ``b`` and held expert
+    ``e`` the first tile and the number of tiles that cover the rows
+    ``start[e] + [ends[b - 1, e], ends[b, e])`` (the block's slots on that
+    expert, which stand together and in token order). A tile that two
+    experts' runs of one block share is the first one's alone: the tiles of a
+    block ascend and each is read once."""
+    start = jnp.cumsum(load) - load
+    hi = start + ends
+    lo = start + jnp.concatenate([jnp.zeros_like(ends[:1]), ends[:-1]])
+    last = jnp.where(hi > lo, (hi - 1) // tile, -1)
+    read = jax.lax.cummax(last, axis=1)  # the last tile the experts up to this one read
+    first = jnp.maximum(lo // tile, jnp.concatenate([jnp.full_like(read[:, :1], -1), read[:, :-1]], axis=1) + 1)
+    return jnp.stack([first, jnp.maximum(last - first + 1, 0)]).astype(jnp.int32)
+
+
+def _token_sum_kernel(first_ref, count_ref, total_ref, inv_ref, w_ref, rows_ref, out_ref, buf, sem, at, state, acc, *,
+                      held: int, tile: int, weighted: bool, precision):
+    """One block of tokens a grid step. ``fill`` walks the block's tiles
+    (``first_ref``/``count_ref``, [blocks * held] in SMEM) from a cursor
+    (expert, tile of its run) and starts one DMA a tile, HBM to the next free
+    tile of the buffer ``buf[half]``, until the buffer is full or the block
+    done; ``flush`` waits for them and adds ``place @ buffer`` to the block's
+    float32 sum, ``place[c, t]`` the weight of the token's choice whose slot
+    is the buffer's row ``c``, contracted over ``c``. The next block's first fill is started before
+    this block's product, into the other half. Rows from ``total`` on hold
+    anything and are selected to zero; the tiles of a block ascend, so those
+    are the buffer's rows from ``live`` on."""
+    b, blocks = pl.program_id(0), pl.num_programs(0)
+    slots = buf.shape[1] // tile
+    total = total_ref[0]
+
+    def copy(half, row0, slot):
+        return pltpu.make_async_copy(
+            rows_ref.at[pl.ds(pl.multiple_of(row0, tile), tile)],
+            buf.at[half, pl.ds(pl.multiple_of(slot * tile, tile), tile)], sem.at[half],
+        )
+
+    def fill(block, half, e, i):
+        """-> the cursor it stopped at, the tiles started and the rows among them that hold a slot"""
+        def step(c):
+            e, i, s, live = c
+            more = i < count_ref[block * held + e]
+            row0 = (first_ref[block * held + e] + i) * tile
+
+            @pl.when(more)
+            def _():
+                copy(half, row0, s).start()
+                at[half, s] = row0
+
+            return (
+                jnp.where(more, e, e + 1), jnp.where(more, i + 1, 0), jnp.where(more, s + 1, s),
+                jnp.where(more, live + jnp.clip(total - row0, 0, tile), live),
+            )
+
+        return jax.lax.while_loop(lambda c: (c[0] < held) & (c[2] < slots), step, (e, i, jnp.int32(0), jnp.int32(0)))
+
+    def flush(half, s, live):
+        """Adds the product with the ``s`` tiles in the buffer's half, ``cols`` columns at a time."""
+        jax.lax.fori_loop(0, s, lambda j, c: (copy(half, 0, j).wait(), c)[1], 0)
+        cols = min(PLACE_COLS, buf.shape[1])
+        inv = inv_ref[...]
+
+        def part(c, carry):
+            base = pl.multiple_of(c * cols, cols)
+            col = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
+            row = jnp.full((cols, 1), -2, jnp.int32)  # the buffer's row each row of this part holds; -2: none
+            for j in range(cols // tile):
+                row = jnp.where(col // tile == j, at[half, c * (cols // tile) + j] + col % tile, row)
+            row = jnp.where(base + col < live, row, -2)
+            # the placement with the tokens along the lanes, as ``inv`` and the weights come ([k, B]): a
+            # choice's places are one row, and the only broadcast along lanes is ``row``'s, once a part
+            place = jnp.zeros((cols, inv.shape[1]), jnp.float32)
+            for j in range(inv.shape[0]):
+                place = jnp.where(inv[j:j + 1, :] == row, w_ref[j:j + 1, :] if weighted else 1.0, place)
+            data = jnp.where(base + col < live, buf[half, pl.ds(base, cols)], jnp.zeros((), buf.dtype))
+            acc[...] += jax.lax.dot_general(
+                place.astype(buf.dtype), data, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+                precision=precision,
+            )
+            return carry
+
+        jax.lax.fori_loop(0, (s * tile + cols - 1) // cols, part, 0)
+
+    def started(half, cursor):
+        for j, v in enumerate(cursor):
+            state[half, j] = v
+
+    @pl.when(b == 0)
+    def _():
+        started(0, fill(0, 0, jnp.int32(0), jnp.int32(0)))
+
+    @pl.when(b + 1 < blocks)
+    def _():
+        started((b + 1) % 2, fill(b + 1, (b + 1) % 2, jnp.int32(0), jnp.int32(0)))
+
+    half = b % 2
+    acc[...] = jnp.zeros_like(acc)
+    flush(half, state[half, 2], state[half, 3])
+
+    def rest(cursor):  # a block whose tiles outnumber the buffer's: fill and multiply again, nothing in flight meanwhile
+        e, i, s, live = fill(b, half, *cursor)
+        flush(half, s, live)
+        return e, i
+
+    jax.lax.while_loop(lambda c: c[0] < held, rest, (state[half, 0], state[half, 1]))
+    out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def slots_to_tokens(rows, runs, total, inv, held, weights=None, *, tiles=TOKEN_TILES, interpret=False):
+    """``_by_token`` as one Pallas kernel that reads each tile of ``rows``
+    [N, d] that holds a slot of a block of tokens once (``runs``:
+    ``token_tiles`` at ``tiles``' block and tile; ``total``: the slots on held
+    experts, ``load.sum()``), where ``_by_token`` gathers ``T`` rows for each
+    of the ``top_k`` choices and throws away those whose expert is not held.
+    The placement of a tile's rows onto the block's tokens is a one-hot
+    product (at most one choice of a token is a given row, so a weight is not
+    rounded; float32 sum, cast once: ``_by_token``'s arithmetic; float32 rows
+    are multiplied at ``HIGHEST``). ``tiles``: ``TOKEN_TILES``, one triple for
+    every shape: on one v5e (PERF.md section 6, PR 49: the kernel alone against
+    ``_by_token`` alone, both forms, the six expert cells' shapes and loads)
+    tiles of 16 rows beat 32 and 64 everywhere (the tiles start on multiples
+    of their size, so a larger one reads more rows that hold no slot), and
+    blocks of 256 and of 512 tokens lie within a quarter of each other, 512
+    ahead at the low loads and 256 at the high ones: at 32,768 tokens x 8 of
+    2,048 with 16 of 128 experts held 0.99 ms (512) and 0.93 (256) against the
+    gathers' 10.5, at 8,192 x 10 of 3,072 with 8 of 256 held 0.27 against 4.6;
+    512 reads 0.19 of ``T * top_k`` rows there where 256 reads 0.25. -> [T, d]
+    in ``rows``' type."""
+    block, tile, width = tiles
+    (t, k), (n, d) = inv.shape, rows.shape
+    blocks, n_held = runs.shape[1:]
+    if blocks != -(-t // block) or n % tile or width % tile or width % min(PLACE_COLS, width) or PLACE_COLS % tile:
+        raise ValueError(f"runs {runs.shape} for {t} tokens in blocks of {block}, {n} rows in tiles of {tile}")
+    # [k, T] for the kernel, tokens along the lanes; the weights as float32 (exact), whose tile takes any ``k``
+    inv = jnp.pad(jnp.where(held, inv, -1), ((0, blocks * block - t), (0, 0)), constant_values=-1).T
+    weighted = weights is not None
+    weights = (
+        jnp.pad(weights.astype(jnp.float32), ((0, blocks * block - t), (0, 0))).T if weighted
+        else jnp.zeros((k, block), jnp.float32)
+    )
+    by_block = pl.BlockSpec((k, block), lambda b, *_: (0, b))
+    out = pl.pallas_call(
+        functools.partial(
+            _token_sum_kernel, held=n_held, tile=tile, weighted=weighted,
+            precision=jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(blocks,),
+            in_specs=[
+                by_block,
+                by_block if weighted else pl.BlockSpec((k, block), lambda b, *_: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((block, d), lambda b, *_: (b, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, width, d), rows.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2, width // tile), jnp.int32),  # the first row of each tile in the buffer
+                pltpu.SMEM((2, 4), jnp.int32),  # where each half's first fill stopped: ``fill``'s result
+                pltpu.VMEM((block, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((blocks * block, d), rows.dtype),
+        # the halves are filled a block ahead: the blocks run in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=96 * 2**20),
+        name="slots_to_tokens",
+        interpret=interpret,
+    )(runs[0].reshape(-1), runs[1].reshape(-1), total.reshape(1).astype(jnp.int32), inv, weights, rows)
+    return out[:t]
+
+
+def token_sum(rows, runs, total, inv, held, weights=None):
+    """``_by_token`` [T, d] in ``rows``' type. On a TPU, where the rows fill
+    the kernel's tiles and lanes, ``slots_to_tokens``, whose reads follow the
+    load; elsewhere the gathers."""
+    if (
+        jax.default_backend() == "tpu"
+        and rows.shape[0] % TOKEN_TILES[1] == 0
+        and rows.shape[1] % 128 == 0
+        and rows.dtype in (jnp.bfloat16, jnp.float32)
+    ):
+        return slots_to_tokens(rows, runs, total, inv, held, weights)
+    return _by_token(rows, inv, held, weights).astype(rows.dtype)
 
 
 # tiles (rows, reduction, columns) of the grouped product's kernel: the best of
@@ -487,7 +697,7 @@ def _chunk_experts_back(a, sizes, w_gate, w_up, w_down, g_rows, scale, act="silu
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _routed(rows: int, act: str, tokens, weights, order, inv, held, load, w_gate, w_up, w_down):
+def _routed(rows: int, act: str, tokens, weights, order, inv, held, load, runs, w_gate, w_up, w_down):
     """The routed part of the share layer: ``out[t] = sum over the held
     choices j of weights[t, j] * expert(tokens[t])``, the buffer (slot order:
     ``order`` [chunks * rows] names the slot of each row, ``inv`` [T, k] the
@@ -495,15 +705,20 @@ def _routed(rows: int, act: str, tokens, weights, order, inv, held, load, w_gate
     the counted slots ``load.sum()`` fill and no more: a loop whose length is
     read in the step, so one traced and compiled body serves every load. A
     chunk gathers its rows of ``tokens``, runs the three grouped products and
-    writes its part of the buffer; then a token's choices are gathered back
-    and summed in float32. Returns ``(out, zeros)``: ``zeros`` is the chunks'
-    count of ``_chunk_experts`` summed (None unless ``act`` is "relu"), taken
-    in these forward chunks and not in the backward pass's. Its own VJP (a
-    loop of unknown length has no transpose): the forward pass keeps the
-    arguments only; the backward pass is a second loop whose chunk runs
-    forward again and then backward, the expert weights' gradients summed
-    over the chunks in float32, and both directions gather by token where a
-    scatter-add would stand."""
+    writes its part of the buffer; then every token takes the sum of its held
+    choices' rows, in float32 (``token_sum``; ``runs``: ``token_tiles``, where
+    each block of tokens has its slots in the buffer). Returns ``(out,
+    zeros)``: ``zeros`` is the chunks' count of ``_chunk_experts`` summed
+    (None unless ``act`` is "relu"), taken in these forward chunks and not in
+    the backward pass's. Its own VJP (a loop of unknown length has no
+    transpose): the forward pass keeps the arguments only; the backward pass
+    is a second loop whose chunk runs forward again and then backward, the
+    expert weights' gradients summed over the chunks in float32, and both
+    directions sum by token where a scatter-add would stand: the forward the
+    weighted results, the backward the cotangents of the chunks' inputs,
+    unweighted. On a TPU that sum reads the tiles of the buffer that hold a
+    slot, once (``slots_to_tokens``); elsewhere it is ``top_k`` gathers of
+    ``T`` rows each (``_by_token``)."""
     k = inv.shape[1]
 
     def chunk(i, carry):
@@ -522,7 +737,7 @@ def _routed(rows: int, act: str, tokens, weights, order, inv, held, load, w_gate
         ),
     )
     with jax.named_scope("moe.combine"):
-        return _by_token(y, inv, held, weights).astype(tokens.dtype), zeros
+        return token_sum(y, runs, load.sum(), inv, held, weights), zeros
 
 
 def _routed_fwd(rows, act, *args):
@@ -530,7 +745,7 @@ def _routed_fwd(rows, act, *args):
 
 
 def _routed_bwd(rows, act, res, g):
-    tokens, weights, order, inv, held, load, w_gate, w_up, w_down = res
+    tokens, weights, order, inv, held, load, runs, w_gate, w_up, w_down = res
     g, _ = g  # the count has no cotangent
     k = inv.shape[1]
     scale = jnp.where(held, weights, 0).reshape(-1)
@@ -561,11 +776,11 @@ def _routed_bwd(rows, act, res, g):
         ),
     )
     with jax.named_scope("moe.dispatch"):
-        d_tokens = _by_token(d_a, inv, held).astype(tokens.dtype)
+        d_tokens = token_sum(d_a, runs, load.sum(), inv, held)
     with jax.named_scope("moe.combine"):
         d_weights = jnp.where(held, dot[jnp.minimum(inv, dot.shape[0] - 1)], 0).astype(weights.dtype)
     d_experts = (d.astype(w.dtype) for d, w in zip(d_experts, (w_gate, w_up, w_down)))
-    return (d_tokens, d_weights, None, None, None, None, *d_experts)
+    return (d_tokens, d_weights, None, None, None, None, None, *d_experts)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -601,14 +816,15 @@ def softmax_route(logits, top_k: int, scaling: float = 1.0):
 class Route(NamedTuple):
     """What ``ExpertShareBlock.route`` hands its experts: each token's
     ``weights`` [T, k] in float32, and the slots' order by held expert
-    (``_routed``'s ``order``, ``inv``, ``held``, ``load``) with the rows of
-    the buffer that the chunks which run will visit."""
+    (``_routed``'s ``order``, ``inv``, ``held``, ``load``, ``runs``) with
+    the rows of the buffer that the chunks which run will visit."""
 
     weights: jax.Array
     order: jax.Array
     inv: jax.Array
     held: jax.Array
     load: jax.Array
+    runs: jax.Array
     visited: jax.Array
 
 
@@ -629,14 +845,19 @@ class ExpertShareBlock(nn.Module):
     token) works through the buffer chunk by chunk (``chunk_rows``), as many
     chunks as the counted slots fill, in a loop whose length is read in the
     step: the rows of width ``d_model`` that move follow the load, not
-    ``T * top_k``. The buffer has a row for every slot, so none on a held
+    ``T * top_k``, on the way back too: the sort is stable, so a block of
+    tokens has its slots on one expert in one run of rows, and the sum by
+    token reads those runs' tiles (``token_tiles``, ``slots_to_tokens``) and
+    not a row a choice. The buffer has a row for every slot, so none on a held
     expert is ever cut: ``slots_dropped`` counts what the chunks that ran
     left out, and reads 0. The router reads what the experts read, or, given
     ``route`` from outside, whatever :meth:`route` was called on
     (``MoELayer`` under ``route_from="layer_input"``: the layer's input,
     before attention). Sows ``expert_load`` ([experts_held] slots an
-    expert), ``slots_dropped`` and ``rows_visited`` ([2]: the rows of the
-    chunks that ran, of ``T * top_k``) for the trainer's step metrics, and
+    expert), ``slots_dropped``, ``rows_visited`` ([2]: the rows of the
+    chunks that ran, of ``T * top_k``) and ``combine_rows`` ([2]: the rows in
+    the tiles one sum by token reads, of the ``T * top_k`` that a gather a
+    choice fetches) for the trainer's step metrics, and
     under ``expert_act="relu"`` ``hidden_zeros`` ([2]: the hidden activations
     ``relu(x W_gate)`` of the slots on held experts that are exactly zero, of
     all of them: the sparsity a down product could skip)."""
@@ -685,8 +906,9 @@ class ExpertShareBlock(nn.Module):
             local = sel - cfg.expert_offset * held
             is_held = (local >= 0) & (local < held)  # [t, k]
             key = jnp.where(is_held, local, held).reshape(t * k)
-            inv, load = counting_sort(key, held + 1)
+            inv, load, ends = counting_sort(key, held + 1, every=TOKEN_TILES[0] * k)
             load = load[:held]
+            runs = token_tiles(ends[:, :held], load)
             # a row of the buffer for every slot, in chunks: the last chunk may overhang
             rows = self._rows(t)
             chunks = -(-t * k // rows)
@@ -694,7 +916,7 @@ class ExpertShareBlock(nn.Module):
                 jnp.arange(t * k, dtype=jnp.int32), unique_indices=True
             )
             visited = jnp.minimum((load.sum() + rows - 1) // rows * rows, t * k)
-        return Route(weights, order, inv.reshape(t, k), is_held, load, visited)
+        return Route(weights, order, inv.reshape(t, k), is_held, load, runs, visited)
 
     def __call__(self, x, select_bias=None, route: Optional[Route] = None):
         cfg = self.cfg
@@ -708,7 +930,7 @@ class ExpertShareBlock(nn.Module):
         w_gate, w_up, w_down = (jnp.asarray(w, cfg.dtype) for w in (self.w_gate, self.w_up, self.w_down))
         y, zeros = _routed(
             self._rows(t), cfg.expert_act, tokens, route.weights.astype(tokens.dtype), route.order, route.inv,
-            route.held, load, w_gate, w_up, w_down,
+            route.held, load, route.runs, w_gate, w_up, w_down,
         )
 
         if cfg.n_shared_experts:
@@ -717,6 +939,7 @@ class ExpertShareBlock(nn.Module):
         self.sow("intermediates", "expert_load", load)
         self.sow("intermediates", "slots_dropped", jnp.maximum(load.sum() - visited, 0))
         self.sow("intermediates", "rows_visited", jnp.stack([visited, jnp.int32(t * k)]))
+        self.sow("intermediates", "combine_rows", jnp.stack([route.runs[1].sum() * TOKEN_TILES[1], jnp.int32(t * k)]))
         if zeros is not None:
             self.sow("intermediates", "hidden_zeros", jnp.stack([zeros, load.sum() * cfg.moe_d_ff]))
         return y.reshape(b, s, d)

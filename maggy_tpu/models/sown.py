@@ -88,19 +88,18 @@ def _expert_load(load, dropped):
     }
 
 
-def _rows_visited(rows):
-    visited, of = _stacked(rows, 2).sum(0)
-    return {"moe_rows_visited_share": visited / of}
+def _summed_share(key: str):
+    """[part, of] a layer -> the layers' parts over the layers' wholes, under ``key``."""
+    def reduce(rows):
+        part, of = _stacked(rows, 2).sum(0)
+        return {key: part / of}
+
+    return reduce
 
 
 def _hidden_zeros(zeros):
     per_layer = _stacked(zeros, 2).astype(jnp.float32)
     return {"moe_hidden_zero_share": jnp.mean(per_layer[:, 0] / jnp.maximum(per_layer[:, 1], 1.0))}
-
-
-def _taps_masked(taps):
-    masked, of = _stacked(taps, 2).sum(0)
-    return {"conv_taps_masked_share": masked / of}
 
 
 def _index_loss(loss):
@@ -154,14 +153,18 @@ COUNTERS: Tuple[Counter, ...] = (
     }),
     # ``ExpertShareBlock``, [visited, of] a layer: the rows of the layers' buffers that the chunks
     # that ran visited over the rows they hold (1.0: every layer worked through its whole buffer)
-    Counter(("rows_visited",), _rows_visited, {"moe_rows_visited_share": "moe.rows_visited_share"}),
+    Counter(("rows_visited",), _summed_share("moe_rows_visited_share"), {"moe_rows_visited_share": "moe.rows_visited_share"}),
+    # ``ExpertShareBlock``, [read, of] a layer: the rows of the layers' buffers that one token-side
+    # sum reads, in the tiles that hold a slot of a block of tokens (``moe.token_tiles``), over the
+    # ``T * top_k`` rows that a gather a choice fetches (1.0: the gathers' traffic)
+    Counter(("combine_rows",), _summed_share("moe_combine_rows_share"), {"moe_combine_rows_share": "moe.combine_rows_share"}),
     # ``ExpertShareBlock`` under ``expert_act="relu"``, [zeros, of] a layer: of the hidden
     # activations ``relu(x W_gate)`` of the slots on held experts those that are exactly zero (the
     # forward chunks' count), a mean over the layers: what a down product that skips zeros would save
     Counter(("hidden_zeros",), _hidden_zeros, {"moe_hidden_zero_share": "moe.hidden_zero_share"}),
     # ``ShortConv``, [masked, of] a layer: the taps zeroed at row and document starts over all
     # taps, which says that the batch's packing reached the operator
-    Counter(("taps_masked",), _taps_masked, {"conv_taps_masked_share": "conv.taps_masked_share"}),
+    Counter(("taps_masked",), _summed_share("conv_taps_masked_share"), {"conv_taps_masked_share": "conv.taps_masked_share"}),
     # ``Attention`` with ``sparse_topk``: the indexer's loss summed over the layers
     Counter(("index_aux_loss",), _index_loss, {"index_loss": "sparse.index_loss"}),
     # ``Attention`` where it selects, [pairs selected, pairs visible, queries off their count] a

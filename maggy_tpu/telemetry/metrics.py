@@ -63,6 +63,9 @@ GAUGES = frozenset(
         # rows of the layers' buffers the chunks that ran visited over the rows
         # the buffers hold (T * top_k a layer); 1.0 = the mechanism does nothing
         "moe.rows_visited_share",
+        # rows of the layers' buffers one token-side sum reads (the tiles that hold a slot of a
+        # block of tokens) over the T * top_k rows a gather a choice fetches; 1.0 = the gathers' traffic
+        "moe.combine_rows_share",
         # ReLU-gated experts (MoEConfig.expert_act "relu"): of the hidden activations
         # relu(x W_gate) of the slots on held experts, the share exactly zero, a mean over layers
         "moe.hidden_zero_share",
@@ -439,6 +442,7 @@ GAUGE_UNITS = {
     "moe.slots_dropped": "count",
     "moe.load_max_over_mean": "ratio",
     "moe.rows_visited_share": "ratio",
+    "moe.combine_rows_share": "ratio",
     "moe.hidden_zero_share": "ratio",
     "conv.taps_masked_share": "ratio",
     "sparse.selected_share": "ratio",

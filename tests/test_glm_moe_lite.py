@@ -261,6 +261,16 @@ def test_every_load_drops_nothing_and_visits_its_chunks(load_case):
     assert [int(v) for v in sown["rows_visited"][0]] == [rows, 256]
 
 
+def test_every_load_reads_the_tiles_that_hold_its_slots(load_case):
+    """128 tokens are one block of the token-side sum, and the slots on the
+    two held experts stand first in the buffer: the tiles of 16 rows up to
+    the load, of 256 rows that the two gathers fetch."""
+    _sizes, pcfg, base, xn, bias, n_slots, _rows = load_case
+    _, mods = moe.ExpertShareBlock(pcfg).apply({"params": base}, xn, bias, mutable=["intermediates"])
+    tile = moe.TOKEN_TILES[1]
+    assert [int(v) for v in mods["intermediates"]["combine_rows"][0]] == [-(-n_slots // tile) * tile, 256]
+
+
 @pytest.mark.parametrize("slots,held,n_experts,of_load,want", [
     (65536, 8, 64, 0.0, 8192),  # the GLM cell: an eighth of 16,384 x 4
     (65536, 64, 64, 0.0, 65536),  # every expert held: every slot is, one chunk
@@ -318,6 +328,89 @@ def test_grouped_kernel_in_the_interpreter_is_the_ragged_dot(sizes):
     np.testing.assert_array_equal(jnp.where(live, got_x, 0), jnp.where(live, want_x, 0))
     np.testing.assert_array_equal(got_w, want_w)
     assert moe.grouped_dot(x, w, group_sizes).shape == (m, n)  # off the TPU: ragged_dot itself
+
+
+# the token-side sum: tokens, top_k, held of experts, width, type, (tokens a block, rows a tile, rows a buffer),
+# rows of the buffer past the slots' (an overhanging last chunk), and how the choices are drawn
+TOKEN_SUMS = {
+    "float32_rows": (128, 2, 2, 8, 128, jnp.float32, (32, 8, 32), 0, "even"),
+    # every choice held, loads off the tiles: the runs of neighbouring experts share tiles, and a
+    # table that gave a shared tile to both (15 tiles for 12) sums its rows twice; six fills of the buffer
+    "every_expert_held_in_one_block": (64, 3, 4, 4, 128, jnp.bfloat16, (64, 16, 32), 0, "even"),
+    "tokens_no_multiple_of_the_block": (100, 4, 4, 4, 128, jnp.bfloat16, (32, 16, 64), 0, "even"),
+    "top_k_8": (96, 8, 3, 16, 256, jnp.bfloat16, (32, 16, 32), 48, "even"),
+    "top_k_10_one_expert_busy": (96, 10, 3, 16, 128, jnp.bfloat16, (32, 16, 32), 16, "busy"),
+    "a_block_on_one_expert": (64, 4, 2, 8, 128, jnp.bfloat16, (32, 16, 64), 0, "block_on_one"),
+    "no_slot_held": (64, 4, 2, 8, 128, jnp.bfloat16, (32, 16, 32), 0, "none_held"),
+    "an_overhanging_last_chunk": (50, 2, 2, 8, 128, jnp.float32, (16, 8, 16), 12, "even"),
+    # a buffer of two parts of ``PLACE_COLS`` columns, the second part partly filled
+    "two_parts_of_the_placement": (192, 6, 6, 8, 128, jnp.bfloat16, (128, 16, 512), 0, "even"),
+}
+
+
+@pytest.fixture(scope="module", params=list(TOKEN_SUMS))
+def token_sum_case(request):
+    """Choices, their counting sort and the kernel's table, with rows that
+    hold NaN from the load on (``_by_token``: "a row past the load may hold
+    anything")."""
+    t, k, held, n_experts, d, dtype, tiles, spare, how = TOKEN_SUMS[request.param]
+    rng = np.random.default_rng(len(request.param))
+    p = np.ones(n_experts)
+    if how == "busy":
+        p[0] = 30
+    if how == "none_held":
+        p[:held] = 1e-12
+    sel = np.argsort(-(rng.gumbel(size=(t, n_experts)) + np.log(p)), axis=1)[:, :k]
+    if how == "block_on_one":  # the second block's tokens all choose expert 1 and no other held one
+        sel[32:] = np.argsort(-rng.gumbel(size=(32, n_experts - held)), axis=1)[:, :k] + held
+        sel[32:, 2] = 1
+    is_held = sel < held
+    key = jnp.asarray(np.where(is_held, sel, held).reshape(-1).astype(np.int32))
+    inv, load, ends = moe.counting_sort(key, held + 1, every=tiles[0] * k)
+    load, inv = load[:held], inv.reshape(t, k)
+    runs = moe.token_tiles(ends[:, :held], load, tiles[1])
+    n = (-(-t * k // tiles[1]) + spare // tiles[1]) * tiles[1]
+    rows = jax.random.normal(jax.random.key(1), (n, d), jnp.float32).astype(dtype).at[int(load.sum()):].set(jnp.nan)
+    weights = jax.random.uniform(jax.random.key(2), (t, k), jnp.float32, 0.1, 1.0).astype(dtype)
+    if request.param in ("float32_rows", "top_k_8"):
+        assert not is_held.any(-1).all()  # a token with no held choice is among them
+    return rows, runs, load, inv, jnp.asarray(is_held), weights, tiles
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["combine", "dispatch_backward"])
+def test_token_sum_kernel_in_the_interpreter_is_the_gathers(token_sum_case, weighted):
+    """``slots_to_tokens`` (what a TPU runs in the place of ``_by_token``'s
+    ``top_k`` gathers), in the interpreter at small tiles, with the weights
+    (the forward's combine) and without (``d_tokens`` in the backward):
+    bfloat16 rows to the bit after the cast (a product of two bfloat16 is
+    exact in float32 and both sum in float32), float32 rows to 1e-6."""
+    rows, runs, load, inv, is_held, weights, tiles = token_sum_case
+    w = weights if weighted else None
+    got = moe.slots_to_tokens(rows, runs, load.sum(), inv, is_held, w, tiles=tiles, interpret=True)
+    want = moe._by_token(rows, inv, is_held, w).astype(rows.dtype)
+    assert got.dtype == rows.dtype and bool(jnp.isfinite(want).all())
+    if rows.dtype == jnp.bfloat16:
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_token_tiles_are_the_tiles_that_hold_a_blocks_slots(token_sum_case):
+    """The table against a count by numpy: for every block of tokens, the
+    tiles listed (expert by expert, ``count`` from ``first``) ascend, none
+    twice, and are the tiles in which a held choice of one of the block's
+    tokens has its row. A tile that two experts' runs share and that the
+    table gave to both would be summed twice."""
+    _rows, runs, load, inv, is_held, _weights, (block, tile, _width) = token_sum_case
+    first, count = np.asarray(runs)
+    inv, is_held = np.asarray(inv), np.asarray(is_held)
+    for b in range(first.shape[0]):
+        listed = [tile_ for e in range(first.shape[1]) for tile_ in range(first[b, e], first[b, e] + count[b, e])]
+        mine = slice(b * block, (b + 1) * block)
+        assert listed == sorted(set(inv[mine][is_held[mine]] // tile))
+    if first.shape == (1, 4):  # "every_expert_held_in_one_block": the case does have runs that share a tile
+        hi = np.cumsum(np.asarray(load))
+        assert int(((hi - 1) // tile - (hi - np.asarray(load)) // tile + 1).sum()) > int(count.sum()) == 12
 
 
 @pytest.mark.parametrize("case", ["random", "ties_only", "empty_held_set", "one_key_absent", "single", "none"])
@@ -493,7 +586,10 @@ def test_dense_decoder_step_is_untouched():
     assert "moe_rows_visited_share" not in out
 
 
-def test_fit_publishes_the_rows_visited_gauge_for_a_share_model_only(tiny, batch):
+@pytest.mark.parametrize("gauge,key", [
+    ("moe.rows_visited_share", "moe_rows_visited_share"), ("moe.combine_rows_share", "moe_combine_rows_share"),
+])
+def test_fit_publishes_the_rows_visited_gauge_for_a_share_model_only(tiny, batch, gauge, key):
     import optax
 
     from maggy_tpu import telemetry
@@ -507,7 +603,7 @@ def test_fit_publishes_the_rows_visited_gauge_for_a_share_model_only(tiny, batch
     ]
     class Recorder(telemetry.Telemetry):
         def gauge(self, name, value):
-            if name == "moe.rows_visited_share":
+            if name == gauge:
                 seen[-1].append(value)
             super().gauge(name, value)
 
@@ -517,8 +613,8 @@ def test_fit_publishes_the_rows_visited_gauge_for_a_share_model_only(tiny, batch
             seen.append([])
             tr = trainer_mod.Trainer(model, optax.adamw(1e-3), mesh)
             _, out = tr.fit(tr.make_state(jax.random.key(0), host), iter([host]), num_steps=1)
-            if "moe_rows_visited_share" in out:
-                assert seen[-1] == [out["moe_rows_visited_share"]]
+            if key in out:
+                assert seen[-1] == [out[key]]
     assert [len(v) for v in seen] == [1, 0]
 
 

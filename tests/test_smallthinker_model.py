@@ -332,7 +332,7 @@ def test_logits_loss_slots_and_the_share_of_zero_hidden_activations(tiny, batch,
     _, parts = jax.jit(lambda p: reference.losses(p, batch, sizes))(leaves)
     np.testing.assert_allclose(trainer_mod.lm_loss_fn(logits, batch), parts["main"], rtol=1e-5)
     counters = sown.step_counters(mods)
-    assert set(counters) == {"moe_slots", "moe_slots_dropped", "moe_load_max_over_mean", "moe_rows_visited_share",
+    assert set(counters) == {"moe_slots", "moe_slots_dropped", "moe_load_max_over_mean", "moe_rows_visited_share", "moe_combine_rows_share",
                              "moe_hidden_zero_share", "window_pairs_share"}
     assert float(counters["moe_slots"]) == float(parts["slots"]) > 0 and float(counters["moe_slots_dropped"]) == 0
     np.testing.assert_allclose(counters["moe_hidden_zero_share"], parts["hidden_zero_share"], rtol=1e-6)

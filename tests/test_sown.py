@@ -29,7 +29,7 @@ from maggy_tpu.train import trainer as trainer_mod  # noqa: E402
 
 KIND = "train_packed_ref"
 S = 128
-EXPERT = {"moe_slots", "moe_slots_dropped", "moe_load_max_over_mean", "moe_rows_visited_share"}
+EXPERT = {"moe_slots", "moe_slots_dropped", "moe_load_max_over_mean", "moe_rows_visited_share", "moe_combine_rows_share"}
 # what each architecture's step reports beside its losses, at the sizes of benchmark/checks/tiny.<name>.json
 REPORTS = {
     "glm-4.7-flash": EXPERT,

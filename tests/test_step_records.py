@@ -175,7 +175,7 @@ def test_last_spans_slots_are_the_calls_own(moe_run):
 @limit(240)
 def test_every_step_counter_is_an_attribute_of_every_step(moe_run):
     counters = set(COUNTER_GAUGES) & set(moe_run.out)
-    assert {"moe_slots", "moe_slots_dropped", "moe_load_max_over_mean", "moe_rows_visited_share"} <= counters
+    assert {"moe_slots", "moe_slots_dropped", "moe_load_max_over_mean", "moe_rows_visited_share", "moe_combine_rows_share"} <= counters
     for r in moe_run.spans("train.step_device"):
         assert counters <= set(r["attrs"])
 

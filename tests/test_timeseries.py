@@ -296,7 +296,7 @@ def test_every_metric_has_a_unit():
 
 @pytest.mark.parametrize("name,unit", [
     ("moe.slots", "count"), ("moe.slots_dropped", "count"), ("moe.load_max_over_mean", "ratio"),
-    ("moe.rows_visited_share", "ratio"),
+    ("moe.rows_visited_share", "ratio"), ("moe.combine_rows_share", "ratio"),
 ])
 def test_expert_share_gauges_are_registered_with_their_units(name, unit):
     """What ``Trainer.fit`` publishes for a model with expert share layers."""

@@ -619,3 +619,59 @@ def test_head_and_loss_in_blocks_keep_no_whole_float32_logits(one_chip, s, d, vo
     whole = [m for m in re.findall(r"f32\[([\d,]+)\]", text) if {str(vocab)} <= set(m.split(",")) and {str(s), str(s - 1)} & set(m.split(","))]
     assert not whole
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * vocab * 4 + 3 * d * vocab * 4
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["combine", "dispatch_backward"])
+@pytest.mark.parametrize(
+    "t,k,d,held,rows", [(32768, 8, 2048, 16, 11 * 24576), (8192, 10, 3072, 8, 8 * 10240)], ids=["sdar-keye", "laguna"]
+)
+def test_token_sum_kernel_compiles_at_the_cells_shapes(one_chip, t, k, d, held, rows, weighted):
+    """``slots_to_tokens`` at ``TOKEN_TILES``, with the weights (the combine)
+    and without (``d_tokens``), at the SDAR and Keye cells' layer (32,768
+    tokens x 8 choices of width 2,048, 16 experts held, a buffer of eleven
+    chunks of 24,576 rows) and the Laguna cell's (8,192 x 10 of 3,072, 8
+    held): the buffer's halves, the sum and the placement fit VMEM, and the
+    DMAs' slices lie on the tiling."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(buffer, runs, total, inv, is_held, weights):
+        return moe.slots_to_tokens(buffer, runs, total, inv, is_held, weights if weighted else None)
+
+    blocks = -(-t // moe.TOKEN_TILES[0])
+    lowered = jax.jit(step).lower(
+        sds((rows, d), jnp.bfloat16), sds((2, blocks, held), jnp.int32), sds((), jnp.int32), sds((t, k), jnp.int32),
+        sds((t, k), jnp.bool_), sds((t, k), jnp.bfloat16),
+    )
+    assert lowered.out_info.shape == (t, d)
+    assert kernel_calls(lowered.compile().as_text(), "slots_to_tokens") == {"slots_to_tokens": 1}
+
+
+def test_sdar_size_share_layer_gathers_no_row_a_token(one_chip, monkeypatch):
+    """One ``ExpertShareBlock`` at the SDAR cell's size (32,768 positions, 8
+    of 128 experts a token, 16 held, chunks of three quarters of the expected
+    load), value and gradient, steered onto the TPU's branches: the token-side
+    sums are the kernel, once in the forward and once in the backward, and no
+    gather under ``moe.combine`` or ``moe.dispatch`` gives ``[T, d_model]``
+    (``_by_token`` gave eight each way)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, d = 32768, 2048
+    cfg = moe.MoEConfig(
+        vocab_size=1024, d_model=d, n_layers=1, n_heads=32, n_kv_heads=4, d_ff=768, moe_d_ff=768, n_experts=128,
+        top_k=8, experts_held=16, router="softmax", chunk_of_load=0.75, dtype=jnp.bfloat16, max_seq_len=t,
+    )
+    block = moe.ExpertShareBlock(cfg)
+    x = jax.ShapeDtypeStruct((1, t, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(lambda: block.init(jax.random.key(0), jnp.zeros((1, 8, d), jnp.bfloat16)))["params"]
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), nn.meta.unbox(params))
+
+    def step(params, x):
+        return jax.value_and_grad(lambda p, x: block.apply({"params": p}, x).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    text = jax.jit(step).lower(params, x).compile().as_text()
+    assert kernel_calls(text, "slots_to_tokens") == {"slots_to_tokens": 2}
+    by_token = [
+        line for line in text.splitlines()
+        if re.search(r"= \w+\[%d,%d\]\S* gather\(" % (t, d), line) and re.search(r"moe\.(combine|dispatch)", line)
+    ]
+    assert not by_token
