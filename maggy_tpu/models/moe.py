@@ -83,6 +83,14 @@ class MoEConfig(DecoderConfig):
     # renormalised over themselves and times ``routed_scaling``; no bias).
     # Either takes a shared expert beside it (``n_shared_experts``)
     router: str = "sigmoid"
+    # the sigmoid router's group limit (DeepSeek-V3's ``n_group`` and
+    # ``topk_group``): the experts in ``n_group`` groups of neighbours, of which
+    # a token keeps the ``topk_group`` best (by the sum of a group's two largest
+    # biased scores) and chooses its ``top_k`` inside them; 1: no limit. Where
+    # a group is a node the limit bounds a token's exchange; on one chip it
+    # only shapes the selection (the exchange is ROADMAP M1)
+    n_group: int = 1
+    topk_group: int = 1
     # what the share form's router reads: "mlp_norm", the layer's residual
     # after attention under ``mlp_norm`` (what the experts read), or
     # "layer_input", the residual as it enters the layer, before ``attn_norm``
@@ -190,6 +198,16 @@ class MoEConfig(DecoderConfig):
                 raise ValueError("router is 'sigmoid' or 'softmax'")
             if self.router == "softmax" and self.select_bias_std:
                 raise ValueError("the softmax router takes no selection bias")
+            if self.n_group > 1 and (
+                self.router != "sigmoid" or self.n_experts % self.n_group or not 1 <= self.topk_group <= self.n_group
+                or self.n_experts // self.n_group < 2 or self.topk_group * (self.n_experts // self.n_group) < self.top_k
+            ):
+                raise ValueError(
+                    "a group limit is the sigmoid router's: n_group divides n_experts into groups of at least two, "
+                    "and the topk_group groups kept hold at least top_k experts"
+                )
+        if self.n_group < 1 or (self.n_group > 1 and not self.experts_held):
+            raise ValueError("n_group >= 1, and a group limit is the share form's")
         if self.route_from not in ("mlp_norm", "layer_input") or self.expert_act not in EXPERT_ACTS:
             raise ValueError(f"route_from is 'mlp_norm' or 'layer_input', expert_act one of {sorted(EXPERT_ACTS)}")
         if self.route_from == "layer_input" and (not self.experts_held or self.decode):
@@ -786,15 +804,28 @@ def _routed_bwd(rows, act, res, g):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-def sigmoid_route(logits, select_bias, top_k: int, scaling: float, norm_eps: float = 1e-20):
-    """DeepSeek-V3's router (``noaux_tc`` without a group limit) from float32
-    logits [..., n_experts]: ``(sel [..., k] expert numbers, weights [..., k])``
-    with ``s = sigmoid(logits)``, ``sel = top_k(s + select_bias)`` (the bias
-    enters the selection only and gets no gradient) and the chosen scores
-    normalised over themselves (their sum plus ``norm_eps``) and scaled."""
+def sigmoid_route(
+    logits, select_bias, top_k: int, scaling: float, norm_eps: float = 1e-20, n_group: int = 1, topk_group: int = 1,
+):
+    """DeepSeek-V3's router (``noaux_tc``) from float32 logits [..., n_experts]:
+    ``(sel [..., k] expert numbers, weights [..., k])`` with ``s =
+    sigmoid(logits)``, ``sel = top_k(s + select_bias)`` (the bias enters the
+    selection only and gets no gradient) and the chosen scores normalised over
+    themselves (their sum plus ``norm_eps``) and scaled. With ``n_group`` > 1
+    the selection is group-limited: the experts stand in ``n_group`` groups of
+    neighbours, a group's score is the sum of its two largest biased scores,
+    the ``topk_group`` best groups stay and the ``top_k`` are chosen inside
+    them. ``n_group`` 1 is the selection over all the experts, as it was."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     biased = scores if select_bias is None else scores + select_bias
-    _, sel = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)
+    biased = jax.lax.stop_gradient(biased)
+    if n_group > 1:
+        grouped = biased.reshape(*biased.shape[:-1], n_group, -1)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)  # [..., n_group]
+        _, kept = jax.lax.top_k(group_score, topk_group)
+        stays = (kept[..., None] == jnp.arange(n_group)).any(-2)  # [..., n_group]
+        biased = jnp.where(stays[..., None], grouped, -jnp.inf).reshape(biased.shape)
+    _, sel = jax.lax.top_k(biased, top_k)
     chosen = jnp.take_along_axis(scores, sel, axis=-1)
     return sel, scaling * chosen / (chosen.sum(-1, keepdims=True) + norm_eps)
 
@@ -899,7 +930,7 @@ class ExpertShareBlock(nn.Module):
                 sel, weights = softmax_route(logits, k, cfg.routed_scaling)
             else:
                 sel, weights = sigmoid_route(
-                    logits, select_bias, k, cfg.routed_scaling, cfg.route_norm_eps
+                    logits, select_bias, k, cfg.routed_scaling, cfg.route_norm_eps, cfg.n_group, cfg.topk_group
                 )  # [t, k]
 
         with jax.named_scope("moe.dispatch"):

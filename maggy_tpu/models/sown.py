@@ -132,6 +132,11 @@ def _diffusion_counts(masked, pairs):
     }
 
 
+def _kda_counts(counts):
+    cut, chunks, decay, of = _stacked(counts, 4).astype(jnp.float32).sum(0)
+    return {"kda_chunks_cut_share": cut / jnp.maximum(chunks, 1.0), "kda_log_decay_mean": decay / jnp.maximum(of, 1.0)}
+
+
 class Counter(NamedTuple):
     """One row: the names a layer sows, what the step makes of every layer's
     leaves under them (one list a name, in order), and for each key of that in
@@ -165,6 +170,14 @@ COUNTERS: Tuple[Counter, ...] = (
     # ``ShortConv``, [masked, of] a layer: the taps zeroed at row and document starts over all
     # taps, which says that the batch's packing reached the operator
     Counter(("taps_masked",), _summed_share("conv_taps_masked_share"), {"conv_taps_masked_share": "conv.taps_masked_share"}),
+    # ``KDA`` (models/transformer.py), [chunks with a document start inside, chunks, the sum of the
+    # log decays ``a``, their count] a layer: the chunks of the delta rule that a document's start
+    # cuts over all chunks, which says that the packing reached the recurrence, and the mean log
+    # decay over tokens, channels and layers (between ``kda_decay_floor`` and 0: how long the
+    # layers remember)
+    Counter(("kda_counts",), _kda_counts, {
+        "kda_chunks_cut_share": "kda.chunks_cut_share", "kda_log_decay_mean": "kda.log_decay_mean",
+    }),
     # ``Attention`` with ``sparse_topk``: the indexer's loss summed over the layers
     Counter(("index_aux_loss",), _index_loss, {"index_loss": "sparse.index_loss"}),
     # ``Attention`` where it selects, [pairs selected, pairs visible, queries off their count] a

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from maggy_tpu.models import head
 from maggy_tpu.ops import attention as ops_attn
 from maggy_tpu.ops import blockdiff, eva, sparse_select
+from maggy_tpu.ops import kda as ops_kda
 from maggy_tpu.ops.flash import (
     FLASH_RESIDUALS,
     flash_attention,
@@ -62,7 +63,9 @@ Dtype = Any
 # Close to the device's memory the compiler makes the room itself, by
 # computing other values twice (PERF.md section 6, PR 29: three matmuls, 14 ms
 # of the 30 the kernel's replay had cost in the GLM cell).
-KEPT_RESIDUALS = (*FLASH_RESIDUALS, *sparse_select.SPARSE_RESIDUALS)
+# A "kda" layer keeps its operator's output (``ops.kda.KDA_RESIDUALS``): its
+# replay then runs no recurrence.
+KEPT_RESIDUALS = (*FLASH_RESIDUALS, *sparse_select.SPARSE_RESIDUALS, *ops_kda.KDA_RESIDUALS)
 REMAT_POLICIES = {
     "nothing": jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS),
     "dots": jax.checkpoint_policies.save_from_both_policies(
@@ -73,7 +76,9 @@ REMAT_POLICIES = {
 }
 
 
-LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "eva_attention")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "eva_attention", "kda", "latent_attention")
+# the kinds whose operator is no softmax attention: no window, no tile of a flash grid
+STATE_KINDS = ("conv", "kda")
 
 
 def _parse_ablated(ablated, n_layers: int):
@@ -175,8 +180,12 @@ class DecoderConfig:
     # kv_lora_rank > 0 the layers' attention is LatentAttention — low-rank
     # query and key-value paths with an inner norm each, rope on a
     # qk_rope_head_dim-wide part whose key is one head shared by all, and
-    # heads of qk_nope_head_dim + qk_rope_head_dim (= v_head_dim), whatever
-    # d_model / n_heads is. n_kv_heads is n_heads there
+    # heads of qk_nope_head_dim + qk_rope_head_dim, whatever d_model / n_heads
+    # is. n_kv_heads is n_heads there. q_lora_rank 0: the query is one
+    # full-rank product ``wq`` with no inner norm. A v_head_dim other than the
+    # query's width goes through the kernels padded with zeros to one width
+    # (``padded_attention``). In ``layer_types`` the
+    # kind "latent_attention" names such a layer beside layers of other kinds
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -189,6 +198,19 @@ class DecoderConfig:
     # would live beside the KV pages: ROADMAP M4)
     layer_types: tuple = ()
     conv_kernel: int = 3
+    # a "kda" layer (``layer_types``; :class:`KDA`, ``ops/kda.py``): Kimi delta
+    # attention, ``n_heads`` heads of ``kda_head_dim`` (keys and values alike)
+    # behind causal depthwise convolutions of ``kda_conv_kernel`` taps, a log
+    # decay a channel bounded below by ``kda_decay_floor`` (``floor *
+    # sigmoid(...)``), the chunked delta rule at ``kda_chunk`` positions a
+    # chunk (its backward makes the chunk states again). Training and
+    # scoring only: its decode state (a
+    # ``[d_k, d_v]`` matrix a head and the convolutions' tails, a snapshot a
+    # sequence and not pages) is not written (ROADMAP M4)
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_decay_floor: float = -5.0
+    kda_chunk: int = ops_kda.CHUNK
     # an RMSNorm over the head's width on every query and key head before the
     # rotary embedding (:class:`Attention` only)
     qk_norm: bool = False
@@ -304,7 +326,7 @@ class DecoderConfig:
     def attention_windows(self) -> tuple:
         """The window of every attention layer (0: none), in order."""
         return tuple(
-            self.attention_form(kind)[1] for kind in self.layer_kinds() if kind != "conv"
+            self.attention_form(kind)[1] for kind in self.layer_kinds() if kind not in STATE_KINDS
         )
 
     def tiles_visited_share(self, segment_ids) -> Optional[float]:
@@ -319,7 +341,7 @@ class DecoderConfig:
         where any form's tiles do not divide the row."""
         if self.stream_block:  # every layer the two grids of the block-wise mask
             return blockdiff.tiles_visited_share(segment_ids, block=self.stream_block, head_dim=self.head_dim)
-        kinds = [kind for kind in self.layer_kinds() if kind != "conv"]
+        kinds = [kind for kind in self.layer_kinds() if kind not in STATE_KINDS]
         forms = [  # (window, chunk) a layer; chunk 0: no summaries
             (self.eva_window, self.eva_chunk) if kind == "eva_attention" else (window, 0)
             for kind, window in zip(kinds, self.attention_windows())
@@ -339,16 +361,10 @@ class DecoderConfig:
 
     def __post_init__(self):
         if self.kv_lora_rank:
-            if not self.q_lora_rank or self.n_kv_heads != self.n_heads:
-                raise ValueError(
-                    "latent attention needs q_lora_rank and n_kv_heads == n_heads"
-                )
-            if self.v_head_dim != self.head_dim or self.qk_rope_head_dim % 2:
-                raise ValueError(
-                    "latent attention takes v_head_dim == qk_nope_head_dim + "
-                    "qk_rope_head_dim (one width through the kernels) and an "
-                    "even qk_rope_head_dim"
-                )
+            if self.q_lora_rank < 0 or self.n_kv_heads != self.n_heads:
+                raise ValueError("latent attention needs n_kv_heads == n_heads (q_lora_rank 0: a full-rank query)")
+            if self.v_head_dim < 1 or self.qk_rope_head_dim < 2 or self.qk_rope_head_dim % 2:
+                raise ValueError("latent attention takes a v_head_dim and an even qk_rope_head_dim")
             if self.decode:
                 raise ValueError("latent attention has no decode cache yet")
         elif not self.head_width and self.d_model % self.n_heads:
@@ -367,6 +383,21 @@ class DecoderConfig:
                     "a conv layer has no decode state yet (the convolution's tail "
                     "beside the KV cache): this model trains and scores, it does not serve"
                 )
+            if "latent_attention" in self.layer_types and not self.kv_lora_rank:
+                raise ValueError("a latent_attention layer needs kv_lora_rank and the head's three widths")
+            if "kda" in self.layer_types:
+                if self.kda_head_dim < 1 or self.kda_conv_kernel < 1 or self.kda_decay_floor >= 0:
+                    raise ValueError("a kda layer needs kda_head_dim, kda_conv_kernel >= 1 and a kda_decay_floor below 0")
+                chunk = self.kda_chunk
+                if chunk < 1 or (chunk > ops_kda.SUB_CHUNK and chunk % ops_kda.SUB_CHUNK):
+                    raise ValueError(f"kda_chunk is at most {ops_kda.SUB_CHUNK} or a multiple of it")
+                if -self.kda_decay_floor * (min(chunk, ops_kda.SUB_CHUNK) - 1) > ops_kda.MAX_EXPONENT:
+                    raise ValueError("kda_decay_floor: a sub-chunk's decays must stay inside float32 (ops/kda.py)")
+                if self.decode:
+                    raise ValueError(
+                        "a kda layer has a training form only: its state a head and the convolutions' "
+                        "tails have no decode cache yet (ROADMAP M4)"
+                    )
             if "sliding_attention" in self.layer_types:
                 if self.sliding_window < 1:
                     raise ValueError("a sliding_attention layer needs sliding_window")
@@ -788,7 +819,7 @@ def flash_tileable(sq: int, sk: int, d: int) -> Optional[str]:
 
 def record_attention_kernel(
     kernel: str, q, k, segment_ids, reason: str = "", selected: int = 0, window: int = 0, chunk: int = 0,
-    block: int = 0,
+    block: int = 0, true_widths: tuple = (),
 ):
     """Journal which kernel the automatic dispatch chose for this shape as
     one ``attention.kernel`` event; for the flash kernels also the tiles they
@@ -816,6 +847,10 @@ def record_attention_kernel(
     causal bound a query), and ``own_block``, how the noised queries' own
     block is computed (``kernel``: the band kernels beside the flash calls;
     ``xla``: inside the explicit mask); ``q`` and ``kv`` are then one stream's.
+    A call whose heads were padded with zeros to a width the kernels take
+    (``padded_attention``; ``true_widths``: the query's and the value's own)
+    says ``lanes`` ``padded`` with ``qk_width`` and ``v_width`` beside
+    ``head_dim``, the width the kernels run at.
     The dispatch runs at trace time, so events count traces (init, forward, a
     rematerialized backward), never steps."""
     from maggy_tpu import telemetry
@@ -839,6 +874,8 @@ def record_attention_kernel(
             tiles = eva.tiles(q.shape[1], window, chunk, q.shape[3])
             blocks, attrs["remote_blocks"] = tiles["local"], list(tiles["remote"])
         attrs.update(zip(("block_q", "block_k", "bwd_block_q", "bwd_block_k"), blocks))
+    if true_widths:
+        attrs.update(lanes="padded", qk_width=int(true_widths[0]), v_width=int(true_widths[1]))
     telemetry.get().event(
         "attention.kernel", kernel=kernel, reason=reason,
         q=list(q.shape), kv=list(k.shape), segmented=segment_ids is not None,
@@ -848,7 +885,7 @@ def record_attention_kernel(
 
 def auto_attention(
     q, k, v, *, causal: bool = True, segment_ids=None, selected=None, reselect=None, topk: int = 0,
-    return_lse: bool = False, window: int = 0,
+    return_lse: bool = False, window: int = 0, true_widths: tuple = (),
 ):
     """Pick the fastest correct kernel for the backend/shape: the Pallas flash
     kernel (fwd+bwd) on TPU when the geometry tiles onto the MXU
@@ -881,9 +918,11 @@ def auto_attention(
     anyway, and None on every other path (the caller normalises by itself).
     ``window`` (a sliding layer's; 0: none) masks the same pairs on every
     path: ``t - s < window`` beside the causal and the segment masks, and in
-    the kernels the visit table's second bound (``ops/flash.py``)."""
+    the kernels the visit table's second bound (``ops/flash.py``).
+    ``true_widths``: the event's only (``record_attention_kernel``)."""
     from maggy_tpu.parallel.mesh import ambient_mesh
 
+    record = functools.partial(record_attention_kernel, true_widths=true_widths)
     masks = {} if selected is None else {"selected": selected}  # beside the causal and the segment masks
     if window:
         masks["window"] = window
@@ -891,7 +930,7 @@ def auto_attention(
     if why is None:
         mesh = ambient_mesh()
         if mesh is None or mesh.size == 1:
-            record_attention_kernel("flash", q, k, segment_ids, selected=topk, window=window)
+            record("flash", q, k, segment_ids, selected=topk, window=window)
             return flash_attention(
                 q, k, v, causal=causal, segment_ids=segment_ids, return_lse=return_lse, reselect=reselect, **masks
             )
@@ -899,10 +938,10 @@ def auto_attention(
             q, k, v, mesh=mesh, causal=causal, segment_ids=segment_ids, reselect=reselect, **masks
         )
         if out is not None:
-            record_attention_kernel("flash_sharded", q, k, segment_ids, selected=topk, window=window)
+            record("flash_sharded", q, k, segment_ids, selected=topk, window=window)
             return (out, None) if return_lse else out
         why = f"mesh {dict(mesh.shape)} does not divide batch/heads or uses seq/stage axes"
-    record_attention_kernel("xla_dense", q, k, segment_ids, why, selected=topk, window=window)
+    record("xla_dense", q, k, segment_ids, why, selected=topk, window=window)
     out = default_attention(q, k, v, causal=causal, segment_ids=segment_ids, **masks)
     return (out, None) if return_lse else out
 
@@ -966,6 +1005,24 @@ def auto_blockdiff_attention(q, k, v, positions, segment_ids, lay, *, block: int
     return jnp.concatenate([out_c, out_n], axis=1)
 
 
+def padded_attention(attn, q, k, v, segment_ids=None):
+    """Causal attention for heads the flash kernels do not take as they are
+    (a query and key width that is no multiple of 128, or a value width of its
+    own: 192 beside 128): q, k and v padded with zeros to one width that fills
+    the lanes, the result cut to the value's. Zeros add nothing to a score and
+    give zero output columns; the kernels divide the scores by the root of the
+    width they see, so q is scaled by ``sqrt(padded / true)`` first. What the
+    padding costs is the kernels' time over the true pairs (the benchmark
+    books the pairs at the true widths). ``attn``: ``auto_attention`` or a
+    function of its signature."""
+    dq, dv = q.shape[-1], v.shape[-1]
+    width = -(-max(dq, dv) // 128) * 128
+    pad = lambda a: jnp.pad(a, ((0, 0),) * 3 + ((0, width - a.shape[-1]),))
+    q = (q.astype(jnp.float32) * (width / dq) ** 0.5).astype(q.dtype)
+    said = {"true_widths": (dq, dv)} if attn is auto_attention else {}
+    return attn(pad(q), pad(k), pad(v), causal=True, segment_ids=segment_ids, **said)[..., :dv]
+
+
 def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selected=None, window: int = 0, bound=None):
     """Reference soft-max attention: q [B,S,H,D], k/v [B,S,Kh,D] with GQA
     head-group broadcast. fp32 logits/softmax for stability. ``selected``
@@ -996,6 +1053,15 @@ def default_attention(q, k, v, *, causal: bool = True, segment_ids=None, selecte
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
     return out.reshape(b, sq, h, d)
+
+
+def head_gate(cfg: DecoderConfig, x, out, n_heads: int):
+    """``out`` [B, S, H, D] times one sigmoid a head and token, from a
+    bias-free projection ``w_head_gate`` of the layer's normed input ``x``
+    (scope ``attn.gate``). Called inside the operator's ``nn.compact`` method."""
+    with jax.named_scope("attn.gate"):  # one scalar a head and token, on the head's output before wo
+        gate = _dense(n_heads, ("embed", "heads"), cfg, "w_head_gate")(x)
+        return (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]).astype(cfg.dtype)
 
 
 class Attention(nn.Module):
@@ -1056,9 +1122,7 @@ class Attention(nn.Module):
             attn = cfg.attention_fn or auto_attention
             out = attn(q, k, v, causal=True, segment_ids=segment_ids)
         if cfg.attn_gate:
-            with jax.named_scope("attn.gate"):  # one scalar a head and token, on the head's output before wo
-                gate = _dense(n_heads, ("embed", "heads"), cfg, "w_head_gate")(x)
-                out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]).astype(cfg.dtype)
+            out = head_gate(cfg, x, out, n_heads)
         out = nn.DenseGeneral(
             features=cfg.d_model,
             axis=(-2, -1),
@@ -1442,7 +1506,11 @@ class LatentAttention(nn.Module):
     norm(c_kv) W_kvb``; each head's query is ``[q_nope ; rope(q_rope)]`` and
     its key ``[k_nope ; rope(k_r)]`` with the one rope key broadcast over the
     heads. The heads then go through the same dispatch as :class:`Attention`'s
-    (``auto_attention``: the flash kernels at the head's full width)."""
+    (``auto_attention``: the flash kernels at the head's full width; padded to
+    it where the value's width is its own, ``padded_attention``). With
+    ``q_lora_rank`` 0 the query is one product ``wq`` and has no inner norm;
+    with ``attn_gate`` a sigmoid gate a head and token on the heads' outputs
+    before ``wo``, as :class:`Attention`'s."""
 
     cfg: DecoderConfig
 
@@ -1452,10 +1520,13 @@ class LatentAttention(nn.Module):
         h, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         # device-side scopes (telemetry/metrics.py SCOPES): metadata only
         with jax.named_scope("mla.q"):
-            c_q = RMSNorm(cfg, name="q_norm")(
-                _dense(cfg.q_lora_rank, ("embed", None), cfg, "wq_a")(x)
-            )
-            q = _dense((h, dn + dr), (None, "heads", None), cfg, "wq_b")(c_q)
+            if cfg.q_lora_rank:
+                c_q = RMSNorm(cfg, name="q_norm")(
+                    _dense(cfg.q_lora_rank, ("embed", None), cfg, "wq_a")(x)
+                )
+                q = _dense((h, dn + dr), (None, "heads", None), cfg, "wq_b")(c_q)
+            else:
+                q = _dense((h, dn + dr), ("embed", "heads", None), cfg, "wq")(x)
         with jax.named_scope("mla.kv"):
             c_kv = _dense(cfg.kv_lora_rank + dr, ("embed", None), cfg, "wkv_a")(x)
             kv = _dense(
@@ -1473,7 +1544,12 @@ class LatentAttention(nn.Module):
                 axis=-1,
             )
         attn = cfg.attention_fn or auto_attention
-        out = attn(q, k, kv[..., dn:], causal=True, segment_ids=segment_ids)
+        if cfg.v_head_dim == dn + dr:  # one width: the call as it always was
+            out = attn(q, k, kv[..., dn:], causal=True, segment_ids=segment_ids)
+        else:
+            out = padded_attention(attn, q, k, kv[..., dn:], segment_ids)
+        if cfg.attn_gate:
+            out = head_gate(cfg, x, out, h)
         return nn.DenseGeneral(
             features=cfg.d_model,
             axis=(-2, -1),
@@ -1485,6 +1561,28 @@ class LatentAttention(nn.Module):
             ),
             name="wo",
         )(out)
+
+
+def causal_taps(z, w, segment_ids=None):
+    """A depthwise causal convolution of ``z`` [B, S, C] (float32) with
+    ``w`` [K, C]: ``c_t = sum over j < K of w[K-1-j] * z[t-j]``, the last tap
+    on the current position. A tap that would reach before the row's start, or
+    with ``segment_ids`` into the previous document of a packed row, is zero.
+    ``(c, taps zeroed)``, the count over ``B * S * K`` taps (int32)."""
+    taps, s = w.shape[0], z.shape[1]
+    c = w[taps - 1] * z
+    masked = jnp.int32(0)
+    for j in range(1, min(taps, s)):
+        back = jnp.pad(z[:, : s - j], ((0, 0), (j, 0), (0, 0)))
+        inside = jnp.arange(s)[None, :] >= j
+        if segment_ids is not None:
+            inside = inside & (
+                jnp.pad(segment_ids[:, : s - j], ((0, 0), (j, 0))) == segment_ids
+            )
+            back = jnp.where(inside[..., None], back, 0.0)
+        masked = masked + jnp.sum(~jnp.broadcast_to(inside, z.shape[:2]))
+        c = c + w[taps - 1 - j] * back
+    return c, masked
 
 
 class ShortConv(nn.Module):
@@ -1519,20 +1617,7 @@ class ShortConv(nn.Module):
         )
         with jax.named_scope("conv.mix"):
             gate_in, gate_out, u = (bcx[..., i, :].astype(jnp.float32) for i in range(3))
-            z = gate_in * u
-            w32 = w.astype(jnp.float32)
-            c = w32[taps - 1] * z
-            masked = jnp.int32(0)
-            for j in range(1, min(taps, s)):
-                back = jnp.pad(z[:, : s - j], ((0, 0), (j, 0), (0, 0)))
-                inside = jnp.arange(s)[None, :] >= j
-                if segment_ids is not None:
-                    inside = inside & (
-                        jnp.pad(segment_ids[:, : s - j], ((0, 0), (j, 0))) == segment_ids
-                    )
-                    back = jnp.where(inside[..., None], back, 0.0)
-                masked = masked + jnp.sum(~jnp.broadcast_to(inside, x.shape[:2]))
-                c = c + w32[taps - 1 - j] * back
+            c, masked = causal_taps(gate_in * u, w.astype(jnp.float32), segment_ids)
             y = (gate_out * c).astype(cfg.dtype)
         self.sow(
             "intermediates", "taps_masked",
@@ -1542,6 +1627,103 @@ class ShortConv(nn.Module):
             return _dense(d, ("channels", "embed"), cfg, "out_proj")(y)
 
 
+def _kda_rate_init(key, shape, dtype=jnp.float32):
+    """``A_log`` as the flash-linear-attention layer draws it: the log of U(1, 16)."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+
+
+def _kda_dt_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias`` as that layer draws it: the inverse softplus of a step log-uniform in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+class KDA(nn.Module):
+    """Kimi delta attention (Kimi Linear, arXiv:2510.26692; ``ops/kda.py``),
+    the operator of a "kda" layer, on the normed input ``u``: ``q~, k~, v~ =
+    u W_q, u W_k, u W_v`` in ``n_heads`` heads of ``kda_head_dim``; each goes
+    through a depthwise causal convolution of ``kda_conv_kernel`` taps
+    (``causal_taps``: a tap that would reach into the previous document is
+    zero) and a SiLU; ``q = l2norm(q) / sqrt(d)``, ``k = l2norm(k)`` over the
+    head's width; ``a = kda_decay_floor * sigmoid(exp(A_log_h) * (u W_f +
+    dt_bias))`` a channel and ``beta = sigmoid(u W_beta)`` a head, float32;
+    ``o = kda(q, k, v, a, beta)``, the delta rule with a state a head that
+    starts at zero with every document; ``y = W_o(sigmoid(u W_g) *
+    RMSNorm_head(o))``, one gate a head. No positions enter it. Five named
+    scopes: ``kda.in_proj`` (the six products), ``kda.conv`` (taps, SiLU, the
+    norms of q and k), ``kda.gate`` (``a``, ``beta``), ``kda.scan`` (all of
+    ``ops/kda.py``) and ``kda.out`` (norm, gate, ``W_o``). Sows ``taps_masked``
+    as :class:`ShortConv` does ([2]: taps zeroed at row and document starts of
+    one stream's ``B * S * K``) and ``kda_counts`` ([4] float32: chunks with a
+    document start inside, chunks, the sum of ``a`` and its count) for the
+    trainer's step metrics; journals ``kda.kernel`` once a trace. The four
+    wide projections and ``wo`` take ``_projection``'s backward rule as
+    :class:`Attention`'s do (``stacked``: see there)."""
+
+    cfg: DecoderConfig
+    stacked: bool = False
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids=None):
+        cfg = self.cfg
+        if cfg.decode:
+            raise NotImplementedError("KDA has no decode state (DecoderConfig refuses it)")
+        h, d, taps = cfg.n_heads, cfg.kda_head_dim, cfg.kda_conv_kernel
+        b, s = x.shape[:2]
+        with jax.named_scope("kda.in_proj"):
+            q, k, v, f = (
+                _dense((h, d), ("embed", "heads", None), cfg, name, head_dot_general)(x) for name in ("wq", "wk", "wv", "wf")
+            )
+            beta = _dense(h, ("embed", "heads"), cfg, "w_beta")(x)
+            gate = _dense(h, ("embed", "heads"), cfg, "w_head_gate")(x)
+        conv = {
+            name: self.param(
+                name, _partitioned(nn.initializers.normal(stddev=0.02), (None, "channels"), cfg), (taps, h * d),
+                cfg.param_dtype,
+            )
+            for name in ("q_conv", "k_conv", "v_conv")
+        }
+        with jax.named_scope("kda.conv"):
+            def stream(z, name):
+                c, masked = causal_taps(z.reshape(b, s, h * d).astype(jnp.float32), conv[name].astype(jnp.float32), segment_ids)
+                return nn.silu(c).reshape(b, s, h, d), masked
+
+            unit = lambda z: z * jax.lax.rsqrt(jnp.sum(z * z, axis=-1, keepdims=True) + 1e-6)
+            (q, masked), (k, _), (v, _) = stream(q, "q_conv"), stream(k, "k_conv"), stream(v, "v_conv")
+            q, k, v = (unit(q) * d**-0.5).astype(cfg.dtype), unit(k).astype(cfg.dtype), v.astype(cfg.dtype)
+        self.sow("intermediates", "taps_masked", jnp.stack([masked, jnp.int32(b * s * taps)]))
+        a_log = self.param("A_log", _partitioned(_kda_rate_init, ("heads",), cfg), (h,), cfg.param_dtype)
+        dt_bias = self.param("dt_bias", _partitioned(_kda_dt_bias_init, ("heads", None), cfg), (h, d), cfg.param_dtype)
+        with jax.named_scope("kda.gate"):
+            rate = jnp.exp(a_log.astype(jnp.float32))[:, None]
+            a = cfg.kda_decay_floor * jax.nn.sigmoid(rate * (f.astype(jnp.float32) + dt_bias.astype(jnp.float32)))
+            beta = jax.nn.sigmoid(beta.astype(jnp.float32))
+        with jax.named_scope("kda.scan"):
+            form = ops_kda.kernel_form(cfg.kda_chunk, d, d, q.dtype)
+            o = ops_kda.kda(q, k, v, a, beta, segment_ids, cfg.kda_chunk, form=form)
+        ids = jnp.ones((b, s), jnp.int32) if segment_ids is None else segment_ids
+        self.sow(
+            "intermediates", "kda_counts",
+            jnp.concatenate([ops_kda.chunks_cut(ids, cfg.kda_chunk).astype(jnp.float32), jnp.stack([a.sum(), jnp.float32(a.size)])]),
+        )
+        from maggy_tpu import telemetry
+
+        telemetry.get().event("kda.kernel", chunk=int(cfg.kda_chunk), head_dim=int(d), form=form)
+        with jax.named_scope("kda.out"):
+            o = RMSNorm(cfg, name="o_norm")(o)
+            o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]).astype(cfg.dtype)
+            return nn.DenseGeneral(
+                features=cfg.d_model,
+                axis=(-2, -1),
+                use_bias=False,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                kernel_init=_partitioned(nn.initializers.normal(stddev=0.02), ("heads", None, "embed"), cfg),
+                dot_general=None if self.stacked else merge_dot_general,
+                name="wo",
+            )(o)
+
+
 def attention_module(cfg: DecoderConfig):
     """The attention class an attention layer of this configuration takes."""
     return LatentAttention if cfg.kv_lora_rank else Attention
@@ -1549,10 +1731,14 @@ def attention_module(cfg: DecoderConfig):
 
 def layer_operator(cfg: DecoderConfig, kind: str, x, positions, segment_ids, stacked: bool = False):
     """The operator a layer of ``kind`` takes (``LAYER_KINDS``) on the normed
-    residual: ``attn`` under ``attn_norm`` or ``conv`` under ``conv_norm``.
-    Called inside the layer's ``nn.compact`` method."""
+    residual: ``attn`` under ``attn_norm``, ``conv`` under ``conv_norm`` or
+    ``kda`` under ``kda_norm``. Called inside the layer's ``nn.compact`` method."""
     if kind == "conv":
         return ShortConv(cfg, name="conv")(RMSNorm(cfg, name="conv_norm")(x), positions, segment_ids)
+    if kind == "kda":
+        return KDA(cfg, stacked, name="kda")(RMSNorm(cfg, name="kda_norm")(x), positions, segment_ids)
+    if kind == "latent_attention":
+        return LatentAttention(cfg, name="attn")(RMSNorm(cfg, name="attn_norm")(x), positions, segment_ids)
     of_kind = {} if kind == "full_attention" else {"kind": kind}
     if stacked and not cfg.kv_lora_rank:
         of_kind["stacked"] = True

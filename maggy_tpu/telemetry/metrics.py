@@ -73,6 +73,12 @@ GAUGES = frozenset(
         # ShortConv): taps zeroed at row and document starts over all taps of
         # the step's conv layers; above 0 the batch's packing reached the operator
         "conv.taps_masked_share",
+        # a model with Kimi-delta-attention layers (models/transformer.py KDA): the chunks of
+        # the delta rule in which a document starts over all chunks (above 0 the batch's
+        # packing reached the recurrence), and the mean of the log decays over tokens,
+        # channels and layers (between the layer's floor and 0: how long the layers remember)
+        "kda.chunks_cut_share",
+        "kda.log_decay_mean",
         # a model with selected-key attention layers (models/transformer.py
         # Attention with sparse_topk, ops/sparse_select.py)
         "sparse.selected_share",  # pairs the selections keep over the pairs visible inside documents
@@ -314,7 +320,12 @@ SCOPES = (
     "conv.in_proj",  # short convolution: the product that makes the two gates and the input
     "conv.mix",  # short convolution: the gates and the taps between the two products (no product)
     "conv.out_proj",  # short convolution: the product back to the model's width
-    "attn.gate",  # the per-head output gate: its projection, the sigmoid and the product (Attention, attn_gate)
+    "attn.gate",  # the per-head output gate: its projection, the sigmoid and the product (Attention and LatentAttention, attn_gate)
+    "kda.in_proj",  # Kimi delta attention: the six products of the normed input (q, k, v, the decay's f, beta, the gate)
+    "kda.conv",  # Kimi delta attention: the three streams' taps, SiLU, the unit norms of q and k
+    "kda.gate",  # Kimi delta attention: the log decays a channel and beta a head, float32
+    "kda.scan",  # Kimi delta attention: everything of ops/kda.py, forward, replay and backward (the chunks' products, the triangular inverse, the kernels kda_fwd and kda_bwd)
+    "kda.out",  # Kimi delta attention: the head's norm, the gate a head, the product back to the model's width
     "sparse.index",  # selected-key attention: the indexer's projections, the index scores, the mask from the thresholds
     "sparse.select",  # selected-key attention: each query's top-k threshold
     "sparse.index_loss",  # selected-key attention: the indexer's loss and its gradient, one pass
@@ -362,6 +373,10 @@ EVENTS = frozenset(
         # the keys a query keeps, where a selection masks the call, and for a
         # two-stream layer ``own_block`` (``kernel`` or ``xla``: ops/blockdiff.py)
         "attention.kernel",
+        # what a Kimi-delta-attention layer's recurrence runs as for a traced shape
+        # (models/transformer.py KDA): ``chunk``, ``head_dim`` and ``form`` (``pallas``: the
+        # kernels kda_fwd / kda_bwd; ``xla``: lax.scan over the same steps)
+        "kda.kernel",
         # whether a step's head and loss run over whole logits or in blocks of
         # the sequence, and the block (models/head.py step_targets)
         "loss.blocks",
@@ -445,6 +460,8 @@ GAUGE_UNITS = {
     "moe.combine_rows_share": "ratio",
     "moe.hidden_zero_share": "ratio",
     "conv.taps_masked_share": "ratio",
+    "kda.chunks_cut_share": "ratio",
+    "kda.log_decay_mean": "ratio",
     "sparse.selected_share": "ratio",
     "sparse.rows_off_k": "count",
     "sparse.index_loss": "ratio",  # nats, like a loss: no unit of its own in the vocabulary

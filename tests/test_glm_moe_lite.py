@@ -619,7 +619,7 @@ def test_fit_publishes_the_rows_visited_gauge_for_a_share_model_only(tiny, batch
 
 
 @pytest.mark.parametrize("bad", [
-    dict(v_head_dim=24), dict(q_lora_rank=0), dict(n_kv_heads=2), dict(decode=True),
+    dict(v_head_dim=0), dict(qk_rope_head_dim=7), dict(n_kv_heads=2), dict(decode=True),
     dict(experts_held=3), dict(expert_offset=4), dict(moe_d_ff=0), dict(n_dense_layers=3), dict(mtp_depth=2),
     dict(ablated=("attn",)),
 ])

@@ -38,6 +38,7 @@ REPORTS = {
     "laguna-s-2.1": EXPERT | {"window_pairs_share"},
     "smallthinker-21ba3b-instruct": EXPERT | {"window_pairs_share", "moe_hidden_zero_share"},
     "evabyte": {"eva_remote_share", "eva_chunks_cut_share"},
+    "ling-3.0-flash": EXPERT | {"conv_taps_masked_share", "kda_chunks_cut_share", "kda_log_decay_mean"},
 }
 FURTHER_HEADS = {"glm-4.7-flash", "evabyte"}  # the architectures that sow ``mtp_logits``
 

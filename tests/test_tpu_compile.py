@@ -675,3 +675,53 @@ def test_sdar_size_share_layer_gathers_no_row_a_token(one_chip, monkeypatch):
         if re.search(r"= \w+\[%d,%d\]\S* gather\(" % (t, d), line) and re.search(r"moe\.(combine|dispatch)", line)
     ]
     assert not by_token
+
+
+def test_the_kda_kernels_compile_at_the_ling_cells_shape(one_chip):
+    """``ops.kda.kda`` and its gradient at the ling-3.0-flash cell's row: one
+    packed row of 8,192, 32 heads of 128, bfloat16, chunks of 64: Mosaic takes
+    ``kda_fwd`` (a chunk's four products against the state in VMEM) and
+    ``kda_bwd``; the backward runs ``kda_fwd`` once more to make the chunk
+    states again."""
+    from maggy_tpu.ops import kda as ops_kda
+
+    b, s, h, d = 1, 8192, 32, 128
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, k, v, a, beta, segment_ids):
+        return jax.grad(
+            lambda *x: jnp.square(ops_kda.kda(*x, segment_ids, form="pallas", interpret=False).astype(jnp.float32)).sum(),
+            argnums=(0, 1, 2, 3, 4),
+        )(q, k, v, a, beta)
+
+    args = (sds((b, s, h, d)),) * 3 + (sds((b, s, h, d), jnp.float32), sds((b, s, h), jnp.float32), sds((b, s), jnp.int32))
+    calls = kernel_calls(jax.jit(step).lower(*args).compile().as_text(), "kda_fwd", "kda_bwd")
+    assert calls == {"kda_fwd": 2, "kda_bwd": 1}
+
+
+def test_the_padded_latent_call_compiles_the_flash_kernels_at_192_and_128(one_chip):
+    """A gated ``LatentAttention`` with a full-rank query at the ling-3.0-flash
+    cell's widths (32 heads, queries and keys of 128 + 64 beside values of
+    128) and row (1 x 8,192, packed), its loss and gradient: the heads go
+    through the flash kernels padded to 256, forward and the fused backward."""
+    cfg = DecoderConfig(
+        d_model=2560, n_heads=32, n_kv_heads=32, q_lora_rank=0, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, attn_gate=True, max_seq_len=8192, partition_params=False,
+        rope_theta=6e6, attention_fn=functools.partial(flash_attention, interpret=False),
+    )
+    layer = LatentAttention(cfg)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.key(0), x, ids, ids),
+    )
+    assert params["params"]["wq"]["kernel"].shape == (2560, 32, 192)
+
+    def step(params, x, positions, segment_ids):
+        return jax.value_and_grad(
+            lambda p, x: layer.apply(p, x, positions, segment_ids).astype(jnp.float32).sum(), argnums=(0, 1)
+        )(params, x)
+
+    calls = flash_calls(jax.jit(step).lower(params, x, ids, ids).compile().as_text())
+    assert calls == {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
